@@ -91,6 +91,17 @@ _NEW_PALETTE = [2, 4, 6, 8, 48, 64]
 _NEW_WEIGHTS = [0.22, 0.22, 0.22, 0.14, 0.10, 0.10]
 
 
+def _dev():
+    """The device every bench model is built on — the accelerator when
+    JAX has one.  A model built with no device is committed to the host
+    CPU, the engine follows its weights, and the report's ``"device"``
+    stamp (read off this same object) would then name a chip that did
+    none of the arithmetic."""
+    from singa_tpu import device
+
+    return device.create_tpu_device(0)
+
+
 def make_workload(n_requests=40, seed=0, n_positions=128):
     rng = np.random.RandomState(seed)
     reqs = []
@@ -223,7 +234,7 @@ def run_prefix_mix(max_slots):
                        n_layer=4, n_head=4, n_inner=384, dropout=0.0,
                        attn_impl="fused")
     m = GPT2LMHead(cfg_m)
-    m.compile([tensor.from_numpy(np.zeros((1, 16), np.int32))],
+    m.compile([tensor.from_numpy(np.zeros((1, 16), np.int32), _dev())],
               is_train=False, use_graph=False)
 
     cfg = PrefixCacheConfig(block_size=16, num_blocks=128)
@@ -650,7 +661,7 @@ def _train_spec_pair(seed=0, steps=60):
     of the PAIR, so the spec measurement needs models that actually
     agree; untrained weights would measure the mechanism at its floor.
     """
-    from singa_tpu import device, opt, tensor
+    from singa_tpu import opt, tensor
     from singa_tpu.models.gpt2 import GPT2Config, GPT2LMHead
 
     rng = np.random.RandomState(seed)
@@ -666,13 +677,14 @@ def _train_spec_pair(seed=0, steps=60):
     labels = np.roll(ids, -1, axis=1).astype(np.int32)
     models = []
     for i, cfg in enumerate((cfg_t, cfg_d)):
-        device.get_default_device().SetRandSeed(seed + i)
+        _dev().SetRandSeed(seed + i)
         m = GPT2LMHead(cfg)
         m.set_optimizer(opt.AdamW(lr=1e-3, weight_decay=0.01))
-        m.compile([tensor.from_numpy(ids)], is_train=True,
+        m.compile([tensor.from_numpy(ids, _dev())], is_train=True,
                   use_graph=True)
         for _ in range(steps):
-            m(tensor.from_numpy(ids), tensor.from_numpy(labels))
+            m(tensor.from_numpy(ids, _dev()),
+              tensor.from_numpy(labels, _dev()))
         m.eval()
         models.append(m)
     return models[0], models[1], ids
@@ -1042,7 +1054,7 @@ def run_ep(ep, tp=_EP_BENCH_TP, max_slots=8):
                      n_layer=4, n_head=4, n_inner=384, dropout=0.0,
                      attn_impl="fused", moe_every=2, moe_experts=4)
     m = GPT2LMHead(cfg)
-    m.compile([tensor.from_numpy(np.zeros((1, 16), np.int32))],
+    m.compile([tensor.from_numpy(np.zeros((1, 16), np.int32), _dev())],
               is_train=False, use_graph=False)
     workload = make_workload(n_positions=cfg.n_positions)
     pcfg = PagedConfig(block_size=16, num_blocks=48)
@@ -1249,7 +1261,7 @@ def run_longctx():
                      n_layer=2, n_head=4, n_inner=256, dropout=0.0,
                      attn_impl="fused")
     m = GPT2LMHead(cfg)
-    m.compile([tensor.from_numpy(np.zeros((1, 16), np.int32))],
+    m.compile([tensor.from_numpy(np.zeros((1, 16), np.int32), _dev())],
               is_train=False, use_graph=False)
     rng = np.random.RandomState(12)
     chats, longs = _longctx_mix(rng, cfg.vocab_size)
@@ -1363,7 +1375,7 @@ def run_longctx():
                       n_layer=2, n_head=4, n_inner=256, dropout=0.0,
                       attn_impl="fused", attn_window=64)
     wm = GPT2LMHead(wcfg)
-    wm.compile([tensor.from_numpy(np.zeros((1, 16), np.int32))],
+    wm.compile([tensor.from_numpy(np.zeros((1, 16), np.int32), _dev())],
                is_train=False, use_graph=False)
     wm.set_states(m.get_states())
 
@@ -1485,7 +1497,7 @@ def run_disagg():
                      n_layer=2, n_head=4, n_inner=256, dropout=0.0,
                      attn_impl="fused")
     m = GPT2LMHead(cfg)
-    m.compile([tensor.from_numpy(np.zeros((1, 16), np.int32))],
+    m.compile([tensor.from_numpy(np.zeros((1, 16), np.int32), _dev())],
               is_train=False, use_graph=False)
     rng = np.random.RandomState(17)
     work = _disagg_mix(rng, cfg.vocab_size)
@@ -1839,7 +1851,7 @@ def main():
                      n_layer=4, n_head=4, n_inner=384, dropout=0.0,
                      attn_impl="fused")
     m = GPT2LMHead(cfg)
-    m.compile([tensor.from_numpy(np.zeros((1, 16), np.int32))],
+    m.compile([tensor.from_numpy(np.zeros((1, 16), np.int32), _dev())],
               is_train=False, use_graph=False)
     workload = make_workload(n_positions=cfg.n_positions)
     useful = sum(w["n_new"] for w in workload)
@@ -1881,7 +1893,7 @@ def main():
 
     report = {
         "bench": "serve_continuous_batching",
-        "device": jax.devices()[0].device_kind,
+        "device": _dev().jax_device.device_kind,
         "config": {
             "model": {"n_embd": cfg.n_embd, "n_layer": cfg.n_layer,
                       "n_head": cfg.n_head, "vocab": cfg.vocab_size,

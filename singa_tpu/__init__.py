@@ -4,34 +4,25 @@ JAX/XLA/Pallas.  See SURVEY.md for the reference layer map this package
 rebuilds and README.md for the design stance.
 """
 
+import os as _os
+
 import jax as _jax
 
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 ships shard_map under jax.experimental only; every
-    # SPMD path here (model._build's DistOpt step, ring attention,
-    # tensor/pipeline parallel) and the virtual-mesh tests call the
-    # stable ``jax.shard_map`` spelling.  The experimental function
-    # accepts the same (f, mesh=, in_specs=, out_specs=) call shape,
-    # so alias it once at import — without this, 21 tier-1 tests fail
-    # on 0.4.x with AttributeError before any singa_tpu code runs.
-    # Deliberately a fill-only patch of the dependency: it installs
-    # ONLY when the attribute is absent (never shadows a real
-    # jax.shard_map), and both this package's call sites and the test
-    # suite use the stable spelling, so a package-private helper
-    # would leave the tests broken.
-    from jax.experimental.shard_map import shard_map as _shard_map
+# Persistent compile cache — the ONE place in the tree that sets it
+# (tests/test_bringup.py greps for a second).  When
+# JAX_COMPILATION_CACHE_DIR is set, JAX reads it and the package does
+# nothing.  Otherwise the cache lives at a FIXED path inside the
+# checkout: the directory is part of the cache key, so a tempfile name,
+# a pid or a timestamp would never hit.
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(_os.path.dirname(_os.path.dirname(
+            _os.path.abspath(__file__))), ".jax_cache"))
 
-    def _compat_shard_map(f, *a, **kw):
-        # the stable API renamed check_rep -> check_vma
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map(f, *a, **kw)
-
-    _jax.shard_map = _compat_shard_map
-
-from . import amp  # noqa: F401
-from . import config  # noqa: F401
-from .config import VERSION as __version__  # noqa: F401
+from . import amp  # noqa: F401,E402
+from . import config  # noqa: F401,E402
+from .config import VERSION as __version__  # noqa: F401,E402
 
 # Submodules are imported lazily by user code (`from singa_tpu import
 # tensor, device, autograd, layer, model, opt, sonnx`), mirroring how

@@ -141,7 +141,7 @@ class Device:
         SINGA v3.1 prints CUDA-event timings per scheduler node; the
         cost table is the static analogue and the parsed trace is the
         measured one (true parity with the reference's v3.1 measured
-        profiling — VERDICT weak #6).  Returns the measured-durations
+        profiling).  Returns the measured-durations
         dict (``{op name: {"count", "total_us"}}``; empty when no trace
         was captured) so tests and tooling can assert on it.
         """
@@ -149,14 +149,8 @@ class Device:
 
         for fn, cost in _model._compiled_cost_tables(self):
             print(f"== time profiling for compiled step {fn} ==")
-            # raw jax cost_analysis() is a one-element LIST of dicts
-            # on some versions — normalize exactly like _cost_args
-            # (latent crash whenever any compiled step existed)
-            c = (cost[0] if isinstance(cost, (list, tuple)) and cost
-                 else cost)
-            if isinstance(c, dict):
-                for k, v in sorted(c.items()):
-                    print(f"  {k}: {v}")
+            for k, v in sorted(cost.items()):
+                print(f"  {k}: {v}")
         measured = self.profiled_durations()
         if measured:
             print("== measured durations (jax.profiler trace, "

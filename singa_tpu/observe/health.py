@@ -430,7 +430,7 @@ def health_report(reg=None, engine_snapshots=(),
                            else _monitor.step_flops()),
             "peak_flops_per_s": (mfu_sample["peak_flops_per_s"]
                                  if mfu_sample
-                                 else _monitor.peak_flops()),
+                                 else _monitor._peak_or_nan()),
             "mfu_denominator": "bf16_peak",
         },
         "step_time": _step_time_sections(snap["histograms"]),

@@ -59,7 +59,8 @@ from .registry import registry as _registry
 
 __all__ = ["FlightRecorder", "flight_recorder", "dump_report",
            "install_crash_handler", "uninstall_crash_handler",
-           "peak_flops", "step_flops", "MfuMeter", "Watchdog",
+           "PEAK_BF16_FLOPS", "peak_flops", "step_flops", "MfuMeter",
+           "Watchdog",
            "heartbeat", "start", "stop", "active", "watchdog",
            "crash_dir"]
 
@@ -320,35 +321,47 @@ def uninstall_crash_handler():
 # MFU accounting
 # ---------------------------------------------------------------------------
 
-# bf16 peak matmul FLOP/s per chip, by device_kind substring (first
-# match wins — list "v5p"/"v5e" before the bare "v5").  The honest
-# limits of this table: peaks are the MXU's dense-bf16 datasheet
-# numbers, so fp32 workloads (executed as multi-pass bf16) and
-# int8/fp8 paths make the ratio conservative/optimistic respectively;
-# unknown kinds (CPU, future TPUs) get nan, never a guess.
-_PEAK_FLOPS = [
-    ("v5 lite", 197e12), ("v5litepod", 197e12), ("v5e", 197e12),
-    ("v5p", 459e12), ("v5", 459e12),
-    ("v4", 275e12), ("v6", 918e12),
-]
+# bf16 peak matmul FLOP/s per chip, keyed by the ``device_kind`` string
+# JAX reports — the ONE peak table in the tree (bench.py and
+# bench_dist.py read it).  Only kinds this repo has actually run on are
+# listed, each with the source of its figure; a kind that is not here is
+# an error on a measuring path, never a default or a substring guess.
+# The peak is the MXU's dense-bf16 datasheet number, so fp32 workloads
+# (executed as multi-pass bf16) make the ratio conservative.
+PEAK_BF16_FLOPS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip.
+    # ``device_kind`` as reported by jax 0.9.0 / libtpu 0.0.34 on
+    # 2026-09-26 (CHANGES.md, PR 21).
+    "TPU v5 lite": 197e12,
+}
 
 
 def peak_flops(device_kind=None) -> float:
     """Per-chip bf16 peak for a ``device_kind`` string (default: the
-    current backend's first device); nan when unknown — the MFU of an
-    unmodeled chip is unknowable, not zero."""
+    current backend's first device).  Raises ``KeyError`` for a kind
+    with no entry: the MFU of an unmodeled chip is unknowable, and a
+    benchmark that printed ``null`` for it would hide that."""
     if device_kind is None:
-        try:
-            import jax
+        import jax
 
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            return float("nan")
-    kind = str(device_kind).lower()
-    for sub, peak in _PEAK_FLOPS:
-        if sub in kind:
-            return peak
-    return float("nan")
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return PEAK_BF16_FLOPS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no bf16 peak on record for device_kind {device_kind!r}: "
+            f"add it to observe.monitor.PEAK_BF16_FLOPS with its "
+            f"source") from None
+
+
+def _peak_or_nan() -> float:
+    """The always-on monitor's view of the peak: it runs on every
+    backend (the CPU test mesh included) and must keep running, so an
+    unknown kind reads nan here — and only here."""
+    try:
+        return peak_flops()
+    except KeyError:
+        return float("nan")
 
 
 def step_flops() -> float:
@@ -417,7 +430,7 @@ class MfuMeter:
             nan = float("nan")
             return {"steps_per_s": nan, "step_flops": step_flops(),
                     "model_flops_per_s": nan,
-                    "peak_flops_per_s": peak_flops(), "mfu": nan}
+                    "peak_flops_per_s": _peak_or_nan(), "mfu": nan}
         self._last = (now, steps)
         rate = (steps - s0) / dt if dt > 0 else float("nan")
         f = step_flops()
@@ -429,7 +442,7 @@ class MfuMeter:
         # pair (the committed BENCH_SERVE health.train bug)
         model_fps = (f * rate if steps != s0
                      else float("nan"))  # nan propagates from f/rate
-        peak = peak_flops()
+        peak = _peak_or_nan()
         mfu = model_fps / peak  # nan when peak unknown (CPU)
         self._g_flops.set(model_fps)
         self._g_mfu.set(mfu)

@@ -65,6 +65,7 @@ import os
 import threading
 import time
 
+import jax
 import numpy as np
 
 from ...observe import federate as _federate
@@ -692,6 +693,18 @@ class DistFleet(ServeFleet):
         if spawn not in ("thread", "process"):
             raise ValueError(
                 f"spawn must be 'thread' or 'process', got {spawn!r}")
+        if spawn == "process" and jax.default_backend() == "tpu":
+            # a chip belongs to one process: this controller (which
+            # built the fleet-side model) holds it, and no worker is
+            # handed a chip of its own yet — each would hang or fail
+            # at JAX start-up, so say so here instead
+            raise RuntimeError(
+                "DistFleet(spawn='process') cannot run on a TPU "
+                "backend yet: the controller process holds the chip "
+                "and no worker is given a device of its own.  The "
+                "multi-process fleet is a CPU correctness harness "
+                "(run it with JAX_PLATFORMS=cpu); on chips, serve "
+                "replicas in ONE process with ServeFleet")
         for k in ("tp", "ep", "pp"):
             if kw.get(k) not in (None, False):
                 raise ValueError(

@@ -126,11 +126,14 @@ class ModelSpec:
         self.compile_len = int(compile_len)
 
     def build(self):
-        from ... import tensor
+        from ... import device, tensor
 
+        # on the accelerator when there is one: a model built with no
+        # device would be committed to — and served from — the host CPU
         m = self.factory(**self.factory_kw)
         m.compile([tensor.from_numpy(
-            np.zeros((1, self.compile_len), np.int32))],
+            np.zeros((1, self.compile_len), np.int32),
+            device.create_tpu_device(0))],
             is_train=False, use_graph=False)
         if self.states:
             m.set_states(self.states)

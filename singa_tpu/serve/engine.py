@@ -122,7 +122,7 @@ from .paged import (PagedConfig, PagedKVArena, _aot_call,
                     _paged_decode_kernel, _paged_decode_step,
                     _paged_spec_kernel, _paged_spec_step)
 from .prefix import (PrefixCache, PrefixCacheConfig, SessionHandle,
-                     _read_slot)
+                     _kv_zeros, _read_slot)
 from .request import (DeadlineExceededError, EngineFailedError,
                       GenerationRequest, GenerationResult, LoadShedError,
                       RequestHandle)
@@ -552,6 +552,25 @@ def _write_slot(kc_arena, vc_arena, kc_row, vc_row, slot):
 
     return (jax.tree.map(wr, kc_arena, kc_row),
             jax.tree.map(wr, vc_arena, vc_row))
+
+
+def _weights_sharding(params):
+    """The sharding an unsharded engine's state is allocated at: the
+    one device its weights are committed to, or — for a plan-sharded
+    model, whose weights span a mesh — replicated over that mesh
+    (what GSPMD assumes for an unplaced operand anyway)."""
+    leaves = jax.tree.leaves(params)
+    devs = set().union(*(a.devices() for a in leaves))
+    if len(devs) == 1:
+        return jax.sharding.SingleDeviceSharding(devs.pop())
+    for a in leaves:
+        if isinstance(a.sharding, jax.sharding.NamedSharding):
+            return jax.sharding.NamedSharding(
+                a.sharding.mesh, jax.sharding.PartitionSpec())
+    raise ValueError(
+        f"model weights are spread over {len(devs)} devices without a "
+        f"mesh sharding ({sorted(d.id for d in devs)}): build the "
+        f"model on one device, or serve it with tp=/ep=/pp=")
 
 
 class _LocalExec:
@@ -1139,6 +1158,14 @@ class InferenceEngine:
         #: and late-statics calls below go through this seam so the
         #: host-side step loop never knows which mesh it runs over
         self._shard = (self.tp_exec or self.ep_exec or self.pp_exec)
+        #: where an UNSHARDED engine's device state lives: with the
+        #: weights.  Arenas, pools and the key table are allocated at
+        #: this sharding (never on the process default device), so a
+        #: model built on chip 2 serves from chip 2 and a model left on
+        #: the host CPU serves — visibly — from the host CPU.  Sharded
+        #: executors own placement themselves (place_cache/_replicated)
+        self._state_sh = (None if self._shard is not None
+                          else _weights_sharding(self._params))
         # the step-anatomy shim wraps the seam permanently: one
         # module-flag read per dispatch when the profiler is off
         # (observe/stepprof.py), dispatch/ready timestamps when on
@@ -1155,11 +1182,8 @@ class InferenceEngine:
         cdt = self._params["wte"].dtype
 
         def _arena(L_, H_, D_, shard=True):
-            if self._quant:
-                z = (jnp.zeros((L_, S, H_, W, D_), jnp.int8),
-                     jnp.zeros((L_, S, H_, W), jnp.float32))
-            else:
-                z = jnp.zeros((L_, S, H_, W, D_), cdt)
+            z = _kv_zeros((L_, S, H_, W), D_, cdt, self._quant,
+                          self._state_sh)
             if self._shard is None:
                 return z
             # target arenas shard on the H_kv axis; the DRAFT arena
@@ -1201,7 +1225,8 @@ class InferenceEngine:
                 paged, L, H_kv, D, cdt, row_width=W,
                 quant=self._quant,
                 engine_label=self.stats.engine_label,
-                reg=self.stats.registry, tp=self._shard)
+                reg=self.stats.registry, tp=self._shard,
+                sharding=self._state_sh)
             self.stats.paged_source = self.paged_arena.snapshot
             self._kc = self._vc = None
         else:
@@ -1231,7 +1256,8 @@ class InferenceEngine:
         self._toks = np.zeros(S, np.int32)  # last emitted token
         self._pos = np.zeros(S, np.int32)
         self._temps = np.zeros(S, np.float32)
-        self._keys = jnp.zeros((S, 2), jnp.uint32)
+        self._keys = jnp.zeros((S, 2), jnp.uint32,
+                               device=self._state_sh)
         if self._shard is not None:
             # committed replicated so the sharded twins never pay a
             # per-dispatch broadcast for the key table
@@ -1299,7 +1325,8 @@ class InferenceEngine:
                 prefix_cache, L, H_kv, D, cdt,
                 engine_label=self.stats.engine_label,
                 reg=self.stats.registry, quant=self._quant,
-                arena=self.paged_arena, tp=self._shard)
+                arena=self.paged_arena, tp=self._shard,
+                sharding=self._state_sh)
             self.prefix_cache.attach_row_geometry(W)
             if self.paged_arena is not None:
                 # cached-but-unreferenced blocks are soft free space:
@@ -3743,12 +3770,13 @@ class InferenceEngine:
                     # PROMPT's length, so a burst of short admissions
                     # stops stalling the decode lanes behind
                     # O(max_len) pad work (the paged bench's TPOT
-                    # tax).  Prefill rows are bitwise invariant to
-                    # the padded width (every op is row-independent
-                    # over positions; pinned by
-                    # tests/test_paged.py::test_prefill_width_bitwise
-                    # _invariance), so streams are unchanged.  One
-                    # executable per distinct width, bounded by
+                    # tax).  Pad lanes cannot reach live rows (every
+                    # op is row-independent over positions), so the
+                    # first token is the same at any width and the
+                    # rows agree to f32 reduction order — XLA may tile
+                    # a wider GEMM differently (pinned by tests/
+                    # test_paged.py::test_prefill_width_invariance).
+                    # One executable per distinct width, bounded by
                     # max_len // block_size — the warmup pass covers
                     # the workload's widths, keeping the recompile
                     # pin intact

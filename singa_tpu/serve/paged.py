@@ -88,11 +88,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..model import _cost_args
 from ..observe import monitor as _monitor
 from ..observe import trace as _trace
 from ..observe.registry import registry as _default_registry
 from ..resilience import faults as _faults
 from ..utils.logging import get_channel
+from .prefix import _kv_zeros
 
 __all__ = ["PagedConfig", "PagedKVArena"]
 
@@ -542,17 +544,15 @@ def _paged_spec_kernel(t_params, d_params, pool_k, pool_v, dkc, dvc,
     return out, a_draft, pool_k, pool_v, dkc, dvc, keys2
 
 
-# -- AOT compile capture (VERDICT weak #6) -----------------------------------
+# -- AOT compile capture -----------------------------------------------------
 # Serve-side executables used to compile invisibly: no span, no cost
 # table, nothing in crash bundles.  The paged steps dispatch through
 # this cache instead — each new (function, shapes, statics) signature
 # is lowered + compiled ONCE under a serve/compile span carrying the
 # XLA cost-analysis scalars, and the tables feed monitor crash bundles
-# through the registered cost source below.  Falls back to the plain
-# jit dispatch if AOT lowering is unavailable.
+# through the registered cost source below.
 
-_MISS = object()
-_aot_cache = {}          # (name, leaf shapes/dtypes, statics) -> Compiled|None
+_aot_cache = {}          # (name, leaf shapes/dtypes, statics) -> Compiled
 _aot_costs = []          # [{"key": ..., "cost": {...}}] for crash bundles
 
 
@@ -563,20 +563,13 @@ def _paged_cost_tables():
 _monitor.register_cost_source(_paged_cost_tables)
 
 
-def _cost_scalars(cost):
-    try:
-        from ..model import _cost_args
-        return _cost_args(cost)
-    except Exception:
-        return {}
-
-
 def _aot_call(name, fn, *args, _memo=None, _token=None, **statics):
     """Dispatch ``fn(*args, **statics)`` through the AOT cache.  The
     compiled executable takes only the traced args (statics were
-    consumed at lowering); the cache key mirrors jit's (leaf shapes +
-    dtypes + statics), so warm/timed engines, supervisor rebuilds, and
-    fleet replicas with identical geometry all share one compile —
+    consumed at lowering); the cache key mirrors jit's (placement +
+    leaf shapes + dtypes + statics), so warm/timed engines, supervisor
+    rebuilds, and same-device fleet replicas with identical geometry
+    all share one compile —
     the same restart-is-a-cache-hit contract the jitted paths keep.
     ``_memo``/``_token``: optional caller-owned signature memo — an
     engine's dispatch shapes are FIXED per (step, batch width), so
@@ -585,28 +578,26 @@ def _aot_call(name, fn, *args, _memo=None, _token=None, **statics):
     (a measurable host tax on the per-step path)."""
     key = _memo.get(_token) if _memo is not None else None
     if key is None:
-        key = (name,
-               tuple((tuple(a.shape), str(a.dtype))
-                     for a in jax.tree.leaves(args)),
+        leaves = jax.tree.leaves(args)
+        # the weights' placement is part of the key: a compiled
+        # executable is bound to its devices, so a replica on another
+        # chip compiles its own instead of being handed this one
+        key = (name, leaves[0].sharding,
+               tuple((tuple(a.shape), str(a.dtype)) for a in leaves),
                tuple(sorted(statics.items())))
         if _memo is not None:
             _memo[_token] = key
-    entry = _aot_cache.get(key, _MISS)
-    if entry is _MISS:
+    entry = _aot_cache.get(key)
+    if entry is None:
         with _trace.span("serve/compile", cat="serve", fn=name) as sp:
-            try:
-                compiled = fn.lower(*args, **statics).compile()
-                scalars = _cost_scalars(compiled.cost_analysis())
-                _aot_costs.append(
-                    {"key": f"serve.paged/{name}", "cost": scalars})
-                sp.set(**scalars)
-                entry = compiled
-            except Exception:
-                entry = None  # no AOT on this backend: plain jit path
+            # a lowering/compile failure surfaces here as itself
+            entry = fn.lower(*args, **statics).compile()
+            scalars = _cost_args(entry.cost_analysis())
+            _aot_costs.append(
+                {"key": f"serve.paged/{name}", "cost": scalars})
+            sp.set(**scalars)
         _aot_cache[key] = entry
-    if entry is not None:
-        return entry(*args)
-    return fn(*args, **statics)
+    return entry(*args)
 
 
 def _compile_cache_size():
@@ -630,7 +621,7 @@ class PagedKVArena:
 
     def __init__(self, config, n_layer, n_kv_head, head_dim, dtype,
                  row_width, quant=False, engine_label="0", reg=None,
-                 tp=None):
+                 tp=None, sharding=None):
         self.config = config
         B, N = config.block_size, config.num_blocks
         self.block_size = B
@@ -650,19 +641,15 @@ class PagedKVArena:
         # block ids are the same on every shard
         self._tp = tp
 
-        def pool(shape_tail):
-            if quant:
-                z = (jnp.zeros((n_layer, N + 1, n_kv_head, B)
-                               + shape_tail, jnp.int8),
-                     jnp.zeros((n_layer, N + 1, n_kv_head, B),
-                               jnp.float32))
-            else:
-                z = jnp.zeros((n_layer, N + 1, n_kv_head, B)
-                              + shape_tail, dtype)
+        def pool():
+            # an unsharded engine hands its weights' ``sharding`` so
+            # the pool is born beside them; a tp executor lays it out
+            z = _kv_zeros((n_layer, N + 1, n_kv_head, B), head_dim,
+                          dtype, quant, sharding)
             return z if tp is None else tp.place_cache(z)
 
-        self.pool_k = pool((head_dim,))
-        self.pool_v = pool((head_dim,))
+        self.pool_k = pool()
+        self.pool_v = pool()
         self._free = list(range(N))
         # LIVE-slot reference counts (the fork round): a block a forked
         # branch shares with its siblings carries an entry here (count

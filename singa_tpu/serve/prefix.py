@@ -359,6 +359,18 @@ class FleetPrefixIndex:
                 "indexed_blocks": self._count}
 
 
+def _kv_zeros(lead, head_dim, dtype, quant, sharding=None):
+    """A zeroed KV pytree with leading dims ``lead`` — a dense
+    ``lead + (head_dim,)`` array, or the int8 ``(values, scales)``
+    pair — allocated DIRECTLY at ``sharding`` (committed; no transient
+    copy on the default device).  ``None`` leaves it on the default
+    device for a sharded executor's ``place_cache`` to lay out."""
+    if quant:
+        return (jnp.zeros(lead + (head_dim,), jnp.int8, device=sharding),
+                jnp.zeros(lead, jnp.float32, device=sharding))
+    return jnp.zeros(lead + (head_dim,), dtype, device=sharding)
+
+
 class PrefixCache:
     """Block-granular radix tree over a pooled KV arena (module
     docstring).  Owned by one engine; the engine drives every device
@@ -367,7 +379,7 @@ class PrefixCache:
 
     def __init__(self, config, n_layer, n_kv_head, head_dim, dtype,
                  engine_label="0", reg=None, quant=False, arena=None,
-                 tp=None):
+                 tp=None, sharding=None):
         self.config = config
         B, N = config.block_size, config.num_blocks
         self.block_size = B
@@ -388,24 +400,15 @@ class PrefixCache:
             self.num_blocks = arena.num_blocks
             self._pool_k = self._pool_v = None
         else:
-            if quant:
-                # (values, scales) pytree pool — same layout as the
-                # int8 engine arena, so the generic copies round-trip
-                self._pool_k = (
-                    jnp.zeros((n_layer, N + 1, n_kv_head, B, head_dim),
-                              jnp.int8),
-                    jnp.zeros((n_layer, N + 1, n_kv_head, B),
-                              jnp.float32))
-                self._pool_v = (
-                    jnp.zeros((n_layer, N + 1, n_kv_head, B, head_dim),
-                              jnp.int8),
-                    jnp.zeros((n_layer, N + 1, n_kv_head, B),
-                              jnp.float32))
-            else:
-                # +1: trash block scatter padding lands in (never read)
-                self._pool_k = jnp.zeros((n_layer, N + 1, n_kv_head, B,
-                                          head_dim), dtype)
-                self._pool_v = jnp.zeros_like(self._pool_k)
+            # +1: trash block scatter padding lands in (never read);
+            # int8 pools are the engine arena's (values, scales) layout.
+            # An unsharded engine hands its weights' ``sharding`` so
+            # the pool is born beside them; a tp executor lays it out
+            lead = (n_layer, N + 1, n_kv_head, B)
+            self._pool_k = _kv_zeros(lead, head_dim, dtype, quant,
+                                     sharding)
+            self._pool_v = _kv_zeros(lead, head_dim, dtype, quant,
+                                     sharding)
             if tp is not None:
                 self._pool_k = tp.place_cache(self._pool_k)
                 self._pool_v = tp.place_cache(self._pool_v)

@@ -168,11 +168,7 @@ class Tensor:
         return _wrap(jnp.squeeze(self.data, axis), self.device)
 
     def reset_like(self, t: "Tensor"):
-        z = jnp.zeros(t.shape, dtype=t.data.dtype)
-        if not _is_tracing(z):
-            z = jax.device_put(z, self.device.jax_device)
-        self.data = z
-        return self
+        return self._settle(jnp.zeros(t.shape, dtype=t.data.dtype))
 
     def as_type(self, dtype):
         return _wrap(self.data.astype(_asdtype(dtype)), self.device)
@@ -195,42 +191,49 @@ class Tensor:
         return self.to_device(device_module.CppCPU())
 
     # -- fills / random ----------------------------------------------------
-    def set_value(self, x, inplace=True):
-        self.data = jnp.full(self.shape, x, dtype=self.data.dtype)
+    def _settle(self, arr):
+        """Rebind ``self.data`` to ``arr`` ON this tensor's device.  A
+        fill computes wherever its operands are (an unplaced PRNG key
+        or constant lands on the process default device); without this
+        a ``gaussian()`` quietly moved a parameter off the device it
+        was created on, and everything that follows the weights (the
+        serve engine) followed it there."""
+        if not _is_tracing(arr):
+            arr = jax.device_put(arr, self.device.jax_device)
+        self.data = arr
         return self
+
+    def set_value(self, x, inplace=True):
+        return self._settle(jnp.full(self.shape, x, dtype=self.data.dtype))
 
     def SetValue(self, x):  # C++-style alias used by reference scripts
         return self.set_value(x)
 
     def gaussian(self, mean=0.0, std=1.0):
         key = self.device.rng_key()
-        self.data = mean + std * jax.random.normal(key, self.shape, dtype=jnp.float32)
-        self.data = self.data.astype(_asdtype(self.dtype))
-        return self
+        return self._settle(
+            mean + std * jax.random.normal(key, self.shape, dtype=jnp.float32))
 
     def uniform(self, low=0.0, high=1.0):
         key = self.device.rng_key()
-        self.data = jax.random.uniform(
+        return self._settle(jax.random.uniform(
             key, self.shape, dtype=jnp.float32, minval=low, maxval=high
-        ).astype(_asdtype(self.dtype))
-        return self
+        ).astype(_asdtype(self.dtype)))
 
     def bernoulli(self, p):
         key = self.device.rng_key()
-        self.data = jax.random.bernoulli(key, p, self.shape).astype(
+        return self._settle(jax.random.bernoulli(key, p, self.shape).astype(
             _asdtype(self.dtype)
-        )
-        return self
+        ))
 
     # -- copies ------------------------------------------------------------
     def copy_from_numpy(self, np_array, offset=0):
         assert np_array.size == self.size(), "array size mismatch"
-        self.data = jnp.asarray(
+        return self._settle(jnp.asarray(
             np.ascontiguousarray(np_array, dtype=np.dtype(self.data.dtype)).reshape(
                 self.shape
             )
-        )
-        return self
+        ))
 
     def copy_data(self, t: "Tensor"):
         """Copy t's buffer into self (shape must match)."""

@@ -510,14 +510,25 @@ def test_kernel_logits_allclose_gather_oracle(model):
         rtol=2e-5, atol=2e-5)
 
 
-def test_prefill_width_bitwise_invariance(model):
+def test_prefill_width_invariance(model):
     """The paged cold-admission fast path prefills at the smallest
     block-multiple width covering the prompt instead of max_len
-    (engine._admit).  The claim it leans on, pinned here empirically:
-    prefill rows (K/V at positions < plen AND the sampled first
-    token) are BITWISE invariant to the padded width — every op in
-    the prefill stack is row-independent over the position axis, so
-    right-pad lanes cannot reach live rows."""
+    (engine._admit).  What it leans on: right-pad lanes cannot reach
+    live rows (every op in the prefill stack is row-independent over
+    the position axis), so the sampled first token is the SAME at any
+    padded width and the K/V at positions < plen agree to float32
+    reduction order.
+
+    Not bitwise: XLA is free to tile the wider GEMM differently, which
+    reorders the f32 accumulation — jax 0.9.0's CPU backend differs by
+    1.2e-6 here (max |d|, values O(1)) where an earlier build happened
+    to agree exactly.  That is a fact about a backend, not the program,
+    and nothing the engine does needs more: narrow and full-width rows
+    are interchangeable to the same 2e-5 the chunk-vs-decode pin above
+    allows, and the warm==cold TOKEN-stream identity the prefix cache
+    promises is held by its own stream-parity tests (test_prefix.py,
+    the warm/cold parity tests in this file), which is where a
+    width-dependent flip would show."""
     import jax
     import jax.numpy as jnp
     from singa_tpu.serve.engine import _prefill_one
@@ -542,10 +553,10 @@ def test_prefill_width_bitwise_invariance(model):
         outs[W] = (int(tok0), np.asarray(kc)[:, :, :, :plen],
                    np.asarray(vc)[:, :, :, :plen])
     assert outs[32][0] == outs[cfg.n_positions][0]
-    np.testing.assert_array_equal(outs[32][1],
-                                  outs[cfg.n_positions][1])
-    np.testing.assert_array_equal(outs[32][2],
-                                  outs[cfg.n_positions][2])
+    for lane in (1, 2):
+        np.testing.assert_allclose(outs[32][lane],
+                                   outs[cfg.n_positions][lane],
+                                   rtol=2e-5, atol=2e-5)
 
 
 def test_prefill_batch_bitwise_equals_single(model):

@@ -1,26 +1,26 @@
-"""Real-chip Mosaic smoke for the flash-attention kernel paths.
+"""On-chip Mosaic sweep for the flash-attention kernel paths.
 
-The pytest suite runs on the forced CPU backend (tests/conftest.py)
-where Pallas executes in interpret mode — so a kernel that passes CI
-can still fail Mosaic compilation on hardware (this environment has
-produced Mosaic-only failures before: oversized tiles surface as
-HTTP 500 tpu_compile_helper errors).  This script exercises every
-kernel entry the wrapper can select ON THE REAL CHIP and records the
-result in TPU_SMOKE.json (round-3 verdict, weak #5 / item 1c):
+The pytest suite runs on the CPU backend (tests/conftest.py), where
+Pallas executes in interpret mode — so a kernel that passes the tests
+can still be refused by Mosaic on hardware (oversized tiles, an
+unsupported relayout).  ``chip_smoke.py`` proves the GPT-2 shapes
+compile; this script sweeps the other kernel entries the wrapper can
+select, ON THE CHIP, by hand through the chip tool:
 
   1. pad-to-block wrapper: unaligned S=1537, causal, fwd + grad
   2. general (B,1,S,S) mask streamed as kernel tiles, fwd + grad
   3. padded head dim D=192 (shrunken block budget)
-  4. the flash kernel INSIDE shard_map on a real 1-device ('seq') mesh
+  4. the flash kernel INSIDE shard_map on a 1-device ('seq') mesh
      (manual-mode Mosaic, the ring-attention composition), fwd + grad
   5. per-head (1,H,S,S) ALiBi-layout mask (modulo index map)
 
-    python tpu_smoke.py            # writes TPU_SMOKE.json
+    python tpu_smoke.py    # prints one JSON line; exit 1 if any failed
+
+It writes nothing into the checkout.
 """
 
 import json
 import math
-import os
 import time
 
 import numpy as np
@@ -57,9 +57,11 @@ def main():
     from singa_tpu.parallel.ring_attention import ring_self_attention
 
     backend = jax.default_backend()
-    assert backend != "cpu", (
-        "tpu_smoke must run on the TPU backend (CPU runs interpret "
-        "mode, which is what this script exists to go beyond)")
+    if backend != "tpu":
+        raise SystemExit(
+            f"tpu_smoke needs the TPU backend, found {backend!r} (the "
+            f"CPU runs interpret mode, which is what this script "
+            f"exists to go beyond)")
 
     rng = np.random.RandomState(0)
 
@@ -151,14 +153,7 @@ def main():
         "device_kind": jax.devices()[0].device_kind,
         "ok": all(c["ok"] for c in checks),
         "checks": checks,
-        "note": ("Mosaic-compiled kernel paths validated on the real "
-                 "chip; the pytest suite covers the same paths in "
-                 "interpret mode on the CPU mesh"),
     }
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "TPU_SMOKE.json")
-    with open(path, "w") as f:
-        json.dump(out, f, indent=1)
     print(json.dumps(out))
     raise SystemExit(0 if out["ok"] else 1)
 
