@@ -430,7 +430,7 @@ def main():
         description="singa_tpu training benchmark harness")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="trace the whole bench run (compile spans with "
-                         "XLA cost tables, train/step dispatches, "
+                         "XLA cost tables, train.step / train.dispatch, "
                          "opt/update traces) and write a Chrome "
                          "trace-event JSON there")
     ap.add_argument("--health-out", default=None, metavar="PATH",

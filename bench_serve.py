@@ -57,7 +57,7 @@ without the memory win).  The ``registry`` key embeds the
 process-wide ``singa_tpu.observe`` metrics snapshot; ``--trace-out
 PATH`` additionally traces the timed engine run and writes a Chrome
 trace-event JSON there (open in https://ui.perfetto.dev — expect
-serve/prefill, serve/decode_step and serve/retire rows).  Tracing is
+serve.step, serve.decode, serve.prefill and serve/retire rows).  Tracing is
 off unless the flag is given, so the default throughput numbers are
 untouched.  ``--request-log PATH`` enables the per-request lifecycle
 ledger (``observe.requests``) for every timed run, writes one

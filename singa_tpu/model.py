@@ -606,6 +606,16 @@ class _GraphRunner:
                 {k: sl(v) for k, v in kwargs.items()})
 
     def run(self, args, kwargs, n_steps=None, repeat=False):
+        # train.step: everything the host does for one call — the state
+        # probe, placing state and inputs, the compile on a new
+        # signature, the dispatch, writing the new state back and the
+        # dist_outputs reduction.  The device runs on after it closes
+        # (train.dispatch, inside, says when XLA took the work).
+        with _trace.phase("train.step", cat="train",
+                          steps=n_steps or 1):
+            return self._run(args, kwargs, n_steps, repeat)
+
+    def _run(self, args, kwargs, n_steps, repeat):
         model = self.model
         # multi-step: key/probe/build on the per-step slice; the leading
         # K axis lives only in the scan's xs.  repeat mode feeds the
@@ -754,9 +764,9 @@ class _GraphRunner:
                 self._m_miss.inc()
                 _trace.event("graph/cache_miss", cat="train",
                              key=_key_digest(key))
-                with _trace.span("graph/compile", cat="train",
-                                 key=_key_digest(key),
-                                 steps=n_steps or 1) as sp:
+                with _trace.phase("graph.compile", cat="train",
+                                  key=_key_digest(key),
+                                  steps=n_steps or 1) as sp:
                     fn = self._build(key_args, key_kwargs, names,
                                      n_steps=n_steps, repeat=repeat)
                     # AOT: a Mosaic or HBM failure surfaces here, once,
@@ -781,10 +791,9 @@ class _GraphRunner:
             # and the per-process straggler histogram
             _mon = _monitor.active()
             _hb_t0 = _time.perf_counter() if _mon else 0.0
-            with _trace.span("train/step", cat="train",
-                             steps=n_steps or 1):
+            with _trace.phase("train.dispatch", cat="train"):
                 # host-side dispatch time: device execution is async, so
-                # the span closes when XLA accepts the work, not when the
+                # this closes when XLA accepts the work, not when the
                 # step finishes — the caller's readback sync (loss fetch)
                 # carries the device tail
                 new_state, out_tree = fn(state_arrays, in_arrays)

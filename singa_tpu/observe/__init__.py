@@ -1,7 +1,10 @@
 """singa_tpu.observe — unified tracing + metrics for train, serve, comms.
 
 The telemetry layer of the ROADMAP north star: one event model
-(``trace.py`` spans/instants), one process-wide metrics surface
+(``trace.py`` spans/instants, and ``phase()`` — the step-level sites of
+the serve engine and the graph runner, always written as
+``jax.profiler.TraceAnnotation`` so they sit on the device trace's
+clock), one process-wide metrics surface
 (``registry.py`` Counter/Gauge/Histogram, adopting the
 ``utils.metrics`` percentile machinery), and three exporters
 (``export.py``: JSONL, Chrome trace-event JSON for Perfetto,
@@ -47,8 +50,8 @@ from . import trace  # noqa: F401
 from .registry import (Counter, Gauge, Histogram,  # noqa: F401
                        MetricsRegistry, registry)
 from .trace import (clear, disable, drain, dropped,  # noqa: F401
-                    enable, event, events, is_enabled, set_max_events,
-                    span, traced)
+                    enable, event, events, is_enabled, phase,
+                    set_max_events, span, traced)
 from . import stepprof  # noqa: F401  (step-anatomy profiler:
 #                                      host/device attribution)
 from .stepprof import StepProfiler  # noqa: F401

@@ -1,39 +1,38 @@
-"""Step-anatomy profiler: host/device attribution for every
-``engine.step()``.
+"""Step-anatomy profiler: a host-clock decomposition of every
+``engine.step()``, and a host-fence ESTIMATE of the device's share.
 
-ROADMAP item 5 ("overlap host scheduling with device compute") needs a
-measuring stick before the surgery: the RequestLedger attributes
-per-REQUEST phases (queue/prefill/decode/stall), but nothing measures
-where one STEP's wall time goes — how much is host bookkeeping between
-dispatches (the device-idle *bubble* the overlap work must close) and
-how much is the device actually executing.  This module is that
-microscope:
+The RequestLedger attributes per-REQUEST phases (queue/prefill/decode/
+stall); this module says where one STEP's wall time goes on the host's
+clock.  It has no fence sites of its own: ``observe.trace.phase()`` —
+the one call at each step-level site of ``serve/engine.py`` — feeds it
+through a hook that :func:`enable` registers, so the segments below are
+the program's ``singa/serve.*`` phases under their short names:
 
-* **host segments** — clock fences at the existing seams in
-  ``serve/engine.py`` decompose the step wall into named host
-  segments: ``schedule`` (the scheduling pass), ``admit`` (one
-  admission's host work), ``prefix_lookup`` (radix-cache probes),
-  ``dispatch`` (building inputs + launching an executable),
-  ``sync`` (host-side copies after the device is done), ``emit``
-  (token emission + callbacks), ``retire`` (slot teardown),
-  ``ledger`` (RequestLedger hooks).  Fences nest; accounting is
-  EXCLUSIVE (a retire inside the emit loop is retire time, never
-  double-counted as emit), and unfenced host time lands in
-  ``other`` — so the segments always sum to the wall exactly, the
-  RequestLedger's seal-time idiom.
-* **device time** — one hook at the executor seam (``engine._x``:
-  ``_LocalExec``, ``TPExecutor``, and the ep/pp executors all route
-  through it, so one wrapper covers every parallelism mode) records
-  dispatch→``block_until_ready`` on each dispatch's output.  Async
-  dispatch is therefore credited, not hidden: host work done while
-  the device runs overlaps the device window instead of extending
-  it.  ``bubble_frac = (wall - device) / wall`` is the fraction of
-  the step during which the device sat idle — the item-5 metric.
-* **zero cost when off** — every fence site is ONE module-flag read
-  (``if stepprof._active:``), the trace.py discipline: no allocation,
-  no clock call, nothing enters jitted code (the hook only adds a
-  ``block_until_ready`` on already-materialized outputs, so the
-  recompile pin holds with the profiler ON).
+* **host segments** — ``schedule`` (the scheduling pass), ``admit``
+  (one admission's host work), ``prefix_lookup`` (radix-cache probes),
+  ``dispatch`` (building inputs + launching an executable, at the
+  executor seam), ``sync`` (the host blocked on the device's result),
+  ``emit`` (token emission, callbacks, retire, ledger hooks).  Phases
+  nest; accounting is EXCLUSIVE (a prefix lookup inside an admission
+  is prefix_lookup time, never double-counted as admit), and time
+  under no segment lands in ``other`` — so the segments always sum to
+  the wall exactly, the RequestLedger's seal-time idiom.
+* **the ``device`` column is a host-fence estimate, not device time** —
+  while the profiler is on, :func:`fence_device` at the executor seam
+  (``engine._ProfExec``: ``_LocalExec``, ``TPExecutor`` and the ep/pp
+  executors all route through it) blocks the host on each dispatch's
+  output and books dispatch-return → ``block_until_ready`` as
+  ``device``.  That serialises the dispatches it times, runs on the
+  host's clock, and includes queueing behind earlier work; ``bubble_frac
+  = (wall - device) / wall`` inherits all three.  The device's real busy
+  and idle time comes from a ``jax.profiler`` trace, where the same
+  phases sit as ``singa/`` spans under the device's operations
+  (docs/OBSERVABILITY.md "Span API").
+* **zero cost when off** — ``phase()`` reads one hook slot; the seam's
+  fence is ONE module-flag read (``if stepprof._active:``).  No clock
+  call, nothing enters jitted code (the fence only adds a
+  ``block_until_ready`` on already-dispatched outputs, so the recompile
+  pin holds with the profiler ON).
 
 Publication surfaces:
 
@@ -46,13 +45,13 @@ Publication surfaces:
   (:func:`forget_engine`) removes its series — the retire-unregisters
   contract.
 * trace: one ``cat="step.host"`` COMPLETE record per step (segment
-  fractions in args) and one ``cat="step.device"`` record per device
+  fractions in args) and one ``cat="step.device"`` record per fenced
   window, emitted through ``trace._emit`` whenever tracing or the
   flight-recorder ring is live — so worker step anatomy rides the
   existing cross-host trace federation (observe/federate.py) and
   shows up as two lanes per host pid in the merged Chrome trace.
 * ring: the last N full step records (per-piece host intervals +
-  device windows) for the dual-lane local Chrome trace
+  fenced windows) for the dual-lane local Chrome trace
   (``export.chrome_trace(steps=...)``).
 * health: :func:`section` → ``health_report()["serve"]
   ["step_anatomy"]``; :func:`why_slow_summary` rides the why_slow
@@ -80,10 +79,19 @@ __all__ = ["StepProfiler", "enable", "disable", "active", "profiler",
            "FRACTION_BUCKETS"]
 
 #: segment taxonomy (docs/OBSERVABILITY.md "Step anatomy"): the named
-#: host segments, the device-execution windows, and the unfenced
-#: remainder.  Fractions over these sum to 1 per step by construction.
+#: host segments, the host-fenced device windows, and the remainder
+#: under no segment.  Fractions over these sum to 1 per step by
+#: construction.
 SEGMENTS = ("schedule", "admit", "prefix_lookup", "dispatch", "device",
-            "sync", "emit", "retire", "ledger", "other")
+            "sync", "emit", "other")
+
+# phase name -> segment.  ``serve.dispatch.<method>`` is ``dispatch``;
+# a phase with no entry (serve.grow, serve.decode, serve.prefill) opens
+# no segment: its time stays with the segment around it, or ``other``.
+_SEGMENT_OF = {"serve.schedule": "schedule", "serve.admit": "admit",
+               "serve.prefix_lookup": "prefix_lookup",
+               "serve.sync": "sync", "serve.emit": "emit"}
+_STEP = object()   # the exit token of a phase that opened a step
 
 #: dedicated step-latency ladder: 100µs–5s.  registry.DEFAULT_BUCKETS
 #: starts at 1ms and tops at 2min — the request ladder, far too coarse
@@ -123,6 +131,7 @@ def enable(clock=None, ring=512, reg=None) -> "StepProfiler":
     global _active, _prof
     _prof = StepProfiler(clock=clock, ring=ring, reg=reg)
     _active = True
+    _trace._set_phase_hook((_phase_enter, _phase_exit))
     return _prof
 
 
@@ -134,6 +143,7 @@ def disable(unregister=True):
     global _active, _prof
     p, _prof = _prof, None
     _active = False
+    _trace._set_phase_hook(None)
     _tls.cur = None
     if p is not None and unregister:
         p.unregister()
@@ -157,75 +167,66 @@ def forget_engine(label):
         _prof.forget_engine(label)
 
 
-# -- fences (serve/engine.py calls these, each behind one _active
-#    read; all are safe no-ops when no step is open on this thread) --
+# -- the hook ``trace.phase()`` calls while the profiler is on ---------
 
-def begin(engine, step=None):
-    p = _prof
-    if p is not None:
-        p.step_begin(engine, step=step)
+def _phase_enter(name, args):
+    """``serve.step`` opens a step record, and so does
+    ``serve.prefix_build`` — an out-of-``step()`` work quantum on a
+    disaggregated prefill specialist, whose engine never runs the
+    decode step loop but whose dispatches are exactly this anatomy —
+    unless a step is already open on this thread (a build driven from
+    inside ``step()`` stays attributed to that step).  Any other phase
+    pushes its segment onto the open step, if there is one.  Returns
+    the token :func:`_phase_exit` wants, or None."""
+    st = getattr(_tls, "cur", None)
+    if name == "serve.step" or (name == "serve.prefix_build"
+                                and st is None):
+        p = _prof
+        if p is None:
+            return None
+        p.step_begin(args.get("engine"), step=args.get("step"))
+        return _STEP
+    if st is None:
+        return None
+    seg = _SEGMENT_OF.get(name)
+    if seg is None:
+        if not name.startswith("serve.dispatch."):
+            return None
+        seg = "dispatch"
+    st.push(seg)
+    return st
 
 
-def end():
+def _phase_exit(token, failed):
+    if token is not _STEP:
+        token.pop()
+        return
     st = getattr(_tls, "cur", None)
     _tls.cur = None
-    if st is not None and st.owner is _prof and _prof is not None:
+    # a step that raised has no meaningful anatomy: drop its record
+    if (not failed and st is not None and st.owner is _prof
+            and _prof is not None):
         _prof._finish(st)
 
 
-def abort():
-    """Drop the open step record (the engine's failure path: a step
-    that raised has no meaningful anatomy)."""
-    _tls.cur = None
-
-
-def begin_quantum(engine, step=None) -> bool:
-    """Open a step for an out-of-``step()`` work quantum — a prefix
-    BUILD chunk on a disaggregated prefill specialist, whose engine
-    never runs the decode step loop but whose dispatches are exactly
-    the host/device anatomy this profiler exists to expose.  No-op
-    (returns False) when a step is already open on this thread — a
-    build driven from inside ``step()`` stays attributed to that
-    step.  The caller pairs True with :func:`end` / :func:`abort`."""
-    p = _prof
-    if p is None or getattr(_tls, "cur", None) is not None:
-        return False
-    p.step_begin(engine, step=step)
-    return True
-
-
-def push(name):
-    st = getattr(_tls, "cur", None)
-    if st is not None:
-        st.push(name)
-
-
-def pop():
-    st = getattr(_tls, "cur", None)
-    if st is not None:
-        st.pop()
-
-
-def timed_dispatch(fn, a, kw):
-    """The executor-seam hook (``engine._ProfExec``): time the host
-    dispatch (building inputs + launching) and the device window
-    (dispatch return → ``block_until_ready`` on the output).  The
-    block is the ONLY added work — it runs on already-dispatched
-    outputs, so nothing new enters jitted code and the recompile pin
-    holds.  Outside an open step (e.g. a prefix build between steps)
-    the call passes straight through."""
+def fence_device(out):
+    """The executor-seam fence (``engine._ProfExec``, behind one
+    ``_active`` read): block the host on a dispatch's output and book
+    dispatch-return → ``block_until_ready`` as the step's ``device``
+    segment.  A HOST-FENCE ESTIMATE: it serialises the dispatches it
+    times and includes queueing behind earlier work — the device trace
+    is the source for device time.  The block is the ONLY added work —
+    it runs on already-dispatched outputs, so nothing new enters
+    jitted code and the recompile pin holds.  Outside an open step
+    it does nothing."""
     st = getattr(_tls, "cur", None)
     if st is None:
-        return fn(*a, **kw)
-    st.push("dispatch")
-    out = fn(*a, **kw)
-    st.pop()
+        return
     st.push("device")
     _block(out)
     t0, dur = st.pop()
     st.dev += dur
     st.dev_windows.append((t0, dur))
-    return out
 
 
 # -- health/monitor read surface --------------------------------------
@@ -399,12 +400,14 @@ class StepProfiler:
                     buckets=STEP_BUCKETS, engine=label),
                 "device": reg.histogram(
                     "serve.step.device_s",
-                    help="device-busy step seconds (dispatch -> "
-                         "block_until_ready, summed per window)",
+                    help="host-fence estimate of device seconds "
+                         "(dispatch -> block_until_ready, summed "
+                         "per window)",
                     buckets=STEP_BUCKETS, engine=label),
                 "bubble": reg.histogram(
                     "serve.step.bubble_frac",
-                    help="device-idle fraction of the step wall",
+                    help="(wall - fenced device) / wall: a host-"
+                         "fence estimate of the idle fraction",
                     buckets=FRACTION_BUCKETS, engine=label),
             }
             self._metrics[label] = m

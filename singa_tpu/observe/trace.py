@@ -50,6 +50,14 @@ Usage::
     def prefill(...): ...
 
     observe.export.write_chrome_trace("/tmp/trace.json")
+
+``phase()`` is the step-level variant for the handful of per-step
+sites of the serve engine and the graph runner: it ALWAYS writes a
+``jax.profiler.TraceAnnotation`` named ``singa/<name>``, so the
+program's phases sit on the device trace's clock whenever anybody
+traces (``jax.profiler.start_trace`` around live traffic); the host
+record above and the step-anatomy segments (``stepprof``) are fed from
+the same call when they are on.  See :func:`phase`.
 """
 
 from __future__ import annotations
@@ -59,8 +67,8 @@ import threading
 import time
 
 __all__ = ["enable", "disable", "is_enabled", "clear", "drain",
-           "events", "span", "event", "traced", "set_max_events",
-           "dropped"]
+           "events", "span", "phase", "event", "traced",
+           "set_max_events", "dropped"]
 
 # Module-global fast path: `if not _active: return _NULL_SPAN` is the
 # ENTIRE disabled cost of a span.  The buffer is a flat list of dicts;
@@ -243,6 +251,103 @@ def span(name: str, cat: str = "app", **args):
     if not _active:
         return _NULL_SPAN
     return _Span(name, cat, args)
+
+
+# -- phase(): one call per step-level site, three sinks ----------------
+#
+# The profiler's annotation class is imported (and subclassed) on the
+# first phase() call, so ``observe`` stays importable without JAX.
+# ``_phase_hook`` is stepprof's ``(enter, exit)`` pair while
+# ``stepprof.enable()`` is on — registered from there, so this module
+# never imports stepprof.
+_phase_hook = None
+_Annotation = None
+_Phase = None
+
+
+def _set_phase_hook(hook):
+    """Internal (stepprof.enable/disable): ``(enter(name, args) ->
+    token | None, exit(token, failed))`` or None."""
+    global _phase_hook
+    _phase_hook = hook
+
+
+def _build_phase_classes():
+    global _Annotation, _Phase
+    from jax.profiler import TraceAnnotation
+
+    class Annotation(TraceAnnotation):
+        """A phase with the profiler as its only sink: enter and exit
+        stay the C++ ``TraceMe``'s own, whose activity check is the
+        whole cost while no profiler session is on."""
+
+        __slots__ = ()
+
+        def set(self, **args):
+            self.set_metadata(**args)
+            return self
+
+    class Phase(Annotation):
+        """The same, plus the host record (``trace._active``) and
+        the step-anatomy segment (``stepprof``'s hook)."""
+
+        __slots__ = ("_name", "_args", "_span", "_hook", "_token")
+
+        def __init__(self, name, cat, args):
+            super().__init__("singa/" + name, **args)
+            self._name = name
+            self._args = args
+            self._span = _Span(name, cat, dict(args)) if _active else None
+            self._hook = _phase_hook
+            self._token = None
+
+        def set(self, **args):
+            self.set_metadata(**args)
+            if self._span is not None:
+                self._span.set(**args)
+            return self
+
+        def __enter__(self):
+            super().__enter__()
+            if self._span is not None:
+                self._span.__enter__()
+            if self._hook is not None:
+                self._token = self._hook[0](self._name, self._args)
+            return self
+
+        def __exit__(self, et, ev, tb):
+            if self._token is not None:
+                self._hook[1](self._token, et is not None)
+            if self._span is not None:
+                self._span.__exit__(et, ev, tb)
+            return super().__exit__(et, ev, tb)
+
+    _Annotation, _Phase = Annotation, Phase
+
+
+def phase(name: str, cat: str = "app", **args):
+    """Context manager around one step-level phase of the program
+    (``serve.step``, ``serve.decode``, ``train.step``, ... — the table
+    in docs/OBSERVABILITY.md), with three sinks:
+
+    * always a ``jax.profiler.TraceAnnotation("singa/" + name,
+      **args)``: a span on the profiler's clock, under the device's
+      operations, whenever a profiler session is on — and only the
+      annotation's own activity check when none is;
+    * while tracing (or the flight recorder) is on, the same host
+      record ``span()`` makes;
+    * while ``stepprof.enable()`` is on, the step-anatomy segment of
+      that name.
+
+    ``.set(**args)`` attaches args found inside the phase to the
+    annotation and the host record.  For the handful of per-step sites
+    only — never per token or per slot: unlike ``span()`` it allocates
+    when everything is off."""
+    if _Annotation is None:
+        _build_phase_classes()
+    if not _active and _phase_hook is None:
+        return _Annotation("singa/" + name, **args)
+    return _Phase(name, cat, args)
 
 
 def event(name: str, cat: str = "app", **args):

@@ -666,17 +666,34 @@ class _LocalExec:
         return _read_slot(kc, vc, slot)
 
 
+def _seam(method):
+    """One executor-seam method of :class:`_ProfExec`."""
+    name = "serve.dispatch." + method
+
+    def call(self, *a, **kw):
+        with _trace.phase(name, cat="serve"):
+            out = getattr(self._inner, method)(*a, **kw)
+        if _stepprof._active:
+            _stepprof.fence_device(out)
+        return out
+
+    call.__name__ = method
+    return call
+
+
 class _ProfExec:
-    """The step-anatomy hook at the executor seam: every dispatch the
-    engine makes routes through ``self._x``, so wrapping HERE times
-    dispatch (host) and dispatch→``block_until_ready`` (device) for
+    """The instrumentation at the executor seam: every dispatch the
+    engine makes routes through ``self._x``, so wrapping HERE covers
     every parallelism mode — ``_LocalExec`` and the tp/ep/pp sharded
-    executors alike — without the step loop knowing.  Disabled cost is
-    one module-flag read per dispatch (the ``trace._active``
-    discipline); with the profiler ON the only added work is a
-    ``block_until_ready`` on outputs the engine was about to sync
-    anyway, so nothing enters jitted code and the recompile pin
-    holds."""
+    executors alike — without the step loop knowing.  Each dispatch is
+    one ``serve.dispatch.<method>`` phase: the HOST's side of it
+    (building inputs + launching), which a profiler trace shows above
+    the device operations it launched — the trace, not this seam, says
+    how long the device ran.  While ``stepprof.enable()`` is on, the
+    seam also blocks on the outputs (``stepprof.fence_device``: a
+    host-fence estimate, outputs the engine was about to sync anyway,
+    so nothing enters jitted code and the recompile pin holds); off,
+    that is one module-flag read per dispatch."""
 
     __slots__ = ("_inner",)
 
@@ -687,55 +704,15 @@ class _ProfExec:
         # non-dispatch surface (executor-specific attrs) falls through
         return getattr(self._inner, name)
 
-    def pool_decode_step(self, *a, **kw):
-        if not _stepprof._active:
-            return self._inner.pool_decode_step(*a, **kw)
-        return _stepprof.timed_dispatch(self._inner.pool_decode_step,
-                                        a, kw)
-
-    def pool_spec_step(self, *a, **kw):
-        if not _stepprof._active:
-            return self._inner.pool_spec_step(*a, **kw)
-        return _stepprof.timed_dispatch(self._inner.pool_spec_step,
-                                        a, kw)
-
-    def paged_decode_step(self, *a, **kw):
-        if not _stepprof._active:
-            return self._inner.paged_decode_step(*a, **kw)
-        return _stepprof.timed_dispatch(self._inner.paged_decode_step,
-                                        a, kw)
-
-    def paged_spec_step(self, *a, **kw):
-        if not _stepprof._active:
-            return self._inner.paged_spec_step(*a, **kw)
-        return _stepprof.timed_dispatch(self._inner.paged_spec_step,
-                                        a, kw)
-
-    def prefill_one(self, *a, **kw):
-        if not _stepprof._active:
-            return self._inner.prefill_one(*a, **kw)
-        return _stepprof.timed_dispatch(self._inner.prefill_one, a, kw)
-
-    def prefill_batch(self, *a, **kw):
-        if not _stepprof._active:
-            return self._inner.prefill_batch(*a, **kw)
-        return _stepprof.timed_dispatch(self._inner.prefill_batch,
-                                        a, kw)
-
-    def chunk_row(self, *a, **kw):
-        if not _stepprof._active:
-            return self._inner.chunk_row(*a, **kw)
-        return _stepprof.timed_dispatch(self._inner.chunk_row, a, kw)
-
-    def write_slot(self, *a, **kw):
-        if not _stepprof._active:
-            return self._inner.write_slot(*a, **kw)
-        return _stepprof.timed_dispatch(self._inner.write_slot, a, kw)
-
-    def read_slot(self, *a, **kw):
-        if not _stepprof._active:
-            return self._inner.read_slot(*a, **kw)
-        return _stepprof.timed_dispatch(self._inner.read_slot, a, kw)
+    pool_decode_step = _seam("pool_decode_step")
+    pool_spec_step = _seam("pool_spec_step")
+    paged_decode_step = _seam("paged_decode_step")
+    paged_spec_step = _seam("paged_spec_step")
+    prefill_one = _seam("prefill_one")
+    prefill_batch = _seam("prefill_batch")
+    chunk_row = _seam("chunk_row")
+    write_slot = _seam("write_slot")
+    read_slot = _seam("read_slot")
 
 
 class _Slot:
@@ -1362,6 +1339,7 @@ class InferenceEngine:
                         if self.paged_arena is not None else None)
         self._prefilling = {}
         self._prefill_seq = itertools.count()
+        self._chunks_run = 0   # chunk-row dispatches (serve.schedule's arg)
         self._own_metrics = []
         if self._budget is not None:
             if self._chunk_statics is None:
@@ -1768,31 +1746,38 @@ class InferenceEngine:
             # lets the watchdog see an armed, then-silent source — a
             # re-arm only after the dispatch returns would never come
             _monitor.heartbeat(self._hb_source)
-        if _stepprof._active:
-            _stepprof.begin(self.stats.engine_label, self.step_count)
-        try:
-            if self.paged_arena is not None:
-                # paged growth: every live slot must own the block(s)
-                # the coming decode/spec chunk will write BEFORE the
-                # dispatch; a slot that cannot grow (pool exhausted,
-                # no strictly-lower-priority victim) swaps ITSELF out
-                self._grow_live_slots()
-            if any(s is not None for s in self._slots):
-                self._decode_once()
-            if _stepprof._active:
-                _stepprof.push("schedule")
-            self._schedule(self._clock())
-            if _stepprof._active:
-                _stepprof.pop()
-        except Exception as e:
-            # a raising step has no meaningful anatomy: drop the open
-            # record so a later dispatch can't land on a stale state
-            _stepprof.abort()
-            raise self._fail(e) from e
-        self.stats.on_schedule(self.scheduler.queue_depth)
-        self.step_count += 1
-        if _stepprof._active:
-            _stepprof.end()
+        with _trace.phase("serve.step", cat="serve",
+                          engine=self.stats.engine_label,
+                          step=self.step_count) as ph:
+            width = 0
+            try:
+                if self.paged_arena is not None:
+                    # paged growth: every live slot must own the
+                    # block(s) the coming decode/spec chunk will write
+                    # BEFORE the dispatch; a slot that cannot grow
+                    # (pool exhausted, no strictly-lower-priority
+                    # victim) swaps ITSELF out
+                    with _trace.phase("serve.grow", cat="serve"):
+                        self._grow_live_slots()
+                if any(s is not None for s in self._slots):
+                    width = self._decode_once()
+                with _trace.phase("serve.schedule", cat="serve") as sp:
+                    n_pf, n_ch = self.stats.prefills, self._chunks_run
+                    self._schedule(self._clock())
+                    sp.set(admitted=self.stats.prefills - n_pf,
+                           chunks=self._chunks_run - n_ch)
+            except Exception as e:
+                # (a raising step has no meaningful anatomy: the
+                # phase's exit drops stepprof's open record)
+                raise self._fail(e) from e
+            qd = self.scheduler.queue_depth
+            self.stats.on_schedule(qd)
+            self.step_count += 1
+            arena = self.paged_arena
+            ph.set(live=self.live_slots, width=width, queue_depth=qd,
+                   blocks_used=(arena.blocks_used if arena is not None
+                                else 0),
+                   prefill_tokens=self.stats.prefill_tokens)
         pending = self.pending
         if not pending and _monitor.active():
             # drained: refresh liveness but DISARM hang detection —
@@ -1985,6 +1970,10 @@ class InferenceEngine:
 
     # -- internals -------------------------------------------------------
     def _decode_once(self):
+        """Decode every live slot by one token (or one speculative
+        chunk) and emit.  Returns the width the pool step ran at (0
+        when a structured dead end emptied the pool before the
+        dispatch)."""
         if _faults._armed:
             # chaos hook: a fault here is exactly a raising pool decode
             # (speculative mode included — the draft scan, the chunk
@@ -2000,194 +1989,218 @@ class InferenceEngine:
         # so the fed step time is real device time
         _mon = _monitor.active()
         _hb_t0 = time.perf_counter() if _mon else 0.0
-        a_draft = None
-        lps = None
-        arena = self.paged_arena
-        # (speculative paged steps run at full width: the DRAFT arena
-        # is slot-indexed — compacting would have to gather/scatter
-        # draft cache rows per step, which is exactly the copy tax
-        # the block tables exist to avoid on the target side)
-        if self.draft is not None:
-            with _trace.span("serve/spec_step", cat="serve",
-                             step=self.step_count, live=n_live,
-                             paged=arena is not None):
-                if arena is not None:
-                    (out, a_draft, arena.pool_k, arena.pool_v,
-                     self._dkc, self._dvc,
-                     self._keys) = self._x.paged_spec_step(
-                        self._params, self._d_params, arena.pool_k,
-                        arena.pool_v, self._dkc, self._dvc,
-                        self._block_tables(), jnp.asarray(self._toks),
-                        jnp.asarray(self._pos), jnp.asarray(live),
-                        self._keys, jnp.asarray(self._temps),
-                        self._top_p, arena.block_size,
-                        kernel=arena.config.kernel)
-                else:
-                    (out, a_draft, self._kc, self._vc, self._dkc,
-                     self._dvc, self._keys) = self._x.pool_spec_step(
-                        self._params, self._d_params, self._kc,
-                        self._vc, self._dkc, self._dvc,
-                        jnp.asarray(self._toks),
-                        jnp.asarray(self._pos), jnp.asarray(live),
-                        self._keys, jnp.asarray(self._temps),
-                        self._top_p)
-                if _stepprof._active:
-                    _stepprof.push("sync")
-                out = np.asarray(out)
-                a_draft = np.asarray(a_draft)
-                if _stepprof._active:
-                    _stepprof.pop()
-        else:
-            # fork/structured pre-dispatch pass (paged, non-spec):
-            # per-slot grammar masks computed on the HOST between
-            # steps, stacked into one fixed-shape (S, V) bool input
-            # (plain slots get all-True rows — a bitwise no-op in the
-            # shared _sample), and the chosen-token logprob output
-            # turned on whenever any live slot belongs to a fork
-            # family.  Both are signature STATICS only in their
-            # presence (masks-or-not, lp-or-not), so the warmed jit
-            # cache covers every grammar and every fork pattern.
-            masks_np = None
-            need_lp = False
-            if arena is not None:
-                t_rej = None
-                for i, s in enumerate(self._slots):
-                    if s is None:
-                        continue
-                    if s.group is not None:
-                        need_lp = True
-                    if s.automaton is None:
-                        continue
-                    m = np.asarray(s.automaton.mask(s.astate), bool)
-                    if not m.any():
-                        # no vocab token continues the grammar from
-                        # here (incomplete output, nothing legal to
-                        # emit): that request is dead, typed — the
-                        # engine keeps serving everyone else
-                        t_rej = self._clock()
-                        rid = s.handle.request.request_id
-                        self._log.warning(
-                            "structured automaton for %s reached a "
-                            "dead end (no legal token); rejecting "
-                            "that request", rid)
-                        self._reject_live(
-                            i, s,
-                            ValueError(
-                                f"{rid}: structured automaton state "
-                                f"{s.astate!r} admits no vocab token "
-                                f"— the grammar cannot complete from "
-                                f"here"),
-                            "structured_dead_end", t_rej)
-                        continue
-                    if masks_np is None:
-                        masks_np = np.ones(
-                            (self.max_slots, self.cfg.vocab_size),
-                            bool)
-                    masks_np[i] = m
-                if t_rej is not None:
-                    live = np.asarray(
-                        [s is not None for s in self._slots])
-                    n_live = int(live.sum())
-                    if n_live == 0:
-                        return
-            with _trace.span("serve/decode_step", cat="serve",
-                             step=self.step_count, live=n_live,
-                             paged=arena is not None):
-                if arena is not None:
-                    # COMPACTED dispatch (the gather-tax round): run
-                    # the pool step at the smallest width bucket
-                    # covering the live slots instead of always at
-                    # max_slots.  Legal precisely because the pool is
-                    # paged — block tables address the KV, so a lane
-                    # permutation is pure host bookkeeping (per-slot
-                    # math is lane-independent; pad lanes are dead:
-                    # clamped inputs, trash-table writes, keys never
-                    # written back).  An over-provisioned engine
-                    # (many slots, few live) stops paying dead-lane
-                    # MLP/vocab/sampling work per step.
-                    lanes = np.flatnonzero(live)
-                    width = self._paged_width(len(lanes))
-                    if width < self.max_slots:
-                        sel = np.full(width, -1, np.intp)
-                        sel[:len(lanes)] = lanes
-                        live_w = np.zeros(width, bool)
-                        live_w[:len(lanes)] = True
-                        sel_in = np.where(sel < 0, 0, sel)
-                        keys_w = _take_rows(self._keys,
-                                            jnp.asarray(sel_in))
-                        # masks/with_lp only when active: the sharded
-                        # executors (tp/ep/pp) predate the fork
-                        # signature and validation refuses fork on
-                        # them, so the plain call must stay kwarg-free
-                        fkw = {}
-                        if masks_np is not None:
-                            fkw["masks"] = jnp.asarray(masks_np[sel_in])
-                        if need_lp:
-                            fkw["with_lp"] = True
-                        res = self._x.paged_decode_step(
-                            self._params, arena.pool_k, arena.pool_v,
-                            self._block_tables(list(sel)),
-                            jnp.asarray(self._toks[sel_in]),
-                            jnp.asarray(self._pos[sel_in]),
-                            jnp.asarray(live_w), keys_w,
-                            jnp.asarray(self._temps[sel_in]),
-                            self._top_p, arena.block_size,
-                            kernel=arena.config.kernel, **fkw)
-                        nt_w, arena.pool_k, arena.pool_v, keys2 = \
-                            res[:4]
-                        self._keys = _set_rows(
-                            self._keys, jnp.asarray(lanes),
-                            keys2[:len(lanes)])
-                        next_toks = np.zeros(self.max_slots, np.int32)
-                        next_toks[lanes] = \
-                            np.asarray(nt_w)[:len(lanes)]
-                        if need_lp:
-                            lps = np.zeros(self.max_slots)
-                            lps[lanes] = \
-                                np.asarray(res[4])[:len(lanes)]
-                    else:
-                        fkw = {}
-                        if masks_np is not None:
-                            fkw["masks"] = jnp.asarray(masks_np)
-                        if need_lp:
-                            fkw["with_lp"] = True
-                        res = self._x.paged_decode_step(
-                            self._params, arena.pool_k, arena.pool_v,
-                            self._block_tables(),
-                            jnp.asarray(self._toks),
-                            jnp.asarray(self._pos), jnp.asarray(live),
-                            self._keys, jnp.asarray(self._temps),
-                            self._top_p, arena.block_size,
-                            kernel=arena.config.kernel, **fkw)
-                        (next_toks, arena.pool_k, arena.pool_v,
-                         self._keys) = res[:4]
-                        if need_lp:
-                            lps = np.asarray(res[4])
-                else:
-                    next_toks, self._kc, self._vc, self._keys = \
-                        self._x.pool_decode_step(
-                            self._params, self._kc, self._vc,
-                            jnp.asarray(self._toks),
-                            jnp.asarray(self._pos),
-                            jnp.asarray(live), self._keys,
-                            jnp.asarray(self._temps), self._top_p)
-                if _stepprof._active:
-                    _stepprof.push("sync")
-                next_toks = np.asarray(next_toks)
-                if _stepprof._active:
-                    _stepprof.pop()
+        # serve.decode: from building the pool step's inputs to its
+        # tokens on the host — input building and launch first, then
+        # serve.sync, the host blocked on the device
+        with _trace.phase("serve.decode", cat="serve",
+                          paged=self.paged_arena is not None) as ph:
+            if self.draft is not None:
+                n_live, width, toks, a_draft, lps = \
+                    self._dispatch_spec(live, n_live)
+            else:
+                n_live, width, toks, a_draft, lps = \
+                    self._dispatch_decode(live, n_live)
+            ph.set(live=n_live, width=width)
+        if n_live == 0:
+            return 0
         if _mon:
             _monitor.heartbeat(
                 self._hb_source,
                 step_time=time.perf_counter() - _hb_t0,
                 fresh_compile=self.stats.decode_steps == 0)
         self.stats.on_decode_step(n_live)
+        # serve.emit: the emit loop — clients' on_token callbacks,
+        # retires and ledger hooks included
+        with _trace.phase("serve.emit", cat="serve") as ph:
+            t0 = self.stats.tokens_out
+            self._emit_step(toks, a_draft, lps)
+            ph.set(tokens=self.stats.tokens_out - t0)
+        return width
+
+    def _dispatch_spec(self, live, n_live):
+        """The speculative pool step: ``(n_live, width, accepted
+        chunk tokens (S, spec_k), accepted draft counts (S,), None)``,
+        on the host."""
+        arena = self.paged_arena
+        # (speculative paged steps run at full width: the DRAFT arena
+        # is slot-indexed — compacting would have to gather/scatter
+        # draft cache rows per step, which is exactly the copy tax
+        # the block tables exist to avoid on the target side)
+        if arena is not None:
+            (out, a_draft, arena.pool_k, arena.pool_v,
+             self._dkc, self._dvc,
+             self._keys) = self._x.paged_spec_step(
+                self._params, self._d_params, arena.pool_k,
+                arena.pool_v, self._dkc, self._dvc,
+                self._block_tables(), jnp.asarray(self._toks),
+                jnp.asarray(self._pos), jnp.asarray(live),
+                self._keys, jnp.asarray(self._temps),
+                self._top_p, arena.block_size,
+                kernel=arena.config.kernel)
+        else:
+            (out, a_draft, self._kc, self._vc, self._dkc,
+             self._dvc, self._keys) = self._x.pool_spec_step(
+                self._params, self._d_params, self._kc,
+                self._vc, self._dkc, self._dvc,
+                jnp.asarray(self._toks),
+                jnp.asarray(self._pos), jnp.asarray(live),
+                self._keys, jnp.asarray(self._temps),
+                self._top_p)
+        with _trace.phase("serve.sync", cat="serve"):
+            out = np.asarray(out)
+            a_draft = np.asarray(a_draft)
+        return n_live, self.max_slots, out, a_draft, None
+
+    def _dispatch_decode(self, live, n_live):
+        """The plain pool step: ``(n_live, width, next tokens (S,),
+        None, chosen-token logprobs (S,) or None)``, on the host.
+        ``n_live`` is 0 when a structured dead end emptied the pool
+        before the dispatch."""
+        arena = self.paged_arena
+        # fork/structured pre-dispatch pass (paged, non-spec):
+        # per-slot grammar masks computed on the HOST between
+        # steps, stacked into one fixed-shape (S, V) bool input
+        # (plain slots get all-True rows — a bitwise no-op in the
+        # shared _sample), and the chosen-token logprob output
+        # turned on whenever any live slot belongs to a fork
+        # family.  Both are signature STATICS only in their
+        # presence (masks-or-not, lp-or-not), so the warmed jit
+        # cache covers every grammar and every fork pattern.
+        masks_np = None
+        need_lp = False
+        if arena is not None:
+            t_rej = None
+            for i, s in enumerate(self._slots):
+                if s is None:
+                    continue
+                if s.group is not None:
+                    need_lp = True
+                if s.automaton is None:
+                    continue
+                m = np.asarray(s.automaton.mask(s.astate), bool)
+                if not m.any():
+                    # no vocab token continues the grammar from
+                    # here (incomplete output, nothing legal to
+                    # emit): that request is dead, typed — the
+                    # engine keeps serving everyone else
+                    t_rej = self._clock()
+                    rid = s.handle.request.request_id
+                    self._log.warning(
+                        "structured automaton for %s reached a "
+                        "dead end (no legal token); rejecting "
+                        "that request", rid)
+                    self._reject_live(
+                        i, s,
+                        ValueError(
+                            f"{rid}: structured automaton state "
+                            f"{s.astate!r} admits no vocab token "
+                            f"— the grammar cannot complete from "
+                            f"here"),
+                        "structured_dead_end", t_rej)
+                    continue
+                if masks_np is None:
+                    masks_np = np.ones(
+                        (self.max_slots, self.cfg.vocab_size),
+                        bool)
+                masks_np[i] = m
+            if t_rej is not None:
+                live = np.asarray(
+                    [s is not None for s in self._slots])
+                n_live = int(live.sum())
+                if n_live == 0:
+                    return 0, 0, None, None, None
+        lanes = lps = None
+        width = self.max_slots
+        if arena is not None:
+            # COMPACTED dispatch (the gather-tax round): run
+            # the pool step at the smallest width bucket
+            # covering the live slots instead of always at
+            # max_slots.  Legal precisely because the pool is
+            # paged — block tables address the KV, so a lane
+            # permutation is pure host bookkeeping (per-slot
+            # math is lane-independent; pad lanes are dead:
+            # clamped inputs, trash-table writes, keys never
+            # written back).  An over-provisioned engine
+            # (many slots, few live) stops paying dead-lane
+            # MLP/vocab/sampling work per step.
+            lanes = np.flatnonzero(live)
+            width = self._paged_width(len(lanes))
+            # masks/with_lp only when active: the sharded
+            # executors (tp/ep/pp) predate the fork
+            # signature and validation refuses fork on
+            # them, so the plain call must stay kwarg-free
+            fkw = {}
+            if need_lp:
+                fkw["with_lp"] = True
+            if width < self.max_slots:
+                sel = np.full(width, -1, np.intp)
+                sel[:len(lanes)] = lanes
+                live_w = np.zeros(width, bool)
+                live_w[:len(lanes)] = True
+                sel_in = np.where(sel < 0, 0, sel)
+                keys_w = _take_rows(self._keys,
+                                    jnp.asarray(sel_in))
+                if masks_np is not None:
+                    fkw["masks"] = jnp.asarray(masks_np[sel_in])
+                res = self._x.paged_decode_step(
+                    self._params, arena.pool_k, arena.pool_v,
+                    self._block_tables(list(sel)),
+                    jnp.asarray(self._toks[sel_in]),
+                    jnp.asarray(self._pos[sel_in]),
+                    jnp.asarray(live_w), keys_w,
+                    jnp.asarray(self._temps[sel_in]),
+                    self._top_p, arena.block_size,
+                    kernel=arena.config.kernel, **fkw)
+                next_toks, arena.pool_k, arena.pool_v, keys2 = \
+                    res[:4]
+                self._keys = _set_rows(
+                    self._keys, jnp.asarray(lanes),
+                    keys2[:len(lanes)])
+            else:
+                lanes = None
+                if masks_np is not None:
+                    fkw["masks"] = jnp.asarray(masks_np)
+                res = self._x.paged_decode_step(
+                    self._params, arena.pool_k, arena.pool_v,
+                    self._block_tables(),
+                    jnp.asarray(self._toks),
+                    jnp.asarray(self._pos), jnp.asarray(live),
+                    self._keys, jnp.asarray(self._temps),
+                    self._top_p, arena.block_size,
+                    kernel=arena.config.kernel, **fkw)
+                (next_toks, arena.pool_k, arena.pool_v,
+                 self._keys) = res[:4]
+            if need_lp:
+                lps = res[4]
+        else:
+            next_toks, self._kc, self._vc, self._keys = \
+                self._x.pool_decode_step(
+                    self._params, self._kc, self._vc,
+                    jnp.asarray(self._toks),
+                    jnp.asarray(self._pos),
+                    jnp.asarray(live), self._keys,
+                    jnp.asarray(self._temps), self._top_p)
+        with _trace.phase("serve.sync", cat="serve"):
+            next_toks = np.asarray(next_toks)
+            if lps is not None:
+                lps = np.asarray(lps)
+        if lanes is not None:
+            # a compacted step's lanes back at their slots
+            wide = np.zeros(self.max_slots, np.int32)
+            wide[lanes] = next_toks[:len(lanes)]
+            next_toks = wide
+            if lps is not None:
+                wide = np.zeros(self.max_slots)
+                wide[lanes] = lps[:len(lanes)]
+                lps = wide
+        return n_live, width, next_toks, None, lps
+
+    def _emit_step(self, next_toks, a_draft, lps):
+        """Emit one decode step's tokens slot by slot
+        (``next_toks``: (S,) plain, (S, spec_k) speculative)."""
         t_emit = self._clock()
         led = _reqs._ledger if _reqs._active else None
         lbl = self.stats.engine_label
-        _sp = _stepprof._active
-        if _sp:
-            _stepprof.push("emit")
         for i, slot in enumerate(self._slots):
             if slot is None:
                 continue
@@ -2201,11 +2214,7 @@ class InferenceEngine:
                     slot.score += float(lps[i])
                 self._emit(i, slot, int(next_toks[i]), t_emit)
                 if led is not None:
-                    if _sp:
-                        _stepprof.push("ledger")
                     led.on_step(rid, engine=lbl, t=t_emit, tokens=1)
-                    if _sp:
-                        _stepprof.pop()
                 self._toks[i] = next_toks[i]
                 self._pos[i] += 1
                 continue
@@ -2219,7 +2228,7 @@ class InferenceEngine:
             self.stats.on_spec(int(a_draft[i]), self.spec_k - 1)
             emitted = 0
             for j in range(a):
-                self._emit(i, slot, int(out[i, j]), t_emit)
+                self._emit(i, slot, int(next_toks[i, j]), t_emit)
                 emitted += 1
                 if self._slots[i] is not slot:
                     break
@@ -2228,18 +2237,12 @@ class InferenceEngine:
                 # emitted tokens (may stop mid-chunk), accepted
                 # proposals, proposals offered (lands on the sealed
                 # entry when the last token retired the request)
-                if _sp:
-                    _stepprof.push("ledger")
                 led.on_step(rid, engine=lbl, t=t_emit, tokens=emitted,
                             accepted=int(a_draft[i]),
                             drafted=self.spec_k - 1)
-                if _sp:
-                    _stepprof.pop()
             if self._slots[i] is slot:
-                self._toks[i] = int(out[i, emitted - 1])
+                self._toks[i] = int(next_toks[i, emitted - 1])
                 self._pos[i] += emitted
-        if _sp:
-            _stepprof.pop()
 
     def _emit(self, idx, slot, token, now):
         slot.emitted.append(token)
@@ -2296,21 +2299,14 @@ class InferenceEngine:
     def _retire(self, idx, slot, now, finish_reason="length"):
         req = slot.handle.request
         n = len(slot.emitted)
-        _sp = _stepprof._active
-        if _sp:
-            _stepprof.push("retire")
         _trace.event("serve/retire", cat="serve",
                      request=req.request_id, slot=idx, tokens=n,
                      step=self.step_count)
         if _reqs._active:
-            if _sp:
-                _stepprof.push("ledger")
             _reqs._ledger.on_retire(req.request_id,
                                     engine=self.stats.engine_label,
                                     t=now, finish_reason=finish_reason,
                                     tokens=n)
-            if _sp:
-                _stepprof.pop()
         submit_t = getattr(slot.handle, "_submit_time", slot.admit_time)
         ttft = slot.first_token_time - submit_t
         tpot = ((now - slot.first_token_time) / (n - 1)
@@ -2344,8 +2340,6 @@ class InferenceEngine:
         self._handles.pop(req.request_id, None)
         if self.paged_arena is not None:
             self._fork_gauge()
-        if _sp:
-            _stepprof.pop()
 
     def _reject_live(self, idx, slot, error, reason, now):
         """Reject a LIVE slot's request typed (client callback raised,
@@ -2937,9 +2931,10 @@ class InferenceEngine:
                 # family would leave branch count dependent on
                 # scheduling noise
                 ph = self._handles[req.request_id]
-                ok = self._admit(free.pop(0), req, now,
-                                 prefilled=prefilled.get(
-                                     req.request_id))
+                with _trace.phase("serve.admit", cat="serve"):
+                    ok = self._admit(free.pop(0), req, now,
+                                     prefilled=prefilled.get(
+                                         req.request_id))
                 if ok and n_br > 1:
                     self._fork_group_admit(req, ph, free, now)
             if not ok:
@@ -3299,12 +3294,14 @@ class InferenceEngine:
                 # ring prefill is ONE mesh-sharded dispatch for the
                 # whole prompt — admit whole and charge the budget,
                 # so no further prefill stacks onto this step
-                ok = self._admit(free[0], req, now)
+                with _trace.phase("serve.admit", cat="serve"):
+                    ok = self._admit(free[0], req, now)
                 if ok:
                     free.pop(0)
                     left = max(0, left - len(req.prompt_ids))
             elif admissible:
-                idx = self._start_prefilling(free[0], req, now)
+                with _trace.phase("serve.admit", cat="serve"):
+                    idx = self._start_prefilling(free[0], req, now)
                 if idx is not None:
                     free.pop(0)
                     ok = True
@@ -3330,16 +3327,10 @@ class InferenceEngine:
         B = arena.block_size
         plen = len(req.prompt_ids)
         cache = self.prefix_cache
-        _sp = _stepprof._active
-        if _sp:
-            _stepprof.push("admit")
         nodes = []
         if cache is not None:
-            if _sp:
-                _stepprof.push("prefix_lookup")
-            nodes = cache.lookup(req.prompt_ids)[:(plen - 1) // B]
-            if _sp:
-                _stepprof.pop()
+            with _trace.phase("serve.prefix_lookup", cat="serve"):
+                nodes = cache.lookup(req.prompt_ids)[:(plen - 1) // B]
             if nodes:
                 cache.acquire(nodes)
         j_lo0 = 0
@@ -3354,8 +3345,6 @@ class InferenceEngine:
         if new_blocks is None:
             if cache is not None and nodes:
                 cache.release(nodes)
-            if _sp:
-                _stepprof.pop()
             return None
         if _reqs._active:
             _reqs._ledger.on_admit(req.request_id,
@@ -3410,8 +3399,6 @@ class InferenceEngine:
                      request=req.request_id, slot=idx,
                      prompt_len=plen, step=self.step_count,
                      chunks=(pf.last_off - pf.off) // B + 1)
-        if _sp:
-            _stepprof.pop()
         return idx
 
     def _advance_prefilling(self, idx, left, now):
@@ -3424,6 +3411,7 @@ class InferenceEngine:
         pf = self._prefilling[idx]
         B = self.paged_arena.block_size
         rid = pf.request.request_id
+        plen = len(pf.request.prompt_ids)
         while left >= B and pf.off <= pf.last_off:
             if _faults._armed:
                 # chaos hook: a fault BETWEEN chunks models a raising
@@ -3436,6 +3424,10 @@ class InferenceEngine:
                 self._params, pf.ids_j, pf.kc_row, pf.vc_row,
                 jnp.int32(pf.off))
             self._c_budget_chunks.inc()
+            self._chunks_run += 1
+            # the prompt positions this chunk really covered: the last
+            # one is cut at the prompt's end, not padded to the block
+            self.stats.on_prefill_tokens(min(B, plen - pf.off))
             if _reqs._active:
                 _reqs._ledger.on_prefill_chunk(
                     rid, engine=self.stats.engine_label,
@@ -3541,12 +3533,7 @@ class InferenceEngine:
         cap exists to bound)."""
         cache = self.prefix_cache
         plen = len(req.prompt_ids)
-        if _stepprof._active:
-            _stepprof.push("prefix_lookup")
-            usable = min(len(cache.lookup(req.prompt_ids)),
-                         (plen - 1) // cache.block_size)
-            _stepprof.pop()
-        else:
+        with _trace.phase("serve.prefix_lookup", cat="serve"):
             usable = min(len(cache.lookup(req.prompt_ids)),
                          (plen - 1) // cache.block_size)
         if usable > 0 and plen - usable * cache.block_size \
@@ -3643,17 +3630,11 @@ class InferenceEngine:
         handle = self._handles[req.request_id]
         plen = len(req.prompt_ids)
         cache = self.prefix_cache
-        _sp = _stepprof._active
-        if _sp:
-            _stepprof.push("admit")
         nodes = []
         if cache is not None:
-            if _sp:
-                _stepprof.push("prefix_lookup")
-            nodes = cache.lookup(req.prompt_ids)[
-                :(plen - 1) // cache.block_size]
-            if _sp:
-                _stepprof.pop()
+            with _trace.phase("serve.prefix_lookup", cat="serve"):
+                nodes = cache.lookup(req.prompt_ids)[
+                    :(plen - 1) // cache.block_size]
         arena = self.paged_arena
         new_blocks = []
         if arena is not None:
@@ -3687,8 +3668,6 @@ class InferenceEngine:
             if new_blocks is None:
                 if cache is not None and nodes:
                     cache.release(nodes)
-                if _sp:
-                    _stepprof.pop()
                 return False
         if _reqs._active:
             # admission started: the queue-wait phase of this hop ends
@@ -3707,11 +3686,11 @@ class InferenceEngine:
             ast0 = req.structured.initial()
             mask0 = jnp.asarray(
                 np.asarray(req.structured.mask(ast0), bool))
-        with _trace.span("serve/prefill", cat="serve",
-                         request=req.request_id, slot=idx,
-                         prompt_len=plen, step=self.step_count,
-                         cached_tokens=(len(nodes) * cache.block_size
-                                        if cache is not None else 0)):
+        cached = len(nodes) * cache.block_size if nodes else 0
+        with _trace.phase("serve.prefill", cat="serve",
+                          request=req.request_id, slot=idx,
+                          prompt_len=plen, step=self.step_count,
+                          cached_tokens=cached):
             ids_j = None
             if prefilled is None:
                 ids = np.zeros((1, self.max_len), np.int32)
@@ -3823,6 +3802,7 @@ class InferenceEngine:
             cache.on_admit(len(nodes), plen,
                            request_id=req.request_id)
         self.stats.on_prefill()
+        self.stats.on_prefill_tokens(plen - cached)
         slot = _Slot(handle, req.max_new_tokens, now, self.step_count)
         slot.prefix_nodes = nodes
         slot.automaton = req.structured
@@ -3849,8 +3829,6 @@ class InferenceEngine:
         else:
             self._keys = self._keys.at[idx].set(carry_key)
         self._emit(idx, slot, tok0, t_first)
-        if _sp:
-            _stepprof.pop()
         return True
 
     def _admit_warm(self, ids, plen, nodes, key0, temp, rid=None,
@@ -3869,6 +3847,7 @@ class InferenceEngine:
         while off <= last_off:
             hidden, kc_row, vc_row = self._x.chunk_row(
                 self._params, ids_j, kc_row, vc_row, jnp.int32(off))
+            self._chunks_run += 1
             if _reqs._active and rid is not None:
                 _reqs._ledger.on_prefill_chunk(
                     rid, engine=self.stats.engine_label,
@@ -3985,37 +3964,31 @@ class InferenceEngine:
         left = (job.last_off - job.off + B if max_tokens is None
                 else int(max_tokens))
         # a prefill specialist never runs the decode step loop, so its
-        # anatomy comes from here: each budgeted advance is one step
-        # quantum (no-op when a step is already open — a build driven
+        # anatomy comes from here: each budgeted advance is one phase
+        # of its own, which the step-anatomy profiler books as a step
+        # quantum (unless a step is already open — a build driven
         # from inside step() stays attributed to that step)
-        quantum = (_stepprof.begin_quantum(self.stats.engine_label,
-                                           step=self.step_count)
-                   if _stepprof._active else False)
-        try:
-            while left >= B and job.off <= job.last_off:
-                if _faults._armed:
-                    _faults.check("serve.prefill_chunk")
-                off = job.off
-                _, job.kc_row, job.vc_row = self._x.chunk_row(
-                    self._params, job.ids_j, job.kc_row, job.vc_row,
-                    jnp.int32(off))
-                job.off += B
-                left -= B
-                if _reqs._active and rid is not None:
-                    if quantum:
-                        _stepprof.push("ledger")
-                    _reqs._ledger.on_prefill_chunk(
-                        rid, engine=self.stats.engine_label,
-                        t=self._clock(), offset=off)
-                    if quantum:
-                        _stepprof.pop()
-        except Exception as e:
-            if quantum:
-                _stepprof.abort()
-            self.abandon_prefix_build(job)
-            raise self._fail(e) from e
-        if quantum:
-            _stepprof.end()
+        with _trace.phase("serve.prefix_build", cat="serve",
+                          engine=self.stats.engine_label,
+                          step=self.step_count):
+            try:
+                while left >= B and job.off <= job.last_off:
+                    if _faults._armed:
+                        _faults.check("serve.prefill_chunk")
+                    off = job.off
+                    _, job.kc_row, job.vc_row = self._x.chunk_row(
+                        self._params, job.ids_j, job.kc_row,
+                        job.vc_row, jnp.int32(off))
+                    self.stats.on_prefill_tokens(B)
+                    job.off += B
+                    left -= B
+                    if _reqs._active and rid is not None:
+                        _reqs._ledger.on_prefill_chunk(
+                            rid, engine=self.stats.engine_label,
+                            t=self._clock(), offset=off)
+            except Exception as e:
+                self.abandon_prefix_build(job)
+                raise self._fail(e) from e
         return job.off > job.last_off
 
     def abandon_prefix_build(self, job):

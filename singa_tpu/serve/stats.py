@@ -95,6 +95,10 @@ class EngineStats:
             help="requests rejected by back-pressure", **lbl)
         self._prefills = reg.counter(
             "serve.prefills", help="admission prefills run", **lbl)
+        self._prefill_tokens = reg.counter(
+            "serve.prefill.tokens",
+            help="prompt positions prefilled (real positions: neither "
+                 "padding nor positions a prefix cache supplied)", **lbl)
         self._decode_steps = reg.counter(
             "serve.decode_steps", help="pool decode steps run", **lbl)
         self._tokens_out = reg.counter(
@@ -133,8 +137,9 @@ class EngineStats:
         self._log = get_channel("serve")
         self._registered = [
             self._submitted, self._completed, self._rej_deadline,
-            self._rej_queue, self._prefills, self._decode_steps,
-            self._tokens_out, self._queue_depth, self._occupancy,
+            self._rej_queue, self._prefills, self._prefill_tokens,
+            self._decode_steps, self._tokens_out, self._queue_depth,
+            self._occupancy,
             self._h_ttft, self._h_tpot, self._h_queue_wait,
             self._h_admission["cold"], self._h_admission["warm"],
         ]
@@ -225,6 +230,10 @@ class EngineStats:
         return self._prefills.value
 
     @property
+    def prefill_tokens(self):
+        return self._prefill_tokens.value
+
+    @property
     def decode_steps(self):
         return self._decode_steps.value
 
@@ -246,6 +255,11 @@ class EngineStats:
 
     def on_prefill(self):
         self._prefills.inc()
+
+    def on_prefill_tokens(self, n: int):
+        """``n`` prompt positions went through a prefill dispatch: a
+        whole admission's, or one chunk's under a token budget."""
+        self._prefill_tokens.inc(n)
 
     def on_admission(self, queue_wait_s, admission_s, warm=False):
         """One admission's latency split: ``queue_wait_s`` (submit ->
@@ -344,6 +358,7 @@ class EngineStats:
                 # definition pair disagree by clock jitter
                 "goodput_tokens_per_s": self.tokens_out / wall,
                 "prefills": self.prefills,
+                "prefill_tokens": self.prefill_tokens,
                 "decode_steps": self.decode_steps,
             },
             "latency": {

@@ -392,11 +392,12 @@ def test_engine_stats_unregister_releases_metrics():
     a = EngineStats(2, FakeClock(), reg=reg)
     b = EngineStats(2, FakeClock(), reg=reg)
     a.on_submit()
-    assert len(reg.metrics()) == 28  # 14 per engine (incl. the
-    #   queue-wait + cold/warm admission request-phase histograms)
+    assert len(reg.metrics()) == 30  # 15 per engine (incl. the
+    #   queue-wait + cold/warm admission request-phase histograms
+    #   and the prefill-token counter)
     a.unregister()
     remaining = reg.metrics()
-    assert len(remaining) == 14
+    assert len(remaining) == 15
     assert all(("engine", b.engine_label) in m.labels
                for m in remaining)
     # a fully-removed NAME frees its kind reservation
@@ -516,11 +517,360 @@ def test_graph_runner_counts_compiles_and_replays():
     assert reg.counter("graph.cache_hit").value == h0 + 2
     assert reg.counter("train.steps").value == s0 + 3
     names = [e["name"] for e in observe.events()]
-    assert names.count("graph/compile") == 1
-    assert names.count("train/step") == 3
+    assert names.count("graph.compile") == 1
+    # one whole-call phase and one dispatch phase per call
+    assert names.count("train.step") == 3
+    assert names.count("train.dispatch") == 3
     assert "graph/cache_miss" in names
     compile_span = next(e for e in observe.events()
-                        if e["name"] == "graph/compile")
+                        if e["name"] == "graph.compile")
     # XLA cost-table estimates ride the span args (flops present on
     # the CPU backend too)
     assert "flops" in compile_span["args"]
+
+
+# ---------------------------------------------------------------------------
+# phase(): the step-level sites, on the profiler's clock
+# ---------------------------------------------------------------------------
+
+def _tiny_model():
+    import numpy as np
+
+    from singa_tpu import tensor
+    from singa_tpu.models.gpt2 import GPT2Config, GPT2LMHead
+
+    m = GPT2LMHead(GPT2Config.tiny(dropout=0.0))
+    m.compile([tensor.from_numpy(np.zeros((1, 16), np.int32))],
+              is_train=False, use_graph=False)
+    return m
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    return _tiny_model()
+
+
+def test_observe_imports_without_jax():
+    """``phase()`` reaches for ``jax.profiler`` on its first call, not
+    when ``observe`` is imported (checked in a fresh interpreter, with
+    the package's own ``__init__`` -- which does import JAX -- stubbed
+    out)."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import sys, types\n"
+        "pkg = types.ModuleType('singa_tpu')\n"
+        f"pkg.__path__ = [{os.path.join(root, 'singa_tpu')!r}]\n"
+        "sys.modules['singa_tpu'] = pkg\n"
+        "import singa_tpu.observe as o\n"
+        "assert 'jax' not in sys.modules, 'observe imported jax'\n"
+        "assert o.phase is o.trace.phase\n"
+        "with o.phase('serve.step', cat='serve', step=1) as ph:\n"
+        "    ph.set(live=2)\n"
+        "assert 'jax' in sys.modules\n")
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert p.returncode == 0, p.stderr
+
+
+def test_phase_off_is_the_bare_annotation():
+    """With tracing and the step profiler off a phase is the profiler's
+    own annotation and nothing else: no host record, no ``_Span``, no
+    clock call -- ``span()`` keeps its shared no-op."""
+    from jax.profiler import TraceAnnotation
+
+    from singa_tpu.observe import trace
+
+    ph = observe.phase("serve.step", cat="serve", step=3)
+    assert isinstance(ph, TraceAnnotation)
+    assert type(ph) is trace._Annotation
+    with ph as inside:
+        assert inside is ph
+        assert ph.set(live=2) is ph
+    assert observe.events() == []
+    assert observe.span("x") is trace._NULL_SPAN
+
+
+def test_phase_feeds_the_host_record_when_tracing_is_on():
+    clk = FakeClock()
+    observe.enable(clock=clk)
+    with observe.phase("serve.step", cat="serve", step=7) as ph:
+        clk.advance(1.0)
+        with observe.phase("serve.decode", cat="serve"):
+            clk.advance(0.5)
+        ph.set(live=2)
+        clk.advance(0.25)
+    inner, outer = observe.events()
+    assert (inner["name"], inner["parent"], inner["dur"]) == \
+        ("serve.decode", "serve.step", 0.5)
+    assert (outer["name"], outer["cat"], outer["dur"]) == \
+        ("serve.step", "serve", 1.75)
+    assert outer["args"] == {"step": 7, "live": 2}
+
+
+def test_phase_feeds_the_step_profiler_through_its_hook_alone():
+    """``stepprof.enable()`` registers the hook and ``disable()`` takes
+    it away; ``trace.py`` never imports ``stepprof``.  On a fake clock
+    the segments are exact: exclusive, ``other`` for time under no
+    segment, summing to the wall."""
+    import ast
+    import inspect
+
+    from singa_tpu.observe import stepprof, trace
+
+    imported = [a.name for n in ast.walk(ast.parse(inspect.getsource(trace)))
+                if isinstance(n, (ast.Import, ast.ImportFrom))
+                for a in n.names]
+    assert "stepprof" not in " ".join(imported)
+    assert trace._phase_hook is None
+    clk = FakeClock()
+    stepprof.enable(clock=clk, reg=MetricsRegistry())
+    try:
+        assert trace._phase_hook is not None
+        with observe.phase("serve.step", cat="serve", engine="9",
+                           step=4):
+            clk.advance(0.001)                      # other
+            with observe.phase("serve.decode", cat="serve"):
+                clk.advance(0.002)                  # no segment: other
+                with observe.phase("serve.dispatch.paged_decode_step",
+                                   cat="serve"):
+                    clk.advance(0.003)              # dispatch
+                with observe.phase("serve.sync", cat="serve"):
+                    clk.advance(0.070)              # sync
+            with observe.phase("serve.emit", cat="serve"):
+                clk.advance(0.004)                  # emit
+            with observe.phase("serve.schedule", cat="serve"):
+                clk.advance(0.005)                  # schedule
+                with observe.phase("serve.admit", cat="serve"):
+                    clk.advance(0.006)              # admit
+                    with observe.phase("serve.prefix_lookup",
+                                       cat="serve"):
+                        clk.advance(0.007)          # prefix_lookup
+        rec, = stepprof.records()
+        assert (rec["engine"], rec["step"]) == ("9", 4)
+        assert rec["segments"] == pytest.approx(
+            {"other": 0.003, "dispatch": 0.003, "sync": 0.070,
+             "emit": 0.004, "schedule": 0.005, "admit": 0.006,
+             "prefix_lookup": 0.007})
+        assert sum(rec["segments"].values()) == \
+            pytest.approx(rec["wall_s"]) == pytest.approx(0.098)
+        # a step that raises leaves no record behind
+        with pytest.raises(RuntimeError):
+            with observe.phase("serve.step", cat="serve", engine="9",
+                               step=5):
+                raise RuntimeError("boom")
+        assert len(stepprof.records()) == 1
+    finally:
+        stepprof.disable()
+    assert trace._phase_hook is None
+
+
+def test_a_quiet_step_makes_no_span_and_no_clock_call(tiny_model,
+                                                      monkeypatch):
+    """No profiler session, ``observe`` off, step profiler off, monitor
+    off: a whole engine step allocates no ``_Span`` and reads no clock
+    through the instrumentation."""
+    import time
+
+    import numpy as np
+
+    from singa_tpu.observe import trace
+    from singa_tpu.serve import GenerationRequest
+
+    eng = tiny_model.serve(max_slots=2)
+    h = eng.submit(GenerationRequest(np.arange(9) % 256,
+                                     max_new_tokens=12, temperature=0.0))
+    eng.step()
+    eng.step()
+    made, calls = [], [0]
+    real_span, real_clock = trace._Span, time.perf_counter
+
+    class Counted(real_span):
+        def __init__(self, *a):
+            made.append(a[0])
+            super().__init__(*a)
+
+    def counting():
+        calls[0] += 1
+        return real_clock()
+
+    try:
+        monkeypatch.setattr(trace, "_Span", Counted)
+        monkeypatch.setattr(time, "perf_counter", counting)
+        monkeypatch.setattr(trace, "_clock", counting)
+        eng.step()
+        assert made == [] and calls[0] == 0
+        monkeypatch.setattr(time, "perf_counter", real_clock)
+    finally:
+        while eng.pending:
+            eng.step()
+        h.result()
+        eng.close()
+
+
+def test_a_profiler_session_records_the_step_with_its_children(
+        tiny_model, tmp_path):
+    """Under ``jax.profiler`` (a CPU session here) every engine step is
+    a ``singa/serve.step`` span carrying its args, with grow, decode
+    (holding the launch and the sync), emit and schedule inside it."""
+    import glob
+
+    import jax
+    import numpy as np
+    from jax.profiler import ProfileData
+
+    from singa_tpu.serve import GenerationRequest, PagedConfig
+
+    eng = tiny_model.serve(
+        max_slots=2, paged=PagedConfig(block_size=8, num_blocks=32,
+                                       prefill_token_budget=16))
+    try:
+        def submit(n):
+            return eng.submit(GenerationRequest(
+                (np.arange(n) * 7) % 256, max_new_tokens=8,
+                temperature=0.0))
+
+        hs = [submit(9)]
+        while not eng.live_slots:       # (compiles outside the session)
+            eng.step()
+        hs.append(submit(21))           # two budgeted chunks to come
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            first = eng.step_count
+            for _ in range(4):
+                eng.step()
+        finally:
+            jax.profiler.stop_trace()
+        while eng.pending:
+            eng.step()
+        for h in hs:
+            h.result()
+        total = eng.stats.prefill_tokens
+    finally:
+        eng.close()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("singa/"):
+                        seen[(e.name, e.start_ns, e.duration_ns)] = \
+                            dict(e.stats)
+    spans = sorted((k + (a,) for k, a in seen.items()),
+                   key=lambda s: s[1])
+    steps = [s for s in spans if s[0] == "singa/serve.step"]
+    assert [s[3]["step"] for s in steps] == list(range(first, first + 4))
+    for name, t0, dur, args in steps:
+        assert {"engine", "step", "live", "width", "queue_depth",
+                "blocks_used", "prefill_tokens"} <= set(args)
+        assert 0 <= args["prefill_tokens"] <= total
+        kids = [s for s in spans
+                if t0 <= s[1] and s[1] + s[2] <= t0 + dur
+                and s[0] != name]
+        names = [k[0] for k in kids]
+        for child in ("singa/serve.grow", "singa/serve.decode",
+                      "singa/serve.emit", "singa/serve.schedule"):
+            assert names.count(child) == 1, (child, names)
+        decode = next(k for k in kids if k[0] == "singa/serve.decode")
+        for inner in ("singa/serve.dispatch.paged_decode_step",
+                      "singa/serve.sync"):
+            k = next(k for k in kids if k[0] == inner)
+            assert decode[1] <= k[1] and \
+                k[1] + k[2] <= decode[1] + decode[2]
+        assert decode[3]["paged"] == 1 and decode[3]["live"] >= 1
+    # the budgeted prefill of the 21-token prompt ran inside the session:
+    # chunk rows under serve.schedule, counted in its args
+    sched = [s for s in spans if s[0] == "singa/serve.schedule"]
+    assert sum(s[3]["chunks"] for s in sched) >= 1
+    assert any(s[0] == "singa/serve.dispatch.chunk_row" for s in spans)
+
+
+@pytest.mark.parametrize("path", ["cold", "budgeted", "warm"])
+def test_prefill_token_counter_counts_real_prompt_positions(tiny_model,
+                                                            path):
+    """``serve.prefill.tokens``: the prompt positions a prefill really
+    computed -- a whole admission's on the cold path, chunk by chunk
+    (the last one cut at the prompt's end, not padded to the block)
+    under a token budget, and less what the prefix cache supplied on
+    the warm path."""
+    import numpy as np
+
+    from singa_tpu.observe.registry import registry
+    from singa_tpu.serve import (GenerationRequest, PagedConfig,
+                                 PrefixCacheConfig)
+
+    kw = {"cold": dict(),
+          "budgeted": dict(paged=PagedConfig(
+              block_size=8, num_blocks=64, prefill_token_budget=16)),
+          "warm": dict(prefix_cache=PrefixCacheConfig(block_size=8))}[path]
+    eng = tiny_model.serve(max_slots=2, **kw)
+    shared = (np.arange(24) * 5 + 1) % 256
+    prompts = [np.concatenate([shared, [7, 8, 9]]),
+               np.concatenate([shared, [3, 1]]), np.asarray([5, 1, 200])]
+    try:
+        cached = 0
+        for p in prompts:                 # one after the other, so the
+            h = eng.submit(GenerationRequest(   # second finds the first
+                p, max_new_tokens=3, temperature=0.0))
+            while eng.pending:
+                eng.step()
+            h.result()
+        if path == "warm":
+            cached = eng.stats.snapshot()["prefix"]["hit_tokens"]
+            assert cached == 24           # the second prompt's three blocks
+        want = sum(len(p) for p in prompts) - cached
+        assert eng.stats.prefill_tokens == want
+        assert eng.stats.snapshot()["throughput"]["prefill_tokens"] == want
+        assert registry().counter(
+            "serve.prefill.tokens",
+            engine=eng.stats.engine_label).value == want
+    finally:
+        eng.close()
+
+
+def test_train_step_phase_holds_its_dispatch():
+    import numpy as np
+
+    from singa_tpu import device, layer, model, opt, tensor
+
+    class Net(model.Model):
+        def __init__(self):
+            super().__init__()
+            self.fc = layer.Linear(3)
+            self.loss = layer.SoftMaxCrossEntropy()
+
+        def forward(self, x):
+            return self.fc(x)
+
+        def train_one_batch(self, x, y):
+            out = self.forward(x)
+            loss = self.loss(out, y)
+            self.optimizer(loss)
+            return out, loss
+
+    dev = device.get_default_device()
+    m = Net()
+    m.set_optimizer(opt.SGD(lr=0.05))
+    rng = np.random.RandomState(0)
+    x = tensor.from_numpy(rng.randn(4, 8).astype(np.float32), dev)
+    y = tensor.from_numpy(rng.randint(0, 3, (4,)).astype(np.int32), dev)
+    m.compile([x], is_train=True, use_graph=True)
+    observe.enable(clock=FakeClock())
+    m(x, y)
+    m(x, y)
+    observe.disable()
+    by = {}
+    for e in observe.events():
+        by.setdefault(e["name"], []).append(e)
+    assert len(by["train.step"]) == len(by["train.dispatch"]) == 2
+    assert all(e["parent"] == "train.step" for e in by["train.dispatch"])
+    assert by["graph.compile"][0]["parent"] == "train.step"
+    assert by["train.step"][0]["args"] == {"steps": 1}

@@ -214,7 +214,8 @@ def test_stats_schema_stable():
         "rejected_queue_full"}
     assert set(snap["throughput"]) == {
         "tokens_out", "wall_s", "uptime_s", "tokens_per_s",
-        "goodput_tokens_per_s", "prefills", "decode_steps"}
+        "goodput_tokens_per_s", "prefills", "prefill_tokens",
+        "decode_steps"}
     assert set(snap["latency"]) == {"ttft", "tpot", "tpot_ewma_s"}
     for series in (snap["latency"]["ttft"], snap["latency"]["tpot"]):
         assert set(series) == {"count", "mean", "p50", "p99", "max"}

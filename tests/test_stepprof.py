@@ -167,6 +167,39 @@ def test_fractions_sum_to_one_and_ring_invariants(model):
         eng.close()
 
 
+def test_segments_are_fed_by_phase_alone(model):
+    """The engine and the graph runner hold no profiler fence of their
+    own: every segment arrives through ``trace.phase()``'s hook, and a
+    drained engine still shows the whole taxonomy it exercised."""
+    import inspect
+    import re
+
+    from singa_tpu import model as model_mod
+    from singa_tpu.serve import engine
+
+    fence = re.compile(
+        r"_stepprof\.(push|pop|begin|end|abort|begin_quantum)\b")
+    assert not fence.search(inspect.getsource(engine))
+    for fn in (engine.InferenceEngine.step,
+               engine.InferenceEngine._decode_once,
+               model_mod._GraphRunner.run, model_mod._GraphRunner._run):
+        src = inspect.getsource(fn)
+        assert "_trace.span(" not in src and "_stepprof" not in src
+    stepprof.enable()
+    eng = model.serve(max_slots=2)
+    try:
+        _drain(eng)
+        fr, = [e["fractions"]
+               for e in stepprof.section()["engines"].values()]
+        assert {"schedule", "admit", "dispatch", "device", "sync",
+                "emit"} <= set(fr) <= set(stepprof.SEGMENTS)
+        for r in stepprof.records():
+            assert sum(r["segments"].values()) == \
+                pytest.approx(r["wall_s"], abs=1e-9)
+    finally:
+        eng.close()
+
+
 # ---------------------------------------------------------------------------
 # invisibility when on: parity + the recompile pin
 # ---------------------------------------------------------------------------
