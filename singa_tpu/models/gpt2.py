@@ -360,6 +360,13 @@ class GPT2LMHead(model.Model):
 
 
     # -- serving (round 6): iteration-level continuous batching --------
+    def served_family(self):
+        """What the serve engine calls this model's math through
+        (models/served.py)."""
+        from .gpt2_decode import FAMILY
+
+        return FAMILY
+
     def serve(self, **kw):
         """An in-process continuous-batching inference engine over this
         model's KV-cached decoder (singa_tpu.serve.InferenceEngine):

@@ -49,7 +49,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-NEG_INF = -1e30
+from ..ops.paged_attention import (NEG_INF, paged_attn as _paged_attn,
+                                   write_block as _write_block)
+from ..ops.sampling import (filter_logits as _filter_logits,
+                            sample as _sample)
+from .served import FEATURES, ServedFamily
 
 
 def extract_params(m, dtype=None):
@@ -838,86 +842,6 @@ def _advance_chunk(params, x, kc, vc, pos, n_head, eps, moe_top_k=2,
 # scale placement as _block_decode: scores scale by kscale outside the
 # int8 contraction, probabilities by vscale before the value einsum).
 
-def _paged_attn(q, pool_k_l, pool_v_l, tbl, p_limit, n_blk, block,
-                trash, k_cur, v_cur, cur_mask, scale, window=None,
-                blk_lo=None):
-    """Online-softmax attention of ``q`` (n_kv, g, Q, d) against one
-    slot's paged KV: pool lanes at positions < ``p_limit`` (blocks
-    ``tbl[0:n_blk]``; trash lanes masked) plus the current chunk's
-    keys ``k_cur``/``v_cur`` (n_kv, Q_k, d, quantized tuples on int8
-    pools) under ``cur_mask`` (Q, Q_k) — the chunk's own causal mask.
-    Accumulates in f32; returns (n_kv, g, Q, d).
-
-    ``window`` (static): sliding-window band — query i (at position
-    ``p_limit + i``) additionally masks pool lanes at positions
-    <= p_limit + i - window, matching the banded prefill/_block_decode
-    semantics on a LINEAR layout.  ``blk_lo`` (traced, default 0):
-    loop start — any value <= the first block holding an in-window
-    lane (the pool-step wrapper passes the min over live slots, so a
-    windowed long chat pays O(window / block) loop iterations instead
-    of O(pos / block); out-of-window blocks the engine already
-    dropped to the free list sit below it as trash-table entries, so
-    correctness never depends on the bound — only work does)."""
-    quant = isinstance(pool_k_l, tuple)
-    qf = q.astype(jnp.float32)
-    n_kv, g, nq, d = qf.shape
-    m0 = jnp.full((n_kv, g, nq), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((n_kv, g, nq), jnp.float32)
-    a0 = jnp.zeros((n_kv, g, nq, d), jnp.float32)
-
-    def update(carry, sc, live, vb, vsc):
-        m, l, acc = carry
-        sc = jnp.where(live, sc, NEG_INF)
-        m2 = jnp.maximum(m, jnp.max(sc, axis=-1))
-        alpha = jnp.exp(m - m2)
-        pr = jnp.exp(sc - m2[..., None])
-        # explicit zero, not just NEG_INF scores: a fully-masked block
-        # leaves m2 at NEG_INF and exp(NEG_INF - NEG_INF) would be 1
-        pr = jnp.where(live, pr, 0.0)
-        l2 = l * alpha + jnp.sum(pr, axis=-1)
-        if vsc is not None:
-            pr = pr * vsc[:, None, None, :]
-        upd = jnp.einsum("kgqb,kbd->kgqd", pr, vb.astype(jnp.float32))
-        return m2, l2, acc * alpha[..., None] + upd
-
-    def body(j, carry):
-        blk = tbl[j]
-        if quant:
-            kb, ksc = pool_k_l[0][blk], pool_k_l[1][blk]
-            vb, vsc = pool_v_l[0][blk], pool_v_l[1][blk]
-            sc = jnp.einsum("kgqd,kbd->kgqb", qf,
-                            kb.astype(jnp.float32))
-            sc = sc * ksc[:, None, None, :] * scale
-        else:
-            kb, vb, vsc = pool_k_l[blk], pool_v_l[blk], None
-            sc = jnp.einsum("kgqd,kbd->kgqb", qf,
-                            kb.astype(jnp.float32)) * scale
-        lane = j * block + jnp.arange(block)
-        live = (lane < p_limit) & (blk != trash)         # (B,)
-        if window is not None:
-            qpos = p_limit + jnp.arange(nq)              # (Q,)
-            live = (live[None, :]
-                    & (lane[None, :] > qpos[:, None] - window))
-            live = live[None, None]                      # (1,1,Q,B)
-        else:
-            live = live[None, None, None, :]
-        return update(carry, sc, live, vb, vsc)
-
-    lo = jnp.int32(0) if blk_lo is None else blk_lo
-    carry = jax.lax.fori_loop(lo, n_blk, body, (m0, l0, a0))
-    # the chunk's own keys — computed this step, not yet in the pool
-    if quant:
-        (kc, kcs), (vc, vcs) = k_cur, v_cur
-        sc = jnp.einsum("kgqd,kbd->kgqb", qf, kc.astype(jnp.float32))
-        sc = sc * kcs[:, None, None, :] * scale
-    else:
-        kc, vc, vcs = k_cur, v_cur, None
-        sc = jnp.einsum("kgqd,kbd->kgqb", qf,
-                        kc.astype(jnp.float32)) * scale
-    m, l, acc = update(carry, sc, cur_mask[None, None], vc, vcs)
-    return acc / l[..., None]
-
-
 def _paged_qkv(x, p, n_head, eps):
     """The pre-attention half of a decode/chunk block, shared by the
     paged kernels below: LN, projections, and the grouped-query
@@ -970,13 +894,7 @@ def _block_decode_paged(x, p, pool_k_l, pool_v_l, tbl, pos, n_blk,
     h = _ln(x, p["ln2_s"], p["ln2_b"], eps)
     x = x + _mlp(h, p, moe_top_k, tp_axis=tp_axis, tp_world=tp_world,
                  ep=ep)
-    off = pos % block
-    cur = tbl[pos // block]
-
-    def rmw(pool_l, new):
-        b = pool_l[cur]
-        start = (0, off) + (0,) * (b.ndim - 2)
-        return jax.lax.dynamic_update_slice(b, new, start)
+    rmw = partial(_write_block, tbl=tbl, pos=pos, block=block)
 
     if quant:
         kb = (rmw(pool_k_l[0], k_cur[0]), rmw(pool_k_l[1], k_cur[1]))
@@ -1169,68 +1087,6 @@ def spec_verify(t_logits, d_probs, props, key, temp, top_p, top_k,
     out = jnp.where(greedy, cands, out_s)
     a_draft = jnp.where(greedy, a_greedy, a_sampled)
     return out, a_draft.astype(jnp.int32)
-
-
-def _filter_logits(logit, temperature, top_p, top_k, use_top_p):
-    """Temperature + top-k + top-p (nucleus) filtered f32 logits —
-    exactly the tensor ``_sample(greedy=False)`` hands to
-    ``jax.random.categorical``, factored out so the speculative
-    rejection-sampling verify (:func:`spec_verify`) scores the SAME
-    post-filter distribution the direct sampler draws from (any drift
-    here is a silent distribution bug, so the code exists once)."""
-    logit = logit.astype(jnp.float32) / temperature
-    if top_k:
-        kth = jax.lax.top_k(logit, top_k)[0][-1]
-        logit = jnp.where(logit < kth, NEG_INF, logit)
-    if use_top_p:
-        order = jnp.argsort(-logit)
-        sp = jax.nn.softmax(logit[order])
-        cum = jnp.cumsum(sp)
-        # smallest prefix with mass >= top_p: drop tokens whose
-        # *preceding* cumulative mass already reached it (the top-1
-        # token is always kept)
-        keep_sorted = (cum - sp) < top_p
-        keep = jnp.zeros_like(keep_sorted).at[order].set(keep_sorted)
-        logit = jnp.where(keep, logit, NEG_INF)
-    return logit
-
-
-def _sample(logit, key, temperature, top_p, greedy, top_k, use_top_p,
-            min_p=1.0, use_min_p=False, rep_mask=None, rep_penalty=1.0,
-            mask=None):
-    """One token from a (V,) logit row.  ``greedy``/``top_k``/
-    ``use_top_p``/``use_min_p`` are static; ``temperature``/``top_p``/
-    ``min_p``/``rep_penalty`` are traced.  Filter order follows the
-    de-facto standard (HF generate): repetition penalty (a processor —
-    applies before greedy argmax too) → temperature → top-k → top-p
-    (nucleus) → min-p → categorical.
-
-    ``rep_mask`` (V,) bool marks tokens already in the sequence
-    (prompt + emitted); their logits are divided by ``rep_penalty``
-    when positive and multiplied when negative (CTRL semantics, as in
-    HF).
-
-    ``mask`` (V,) bool is the CONSTRAINED-decoding vocab mask (the
-    serve engine's grammar automaton, serve/structured.py): False
-    lanes drop to NEG_INF before greedy argmax AND before the filter
-    chain, so both modes sample only grammar-legal tokens.  None (the
-    default) and an all-True mask are bitwise no-ops — unconstrained
-    streams cannot drift."""
-    logit = logit.astype(jnp.float32)
-    if mask is not None:
-        logit = jnp.where(mask, logit, NEG_INF)
-    if rep_mask is not None:
-        pen = jnp.where(logit > 0, logit / rep_penalty,
-                        logit * rep_penalty)
-        logit = jnp.where(rep_mask, pen, logit)
-    if greedy:
-        return jnp.argmax(logit).astype(jnp.int32)
-    logit = _filter_logits(logit, temperature, top_p, top_k, use_top_p)
-    if use_min_p:
-        # keep p >= min_p·p_max  ⇔  logit >= max + ln(min_p)
-        logit = jnp.where(logit < jnp.max(logit) + jnp.log(min_p),
-                          NEG_INF, logit)
-    return jax.random.categorical(key, logit).astype(jnp.int32)
 
 
 def _rep_mask_init(ids, live, vocab):
@@ -1946,3 +1802,73 @@ def generate_speculative(target, draft, prompt_ids, max_new_tokens=20,
     outs = [np.concatenate([r, out[i, :max_new_tokens]]).astype(np.int32)
             for i, r in enumerate(rows)]
     return (outs[0] if single else outs), stats
+
+
+# -- the family the serve engine is handed (models/served.py) --------------
+
+class _GPT2Family(ServedFamily):
+    """GPT-2 behind the served-model contract: learned positions,
+    K/V as the only per-sequence state, every engine feature."""
+
+    name = "gpt2"
+    features = FEATURES
+
+    def extract_params(self, model, dtype=None):
+        return extract_params(model, dtype=dtype)
+
+    def kv_geometry(self, cfg):
+        return cfg.n_layer, cfg.n_kv_head, cfg.n_embd // cfg.n_head
+
+    def window(self, cfg):
+        return _norm_window(cfg)
+
+    def quant_flag(self, cache_dtype):
+        return _quant_flag(cache_dtype)
+
+    def chunk_row(self, params, ids, kc_row, vc_row, state, off, n_valid,
+                  *, chunk, n_head, eps, moe_top_k=2, window=None,
+                  tp_axis=None, tp_world=1, ep=None):
+        toks = jax.lax.dynamic_slice(ids, (0, off), (1, chunk))
+        pos = off + jnp.arange(chunk)
+        x = jnp.take(params["wte"], toks[0], axis=0)[None] + \
+            jnp.take(params["wpe"], pos, axis=0)[None]
+        hidden, kc_row, vc_row = prefill_chunk(
+            params, x, kc_row, vc_row, off, n_head, eps,
+            moe_top_k=moe_top_k, window=window, tp_axis=tp_axis,
+            tp_world=tp_world, ep=ep)
+        return hidden, kc_row, vc_row, None
+
+    def decode_step(self, params, pool_k, pool_v, state, slots, tables,
+                    toks, pos, live, n_blk, *, block, trash, n_head,
+                    eps, moe_top_k=2, window=None, blk_lo=None,
+                    tp_axis=None, tp_world=1):
+        """Per lane: online-softmax attention over its live blocks plus
+        the step's own K/V, and the read-modified block containing
+        ``pos`` handed back; then one scatter of those blocks (dead
+        lanes write the trash block)."""
+
+        def row(tbl, tok, pos_r, live_r):
+            p_c = jnp.where(live_r, pos_r, 0)
+            t_c = jnp.where(live_r, tok, 0)
+            x = (params["wte"][t_c] + params["wpe"][p_c])[None, None, :]
+            logits, kb, vb = decode_step_paged(
+                params, x, pool_k, pool_v, tbl, p_c, n_blk, n_head,
+                eps, block=block, trash=trash, moe_top_k=moe_top_k,
+                window=window, blk_lo=blk_lo, tp_axis=tp_axis,
+                tp_world=tp_world)
+            dst = jnp.where(live_r, tbl[p_c // block], trash)
+            return logits[0], kb, vb, dst
+
+        logits, kb, vb, dst = jax.vmap(
+            row, out_axes=(0, 1, 1, 0))(tables, toks, pos, live)
+        pool_k = jax.tree.map(lambda p, b: p.at[:, dst].set(b),
+                              pool_k, kb)
+        pool_v = jax.tree.map(lambda p, b: p.at[:, dst].set(b),
+                              pool_v, vb)
+        return logits, pool_k, pool_v, None
+
+    def logits(self, params, hidden):
+        return _logits(hidden, params)
+
+
+FAMILY = _GPT2Family()

@@ -106,11 +106,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.gpt2_decode import (_advance_chunk, _advance_one,
-                                  _filter_logits, _logits, _norm_window,
-                                  _quant_flag, _sample, decode_step,
-                                  extract_params, prefill, prefill_chunk,
-                                  spec_verify)
+# the model's math comes through ``model.served_family()``
+# (models/served.py) on the paged path; ``_gpt2`` is named only by the
+# paths no other family has yet: the slot arena, whole-prompt prefill,
+# speculation, and the sharded executors' per-row twins
+from ..models import gpt2_decode as _gpt2
+from ..models.served import FEATURES
+from ..ops.sampling import filter_logits as _filter_logits
+from ..ops.sampling import sample as _sample
 from ..observe import monitor as _monitor
 from ..observe import requests as _reqs
 from ..observe import stepprof as _stepprof
@@ -128,6 +131,12 @@ from .request import (DeadlineExceededError, EngineFailedError,
                       RequestHandle)
 from .scheduler import FIFOScheduler, PriorityScheduler
 from .stats import EngineStats
+
+
+def _default_family():
+    """The family of a paged program called with none: the sharded
+    executors' twins (tp/ep/pp), which serve GPT-2 alone."""
+    return _gpt2.FAMILY
 
 
 def _select_sample(logit, key, temp, top_k, top_p, use_top_p,
@@ -164,7 +173,7 @@ def _decode_row(params, kc_r, vc_r, tok, pos_r, live_r, key, temp,
     p_c = jnp.where(live_r, pos_r, 0)
     t_c = jnp.where(live_r, tok, 0)
     x = (params["wte"][t_c] + params["wpe"][p_c])[None, None, :]
-    logits, kc2, vc2 = decode_step(
+    logits, kc2, vc2 = _gpt2.decode_step(
         params, x, jax.tree.map(lambda a: a[:, None], kc_r),
         jax.tree.map(lambda a: a[:, None], vc_r), p_c, n_head, eps,
         moe_top_k=moe_top_k, tp_axis=tp_axis, tp_world=tp_world,
@@ -229,14 +238,14 @@ def _prefill_one(params, ids, prompt_len, key, temp, top_p, n_head,
     (``rolling=False`` — the paged engine's block tables address
     positions directly; the offline rolling layout would scramble
     them)."""
-    hidden, kc, vc = prefill(params, ids, n_head, eps,
+    hidden, kc, vc = _gpt2.prefill(params, ids, n_head, eps,
                              moe_top_k=moe_top_k, quant_cache=quant,
                              window=window, rolling=False,
                              tp_axis=tp_axis, tp_world=tp_world,
                              ep=ep)
     last_h = jax.lax.dynamic_index_in_dim(
         hidden, prompt_len - 1, axis=1, keepdims=False)      # (1, E)
-    logit0 = _logits(last_h[:, None, :], params)[0, 0]       # (V,)
+    logit0 = _gpt2._logits(last_h[:, None, :], params)[0, 0]  # (V,)
     ks = jax.random.split(key)
     tok0 = _select_sample(logit0, ks[0], temp, top_k, top_p, use_top_p,
                           mask=mask)
@@ -285,46 +294,53 @@ def _prefill_rows(params, ids, n_head, eps, moe_top_k, quant=False):
     admission token is always the TARGET's, sampled by ``_prefill_one``
     / the warm path — which is what keeps spec admission tokens
     byte-identical to non-speculative admission)."""
-    _, kc, vc = prefill(params, ids, n_head, eps, moe_top_k=moe_top_k,
-                        quant_cache=quant)
+    _, kc, vc = _gpt2.prefill(params, ids, n_head, eps,
+                              moe_top_k=moe_top_k, quant_cache=quant)
     return kc, vc
 
 
 @partial(jax.jit,
          static_argnames=("n_head", "eps", "moe_top_k", "chunk",
-                          "window", "tp_axis", "tp_world"),
+                          "window", "tp_axis", "tp_world", "fam"),
          donate_argnums=(2, 3))
-def _chunk_row(params, ids, kc_row, vc_row, off, n_head, eps,
-               moe_top_k, chunk, window=None, tp_axis=None,
-               tp_world=1, ep=None):
-    """Offset prefill of ONE block-width window: embed tokens at
-    positions [off, off+chunk) of the padded ``ids`` row and advance
-    them through ``gpt2_decode.prefill_chunk`` against a cache row
-    that already holds canonical K/V below ``off``.  ``off`` is
-    traced, so every warm admission's every window rides one
-    executable.  Returns ((1, chunk, E) final-LN hidden, kc_row,
-    vc_row) — rows donated, the warm-admission loop rebinds."""
-    toks = jax.lax.dynamic_slice(ids, (0, off), (1, chunk))
-    pos = off + jnp.arange(chunk)
-    x = jnp.take(params["wte"], toks[0], axis=0)[None] + \
-        jnp.take(params["wpe"], pos, axis=0)[None]
-    return prefill_chunk(params, x, kc_row, vc_row, off, n_head, eps,
-                         moe_top_k=moe_top_k, window=window,
-                         tp_axis=tp_axis, tp_world=tp_world, ep=ep)
+def _chunk_row(params, ids, kc_row, vc_row, off, state=None,
+               n_valid=None, *, n_head, eps, moe_top_k, chunk,
+               window=None, tp_axis=None, tp_world=1, ep=None, fam=None):
+    """Offset prefill of ONE block-width window through the family's
+    ``chunk_row`` (models/served.py): tokens at positions
+    [off, off+chunk) of the padded ``ids`` row, advanced against a
+    cache row that already holds canonical K/V below ``off`` and the
+    per-slot ``state`` the row before left (None for a family that
+    keeps none; with it ``n_valid``, how many of the window's tokens
+    are the prompt's and not padding).  ``off`` is traced, so every
+    admission's every window rides one executable.  Returns ((1, chunk,
+    E) final-norm hidden, kc_row, vc_row[, state]) — rows donated, the
+    admission loop rebinds.  ``fam=None`` is the GPT-2 family: the
+    sharded executors (tp/ep/pp) wrap this function and predate the
+    contract."""
+    fam = fam or _default_family()
+    hidden, kc_row, vc_row, state = fam.chunk_row(
+        params, ids, kc_row, vc_row, state, off, n_valid, chunk=chunk,
+        n_head=n_head, eps=eps, moe_top_k=moe_top_k, window=window,
+        tp_axis=tp_axis, tp_world=tp_world, ep=ep)
+    if state is None:
+        return hidden, kc_row, vc_row
+    return hidden, kc_row, vc_row, state
 
 
-@partial(jax.jit, static_argnames=("top_k", "use_top_p"))
+@partial(jax.jit, static_argnames=("top_k", "use_top_p", "fam"))
 def _first_from_hidden(params, hidden, row, key, temp, top_p, top_k,
-                       use_top_p, mask=None):
+                       use_top_p, mask=None, fam=None):
     """Sample the admission token from a chunk's hidden block: row
     ``row`` of ``hidden`` (1, chunk, E) is position prompt_len-1.
     Mirrors the tail of ``_prefill_one`` exactly — same (1, 1, E)
     logits projection, same key split, same ``_select_sample`` — so a
     warm admission's first token matches the cold path's bit for bit
     given a bitwise-equal hidden row."""
+    fam = fam or _default_family()
     last_h = jax.lax.dynamic_index_in_dim(hidden, row, axis=1,
                                           keepdims=False)     # (1, E)
-    logit0 = _logits(last_h[:, None, :], params)[0, 0]        # (V,)
+    logit0 = fam.logits(params, last_h[:, None, :])[0, 0]     # (V,)
     ks = jax.random.split(key)
     tok0 = _select_sample(logit0, ks[0], temp, top_k, top_p, use_top_p,
                           mask=mask)
@@ -357,7 +373,7 @@ def _draft_propose(d_params, dkc_r, dvc_r, t_c, p_c, k_draft, temp,
     def dstep(c, k):
         dkc_b, dvc_b, tok_, dpos = c
         x = (d_params["wte"][tok_] + d_params["wpe"][dpos])[None, None]
-        lg, dkc_b, dvc_b = _advance_one(d_params, x, dkc_b, dvc_b,
+        lg, dkc_b, dvc_b = _gpt2._advance_one(d_params, x, dkc_b, dvc_b,
                                         dpos, dn, de, moe_top_k=dm)
         # post-filter draft distribution (the q of the accept
         # ratio) AND the proposal drawn from it — the identical
@@ -404,11 +420,11 @@ def _spec_row(t_params, d_params, kc_r, vc_r, dkc_r, dvc_r, tok, pos_r,
     # scan above runs replicated on every shard (same inputs → same
     # proposals bitwise), which is what keeps any draft geometry legal
     # whatever the tp width
-    lg, kc2, vc2 = _advance_chunk(t_params, xs, _batch1(kc_r),
+    lg, kc2, vc2 = _gpt2._advance_chunk(t_params, xs, _batch1(kc_r),
                                   _batch1(vc_r), p_c, tn, te,
                                   moe_top_k=tm, tp_axis=tp_axis,
                                   tp_world=tp_world, ep=ep)
-    out, a_draft = spec_verify(lg[0], d_probs, props, k_verify,
+    out, a_draft = _gpt2.spec_verify(lg[0], d_probs, props, k_verify,
                                temp, top_p, top_k, use_top_p)
     return (out, a_draft, _unbatch1(kc2), _unbatch1(vc2),
             _unbatch1(dkc_b), _unbatch1(dvc_b), k_next)
@@ -429,12 +445,10 @@ def _decode_row_paged(params, pool_k, pool_v, tbl, tok, pos_r, live_r,
     reduction-order (online softmax), which is token-identity away
     from exact argmax/CDF ties — the parity pin tests/test_paged.py
     holds the kernel to."""
-    from ..models.gpt2_decode import decode_step_paged
-
     p_c = jnp.where(live_r, pos_r, 0)
     t_c = jnp.where(live_r, tok, 0)
     x = (params["wte"][t_c] + params["wpe"][p_c])[None, None, :]
-    logits, kb, vb = decode_step_paged(
+    logits, kb, vb = _gpt2.decode_step_paged(
         params, x, pool_k, pool_v, tbl, p_c, n_blk, n_head, eps,
         block=block, trash=trash, moe_top_k=moe_top_k,
         window=window, blk_lo=blk_lo,
@@ -462,8 +476,6 @@ def _spec_row_paged(t_params, d_params, pool_k, pool_v, dkc_r, dvc_r,
     the online-softmax accumulator).  Returns the DOUBLE blocks the
     chunk wrote (kdbl/vdbl, (L, H_kv, 2B, D)-stacked); the pool step
     splits the halves and scatters them."""
-    from ..models.gpt2_decode import chunk_step_paged
-
     p_c = jnp.where(live_r, pos_r, 0)
     t_c = jnp.where(live_r, tok, 0)
     k_draft, k_verify, k_next = jax.random.split(key, 3)
@@ -475,11 +487,11 @@ def _spec_row_paged(t_params, d_params, pool_k, pool_v, dkc_r, dvc_r,
     xs = (jnp.take(t_params["wte"], chunk_toks, axis=0)
           + jnp.take(t_params["wpe"],
                      p_c + jnp.arange(spec_k), axis=0))[None]
-    lg, kdbl, vdbl = chunk_step_paged(
+    lg, kdbl, vdbl = _gpt2.chunk_step_paged(
         t_params, xs, pool_k, pool_v, tbl, p_c, n_blk, tn, te,
         block=block, trash=trash, moe_top_k=tm, window=window,
         blk_lo=blk_lo, tp_axis=tp_axis, tp_world=tp_world, ep=ep)
-    out, a_draft = spec_verify(lg[0], d_probs, props, k_verify,
+    out, a_draft = _gpt2.spec_verify(lg[0], d_probs, props, k_verify,
                                temp, top_p, top_k, use_top_p)
     return (out, a_draft, kdbl, vdbl,
             _unbatch1(dkc_b), _unbatch1(dvc_b), k_next)
@@ -554,6 +566,51 @@ def _write_slot(kc_arena, vc_arena, kc_row, vc_row, slot):
             jax.tree.map(wr, vc_arena, vc_row))
 
 
+def _on(knob):
+    """Whether a constructor knob asks for its feature (None and False
+    are off)."""
+    return knob is not None and knob is not False
+
+
+def _paged_knob(paged, name, default):
+    """One field of ``paged=`` as the constructor was handed it (a
+    PagedConfig, a kwargs dict, True; anything else is refused later,
+    where the knob is parsed)."""
+    if isinstance(paged, dict):
+        return paged.get(name, default)
+    return getattr(paged, name, default)
+
+
+def _require(fam, **asked):
+    """The one capability check: refuse, by name, each asked-for
+    feature that the model's family does not implement
+    (``ServedFamily.features``)."""
+    assert set(asked) <= FEATURES, set(asked) - FEATURES
+    for feature, on in asked.items():
+        if on and feature not in fam.features:
+            raise NotImplementedError(
+                f"the {fam.name} family does not support {feature} on "
+                f"the serve engine yet: its served-model contract "
+                f"(models/served.py) implements "
+                f"{sorted(fam.features) or 'the budgeted paged path'}"
+                f" only")
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _write_state(arena, rows, slot):
+    """Lay one slot's per-layer state ``rows`` {kind: (L, ...)} into
+    row ``slot`` of each arena {kind: (L, S+1, ...)} (traced index —
+    one executable for every slot)."""
+    return {k: a.at[:, slot].set(rows[k].astype(a.dtype))
+            for k, a in arena.items()}
+
+
+@jax.jit
+def _read_state(arena, slot):
+    """Row ``slot`` of each state arena: {kind: (L, ...)}."""
+    return {k: a[:, slot] for k, a in arena.items()}
+
+
 def _weights_sharding(params):
     """The sharding an unsharded engine's state is allocated at: the
     one device its weights are committed to, or — for a plan-sharded
@@ -605,15 +662,20 @@ class _LocalExec:
 
     def paged_decode_step(self, params, pool_k, pool_v, tables, toks,
                           pos, live, keys, temps, top_p, block,
-                          kernel="block", masks=None, with_lp=False):
+                          kernel="block", masks=None, with_lp=False,
+                          state=None, slots=None):
         name, fn = (("paged_decode_kernel", _paged_decode_kernel)
                     if kernel == "block"
                     else ("paged_decode_step", _paged_decode_step))
-        extra = ({"window": self._e._window} if kernel == "block"
-                 else {})  # gather path is refused for windowed models
+        # the gather path is refused for windowed models, and for a
+        # family with state of its own; it knows GPT-2's rows alone
+        args, extra = (masks,), {}
+        if kernel == "block":
+            args = (masks, state, slots)
+            extra = {"window": self._e._window, "fam": self._e._fam}
         return _aot_call(name, fn,
                          params, pool_k, pool_v, tables, toks, pos,
-                         live, keys, temps, top_p, masks, block=block,
+                         live, keys, temps, top_p, *args, block=block,
                          _memo=self._aot_memo,
                          _token=(name, toks.shape[0],
                                  masks is not None, with_lp),
@@ -655,9 +717,18 @@ class _LocalExec:
                               top_p, **e._statics, quant=e._quant,
                               window=e._window)
 
-    def chunk_row(self, params, ids, kc_row, vc_row, off):
-        return _chunk_row(params, ids, kc_row, vc_row, off,
-                          **self._e._chunk_statics)
+    def chunk_row(self, params, ids, kc_row, vc_row, off, state=None,
+                  n_valid=None):
+        e = self._e
+        if state is None:
+            return _chunk_row(params, ids, kc_row, vc_row, off,
+                              fam=e._fam, **e._chunk_statics)
+        # a family with state of its own names scopes inside its
+        # programs: through the AOT cache, which keeps them
+        return _aot_call("chunk_row", _chunk_row, params, ids, kc_row,
+                         vc_row, off, state, n_valid, fam=e._fam,
+                         _memo=self._aot_memo, _token="chunk_row",
+                         **e._chunk_statics)
 
     def write_slot(self, kc, vc, kc_row, vc_row, slot):
         return _write_slot(kc, vc, kc_row, vc_row, slot)
@@ -770,9 +841,9 @@ class _Prefilling:
     free list."""
 
     __slots__ = ("handle", "request", "ids_j", "kc_row", "vc_row",
-                 "hidden", "off", "last_off", "blocks", "n_shared",
-                 "nodes", "key0", "temp", "t_admit", "admitted_step",
-                 "seq")
+                 "state", "hidden", "off", "last_off", "blocks",
+                 "n_shared", "nodes", "key0", "temp", "t_admit",
+                 "admitted_step", "seq")
 
 
 class _PrefixJob:
@@ -804,8 +875,8 @@ class _Swapped:
 
     __slots__ = ("handle", "request", "emitted", "remaining",
                  "first_token_time", "admit_time", "admitted_step",
-                 "pos", "tok", "temp", "key", "image", "dkc_h",
-                 "dvc_h", "n_data", "seq", "t_preempt", "j_lo",
+                 "pos", "tok", "temp", "key", "image", "state",
+                 "dkc_h", "dvc_h", "n_data", "seq", "t_preempt", "j_lo",
                  "group", "branch", "score", "automaton", "astate")
 
     @property
@@ -866,6 +937,21 @@ class InferenceEngine:
                  draft_model=None, spec_k=None, cache_dtype=None,
                  paged=None, tp=None, ep=None, pp=None):
         cfg = model.cfg
+        # the model's math, and which of the engine's optional features
+        # it implements (models/served.py); what it does not is refused
+        # here, by name, before any state exists
+        fam = self._fam = model.served_family()
+        _require(fam, **{
+            "tp=": _on(tp), "ep=": _on(ep), "pp=": _on(pp),
+            "draft_model=": draft_model is not None,
+            "cache_dtype='int8'": cache_dtype is not None,
+            "prefix_cache=": _on(prefix_cache),
+            "the slot arena (serving without paged=)": not _on(paged),
+            "whole-prompt admission (paged= without "
+            "prefill_token_budget)": _on(paged) and _paged_knob(
+                paged, "prefill_token_budget", None) is None,
+            "the gather kernel (PagedConfig(kernel='gather'))":
+                _paged_knob(paged, "kernel", "block") == "gather"})
         # sliding-window models serve in PAGED mode only (the
         # long-context round): block tables are position-indexed, so
         # a windowed slot drops fully-out-of-window blocks back to
@@ -875,7 +961,7 @@ class InferenceEngine:
         # paged= stays refused, as does the "gather" parity kernel
         # (it materializes the whole row and would attend freed
         # blocks) — both checked below once the paged config parses.
-        self._window = _norm_window(cfg)
+        self._window = fam.window(cfg)
         if self._window is not None and (paged is None
                                          or paged is False):
             raise NotImplementedError(
@@ -931,7 +1017,7 @@ class InferenceEngine:
         # every incompatible combination is rejected HERE with a typed
         # error naming the conflict, never deep inside a jitted
         # dispatch where the failure surfaces as a shape/dtype trace
-        self._quant = _quant_flag(cache_dtype)   # bool; rejects typos
+        self._quant = fam.quant_flag(cache_dtype)  # bool; rejects typos
         self.cache_dtype = cache_dtype
         if spec_k is not None and draft_model is None:
             raise ValueError(
@@ -957,7 +1043,8 @@ class InferenceEngine:
                     f"draft n_positions ({dcfg.n_positions}) < engine "
                     f"max_len ({self.max_len}): the draft cache must "
                     "cover every arena position the target can reach")
-            if _norm_window(dcfg) is not None:
+            if draft_model.served_family().window(dcfg) \
+                    is not None:
                 raise NotImplementedError(
                     "speculative serve does not support sliding-window "
                     f"drafts (attn_window={dcfg.attn_window}); same "
@@ -1070,7 +1157,7 @@ class InferenceEngine:
         self._log = get_channel("serve")
 
         model.eval()
-        self._params = extract_params(model, dtype=dtype)
+        self._params = fam.extract_params(model, dtype=dtype)
         self._statics = dict(
             n_head=cfg.n_head, eps=float(cfg.layer_norm_eps),
             moe_top_k=int(getattr(cfg, "moe_top_k", 2) or 2),
@@ -1153,9 +1240,8 @@ class InferenceEngine:
         # or (int8 values, f32 scales) tuples for cache_dtype="int8"
         # (half the bytes per element on a cache-read-bound loop; the
         # same (values, scales) layout gpt2_decode._quantize_kv makes)
-        L, S, W = cfg.n_layer, self.max_slots, self.max_len
-        H_kv = cfg.n_kv_head
-        D = cfg.n_embd // cfg.n_head
+        S, W = self.max_slots, self.max_len
+        L, H_kv, D = fam.kv_geometry(cfg)
         cdt = self._params["wte"].dtype
 
         def _arena(L_, H_, D_, shard=True):
@@ -1209,6 +1295,20 @@ class InferenceEngine:
         else:
             self._kc = _arena(L, H_kv, D)
             self._vc = _arena(L, H_kv, D)
+        # -- per-slot state that is not K/V (models/served.py): one
+        # arena a kind, (L, S + 1, *shape) beside the pool — no
+        # position axis, so nothing to page.  Row S is the trash row
+        # dead lanes write.  A slot's row is written when its prefill
+        # finishes (from the zeroed state the chunk rows carried),
+        # advanced in place by every decode step (donated, like the
+        # pool), and saved and restored with the slot's blocks
+        self._state_spec = fam.state_spec(cfg)
+        self._state = None
+        if self._state_spec:
+            self._state = {
+                k: jnp.zeros((L, S + 1) + tuple(shape), dt,
+                             device=self._state_sh)
+                for k, (shape, dt) in self._state_spec.items()}
         # draft-side state (speculative decoding): its own params and
         # its own (cheap) KV arena, advanced in lockstep by the spec
         # pool step
@@ -1216,7 +1316,8 @@ class InferenceEngine:
         self._dkc = self._dvc = None
         if self.draft is not None:
             self.draft.eval()
-            self._d_params = extract_params(self.draft, dtype=dtype)
+            self._d_params = self.draft.served_family().extract_params(
+                self.draft, dtype=dtype)
             dcfg = self.draft.cfg
             self._d_statics = (dcfg.n_head, float(dcfg.layer_norm_eps),
                                int(getattr(dcfg, "moe_top_k", 2) or 2))
@@ -1341,6 +1442,28 @@ class InferenceEngine:
         self._prefill_seq = itertools.count()
         self._chunks_run = 0   # chunk-row dispatches (serve.schedule's arg)
         self._own_metrics = []
+        if self._state is not None:
+            reg, lbl = self.stats.registry, self.stats.engine_label
+            self._c_state_resets = reg.counter(
+                "serve.state.resets",
+                help="per-slot states zeroed for an admission",
+                engine=lbl)
+            self._c_state_snapshots = reg.counter(
+                "serve.state.snapshots",
+                help="per-slot states copied to the host with a "
+                     "preempted slot", engine=lbl)
+            self._c_state_restores = reg.counter(
+                "serve.state.restores",
+                help="per-slot states copied back for a resumed slot",
+                engine=lbl)
+            g_bytes = reg.gauge(
+                "serve.state.bytes",
+                help="device bytes of the per-slot state arenas",
+                engine=lbl)
+            g_bytes.set(sum(a.nbytes for a in self._state.values()))
+            self._own_metrics.extend([
+                self._c_state_resets, self._c_state_snapshots,
+                self._c_state_restores, g_bytes])
         if self._budget is not None:
             if self._chunk_statics is None:
                 self._chunk_statics = dict(
@@ -1536,6 +1659,7 @@ class InferenceEngine:
                     f"paged pool holds {self.paged_arena.num_blocks}; "
                     f"raise PagedConfig.num_blocks or lower "
                     f"max_new_tokens")
+        _require(self._fam, fork=request.n > 1)
         if request.n > 1 or request.structured is not None:
             what = (f"n={request.n}" if request.n > 1
                     else "structured decoding")
@@ -1686,6 +1810,7 @@ class InferenceEngine:
         self._own_metrics = []
         self._kc = self._vc = None
         self._dkc = self._dvc = None
+        self._state = None
         self._params = self._d_params = None
         self._swapped = []
         self._prefilling = {}
@@ -1777,7 +1902,9 @@ class InferenceEngine:
             ph.set(live=self.live_slots, width=width, queue_depth=qd,
                    blocks_used=(arena.blocks_used if arena is not None
                                 else 0),
-                   prefill_tokens=self.stats.prefill_tokens)
+                   prefill_tokens=self.stats.prefill_tokens,
+                   state_slots=(self.live_slots + len(self._prefilling)
+                                if self._state_spec else 0))
         pending = self.pending
         if not pending and _monitor.active():
             # drained: refresh liveness but DISARM hang detection —
@@ -2142,6 +2269,12 @@ class InferenceEngine:
                                     jnp.asarray(sel_in))
                 if masks_np is not None:
                     fkw["masks"] = jnp.asarray(masks_np[sel_in])
+                if self._state is not None:
+                    # each lane's row of the state arenas; a pad lane
+                    # reads and writes the trash row
+                    fkw.update(state=self._state, slots=jnp.asarray(
+                        np.where(sel < 0, self.max_slots, sel)
+                        .astype(np.int32)))
                 res = self._x.paged_decode_step(
                     self._params, arena.pool_k, arena.pool_v,
                     self._block_tables(list(sel)),
@@ -2160,6 +2293,10 @@ class InferenceEngine:
                 lanes = None
                 if masks_np is not None:
                     fkw["masks"] = jnp.asarray(masks_np)
+                if self._state is not None:
+                    fkw.update(state=self._state, slots=jnp.asarray(
+                        np.where(live, np.arange(self.max_slots),
+                                 self.max_slots).astype(np.int32)))
                 res = self._x.paged_decode_step(
                     self._params, arena.pool_k, arena.pool_v,
                     self._block_tables(),
@@ -2172,6 +2309,8 @@ class InferenceEngine:
                  self._keys) = res[:4]
             if need_lp:
                 lps = res[4]
+            if self._state is not None:
+                self._state = res[-1]
         else:
             next_toks, self._kc, self._vc, self._keys = \
                 self._x.pool_decode_step(
@@ -2639,6 +2778,15 @@ class InferenceEngine:
         # (serve/kvimage.py) — the same one KV shipping uses, so the
         # two host-image paths cannot drift
         sw.image = arena.swap_out(slot.blocks[sw.j_lo:], sw.n_data)
+        sw.state = None
+        if self._state is not None:
+            # the slot's row of every state arena travels with its
+            # blocks, byte for byte
+            with _trace.phase("serve.state.snapshot", cat="serve"):
+                sw.state = jax.tree.map(
+                    np.asarray,
+                    _read_state(self._state, jnp.int32(idx)))
+            self._c_state_snapshots.inc()
         sw.dkc_h = sw.dvc_h = None
         if self.draft is not None:
             dkc_row, dvc_row = _read_slot(self._dkc, self._dvc,
@@ -2692,6 +2840,13 @@ class InferenceEngine:
                 return
             idx = free[0]
             arena.swap_in(sw.image, blocks[:sw.n_data])
+            if sw.state is not None:
+                with _trace.phase("serve.state.restore", cat="serve"):
+                    self._state = _write_state(
+                        self._state,
+                        jax.tree.map(jnp.asarray, sw.state),
+                        jnp.int32(idx))
+                self._c_state_restores.inc()
             if self.draft is not None and sw.dkc_h is not None:
                 self._dkc, self._dvc = _write_slot(
                     self._dkc, self._dvc,
@@ -3094,6 +3249,7 @@ class InferenceEngine:
         ``fold_in`` of the parent's current key by the branch index);
         ``max_new_tokens`` caps the new branch's REMAINING budget
         (default: inherit the parent's)."""
+        _require(self._fam, fork=True)
         if self._closed:
             raise RuntimeError(
                 "engine is closed; build a new one with model.serve()")
@@ -3379,6 +3535,15 @@ class InferenceEngine:
         pf.request = req
         pf.ids_j = jnp.asarray(ids)
         pf.kc_row, pf.vc_row = kc_row, vc_row
+        # per-slot state starts from zero at admission, whoever held
+        # the slot before; each chunk row carries it on
+        pf.state = None
+        if self._state_spec:
+            pf.state = {
+                k: jnp.zeros((self._state[k].shape[0],) + tuple(shape),
+                             dt, device=self._state_sh)
+                for k, (shape, dt) in self._state_spec.items()}
+            self._c_state_resets.inc()
         pf.hidden = None
         pf.off = len(nodes) * B
         pf.last_off = ((plen - 1) // B) * B
@@ -3420,9 +3585,16 @@ class InferenceEngine:
                 # streamed), and _fail returns the partial blocks to
                 # the free list (RESILIENCE.md; chaos_longctx)
                 _faults.check("serve.prefill_chunk")
-            pf.hidden, pf.kc_row, pf.vc_row = self._x.chunk_row(
-                self._params, pf.ids_j, pf.kc_row, pf.vc_row,
-                jnp.int32(pf.off))
+            if pf.state is None:
+                pf.hidden, pf.kc_row, pf.vc_row = self._x.chunk_row(
+                    self._params, pf.ids_j, pf.kc_row, pf.vc_row,
+                    jnp.int32(pf.off))
+            else:
+                pf.hidden, pf.kc_row, pf.vc_row, pf.state = \
+                    self._x.chunk_row(
+                        self._params, pf.ids_j, pf.kc_row, pf.vc_row,
+                        jnp.int32(pf.off), state=pf.state,
+                        n_valid=jnp.int32(min(B, plen - pf.off)))
             self._c_budget_chunks.inc()
             self._chunks_run += 1
             # the prompt positions this chunk really covered: the last
@@ -3458,7 +3630,11 @@ class InferenceEngine:
             self._params, pf.hidden,
             jnp.int32(plen - 1 - pf.last_off), pf.key0, pf.temp,
             self._top_p, top_k=self._statics["top_k"],
-            use_top_p=self._statics["use_top_p"], mask=mask0)
+            use_top_p=self._statics["use_top_p"], mask=mask0,
+            fam=self._fam)
+        if pf.state is not None:
+            self._state = _write_state(self._state, pf.state,
+                                       jnp.int32(idx))
         lanes = {j: pf.blocks[j]
                  for j in range(pf.n_shared, plen // arena.block_size
                                 + 1)
@@ -3856,7 +4032,8 @@ class InferenceEngine:
         tok0, carry_key = _first_from_hidden(
             self._params, hidden, jnp.int32(plen - 1 - last_off),
             key0, temp, self._top_p, top_k=self._statics["top_k"],
-            use_top_p=self._statics["use_top_p"], mask=mask)
+            use_top_p=self._statics["use_top_p"], mask=mask,
+            fam=self._fam)
         return tok0, carry_key, kc_row, vc_row
 
     # -- disaggregated prefill / KV shipping (the disagg round) ----------
@@ -3872,6 +4049,7 @@ class InferenceEngine:
     # engine, and the image is a byte copy of canonical chunk KV.
 
     def _require_ship_support(self):
+        _require(self._fam, **{"KV image ship": True})
         if self._closed:
             raise RuntimeError(
                 "engine is closed; build a new one with model.serve()")

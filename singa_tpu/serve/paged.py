@@ -81,6 +81,7 @@ executables show up in Chrome traces and crash bundles exactly like
 
 from __future__ import annotations
 
+import re
 from dataclasses import dataclass
 from functools import partial
 
@@ -420,21 +421,28 @@ def _paged_spec_step(t_params, d_params, pool_k, pool_v, dkc, dvc,
 @partial(jax.jit,
          static_argnames=("block", "n_head", "eps", "moe_top_k",
                           "top_k", "use_top_p", "window", "tp_axis",
-                          "tp_world", "with_lp"),
-         donate_argnums=(1, 2))
+                          "tp_world", "with_lp", "fam"),
+         donate_argnums=(1, 2, 11))
 def _paged_decode_kernel(params, pool_k, pool_v, tables, toks, pos,
                          live, keys, temps, top_p, masks=None,
+                         state=None, slots=None,
                          block=None, n_head=None, eps=None,
                          moe_top_k=None, top_k=None, use_top_p=None,
                          window=None, tp_axis=None, tp_world=1,
-                         with_lp=False):
+                         with_lp=False, fam=None):
     """Advance EVERY slot one token against the block pool WITHOUT
-    gathering rows: per slot, online-softmax attention over its live
-    blocks (beyond-``pos`` and trash lanes masked) plus the step's
-    own K/V as the current lane, then scatter back ONLY the
-    read-modified block containing ``pos`` (dead slots write the
-    trash block).  Returns (next_toks, pool_k, pool_v, new_keys) —
-    the same contract as :func:`_paged_decode_step`.
+    gathering rows, through the family's ``decode_step``
+    (models/served.py): per slot, online-softmax attention over its
+    live blocks (beyond-``pos`` and trash lanes masked) plus the step's
+    own K/V as the current lane, the new K/V written back into the
+    block containing ``pos`` (dead slots write the trash block) — and,
+    for a family with per-slot state, row ``slots[w]`` of each
+    ``state`` arena read, advanced and written back (dead lanes: the
+    trash row).  Then each lane samples from its logits.  Returns
+    (next_toks, pool_k, pool_v, new_keys[, logprobs][, state]) — the
+    same contract as :func:`_paged_decode_step`.  ``fam=None`` is the
+    GPT-2 family (the sharded executors wrap this function and predate
+    the contract).
 
     ``window`` (static): sliding-window decode (the long-context
     round) — each slot's query additionally masks pool lanes at
@@ -445,8 +453,9 @@ def _paged_decode_kernel(params, pool_k, pool_v, tables, toks, pos,
     blocks back to the free list host-side; their table entries are
     trash by then, so the bound is a work optimization, never a
     correctness input)."""
-    from .engine import _decode_row_paged
+    from .engine import _default_family, _select_sample
 
+    fam = fam or _default_family()
     trash = jax.tree.leaves(pool_k)[0].shape[1] - 1
     p_all = jnp.where(live, pos, 0)
     n_blk = jnp.max((p_all + block - 1) // block)
@@ -454,30 +463,32 @@ def _paged_decode_kernel(params, pool_k, pool_v, tables, toks, pos,
     if window is not None:
         lo = jnp.maximum(0, (p_all - window + 1) // block)
         blk_lo = jnp.min(jnp.where(live, lo, n_blk))
+    logits, pool_k, pool_v, state = fam.decode_step(
+        params, pool_k, pool_v, state, slots, tables, toks, pos, live,
+        n_blk, block=block, trash=trash, n_head=n_head, eps=eps,
+        moe_top_k=moe_top_k, window=window, blk_lo=blk_lo,
+        tp_axis=tp_axis, tp_world=tp_world)
 
-    def row(tbl, tok, pos_r, live_r, key, temp, mask_r):
-        res = _decode_row_paged(
-            params, pool_k, pool_v, tbl, tok, pos_r, live_r, key,
-            temp, top_p, n_blk, block, trash, n_head, eps, moe_top_k,
-            top_k, use_top_p, window=window, blk_lo=blk_lo,
-            tp_axis=tp_axis, tp_world=tp_world, mask=mask_r,
-            with_lp=with_lp)
-        nxt, kb, vb, k2 = res[:4]
-        lp = res[4] if with_lp else jnp.float32(0.0)
-        p_c = jnp.where(live_r, pos_r, 0)
-        dst = jnp.where(live_r, tbl[p_c // block], trash)
-        return nxt, kb, vb, dst, k2, lp
+    def choose(logit, key, temp, mask_r):
+        ks = jax.random.split(key)
+        nxt = _select_sample(logit, ks[0], temp, top_k, top_p,
+                             use_top_p, mask=mask_r)
+        # chosen-token logprob under the RAW model distribution (the
+        # fork round's ranking signal): an output, never an input
+        lp = (jax.nn.log_softmax(logit.astype(jnp.float32))[nxt]
+              if with_lp else jnp.float32(0.0))
+        return nxt, ks[1], lp
 
-    m_ax = None if masks is None else 0
-    nxt, kb, vb, dst, keys2, lps = jax.vmap(
-        row, in_axes=(0, 0, 0, 0, 0, 0, m_ax),
-        out_axes=(0, 1, 1, 0, 0, 0))(tables, toks, pos, live, keys,
-                                     temps, masks)
-    pool_k = jax.tree.map(lambda p, b: p.at[:, dst].set(b), pool_k, kb)
-    pool_v = jax.tree.map(lambda p, b: p.at[:, dst].set(b), pool_v, vb)
+    with jax.named_scope("sample"):
+        nxt, keys2, lps = jax.vmap(
+            choose, in_axes=(0, 0, 0, None if masks is None else 0))(
+                logits, keys, temps, masks)
+    out = (nxt, pool_k, pool_v, keys2)
     if with_lp:
-        return nxt, pool_k, pool_v, keys2, lps
-    return nxt, pool_k, pool_v, keys2
+        out += (lps,)
+    if state is not None:
+        out += (state,)
+    return out
 
 
 @partial(jax.jit,
@@ -560,6 +571,33 @@ def _paged_cost_tables():
     return list(_aot_costs)
 
 
+_aot_scopes = {}         # program name -> {"<instruction> <result>": scope}
+_HLO_LINE = re.compile(
+    r'^\s*(?:ROOT )?%([\w.\-]+) = \(?(\w+\[[\d,]*\])[^\n]*'
+    r'op_name="([^"]*)"', re.M)
+
+
+def _keep_scopes(name, scopes, hlo_text):
+    """Which instruction of a compiled program lies under which of the
+    family's ``jax.named_scope`` names (``ServedFamily.scopes``), keyed
+    by the instruction's name and result as a device trace prints them:
+    a trace names operations, not scopes, so this is what lets a reader
+    split a program's device time by mixer."""
+    found = _aot_scopes.setdefault(name, {})
+    for inst, result, op_name in _HLO_LINE.findall(hlo_text):
+        parts = op_name.split("/")
+        # the innermost of the family's scopes on the path
+        scope = next((p for p in reversed(parts) if p in scopes), None)
+        if scope is not None:
+            found[f"{inst} {result}"] = scope
+
+
+def program_scopes():
+    """{program name: {"<instruction> <result>": scope}} of the paged
+    programs compiled so far for families that name scopes."""
+    return {k: dict(v) for k, v in _aot_scopes.items()}
+
+
 _monitor.register_cost_source(_paged_cost_tables)
 
 
@@ -596,6 +634,9 @@ def _aot_call(name, fn, *args, _memo=None, _token=None, **statics):
             _aot_costs.append(
                 {"key": f"serve.paged/{name}", "cost": scalars})
             sp.set(**scalars)
+            scopes = getattr(statics.get("fam"), "scopes", ())
+            if scopes:
+                _keep_scopes(name, scopes, entry.as_text())
         _aot_cache[key] = entry
     return entry(*args)
 

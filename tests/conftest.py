@@ -49,3 +49,22 @@ def _bound_jax_executable_maps():
     unaffected."""
     yield
     jax.clear_caches()
+
+
+# tests/benchmark/test_bench_loader.py::test_cell_loads_with_all_its_files
+# asserts, for every cell of BENCHMARK.json, that its configuration's
+# family is "gpt2".  PR 27 added the first cell of another family
+# (falconh1-serve-docqa), and a model_config PR may edit no file the
+# benchmark already has; tests/benchmark/test_bench_falcon_h1.py makes the
+# test's other assertions for that cell.  Take this hook out with that
+# line, in the next benchmark PR (PERF.md, Open questions).
+_OTHER_FAMILY = ("test_bench_loader.py::test_cell_loads_with_all_its_files"
+                 "[falconh1-serve-docqa]")
+
+
+def pytest_collection_modifyitems(items):
+    for item in items:
+        if item.nodeid.endswith(_OTHER_FAMILY):
+            item.add_marker(pytest.mark.xfail(
+                strict=True, reason="asserts family == 'gpt2' of every "
+                                    "cell; this one is falcon_h1"))
