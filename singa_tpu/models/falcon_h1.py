@@ -34,7 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import autograd, model
-from ..ops.paged_attention import paged_attn, rotary, write_rows
+from ..ops.paged_attention import (paged_attn, rotary, row_to_blocks,
+                                   write_rows)
 from ..tensor import Tensor
 from .served import ServedFamily
 
@@ -166,6 +167,12 @@ def _qkv(h, p, c):
                                                    c.head_dim)
     v = (u @ p["wv"]).reshape(t, c.n_kv_head, c.head_dim)
     return q, k, v
+
+
+def _rows(x):
+    """Keys or values by head (KV, T, D) as the pool stores them: a
+    token a row, (T, KV·D)."""
+    return x.transpose(1, 0, 2).reshape(x.shape[1], -1)
 
 
 def _mixer_inputs(h, p, c):
@@ -398,14 +405,9 @@ class FalconH1Family(ServedFamily):
              ).astype(params["wte"].dtype)
         n_l, _, n_kv, width, d = kc_row.shape
         g = c.n_head // n_kv
-        nb = width // chunk
-
-        def blocks(row):         # the row as (L, W/B, H_kv, B, D) blocks
-            return row.reshape(n_l, n_kv, nb, chunk, d).transpose(
-                0, 2, 1, 3, 4)
-
-        kb, vb = blocks(kc_row), blocks(vc_row)    # what lies below off
-        tbl = jnp.arange(nb)
+        # what lies below off, as the blocks of a pool
+        kb, vb = row_to_blocks(kc_row, chunk), row_to_blocks(vc_row, chunk)
+        tbl = jnp.arange(width // chunk)
         cur = jnp.tril(jnp.ones((chunk, chunk), bool))
 
         def layer(carry, lp):
@@ -418,9 +420,9 @@ class FalconH1Family(ServedFamily):
                 k = rotary(k.transpose(1, 0, 2), pos, c.rope_theta)
                 v = v.transpose(1, 0, 2)                    # (KV, T, D)
                 a = paged_attn(
-                    q.reshape(n_kv, g, chunk, d), kb, vb, tbl, off,
-                    off // chunk, chunk, -1, k, v, cur,
-                    1.0 / math.sqrt(d), layer=li)
+                    q.reshape(n_kv, g, chunk, d), kb, vb, li, tbl, off,
+                    off // chunk, chunk, -1, _rows(k), _rows(v), cur,
+                    1.0 / math.sqrt(d))
                 a = a.transpose(2, 0, 1, 3).reshape(chunk, -1)
                 att = c.attention_out_multiplier * (
                     a.astype(x.dtype) @ p["wo"])
@@ -472,19 +474,20 @@ class FalconH1Family(ServedFamily):
                     at = pos_r[None]
                     q_r = rotary(q_r[:, None], at, c.rope_theta)
                     k_r = rotary(k_r[:, None], at, c.rope_theta)
+                    k_r = _rows(k_r)
                     a = paged_attn(
-                        q_r.reshape(n_kv, g, 1, d), pool_k, pool_v, tbl,
-                        pos_r, n_blk, block, trash, k_r, v_r[:, None],
-                        one, 1.0 / math.sqrt(d), layer=li)
-                    return a.reshape(-1), k_r[:, 0]
+                        q_r.reshape(n_kv, g, 1, d), pool_k, pool_v, li,
+                        tbl, pos_r, n_blk, block, trash, k_r,
+                        v_r.reshape(1, -1), one, 1.0 / math.sqrt(d))
+                    return a.reshape(-1), k_r
 
                 a, k = jax.vmap(lane)(q, k, v, tables, p_c)
                 att = c.attention_out_multiplier * (
                     a.astype(x.dtype) @ p["wo"])
                 pool_k = write_rows(pool_k, li, k, tables, p_c, live,
                                     block, trash)
-                pool_v = write_rows(pool_v, li, v, tables, p_c, live,
-                                    block, trash)
+                pool_v = write_rows(pool_v, li, v.reshape(len(v), 1, -1),
+                                    tables, p_c, live, block, trash)
             with jax.named_scope("ssm_proj"):
                 m, ssm, conv = _mamba_step(h, p, c, ssm, conv, li, slots)
             x = x + att + m.astype(x.dtype)

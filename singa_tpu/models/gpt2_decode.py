@@ -38,6 +38,11 @@ against its query group without materializing a repeat
 cache — position p lives in slot p % W — and the int8 cache
 (``cache_dtype="int8"``) stores (values, scales) tuples with the
 scales folded into the score/prob contractions; all of these compose.
+
+Every walk over the layers here is :func:`_over_layers`: a dense model's
+blocks arrive stacked on a layer axis (``extract_params``) and the walk
+is one ``lax.scan`` of the layer body; a mixed dense/MoE stack arrives
+as a list and unrolls.
 """
 
 from __future__ import annotations
@@ -50,7 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.paged_attention import (NEG_INF, paged_attn as _paged_attn,
-                                   write_block as _write_block)
+                                   write_rows as _write_rows)
 from ..ops.sampling import (filter_logits as _filter_logits,
                             sample as _sample)
 from .served import FEATURES, ServedFamily
@@ -76,6 +81,18 @@ def extract_params(m, dtype=None):
     gets its chosen experts).  Token-parity with the windowed sampler
     therefore holds exactly when the windowed forward drops nothing —
     the regime its capacity_factor is tuned for.
+
+    LAYERS: where every block holds the same kinds of weights at the
+    same shapes (any dense model; an MoE model whose every block routes)
+    and no plan lays them out, ``params["blocks"]`` is ONE dict of
+    stacked ``(L, ...)`` arrays, and every function here that walks the
+    layers scans one layer body over it (:func:`_over_layers`): a
+    36-layer program compiles as one layer does, and on the device it is
+    one loop, not 36 layers' worth of separate operations.  The stack is
+    a copy of the blocks' weights (the cast to ``dtype`` is one anyway;
+    uncast float32 serving holds the model's tensors and the stack).
+    Mixed dense/MoE stacks and plan-sharded models keep the list of
+    per-layer dicts, and the same functions unroll over it.
 
     SESSION CACHE (round 5): the extracted (cast, plan-laid-out)
     pytree is cached on the model, keyed by ``dtype``/plan and the
@@ -122,19 +139,85 @@ def extract_params(m, dtype=None):
                 f"{type(mlp).__name__}")
         blocks.append(common)
     head = None if m.cfg.tie_weights else m.lm_head.W.data
-    params = dict(wte=t.wte.W.data, wpe=t.wpe.W.data, blocks=blocks,
-                  lnf_s=t.ln_f.scale.data, lnf_b=t.ln_f.bias.data,
-                  head=head)
-    if dtype is not None:
-        params = jax.tree.map(
+    def cast(tree):
+        if dtype is None:
+            return tree
+        return jax.tree.map(
             lambda a: a.astype(dtype)
-            if jnp.issubdtype(a.dtype, jnp.floating) else a, params)
+            if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
+
+    params = cast(dict(wte=t.wte.W.data, wpe=t.wpe.W.data,
+                       lnf_s=t.ln_f.scale.data, lnf_b=t.ln_f.bias.data,
+                       head=head))
     if m.plan is not None:
-        params = _shard_params(m, params)
+        params = _shard_params(m, dict(params, blocks=cast(blocks)))
+    else:
+        params["blocks"] = _stack_blocks(blocks, cast)
     # the strong refs to the keyed buffers make the id() signature
     # sound: while this entry lives, no new array can recycle their ids
     m._decode_param_cache = (sig, bufs, params)
     return params
+
+
+def _stack_blocks(blocks, cast):
+    """The per-layer dicts, ``cast``, as one dict of stacked (L, ...)
+    arrays where the layers are alike (same keys, shapes and dtypes);
+    else (a mixed dense/MoE stack) the list of them.  A kind of weight
+    at a time, so that what is alive beside the stack is one kind's
+    casts, not a second copy of all the weights."""
+    kinds = {tuple(sorted((k, v.shape, str(v.dtype)) for k, v in b.items()))
+             for b in blocks}
+    if len(kinds) != 1:
+        return cast(blocks)
+    return {k: jnp.stack(cast([b[k] for b in blocks])) for k in blocks[0]}
+
+
+def _n_layers(params):
+    blocks = params["blocks"]
+    if isinstance(blocks, dict):
+        return len(next(iter(blocks.values())))
+    return len(blocks)
+
+
+def _layers(params):
+    """The per-layer weight dicts, for code that unrolls the layers
+    whatever form ``params["blocks"]`` has."""
+    blocks = params["blocks"]
+    if isinstance(blocks, dict):
+        return [{k: v[i] for k, v in blocks.items()}
+                for i in range(_n_layers(params))]
+    return blocks
+
+
+def _over_layers(params, body, carry, xs=None):
+    """``carry, y = body(carry, li, p, x)`` for every layer in turn:
+    ``p`` the layer's weights, ``x`` the layer's slice of ``xs`` (a
+    pytree of (L, ...) arrays, or None), ``li`` its index.  Returns
+    ``(carry, ys)``, the ``y`` stacked on a leading layer axis (None
+    where ``body`` returns none).  Stacked blocks (``extract_params``)
+    run as ONE ``lax.scan`` of the layer body (``li`` traced);
+    a list of per-layer dicts unrolls (``li`` a Python int)."""
+    blocks = params["blocks"]
+    if isinstance(blocks, dict):
+        def step(c, lx):
+            # what an expert-parallel MoE layer records of its routing
+            # belongs to this trace: out with the ys, summed after
+            with _ep_collecting() as rec:
+                c, y = body(c, *lx)
+            return c, (y, _ep_lane_stats(rec, True))
+
+        carry, (ys, stats) = jax.lax.scan(
+            step, carry, (jnp.arange(_n_layers(params)), blocks, xs))
+        _ep_record_lanes(stats)
+        return carry, ys
+    ys = []
+    for li, p in enumerate(blocks):
+        carry, y = body(carry, li, p,
+                        jax.tree.map(lambda a: a[li], xs))
+        ys.append(y)
+    if ys[0] is None:
+        return carry, None
+    return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
 
 
 def _shard_params(m, params):
@@ -504,6 +587,26 @@ def _ep_record(counts, dropped):
         stack[-1].append((counts, dropped))
 
 
+def _ep_lane_stats(rec, live):
+    """What one lane's trace collected under :class:`_ep_collecting`,
+    summed over its MoE layers and zeroed for a dead lane (it runs
+    clamped garbage through the router and must not pollute the load
+    counters); ``()`` where nothing routed under ``ep``.  A lane is
+    traced under ``jax.vmap``: its tracers must leave as outputs of the
+    lane, and :func:`_ep_record_lanes` hands their sum on."""
+    if not rec:
+        return ()
+    return (jnp.where(live, sum(c for c, _ in rec), 0),
+            jnp.where(live, sum(d for _, d in rec), 0))
+
+
+def _ep_record_lanes(stats):
+    """Record the lanes' :func:`_ep_lane_stats` (stacked by the vmap)
+    with the collector around the whole program."""
+    if stats:
+        _ep_record(stats[0].sum(0), stats[1].sum())
+
+
 def _moe_ffn_ep(h, p, top_k, ep):
     """Expert-parallel MoE FFN: ``ep = (axis, world, cap_factor)`` —
     the mesh axis the stacked expert weights shard over, its size, and
@@ -620,8 +723,7 @@ def prefill(params, ids, n_head, eps, start=None, moe_top_k=2,
         pe_ = (sp if prompt_end is None else prompt_end) - 1
         w = jnp.arange(window)
         roll = jnp.clip(pe_ - ((pe_ - w) % window), 0, sp - 1)
-    ks, vs = [], []
-    for p in params["blocks"]:
+    def layer(x, li, p, _):
         x, k, v = _block_prefill(x, p, n_head, eps, start=start,
                                  moe_top_k=moe_top_k, window=window,
                                  tp_axis=tp_axis, tp_world=tp_world,
@@ -636,10 +738,11 @@ def prefill(params, ids, n_head, eps, start=None, moe_top_k=2,
             vh = jnp.take(vh, roll, axis=2)
         if quant_cache:
             kh, vh = _quantize_kv(kh), _quantize_kv(vh)
-        ks.append(kh)
-        vs.append(vh)
+        return x, (kh, vh)
+
+    x, (kc, vc) = _over_layers(params, layer, x)
     x = _ln(x, params["lnf_s"], params["lnf_b"], eps)
-    return x, _cache_stack(ks), _cache_stack(vs)
+    return x, kc, vc
 
 
 def _advance_one(params, x, kc, vc, pos, n_head, eps, start=None,
@@ -650,17 +753,14 @@ def _advance_one(params, x, kc, vc, pos, n_head, eps, start=None,
     ((B, V) logits, new kc, new vc).  Shared by sampling
     (_generate_row), the left-padded ragged path, and beam search so
     the paths cannot drift."""
-    new_kc, new_vc = [], []
-    for li, p in enumerate(params["blocks"]):
-        x, kl, vl = _block_decode(x, p, _cache_layer(kc, li),
-                                  _cache_layer(vc, li), pos, n_head,
-                                  eps, start=start, moe_top_k=moe_top_k,
+    def layer(x, li, p, caches):
+        x, kl, vl = _block_decode(x, p, *caches, pos, n_head, eps,
+                                  start=start, moe_top_k=moe_top_k,
                                   window=window, tp_axis=tp_axis,
                                   tp_world=tp_world, ep=ep)
-        new_kc.append(kl)
-        new_vc.append(vl)
-    kc = _cache_stack(new_kc)
-    vc = _cache_stack(new_vc)
+        return x, (kl, vl)
+
+    x, (kc, vc) = _over_layers(params, layer, x, (kc, vc))
     x = _ln(x, params["lnf_s"], params["lnf_b"], eps)
     return _logits(x, params)[:, 0], kc, vc
 
@@ -788,18 +888,16 @@ def prefill_chunk(params, x, kc, vc, pos, n_head, eps, *, moe_top_k=2,
     full ``prefill``'s attend float ones, which is why int8 engines
     with a prefix cache route every admission (cold included) through
     the chunked path (engine._admit)."""
-    new_kc, new_vc = [], []
-    for li, p in enumerate(params["blocks"]):
-        x, kl, vl = _block_chunk(x, p, _cache_layer(kc, li),
-                                 _cache_layer(vc, li), pos, n_head,
-                                 eps, moe_top_k=moe_top_k,
-                                 window=window,
+    def layer(x, li, p, caches):
+        x, kl, vl = _block_chunk(x, p, *caches, pos, n_head, eps,
+                                 moe_top_k=moe_top_k, window=window,
                                  tp_axis=tp_axis, tp_world=tp_world,
                                  ep=ep)
-        new_kc.append(kl)
-        new_vc.append(vl)
+        return x, (kl, vl)
+
+    x, (kc, vc) = _over_layers(params, layer, x, (kc, vc))
     x = _ln(x, params["lnf_s"], params["lnf_b"], eps)
-    return x, _cache_stack(new_kc), _cache_stack(new_vc)
+    return x, kc, vc
 
 
 def _advance_chunk(params, x, kc, vc, pos, n_head, eps, moe_top_k=2,
@@ -829,6 +927,15 @@ def _advance_chunk(params, x, kc, vc, pos, n_head, eps, moe_top_k=2,
 # O(block_size) and the loop runs ``ceil(pos / block)`` iterations, so
 # long-context slots stop paying for their own padding.
 #
+# The lanes of a step go through each layer together — (W, Q, E)
+# through the projections and the MLP — and only the attention runs per
+# lane (``jax.vmap`` over ``paged_attn``); each layer then writes the
+# lanes' new rows into the whole pool it carries (``write_rows``: whole
+# blocks read, changed and scattered back at ``pool.at[layer, dst]``).
+# No layer is sliced out of the pool and no block leaves the layer that
+# wrote it, which is what lets the compiler update the donated pool
+# where it lies (ops/paged_attention.py; tests/test_tpu_compile.py).
+#
 # Parity pins (docs/SERVING.md "Paged KV and preemption"): online
 # softmax REORDERS the float reduction, so bitwise equality to the
 # row-softmax gather path is impossible by construction — the contract
@@ -837,176 +944,130 @@ def _advance_chunk(params, x, kc, vc, pos, n_head, eps, moe_top_k=2,
 # the same caveat TP serving documents for its psum, and (b) per-step
 # logits allclose to the gather oracle (tests/test_paged.py pins both,
 # plus byte equality of the untouched lanes of every written block —
-# the read-modify-write below keeps pool bytes round-tripping).  int8
+# the read-modify-write keeps pool bytes round-tripping).  int8
 # pools dequantize PER BLOCK inside the accumulator (the same folded
 # scale placement as _block_decode: scores scale by kscale outside the
 # int8 contraction, probabilities by vscale before the value einsum).
 
-def _paged_qkv(x, p, n_head, eps):
-    """The pre-attention half of a decode/chunk block, shared by the
-    paged kernels below: LN, projections, and the grouped-query
-    reshape.  x (1, Q, E) -> (q (n_kv, g, Q, d), k/v (n_kv, Q, d))
-    with n_kv the LOCAL kv-head count read off the weight widths
-    (which is also why no tp_world is needed here — shard-local
-    widths carry the layout)."""
-    _, nq, e = x.shape
-    d = e // n_head
+def _mlp_lanes(h, p, moe_top_k, live, tp_axis, tp_world, ep):
+    """The feed-forward of lane-batched activations ``h`` (W, Q, E).
+    Dense and capacity-free MoE blocks treat every token alone, so the
+    batch goes through as one.  Under expert parallelism a dispatch's
+    tokens share the experts' capacity, and a lane's chunk is the
+    routing group it has always been: each lane routes on its own, and
+    a dead lane's garbage stays out of the load counters."""
+    if ep is None or "moe_wg" not in p:
+        return _mlp(h, p, moe_top_k, tp_axis=tp_axis, tp_world=tp_world,
+                    ep=ep)
+
+    def lane(h_r, live_r):
+        with _ep_collecting() as rec:
+            y = _moe_ffn_ep(h_r[None], p, moe_top_k, ep)[0]
+        return y, _ep_lane_stats(rec, live_r)
+
+    y, stats = jax.vmap(lane)(h, live)
+    _ep_record_lanes(stats)
+    return y
+
+
+def _block_paged(x, pool_k, pool_v, p, li, tables, pos, live, n_blk,
+                 n_head, eps, block, trash, moe_top_k=2, window=None,
+                 blk_lo=None, tp_axis=None, tp_world=1, ep=None):
+    """Layer ``li``'s block-native step for every lane: x (W, Q, E) at
+    positions ``pos[w]..pos[w]+Q-1`` (Q = 1: a decode step; Q = K: a
+    speculative verify chunk), the whole pools ((L, N+1, B, H_kv·D)
+    dense or (values, scales)), ``tables`` (W, W//B) the lanes'
+    trash-padded block tables.  Pool lanes < ``pos`` are visible to
+    every query; the chunk's own keys are causal within the chunk — the
+    same mask structure _block_chunk applies to its materialized row.
+    The attention never materializes a row: O(block_size) workspace,
+    ``n_blk`` loop iterations (trash / beyond-``pos`` lanes masked).
+    Returns (x, pool_k, pool_v) with the lanes' new K/V rows written
+    (dead lanes: into the trash block; every other row of a touched
+    block a byte copy)."""
+    quant = isinstance(pool_k, tuple)
+    w, nq, e = x.shape
+    d = e // n_head        # full head dim: x is replicated under TP
     h = _ln(x, p["ln1_s"], p["ln1_b"], eps)
     q = h @ p["wq"] + p["bq"]
-    k = h @ p["wk"] + p["bk"]
-    v = h @ p["wv"] + p["bv"]
-    n_kv = k.shape[-1] // d
+    k_cur = h @ p["wk"] + p["bk"]          # (W, Q, n_kv·d): pool rows
+    v_cur = h @ p["wv"] + p["bv"]
+    # n_kv is the LOCAL kv-head count, read off the weight widths
+    # (shard-local widths carry the layout; no tp_world needed)
+    n_kv = k_cur.shape[-1] // d
     g = q.shape[-1] // (n_kv * d)
-    q = q.reshape(nq, n_kv, g, d).transpose(1, 2, 0, 3)
-    k = k.reshape(nq, n_kv, d).transpose(1, 0, 2)
-    v = v.reshape(nq, n_kv, d).transpose(1, 0, 2)
-    return q, k, v
-
-
-def _block_decode_paged(x, p, pool_k_l, pool_v_l, tbl, pos, n_blk,
-                        n_head, eps, block, trash, moe_top_k=2,
-                        window=None, blk_lo=None, tp_axis=None,
-                        tp_world=1, ep=None):
-    """One layer's block-native decode step: x (1, 1, E) at position
-    ``pos``, one layer's pool leaves ((N+1, H_kv, B, D) dense or
-    (values, scales)), ``tbl`` the slot's trash-padded block table.
-    Returns (x, kb, vb) where kb/vb are the UPDATED block containing
-    ``pos`` — a read-modify-write of one pool block (this step's K/V
-    row inserted at pos % block, every other lane a byte copy), which
-    is what the caller scatters back.  The attention itself never
-    materializes a row: O(block_size) workspace, ``n_blk`` loop
-    iterations (trash / beyond-``pos`` lanes masked)."""
-    quant = isinstance(pool_k_l, tuple)
-    _, _, e = x.shape
-    d = e // n_head        # full head dim: x is replicated under TP
-    q, k_new, v_new = _paged_qkv(x, p, n_head, eps)
+    q = q.reshape(w, nq, n_kv, g, d).transpose(0, 2, 3, 1, 4)
     if quant:
-        k_cur, v_cur = _quantize_kv(k_new), _quantize_kv(v_new)
-    else:
-        k_cur, v_cur = k_new, v_new
-    a = _paged_attn(q, pool_k_l, pool_v_l, tbl, pos, n_blk, block,
-                    trash, k_cur, v_cur,
-                    jnp.ones((1, 1), bool), 1.0 / math.sqrt(d),
-                    window=window, blk_lo=blk_lo)
-    a = a.astype(x.dtype).transpose(2, 0, 1, 3).reshape(
-        1, 1, e // tp_world)
-    x = x + (_tp_psum(a @ p["wo"], tp_axis, tp_world) + p["bo"])
-    h = _ln(x, p["ln2_s"], p["ln2_b"], eps)
-    x = x + _mlp(h, p, moe_top_k, tp_axis=tp_axis, tp_world=tp_world,
-                 ep=ep)
-    rmw = partial(_write_block, tbl=tbl, pos=pos, block=block)
+        def quantize(rows):
+            q8, sc = _quantize_kv(rows.reshape(w, nq, n_kv, d))
+            return q8.reshape(w, nq, n_kv * d), sc
 
-    if quant:
-        kb = (rmw(pool_k_l[0], k_cur[0]), rmw(pool_k_l[1], k_cur[1]))
-        vb = (rmw(pool_v_l[0], v_cur[0]), rmw(pool_v_l[1], v_cur[1]))
-    else:
-        kb, vb = rmw(pool_k_l, k_cur), rmw(pool_v_l, v_cur)
-    return x, kb, vb
-
-
-def _block_chunk_paged(x, p, pool_k_l, pool_v_l, tbl, pos, n_blk,
-                       n_head, eps, block, trash, moe_top_k=2,
-                       window=None, blk_lo=None, tp_axis=None,
-                       tp_world=1, ep=None):
-    """The chunk-query variant (speculative verify): x (1, K, E) at
-    positions ``pos..pos+K-1``.  Pool lanes < ``pos`` are visible to
-    every query; the chunk's own keys are causal within the chunk —
-    the same mask structure _block_chunk applies to its materialized
-    row.  Returns (x, kdbl, vdbl): the DOUBLE block (blocks pos // B
-    and (pos+K-1) // B concatenated on the position axis, K <= B so a
-    chunk spans at most two) with the chunk's K/V rows inserted at
-    pos % B — the caller splits and scatters the halves."""
-    quant = isinstance(pool_k_l, tuple)
-    _, klen, e = x.shape
-    d = e // n_head
-    q, k_new, v_new = _paged_qkv(x, p, n_head, eps)
-    if quant:
-        k_cur, v_cur = _quantize_kv(k_new), _quantize_kv(v_new)
-    else:
-        k_cur, v_cur = k_new, v_new
-    cur_mask = jnp.tril(jnp.ones((klen, klen), bool))
+        k_cur, v_cur = quantize(k_cur), quantize(v_cur)
+    cur_mask = jnp.tril(jnp.ones((nq, nq), bool))
     if window is not None:
         # within-chunk banding: query i attends chunk key j at
         # position pos+j only when (pos+i) - (pos+j) < window
-        i = jnp.arange(klen)
+        i = jnp.arange(nq)
         cur_mask = cur_mask & (i[:, None] - i[None, :] < window)
-    a = _paged_attn(q, pool_k_l, pool_v_l, tbl, pos, n_blk, block,
-                    trash, k_cur, v_cur, cur_mask,
-                    1.0 / math.sqrt(d), window=window, blk_lo=blk_lo)
-    a = a.astype(x.dtype).transpose(2, 0, 1, 3).reshape(
-        1, klen, e // tp_world)
+    a = jax.vmap(
+        lambda q_r, k_r, v_r, tbl, pos_r: _paged_attn(
+            q_r, pool_k, pool_v, li, tbl, pos_r, n_blk, block, trash,
+            k_r, v_r, cur_mask, 1.0 / math.sqrt(d), window=window,
+            blk_lo=blk_lo))(q, k_cur, v_cur, tables, pos)
+    # the new rows depend on nothing the block loop computes, so the
+    # compiler is free to put a layer's scatter before its reads of the
+    # pool, and then keeps a second pool to read from: tie the rows to
+    # the attention's result
+    a, k_cur, v_cur = jax.lax.optimization_barrier((a, k_cur, v_cur))
+    a = a.astype(x.dtype).transpose(0, 3, 1, 2, 4).reshape(
+        w, nq, e // tp_world)
     x = x + (_tp_psum(a @ p["wo"], tp_axis, tp_world) + p["bo"])
     h = _ln(x, p["ln2_s"], p["ln2_b"], eps)
-    x = x + _mlp(h, p, moe_top_k, tp_axis=tp_axis, tp_world=tp_world,
-                 ep=ep)
-    b0 = pos // block
-    b1 = (pos + klen - 1) // block
-    off = pos % block
+    x = x + _mlp_lanes(h, p, moe_top_k, live, tp_axis, tp_world, ep)
+    def lay(pool, new):
+        return _write_rows(pool, li, new, tables, pos, live, block, trash)
 
-    def rmw2(pool_l, new):
-        dd = jnp.concatenate([pool_l[tbl[b0]], pool_l[tbl[b1]]],
-                             axis=1)
-        start = (0, off) + (0,) * (dd.ndim - 2)
-        return jax.lax.dynamic_update_slice(dd, new, start)
-
-    if quant:
-        kdbl = (rmw2(pool_k_l[0], k_cur[0]),
-                rmw2(pool_k_l[1], k_cur[1]))
-        vdbl = (rmw2(pool_v_l[0], v_cur[0]),
-                rmw2(pool_v_l[1], v_cur[1]))
-    else:
-        kdbl, vdbl = rmw2(pool_k_l, k_cur), rmw2(pool_v_l, v_cur)
-    return x, kdbl, vdbl
+    return (x, jax.tree.map(lay, pool_k, k_cur),
+            jax.tree.map(lay, pool_v, v_cur))
 
 
-def decode_step_paged(params, x, pool_k, pool_v, tbl, pos, n_blk,
-                      n_head, eps, *, block, trash, moe_top_k=2,
-                      window=None, blk_lo=None, tp_axis=None,
-                      tp_world=1, ep=None):
-    """PUBLIC block-native single-step decode (the paged serve
-    engine's hot path; serve/paged.py ``_paged_decode_kernel``).
-    ``x``: (1, 1, E) embedded input at ``pos``; ``pool_k/v``: the full
-    (L, N+1, H_kv, B, D) pools (int8 pools are (values, scales));
-    ``tbl``: (W//B,) trash-padded block table; ``n_blk``: loop bound —
-    any traced value >= ceil(pos / block) (the pool-step wrapper
-    passes the max over live slots so one executable serves the whole
-    pool).  Returns ((1, V) logits, kb, vb) with kb/vb the updated
-    (L, H_kv, B, D)-stacked blocks containing ``pos``."""
-    kbs, vbs = [], []
-    for li, p in enumerate(params["blocks"]):
-        x, kb, vb = _block_decode_paged(
-            x, p, _cache_layer(pool_k, li), _cache_layer(pool_v, li),
-            tbl, pos, n_blk, n_head, eps, block, trash,
-            moe_top_k=moe_top_k, window=window, blk_lo=blk_lo,
-            tp_axis=tp_axis, tp_world=tp_world, ep=ep)
-        kbs.append(kb)
-        vbs.append(vb)
-    x = _ln(x, params["lnf_s"], params["lnf_b"], eps)
-    return _logits(x, params)[:, 0], _cache_stack(kbs), \
-        _cache_stack(vbs)
-
-
-def chunk_step_paged(params, x, pool_k, pool_v, tbl, pos, n_blk,
-                     n_head, eps, *, block, trash, moe_top_k=2,
+def chunk_step_paged(params, x, pool_k, pool_v, tables, pos, live,
+                     n_blk, n_head, eps, *, block, trash, moe_top_k=2,
                      window=None, blk_lo=None, tp_axis=None,
                      tp_world=1, ep=None):
-    """PUBLIC block-native chunk advance (speculative verify against
-    the pool; serve/paged.py ``_paged_spec_kernel``).  ``x``:
-    (1, K, E) embedded chunk at ``pos..pos+K-1``.  Returns
-    ((1, K, V) logits, kdbl, vdbl) with the double blocks
-    (L, H_kv, 2B, D)-stacked — the caller splits the halves and
-    scatters them at ``tbl[pos // B]`` / ``tbl[(pos+K-1) // B]``."""
-    kds, vds = [], []
-    for li, p in enumerate(params["blocks"]):
-        x, kd, vd = _block_chunk_paged(
-            x, p, _cache_layer(pool_k, li), _cache_layer(pool_v, li),
-            tbl, pos, n_blk, n_head, eps, block, trash,
-            moe_top_k=moe_top_k, window=window, blk_lo=blk_lo,
-            tp_axis=tp_axis, tp_world=tp_world, ep=ep)
-        kds.append(kd)
-        vds.append(vd)
+    """PUBLIC block-native chunk advance of every lane (speculative
+    verify against the pool; serve/paged.py ``_paged_spec_kernel``).
+    ``x``: (W, K, E) embedded chunks at ``pos[w]..pos[w]+K-1`` (dead
+    lanes clamped to position 0 by the caller); ``pool_k/v``: the full
+    (L, N+1, B, H_kv·D) pools (int8 pools are (values, scales));
+    ``tables``: (W, W//B) trash-padded block tables; ``n_blk``: loop
+    bound — any traced value >= ceil(max live pos / block).  Returns
+    ((W, K, V) logits, pool_k, pool_v), each layer's new rows written
+    at ``tables[w, pos // B]`` (and the block after it where the chunk
+    runs on; dead lanes: the trash block)."""
+    def layer(carry, li, p, _):
+        return _block_paged(
+            *carry, p, li, tables, pos, live, n_blk, n_head, eps, block,
+            trash, moe_top_k=moe_top_k, window=window, blk_lo=blk_lo,
+            tp_axis=tp_axis, tp_world=tp_world, ep=ep), None
+
+    (x, pool_k, pool_v), _ = _over_layers(params, layer,
+                                          (x, pool_k, pool_v))
     x = _ln(x, params["lnf_s"], params["lnf_b"], eps)
-    return _logits(x, params), _cache_stack(kds), _cache_stack(vds)
+    return _logits(x, params), pool_k, pool_v
+
+
+def decode_step_paged(params, x, pool_k, pool_v, tables, pos, live,
+                      n_blk, n_head, eps, **kw):
+    """PUBLIC block-native single-step decode of every lane (the paged
+    serve engine's hot path; serve/paged.py ``_paged_decode_kernel``):
+    :func:`chunk_step_paged` at one token a lane.  ``x``: (W, E)
+    embedded inputs at ``pos``; returns ((W, V) logits, pool_k,
+    pool_v)."""
+    logits, pool_k, pool_v = chunk_step_paged(
+        params, x[:, None], pool_k, pool_v, tables, pos, live, n_blk,
+        n_head, eps, **kw)
+    return logits[:, 0], pool_k, pool_v
 
 
 def spec_verify(t_logits, d_probs, props, key, temp, top_p, top_k,
@@ -1841,30 +1902,19 @@ class _GPT2Family(ServedFamily):
     def decode_step(self, params, pool_k, pool_v, state, slots, tables,
                     toks, pos, live, n_blk, *, block, trash, n_head,
                     eps, moe_top_k=2, window=None, blk_lo=None,
-                    tp_axis=None, tp_world=1):
-        """Per lane: online-softmax attention over its live blocks plus
-        the step's own K/V, and the read-modified block containing
-        ``pos`` handed back; then one scatter of those blocks (dead
-        lanes write the trash block)."""
-
-        def row(tbl, tok, pos_r, live_r):
-            p_c = jnp.where(live_r, pos_r, 0)
-            t_c = jnp.where(live_r, tok, 0)
-            x = (params["wte"][t_c] + params["wpe"][p_c])[None, None, :]
-            logits, kb, vb = decode_step_paged(
-                params, x, pool_k, pool_v, tbl, p_c, n_blk, n_head,
-                eps, block=block, trash=trash, moe_top_k=moe_top_k,
-                window=window, blk_lo=blk_lo, tp_axis=tp_axis,
-                tp_world=tp_world)
-            dst = jnp.where(live_r, tbl[p_c // block], trash)
-            return logits[0], kb, vb, dst
-
-        logits, kb, vb, dst = jax.vmap(
-            row, out_axes=(0, 1, 1, 0))(tables, toks, pos, live)
-        pool_k = jax.tree.map(lambda p, b: p.at[:, dst].set(b),
-                              pool_k, kb)
-        pool_v = jax.tree.map(lambda p, b: p.at[:, dst].set(b),
-                              pool_v, vb)
+                    tp_axis=None, tp_world=1, ep=None):
+        """The lanes through each layer together; per lane only the
+        online-softmax attention over its live blocks plus the step's
+        own K/V; each layer's new rows written straight into the pool
+        (dead lanes write the trash block)."""
+        p_c = jnp.where(live, pos, 0)
+        t_c = jnp.where(live, toks, 0)
+        x = params["wte"][t_c] + params["wpe"][p_c]          # (W, E)
+        logits, pool_k, pool_v = decode_step_paged(
+            params, x, pool_k, pool_v, tables, p_c, live, n_blk,
+            n_head, eps, block=block, trash=trash,
+            moe_top_k=moe_top_k, window=window, blk_lo=blk_lo,
+            tp_axis=tp_axis, tp_world=tp_world, ep=ep)
         return logits, pool_k, pool_v, None
 
     def logits(self, params, hidden):

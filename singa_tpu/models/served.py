@@ -12,7 +12,7 @@ one compiled program.
 Two kinds of per-sequence state exist side by side:
 
 * **paged K/V** -- ``kv_geometry`` gives ``(layers, K/V heads, head
-  size)``; the engine owns the block pool ``(L, N+1, H_kv, B, D)``, the
+  size)``; the engine owns the block pool ``(L, N+1, B, H_kv·D)``, the
   block tables and a prefilling request's private row ``(L, 1, H_kv, W,
   D)``.  Attention over the pool and the write of new rows are shared
   code (``ops/paged_attention.py``).

@@ -41,7 +41,7 @@ from .sharding import DATA, MODEL, SEQ, P, ShardingPlan, constrain
 __all__ = [
     "ColumnParallelLinear", "RowParallelLinear", "VocabParallelEmbedding",
     "ParallelMLP", "ParallelMHA", "ParallelTransformerBlock",
-    "decode_param_specs", "decode_cache_spec",
+    "decode_param_specs", "decode_cache_spec", "decode_pool_spec",
 ]
 
 
@@ -81,8 +81,10 @@ def decode_param_specs(params, axis=MODEL, ep_axis=None):
     are rejected here so the failure is a typed construction error
     naming the ``serve(ep=)`` path, not a shape mismatch deep inside a
     shard_map trace."""
+    stacked = isinstance(params["blocks"], dict)
     blocks = []
-    for li, blk in enumerate(params["blocks"]):
+    for li, blk in enumerate([params["blocks"]] if stacked
+                             else params["blocks"]):
         if "moe_wg" in blk and ep_axis is None:
             raise NotImplementedError(
                 f"block {li} is an MoE block: expert weights shard "
@@ -102,22 +104,33 @@ def decode_param_specs(params, axis=MODEL, ep_axis=None):
                 spec[k] = P(ep_axis)
             else:
                 spec[k] = P()
+            if stacked:          # a leading layer axis, never sharded
+                spec[k] = P(None, *spec[k])
         blocks.append(spec)
     out = {k: (None if v is None else P())
            for k, v in params.items() if k != "blocks"}
-    out["blocks"] = blocks
+    out["blocks"] = blocks[0] if stacked else blocks
     return out
 
 
 def decode_cache_spec(axis=MODEL):
-    """PartitionSpec for every KV-cache pytree leaf the serve engine
-    owns — slot arenas ``(L, S, H_kv, W, D)``, paged pools
-    ``(L, num_blocks+1, H_kv, B, D)``, cache rows ``(L, 1, H_kv, W,
-    D)`` and their trailing-axis-free int8 scales leaves: the KV-HEAD
-    axis (always axis 2) shards over ``axis``, everything else stays
-    local.  One spec serves every leaf rank because PartitionSpec
-    trailing dims default to unsharded."""
+    """PartitionSpec for every slot-arena and cache-row leaf the serve
+    engine owns — slot arenas ``(L, S, H_kv, W, D)``, cache rows
+    ``(L, 1, H_kv, W, D)`` and their trailing-axis-free int8 scales
+    leaves: the KV-HEAD axis (always axis 2) shards over ``axis``,
+    everything else stays local.  One spec serves every leaf rank
+    because PartitionSpec trailing dims default to unsharded."""
     return P(None, None, axis)
+
+
+def decode_pool_spec(axis=MODEL):
+    """PartitionSpec for every leaf of a paged block pool — values
+    ``(L, num_blocks+1, B, H_kv·D)``, int8 scales
+    ``(L, num_blocks+1, B, H_kv)``: a row is the K/V heads side by
+    side, so the LAST axis shards over ``axis`` (contiguous heads a
+    shard, the same heads as :func:`decode_cache_spec` gives it of a
+    cache row)."""
+    return P(None, None, None, axis)
 
 
 class ColumnParallelLinear(Layer):

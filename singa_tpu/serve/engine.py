@@ -403,8 +403,8 @@ def _spec_row(t_params, d_params, kc_r, vc_r, dkc_r, dvc_r, tok, pos_r,
     in the SAME executable (temp is traced, like ``_select_sample``).
     Shared by the slot-arena spec step and the paged GATHER spec step
     (serve/paged.py) — one definition, no drift; the paged BLOCK
-    kernel's row is :func:`_spec_row_paged` below (same draft scan
-    and verify, chunk-query block-native target attention)."""
+    kernel (``paged._paged_spec_kernel``) runs the same draft scan and
+    verify per lane around a lane-batched block-native target chunk."""
     p_c = jnp.where(live_r, pos_r, 0)
     t_c = jnp.where(live_r, tok, 0)
     k_draft, k_verify, k_next = jax.random.split(key, 3)
@@ -427,73 +427,6 @@ def _spec_row(t_params, d_params, kc_r, vc_r, dkc_r, dvc_r, tok, pos_r,
     out, a_draft = _gpt2.spec_verify(lg[0], d_probs, props, k_verify,
                                temp, top_p, top_k, use_top_p)
     return (out, a_draft, _unbatch1(kc2), _unbatch1(vc2),
-            _unbatch1(dkc_b), _unbatch1(dvc_b), k_next)
-
-
-def _decode_row_paged(params, pool_k, pool_v, tbl, tok, pos_r, live_r,
-                      key, temp, top_p, n_blk, block, trash, n_head,
-                      eps, moe_top_k, top_k, use_top_p, window=None,
-                      blk_lo=None, tp_axis=None, tp_world=1, ep=None,
-                      mask=None, with_lp=False):
-    """ONE slot's BLOCK-NATIVE decode-step math (the gather-tax
-    round): same embed / sample chain as :func:`_decode_row`, but the
-    attention runs directly over the block pool through
-    ``gpt2_decode.decode_step_paged`` — no materialized row, and the
-    only cache state returned is the one (L, H_kv, B, D) block the
-    step wrote (read-modify-write, so untouched lanes stay byte
-    copies).  Logits agree with the gather path to float
-    reduction-order (online softmax), which is token-identity away
-    from exact argmax/CDF ties — the parity pin tests/test_paged.py
-    holds the kernel to."""
-    p_c = jnp.where(live_r, pos_r, 0)
-    t_c = jnp.where(live_r, tok, 0)
-    x = (params["wte"][t_c] + params["wpe"][p_c])[None, None, :]
-    logits, kb, vb = _gpt2.decode_step_paged(
-        params, x, pool_k, pool_v, tbl, p_c, n_blk, n_head, eps,
-        block=block, trash=trash, moe_top_k=moe_top_k,
-        window=window, blk_lo=blk_lo,
-        tp_axis=tp_axis, tp_world=tp_world, ep=ep)
-    ks = jax.random.split(key)
-    nxt = _select_sample(logits[0], ks[0], temp, top_k, top_p,
-                         use_top_p, mask=mask)
-    if with_lp:
-        lp = jax.nn.log_softmax(
-            logits[0].astype(jnp.float32))[nxt]
-        return nxt, kb, vb, ks[1], lp
-    return nxt, kb, vb, ks[1]
-
-
-def _spec_row_paged(t_params, d_params, pool_k, pool_v, dkc_r, dvc_r,
-                    tbl, tok, pos_r, live_r, key, temp, top_p, n_blk,
-                    spec_k, block, trash, tn, te, tm, dn, de, dm,
-                    top_k, use_top_p, window=None, blk_lo=None,
-                    tp_axis=None, tp_world=1, ep=None):
-    """ONE slot's BLOCK-NATIVE speculative chunk: the SAME draft
-    proposal scan and the SAME ``spec_verify`` as :func:`_spec_row`
-    (shared helpers — the accept logic cannot drift), with the target
-    chunk advance running block-natively over the pool
-    (``gpt2_decode.chunk_step_paged`` — the chunk-query variant of
-    the online-softmax accumulator).  Returns the DOUBLE blocks the
-    chunk wrote (kdbl/vdbl, (L, H_kv, 2B, D)-stacked); the pool step
-    splits the halves and scatters them."""
-    p_c = jnp.where(live_r, pos_r, 0)
-    t_c = jnp.where(live_r, tok, 0)
-    k_draft, k_verify, k_next = jax.random.split(key, 3)
-    props, d_probs, dkc_b, dvc_b = _draft_propose(
-        d_params, dkc_r, dvc_r, t_c, p_c, k_draft, temp, top_p,
-        spec_k, dn, de, dm, top_k, use_top_p)
-
-    chunk_toks = jnp.concatenate([t_c[None], props])
-    xs = (jnp.take(t_params["wte"], chunk_toks, axis=0)
-          + jnp.take(t_params["wpe"],
-                     p_c + jnp.arange(spec_k), axis=0))[None]
-    lg, kdbl, vdbl = _gpt2.chunk_step_paged(
-        t_params, xs, pool_k, pool_v, tbl, p_c, n_blk, tn, te,
-        block=block, trash=trash, moe_top_k=tm, window=window,
-        blk_lo=blk_lo, tp_axis=tp_axis, tp_world=tp_world, ep=ep)
-    out, a_draft = _gpt2.spec_verify(lg[0], d_probs, props, k_verify,
-                               temp, top_p, top_k, use_top_p)
-    return (out, a_draft, kdbl, vdbl,
             _unbatch1(dkc_b), _unbatch1(dvc_b), k_next)
 
 
@@ -4249,7 +4182,7 @@ class InferenceEngine:
             # validation HERE (the scatter path's lives inside
             # arena.import_image — exactly one validate either way)
             image.validate(arena.block_size, arena.quant,
-                           pool_k=arena.pool_k)
+                           pool_k=arena.pool_k, head_dim=arena.head_dim)
             cache.touch(existing)
             cache.acquire(existing)
             return existing

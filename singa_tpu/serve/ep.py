@@ -81,7 +81,8 @@ from ..parallel.sharding import EP as EP_AXIS
 from ..parallel.sharding import TP as TP_AXIS
 from ..parallel.sharding import create_ep_mesh
 from ..parallel.tensor_parallel import (decode_cache_spec,
-                                        decode_param_specs)
+                                        decode_param_specs,
+                                        decode_pool_spec)
 from ..resilience import faults as _faults
 from ..utils.logging import get_channel
 
@@ -94,6 +95,7 @@ _R = P()
 #: KV leaves: head axis (axis 2) over tp, replicated over ep —
 #: experts hold no KV, so the cache layout is exactly serve/tp.py's
 _CS = decode_cache_spec(TP_AXIS)
+_PS = decode_pool_spec(TP_AXIS)
 
 # module-wide twin cache, keyed like tp.py's: (base, extra statics,
 # executor key) -> jitted sharded executable
@@ -293,6 +295,8 @@ class EPExecutor:
         self._window = None
         self._pspec = None
         self._cache_sh = NamedSharding(self.mesh, _CS)
+        self._pool_sh = NamedSharding(self.mesh, _PS)
+        self._head_dim = int(cfg.n_embd) // int(cfg.n_head)
         self._repl_sh = NamedSharding(self.mesh, _R)
         self._kv_bytes = 0
         self._log = get_channel("serve")
@@ -360,13 +364,18 @@ class EPExecutor:
             lambda a, s: jax.device_put(
                 a, NamedSharding(self.mesh, s)), params, self._pspec)
 
-    def place_cache(self, tree):
-        placed = jax.tree.map(
-            lambda a: jax.device_put(a, self._cache_sh), tree)
+    def place_cache(self, tree, sharding=None):
+        sh = sharding or self._cache_sh
+        placed = jax.tree.map(lambda a: jax.device_put(a, sh), tree)
         self._kv_bytes += sum(a.nbytes
                               for a in jax.tree.leaves(tree)) // self.tp
         self._g_kv.set(self._kv_bytes)
         return placed
+
+    def place_pool(self, tree):
+        """A block pool: sharded over ``tp`` on its last axis (a pool
+        row is the K/V heads side by side)."""
+        return self.place_cache(tree, self._pool_sh)
 
     def place_replicated(self, tree):
         return jax.tree.map(
@@ -463,15 +472,15 @@ class EPExecutor:
             "prefill_one": (ps, _R, _R, _R, _R, _R),
             "prefill_batch": (ps, _R, _R, _R, _R, _R),
             "chunk_row": (ps, _R, _CS, _CS, _R),
-            "paged_decode": (ps, _CS, _CS, _R, _R, _R, _R, _R, _R,
+            "paged_decode": (ps, _PS, _PS, _R, _R, _R, _R, _R, _R,
                              _R),
-            "paged_spec": (ps, _R, _CS, _CS, _R, _R, _R, _R, _R, _R,
+            "paged_spec": (ps, _R, _PS, _PS, _R, _R, _R, _R, _R, _R,
                            _R, _R, _R),
             "write_slot": (_CS, _CS, _CS, _CS, _R),
             "read_slot": (_CS, _CS, _R),
-            "pool_to_row": (_CS, _CS, _R, _R),
-            "row_to_pool": (_CS, _CS, _CS, _CS, _R),
-            "rows_to_pool": (_CS, _CS, _CS, _CS, _R, _R),
+            "pool_to_row": (_PS, _PS, _R, _R),
+            "row_to_pool": (_PS, _PS, _CS, _CS, _R),
+            "rows_to_pool": (_PS, _PS, _CS, _CS, _R, _R),
         }[base]
 
     def _out_specs(self, base):
@@ -484,22 +493,23 @@ class EPExecutor:
             "prefill_one": (_R, _R, _CS, _CS, _R, _R),
             "prefill_batch": (_R, _R, _CS, _CS, _R, _R),
             "chunk_row": (_R, _CS, _CS, _R, _R),
-            "paged_decode": (_R, _CS, _CS, _R, _R, _R),
-            "paged_spec": (_R, _R, _CS, _CS, _R, _R, _R, _R, _R),
+            "paged_decode": (_R, _PS, _PS, _R, _R, _R),
+            "paged_spec": (_R, _R, _PS, _PS, _R, _R, _R, _R, _R),
             "write_slot": (_CS, _CS),
             "read_slot": (_CS, _CS),
             "pool_to_row": (_CS, _CS),
-            "row_to_pool": (_CS, _CS),
-            "rows_to_pool": (_CS, _CS),
+            "row_to_pool": (_PS, _PS),
+            "rows_to_pool": (_PS, _PS),
         }[base]
 
     # -- twin bodies ------------------------------------------------------
-    # The engine's pool steps vmap a per-row function; the EP stats
-    # collector must be consumed INSIDE the vmapped row (its tracers
-    # belong to the row's trace), so the small vmap wrappers are
-    # restated here with the shared row math untouched — the per-slot
-    # ops are engine._decode_row/_spec_row/_decode_row_paged/... with
-    # the ep triple threaded, one definition, no drift.
+    # The engine's slot-arena steps vmap a per-row function; the EP
+    # stats collector must be consumed INSIDE the vmapped row (its
+    # tracers belong to the row's trace), so the small vmap wrappers
+    # are restated here with the shared row math untouched — the
+    # per-slot ops are engine._decode_row/_spec_row with the ep triple
+    # threaded, one definition, no drift.  The paged steps take the
+    # triple themselves (:meth:`_mk_paged`).
 
     def _mk_pool_decode(self):
         from .engine import _decode_row
@@ -560,158 +570,44 @@ class EPExecutor:
 
         return body
 
-    def _mk_paged_decode(self, block, kernel):
-        from .engine import _decode_row, _decode_row_paged
-        from .paged import _gather_leaf
-
+    def _mk_paged(self, base, **statics):
+        """A paged pool step of serve/paged.py (``base``, block kernel
+        or gather oracle) with the ep triple threaded: the lanes fold
+        their own routing stats (``gpt2_decode._ep_lane_stats``), the
+        collector around the program hands the sums out."""
         from ..models import gpt2_decode as G
 
-        st, ep3, tpw = self._statics, self._ep3, self.tp
         ne = self.n_expert
-        window = self._window
+        statics.update(tp_axis=TP_AXIS, tp_world=self.tp, ep=self._ep3)
 
-        def body(params, pool_k, pool_v, tables, toks, pos, live,
-                 keys, temps, top_p):
-            trash = jax.tree.leaves(pool_k)[0].shape[1] - 1
-            p_all = jnp.where(live, pos, 0)
-            n_blk = jnp.max((p_all + block - 1) // block)
-            blk_lo = None
-            if kernel == "block" and window is not None:
-                lo = jnp.maximum(0, (p_all - window + 1) // block)
-                blk_lo = jnp.min(jnp.where(live, lo, n_blk))
-
-            def row(tbl, tok, pos_r, live_r, key, temp):
-                with G._ep_collecting() as rec:
-                    if kernel == "block":
-                        nxt, kb, vb, k2 = _decode_row_paged(
-                            params, pool_k, pool_v, tbl, tok, pos_r,
-                            live_r, key, temp, top_p, n_blk, block,
-                            trash, **st, window=window, blk_lo=blk_lo,
-                            tp_axis=TP_AXIS, tp_world=tpw, ep=ep3)
-                    else:
-                        kc_r = jax.tree.map(
-                            lambda p: _gather_leaf(p, tbl), pool_k)
-                        vc_r = jax.tree.map(
-                            lambda p: _gather_leaf(p, tbl), pool_v)
-                        nxt, kc2, vc2, k2 = _decode_row(
-                            params, kc_r, vc_r, tok, pos_r, live_r,
-                            key, temp, top_p, **st, tp_axis=TP_AXIS,
-                            tp_world=tpw, ep=ep3)
-                        from .paged import _slice_block
-                        p_c0 = jnp.where(live_r, pos_r, 0)
-                        off = (p_c0 // block) * block
-                        kb = jax.tree.map(
-                            lambda a: _slice_block(a, off, block), kc2)
-                        vb = jax.tree.map(
-                            lambda a: _slice_block(a, off, block), vc2)
-                cnt, drp = _fold_ep_stats(rec, ne, live_r)
-                p_c = jnp.where(live_r, pos_r, 0)
-                dst = jnp.where(live_r, tbl[p_c // block], trash)
-                return nxt, kb, vb, dst, k2, cnt, drp
-
-            nxt, kb, vb, dst, keys2, cnt, drp = jax.vmap(
-                row, in_axes=(0, 0, 0, 0, 0, 0),
-                out_axes=(0, 1, 1, 0, 0, 0, 0))(tables, toks, pos,
-                                                live, keys, temps)
-            pool_k = jax.tree.map(lambda p, b: p.at[:, dst].set(b),
-                                  pool_k, kb)
-            pool_v = jax.tree.map(lambda p, b: p.at[:, dst].set(b),
-                                  pool_v, vb)
-            return nxt, pool_k, pool_v, keys2, cnt.sum(0), drp.sum()
+        def body(*args):
+            with G._ep_collecting() as rec:
+                out = base.__wrapped__(*args, **statics)
+            return (*out, *_fold_ep_stats(rec, ne))
 
         return body
+
+    def _mk_paged_decode(self, block, kernel):
+        from .paged import _paged_decode_kernel, _paged_decode_step
+
+        if kernel == "block":
+            return self._mk_paged(_paged_decode_kernel, block=block,
+                                  window=self._window, **self._statics)
+        return self._mk_paged(_paged_decode_step, block=block,
+                              **self._statics)
 
     def _mk_paged_spec(self, block, kernel):
-        from .engine import _spec_row, _spec_row_paged
-        from .paged import _gather_leaf, _slice_block
+        from .paged import _paged_spec_kernel, _paged_spec_step
 
-        from ..models import gpt2_decode as G
-
-        st, ep3, tpw = self._statics, self._ep3, self.tp
-        ne = self.n_expert
-        window = self._window
+        st = self._statics
         spec_k, (dn, de, dm) = self._spec
-
-        def body(t_params, d_params, pool_k, pool_v, dkc, dvc, tables,
-                 toks, pos, live, keys, temps, top_p):
-            trash = jax.tree.leaves(pool_k)[0].shape[1] - 1
-            p_all = jnp.where(live, pos, 0)
-            n_blk = jnp.max((p_all + block - 1) // block)
-            blk_lo = None
-            if kernel == "block" and window is not None:
-                lo = jnp.maximum(0, (p_all - window + 1) // block)
-                blk_lo = jnp.min(jnp.where(live, lo, n_blk))
-
-            def row(dkc_r, dvc_r, tbl, tok, pos_r, live_r, key, temp):
-                with G._ep_collecting() as rec:
-                    if kernel == "block":
-                        (out, a_draft, kdbl, vdbl, dkc2, dvc2,
-                         k2) = _spec_row_paged(
-                            t_params, d_params, pool_k, pool_v, dkc_r,
-                            dvc_r, tbl, tok, pos_r, live_r, key, temp,
-                            top_p, n_blk, spec_k, block, trash,
-                            st["n_head"], st["eps"], st["moe_top_k"],
-                            dn, de, dm, st["top_k"], st["use_top_p"],
-                            window=window, blk_lo=blk_lo,
-                            tp_axis=TP_AXIS, tp_world=tpw, ep=ep3)
-                        kb0 = jax.tree.map(lambda a: a[:, :, :block],
-                                           kdbl)
-                        vb0 = jax.tree.map(lambda a: a[:, :, :block],
-                                           vdbl)
-                        kb1 = jax.tree.map(lambda a: a[:, :, block:],
-                                           kdbl)
-                        vb1 = jax.tree.map(lambda a: a[:, :, block:],
-                                           vdbl)
-                    else:
-                        kc_r = jax.tree.map(
-                            lambda p: _gather_leaf(p, tbl), pool_k)
-                        vc_r = jax.tree.map(
-                            lambda p: _gather_leaf(p, tbl), pool_v)
-                        (out, a_draft, kc2, vc2, dkc2, dvc2,
-                         k2) = _spec_row(
-                            t_params, d_params, kc_r, vc_r, dkc_r,
-                            dvc_r, tok, pos_r, live_r, key, temp,
-                            top_p, spec_k, st["n_head"], st["eps"],
-                            st["moe_top_k"], dn, de, dm, st["top_k"],
-                            st["use_top_p"], tp_axis=TP_AXIS,
-                            tp_world=tpw, ep=ep3)
-                        p_c0 = jnp.where(live_r, pos_r, 0)
-                        o0 = (p_c0 // block) * block
-                        o1 = ((p_c0 + spec_k - 1) // block) * block
-                        kb0 = jax.tree.map(
-                            lambda a: _slice_block(a, o0, block), kc2)
-                        vb0 = jax.tree.map(
-                            lambda a: _slice_block(a, o0, block), vc2)
-                        kb1 = jax.tree.map(
-                            lambda a: _slice_block(a, o1, block), kc2)
-                        vb1 = jax.tree.map(
-                            lambda a: _slice_block(a, o1, block), vc2)
-                cnt, drp = _fold_ep_stats(rec, ne, live_r)
-                p_c = jnp.where(live_r, pos_r, 0)
-                b0 = p_c // block
-                b1 = (p_c + spec_k - 1) // block
-                dst0 = jnp.where(live_r, tbl[b0], trash)
-                dst1 = jnp.where(live_r & (b1 > b0), tbl[b1], trash)
-                return (out, a_draft, kb0, vb0, dst0, kb1, vb1, dst1,
-                        dkc2, dvc2, k2, cnt, drp)
-
-            (out, a_draft, kb0, vb0, dst0, kb1, vb1, dst1, dkc, dvc,
-             keys2, cnt, drp) = jax.vmap(
-                row, in_axes=(1, 1, 0, 0, 0, 0, 0, 0),
-                out_axes=(0, 0, 1, 1, 0, 1, 1, 0, 1, 1, 0, 0, 0))(
-                dkc, dvc, tables, toks, pos, live, keys, temps)
-            pool_k = jax.tree.map(lambda p, b: p.at[:, dst0].set(b),
-                                  pool_k, kb0)
-            pool_v = jax.tree.map(lambda p, b: p.at[:, dst0].set(b),
-                                  pool_v, vb0)
-            pool_k = jax.tree.map(lambda p, b: p.at[:, dst1].set(b),
-                                  pool_k, kb1)
-            pool_v = jax.tree.map(lambda p, b: p.at[:, dst1].set(b),
-                                  pool_v, vb1)
-            return (out, a_draft, pool_k, pool_v, dkc, dvc, keys2,
-                    cnt.sum(0), drp.sum())
-
-        return body
+        kw = dict(block=block, spec_k=spec_k, tn=st["n_head"],
+                  te=st["eps"], tm=st["moe_top_k"], dn=dn, de=de, dm=dm,
+                  top_k=st["top_k"], use_top_p=st["use_top_p"])
+        if kernel == "block":
+            return self._mk_paged(_paged_spec_kernel,
+                                  window=self._window, **kw)
+        return self._mk_paged(_paged_spec_step, **kw)
 
     def _mk_prefill_one(self):
         from .engine import _prefill_one
@@ -856,24 +752,27 @@ class EPExecutor:
         return self._dispatch(fn, kc, vc, slot)
 
     def pool_to_row(self, pool_k, pool_v, idx, n_used):
-        from .tp import _pool_to_row_body
+        from functools import partial
+
+        from .paged import _pool_to_row
 
         fn = self._twin("pool_to_row", (),
-                        lambda: _pool_to_row_body)
+                        lambda: partial(_pool_to_row.__wrapped__,
+                                        head_dim=self._head_dim))
         return self._dispatch(fn, pool_k, pool_v, idx, n_used)
 
     def row_to_pool(self, pool_k, pool_v, kc_row, vc_row, idx):
-        from .tp import _row_to_pool_body
+        from .paged import _row_to_pool
 
-        fn = self._twin("row_to_pool", (), lambda: _row_to_pool_body,
-                        donate=(0, 1))
+        fn = self._twin("row_to_pool", (),
+                        lambda: _row_to_pool.__wrapped__, donate=(0, 1))
         return self._dispatch(fn, pool_k, pool_v, kc_row, vc_row, idx)
 
     def rows_to_pool(self, pool_k, pool_v, kc_rows, vc_rows, sel, idx):
-        from .tp import _rows_to_pool_body
+        from .paged import _rows_to_pool
 
         fn = self._twin("rows_to_pool", (),
-                        lambda: _rows_to_pool_body, donate=(0, 1))
+                        lambda: _rows_to_pool.__wrapped__, donate=(0, 1))
         return self._dispatch(fn, pool_k, pool_v, kc_rows, vc_rows,
                               sel, idx)
 

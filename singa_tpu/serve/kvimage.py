@@ -143,14 +143,15 @@ class KVImage:
                        for a in _leaf_list(self.kc)
                        + _leaf_list(self.vc)))
 
-    def validate(self, block_size, quant, pool_k=None):
+    def validate(self, block_size, quant, pool_k=None, head_dim=None):
         """Typed validation before any scatter: version supported,
         geometry matches the consuming arena (``block_size``,
         ``quant``), arrays consistent with the pack-time header
         (truncated or mutated images fail HERE), lane width a block
         multiple covering ``n_data`` blocks, and — when the consuming
-        pool's K leaves are handed in — per-leaf dtype and
-        (L, H, tail) compatibility with the pool.  Raises
+        pool's K leaves are handed in — per-leaf dtype, layers and
+        row width (H_kv·D of a pool row; ``head_dim`` pins D itself)
+        against the pool.  Raises
         :class:`KVImageError`; returns None."""
         if self.version != KVIMAGE_VERSION:
             raise KVImageError(
@@ -210,10 +211,12 @@ class KVImage:
                     f"pool has {len(pool_leaves)} (dense vs int8 "
                     f"layout drift)")
             for img, pool in zip(k_leaves, pool_leaves):
-                # pool: (L, N+1, H, B, ...) vs image: (L, 1, H, W, ...)
+                # pool: (L, N+1, B, H[·D]) vs image: (L, 1, H, W[, D])
+                width = img.shape[2] * int(np.prod(img.shape[4:]))
                 if (img.shape[0] != pool.shape[0]
-                        or img.shape[2] != pool.shape[2]
-                        or img.shape[4:] != pool.shape[4:]
+                        or width != pool.shape[3]
+                        or (head_dim is not None
+                            and img.shape[4:] not in ((), (head_dim,)))
                         or str(img.dtype) != str(pool.dtype)):
                     raise KVImageError(
                         f"KV image leaf {tuple(img.shape)}/{img.dtype}"

@@ -15,7 +15,7 @@ behind the pluggable ``engine._x`` seam:
   sharded ``P(pp)`` on the layer axis (each rank materializes only its
   L/P resident layers — the memory win), embeddings/norms/LM-head
   replicated; the paged block pool shards the SAME way:
-  ``(L/P, num_blocks+1, H_kv, B, D)`` per stage with GLOBAL block ids,
+  ``(L/P, num_blocks+1, B, H_kv·D)`` per stage with GLOBAL block ids,
   so the host-side free list, block tables, radix tree, scheduler,
   preemption/swap bookkeeping, and request ledger run unchanged;
 * **microbatched decode** — the jitted pool step runs the GPipe
@@ -254,15 +254,6 @@ def fleet_pp_configs(pp, replicas, devices=None):
             for i in range(replicas)]
 
 
-def _stack_blocks(blocks):
-    """Stack the per-layer block dicts into one dict of (L, ...)
-    arrays — the stage-shardable layout (parallel/pipeline.py's
-    stacked-parameter idiom restated for the decode pytree).  Typed
-    refusal on heterogeneous stacks is check_pp's job (MoE)."""
-    keys = blocks[0].keys()
-    return {k: jnp.stack([b[k] for b in blocks]) for k in keys}
-
-
 class PPExecutor:
     """The engine's pipeline-parallel executor: owns the ``pp`` mesh,
     the stage-stacked weight placement, the GPipe-scheduled sharded
@@ -292,6 +283,7 @@ class PPExecutor:
         self._window = None
         self._pspec = None
         self._layer_sh = NamedSharding(self.mesh, _LS)
+        self._head_dim = int(cfg.n_embd) // int(cfg.n_head)
         self._repl_sh = NamedSharding(self.mesh, _R)
         self._kv_bytes = 0
         self._log = get_channel("serve")
@@ -342,7 +334,10 @@ class PPExecutor:
         structure from here on — the host-side step loop never reads
         inside ``params``."""
         out = {k: v for k, v in params.items() if k != "blocks"}
-        out["blocks"] = _stack_blocks(params["blocks"])
+        # one dict of (L, ...) arrays — the stage-shardable layout, and
+        # what ``extract_params`` hands over for a dense model (typed
+        # refusal on heterogeneous stacks is check_pp's job: MoE)
+        out["blocks"] = params["blocks"]
         spec = {k: (None if v is None else _R)
                 for k, v in out.items() if k != "blocks"}
         spec["blocks"] = {k: _LS for k in out["blocks"]}
@@ -359,6 +354,9 @@ class PPExecutor:
             a.nbytes for a in jax.tree.leaves(tree)) // self.stages
         self._g_kv.set(self._kv_bytes)
         return placed
+
+    #: a pool is layer-sliced like every other KV leaf here
+    place_pool = place_cache
 
     def place_replicated(self, tree):
         return jax.tree.map(
@@ -508,21 +506,6 @@ class PPExecutor:
             toks_out = jnp.zeros((S,), jnp.int32)
             keys_out = keys
 
-            def slot_fn(h_r, tbl_r, pc_r):
-                x = h_r[None, None, :]
-                kbs, vbs = [], []
-                for i in range(L_loc):
-                    lp = {k: v[i] for k, v in blocks.items()}
-                    x, kb, vb = G._block_decode_paged(
-                        x, lp, G._cache_layer(pool_k, i),
-                        G._cache_layer(pool_v, i), tbl_r, pc_r,
-                        n_blk, n_head, eps, block, trash,
-                        moe_top_k=moe_top_k)
-                    kbs.append(kb)
-                    vbs.append(vb)
-                return (x[0, 0], G._cache_stack(kbs),
-                        G._cache_stack(vbs))
-
             def samp(lg_r, key, temp):
                 ks = jax.random.split(key)
                 nxt = _select_sample(lg_r, ks[0], temp, top_k, top_p,
@@ -545,18 +528,17 @@ class PPExecutor:
                 # pipeline entry (rank 0): embed this tick's
                 # microbatch; later stages consume the hop buffer
                 x0 = params["wte"][t_c] + params["wpe"][p_c]
-                h_in = jnp.where(rank == 0, x0, buf)
-                h_out, kb, vb = jax.vmap(
-                    slot_fn, in_axes=(0, 0, 0),
-                    out_axes=(0, 1, 1))(h_in, tb, p_c)
-                # each rank writes ITS layer slice of the touched
-                # block per slot; invalid/dead lanes land in trash
-                dst = jnp.where(
-                    lv, tb[jnp.arange(mbw), p_c // block], trash)
-                pool_k = jax.tree.map(
-                    lambda p, b: p.at[:, dst].set(b), pool_k, kb)
-                pool_v = jax.tree.map(
-                    lambda p, b: p.at[:, dst].set(b), pool_v, vb)
+                # each rank runs the microbatch through ITS layers and
+                # writes their new rows into its layer slice of the
+                # pool; invalid/dead lanes land in trash
+                h_out = jnp.where(rank == 0, x0, buf)[:, None, :]
+                for i in range(L_loc):
+                    lp = {k: v[i] for k, v in blocks.items()}
+                    h_out, pool_k, pool_v = G._block_paged(
+                        h_out, pool_k, pool_v, lp, i, tb, p_c, lv,
+                        n_blk, n_head, eps, block, trash,
+                        moe_top_k=moe_top_k)
+                h_out = h_out[:, 0]
                 # pipeline exit (rank P-1): final LN + head + sample
                 # for the microbatch that just left the last stage.
                 # Every rank traces this (SPMD), only the last one's
@@ -775,23 +757,27 @@ class PPExecutor:
     read_slot = write_slot
 
     def pool_to_row(self, pool_k, pool_v, idx, n_used):
-        from .tp import _pool_to_row_body
+        from functools import partial
 
-        fn = self._twin("pool_to_row", (), lambda: _pool_to_row_body)
+        from .paged import _pool_to_row
+
+        fn = self._twin("pool_to_row", (),
+                        lambda: partial(_pool_to_row.__wrapped__,
+                                        head_dim=self._head_dim))
         return self._dispatch(fn, pool_k, pool_v, idx, n_used)
 
     def row_to_pool(self, pool_k, pool_v, kc_row, vc_row, idx):
-        from .tp import _row_to_pool_body
+        from .paged import _row_to_pool
 
-        fn = self._twin("row_to_pool", (), lambda: _row_to_pool_body,
-                        donate=(0, 1))
+        fn = self._twin("row_to_pool", (),
+                        lambda: _row_to_pool.__wrapped__, donate=(0, 1))
         return self._dispatch(fn, pool_k, pool_v, kc_row, vc_row, idx)
 
     def rows_to_pool(self, pool_k, pool_v, kc_rows, vc_rows, sel, idx):
-        from .tp import _rows_to_pool_body
+        from .paged import _rows_to_pool
 
         fn = self._twin("rows_to_pool", (),
-                        lambda: _rows_to_pool_body, donate=(0, 1))
+                        lambda: _rows_to_pool.__wrapped__, donate=(0, 1))
         return self._dispatch(fn, pool_k, pool_v, kc_rows, vc_rows,
                               sel, idx)
 

@@ -7,7 +7,7 @@ every admission anyway.  This module is the RadixAttention/SGLang idea
 (Zheng et al. 2023) rebuilt for the fixed-shape TPU engine:
 
 * **block pool** — one preallocated arena of ``num_blocks`` KV blocks
-  per K/V, shape ``(L, num_blocks + 1, H_kv, block_size, D)`` (the +1
+  per K/V, shape ``(L, num_blocks + 1, block_size, H_kv·D)`` (the +1
   is a trash block scatter padding writes into).  A cached prefix is a
   chain of blocks; all device copies between the pool and a slot's
   cache row are ONE fixed-shape gather/scatter executable each,
@@ -49,7 +49,6 @@ from __future__ import annotations
 
 import itertools
 from dataclasses import dataclass
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -57,8 +56,10 @@ import numpy as np
 
 from ..observe import requests as _reqs
 from ..observe.registry import registry as _default_registry
+from ..ops.paged_attention import pool_zeros
 from ..resilience import faults as _faults
 from ..utils.logging import get_channel
+from .paged import _pool_to_row, _row_to_pool
 
 __all__ = ["PrefixCacheConfig", "PrefixCache", "SessionHandle",
            "FleetPrefixIndex"]
@@ -91,50 +92,9 @@ class PrefixCacheConfig:
 
 
 # -- fixed-shape device copies ----------------------------------------------
-# Shapes are keyed on (pool, row) geometry only: every call below is
-# compiled once per engine and reused for any chain length, because the
-# block-index vector is always the full row's worth of block slots
-# (W // block_size entries) with unused lanes masked / pointed at the
-# trash block.  PYTREE-GENERIC since the paged round (the leaf helpers
-# live in serve/paged.py): dense pools are plain arrays, int8 pools are
-# (values, scales) tuples — the per-leaf block width comes off the
-# leaf's own shape, so the trailing-axis-free scales leaf rides the
-# same executables.  This is what lifted the old int8 + prefix-cache
-# refusal.
-
-@jax.jit
-def _blocks_to_row(pool_k, pool_v, idx, n_used):
-    """Gather ``idx`` (nb,) pool blocks into a fresh (L, 1, H, W, ...)
-    cache row per leaf: block j covers positions [j*B, (j+1)*B).
-    Lanes ``>= n_used`` (traced) are zeroed — junk that the chunked
-    prefill and the decode mask never read live."""
-    from .paged import _leaf_to_row
-
-    def gather(pool):
-        return _leaf_to_row(pool, idx, n_used, pool.shape[3])
-
-    return jax.tree.map(gather, pool_k), jax.tree.map(gather, pool_v)
-
-
-@partial(jax.jit, donate_argnums=(0, 1))
-def _row_to_blocks(pool_k, pool_v, kc_row, vc_row, idx):
-    """Scatter a cache row's blocks into the pool at ``idx`` (nb,)
-    block slots.  Lanes that should not store anything point at the
-    trash block (index ``num_blocks``, reserved by the pool for
-    exactly this) so one executable serves every donation size.
-    Duplicate trash-lane writes collide only with each other.  The
-    pool buffers are DONATED (the caller rebinds) — without that,
-    every retirement's donation would copy the whole pool (hundreds
-    of MB at production block counts) instead of scattering in
-    place."""
-    from .paged import _leaf_to_pool
-
-    def scatter(pool, row):
-        return _leaf_to_pool(pool, row, idx, pool.shape[3])
-
-    return (jax.tree.map(scatter, pool_k, kc_row),
-            jax.tree.map(scatter, pool_v, vc_row))
-
+# The cache-owned pool is stored and copied as the paged arena's is
+# (serve/paged.py ``_pool_to_row`` / ``_row_to_pool``: one executable
+# per (pool, row) geometry whatever the chain length, dense or int8).
 
 @jax.jit
 def _read_slot(kc_arena, vc_arena, slot):
@@ -396,6 +356,7 @@ class PrefixCache:
         # counts, and LRU state are untouched — a cached block is the
         # same logical block on every shard
         self._tp = tp
+        self._head_dim = int(head_dim)
         if arena is not None:
             self.num_blocks = arena.num_blocks
             self._pool_k = self._pool_v = None
@@ -404,14 +365,12 @@ class PrefixCache:
             # int8 pools are the engine arena's (values, scales) layout.
             # An unsharded engine hands its weights' ``sharding`` so
             # the pool is born beside them; a tp executor lays it out
-            lead = (n_layer, N + 1, n_kv_head, B)
-            self._pool_k = _kv_zeros(lead, head_dim, dtype, quant,
-                                     sharding)
-            self._pool_v = _kv_zeros(lead, head_dim, dtype, quant,
-                                     sharding)
+            self._pool_k, self._pool_v = (
+                pool_zeros(n_layer, N + 1, B, n_kv_head, head_dim,
+                           dtype, quant, sharding) for _ in "kv")
             if tp is not None:
-                self._pool_k = tp.place_cache(self._pool_k)
-                self._pool_v = tp.place_cache(self._pool_v)
+                self._pool_k = tp.place_pool(self._pool_k)
+                self._pool_v = tp.place_pool(self._pool_v)
         self._root = _Node((), None, -1, 0)
         self._free = [] if arena is not None else list(range(N))
         self._nodes_by_block = {}       # pool slot -> node
@@ -615,8 +574,9 @@ class PrefixCache:
         if self._tp is not None:
             return self._tp.pool_to_row(self._pool_k, self._pool_v,
                                         idx, jnp.int32(len(nodes)))
-        return _blocks_to_row(self._pool_k, self._pool_v, idx,
-                              jnp.int32(len(nodes)))
+        return _pool_to_row(self._pool_k, self._pool_v, idx,
+                            jnp.int32(len(nodes)),
+                            head_dim=self._head_dim)
 
     def adopt_blocks(self, tokens, blocks, n_goal):
         """ZERO-COPY donation (arena mode): insert tree nodes that
@@ -694,7 +654,7 @@ class PrefixCache:
                         self._pool_k, self._pool_v, kc_row, vc_row,
                         jnp.asarray(idx))
                 else:
-                    self._pool_k, self._pool_v = _row_to_blocks(
+                    self._pool_k, self._pool_v = _row_to_pool(
                         self._pool_k, self._pool_v, kc_row, vc_row,
                         jnp.asarray(idx))
                 self._g_cached.set(self.cached_blocks)

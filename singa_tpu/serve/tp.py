@@ -19,11 +19,13 @@ whatever fits one device.  This module shards ONE engine instead:
   ``lax.psum`` each (``gpt2_decode._tp_psum`` — 2 collectives per
   layer per step, recorded with axis name + mesh size so Chrome traces
   can attribute them);
-* **sharded KV** — each shard owns a ``(L, num_blocks+1, H_kv/tp,
-  block_size, D)`` slice of the paged block pool (and of the int8
-  scales leaf, slot arenas, prefix-cache pool, and every cache row):
-  ``decode_cache_spec`` pins the KV-head axis, which is ALWAYS axis 2,
-  whatever the leaf rank.  Block ids are global — a pool block is the
+* **sharded KV** — each shard owns its H_kv/tp contiguous heads of
+  every KV leaf: of slot arenas and cache rows the head axis
+  (``decode_cache_spec``: ALWAYS axis 2, whatever the leaf rank), of
+  the paged block pool and the prefix-cache pool the last axis — a
+  ``(L, num_blocks+1, block_size, H_kv/tp·D)`` slice, and the same
+  slice of the int8 scales leaf (``decode_pool_spec``; a pool row is
+  the heads side by side).  Block ids are global — a pool block is the
   same logical block on every shard — so the host-side free list,
   block tables, radix tree, preemption/swap bookkeeping, scheduler,
   and request ledger are untouched and see a single logical engine;
@@ -77,7 +79,8 @@ from ..observe.registry import registry as _default_registry
 from ..parallel.sharding import TP as TP_AXIS
 from ..parallel.sharding import create_tp_mesh
 from ..parallel.tensor_parallel import (decode_cache_spec,
-                                        decode_param_specs)
+                                        decode_param_specs,
+                                        decode_pool_spec)
 from ..resilience import faults as _faults
 from ..utils.logging import get_channel
 
@@ -86,8 +89,10 @@ __all__ = ["TPConfig", "TPExecutor", "fleet_tp_configs"]
 #: replicated spec (host scalars, token/pos/live vectors, draft state,
 #: sampling keys — everything the twins do not shard)
 _R = P()
-#: every KV leaf: head axis (axis 2) over the tp mesh
+#: every arena / cache-row leaf: head axis (axis 2) over the tp mesh
 _CS = decode_cache_spec(TP_AXIS)
+#: every block-pool leaf: the row axis (the last; heads side by side)
+_PS = decode_pool_spec(TP_AXIS)
 
 # module-wide twin cache: (base, extra statics, executor key) -> jitted
 # sharded executable.  Engines, supervisor rebuilds, and same-device
@@ -245,6 +250,8 @@ class TPExecutor:
         self._top = None
         self._pspec = None     # set by place_params
         self._cache_sh = NamedSharding(self.mesh, _CS)
+        self._pool_sh = NamedSharding(self.mesh, _PS)
+        self._head_dim = int(cfg.n_embd) // int(cfg.n_head)
         self._repl_sh = NamedSharding(self.mesh, _R)
         self._kv_bytes = 0
         self._log = get_channel("serve")
@@ -304,16 +311,21 @@ class TPExecutor:
             lambda a, s: jax.device_put(
                 a, NamedSharding(self.mesh, s)), params, self._pspec)
 
-    def place_cache(self, tree):
-        """Place a KV pytree (arena/pool/row; dense or (values,
-        scales)) sharded on its head axis, and account its per-shard
-        bytes in ``serve.tp.kv_bytes_per_shard``."""
-        placed = jax.tree.map(
-            lambda a: jax.device_put(a, self._cache_sh), tree)
+    def place_cache(self, tree, sharding=None):
+        """Place a KV pytree (arena/row; dense or (values, scales))
+        sharded on its head axis, and account its per-shard bytes in
+        ``serve.tp.kv_bytes_per_shard``."""
+        sh = sharding or self._cache_sh
+        placed = jax.tree.map(lambda a: jax.device_put(a, sh), tree)
         self._kv_bytes += sum(a.nbytes
                               for a in jax.tree.leaves(tree)) // self.tp
         self._g_kv.set(self._kv_bytes)
         return placed
+
+    def place_pool(self, tree):
+        """:meth:`place_cache` for a block pool: sharded on its last
+        axis, each shard its contiguous heads' part of every row."""
+        return self.place_cache(tree, self._pool_sh)
 
     def place_replicated(self, tree):
         """Commit a pytree replicated across the mesh (draft params
@@ -394,15 +406,15 @@ class TPExecutor:
             "prefill_one": (ps, _R, _R, _R, _R, _R),
             "prefill_batch": (ps, _R, _R, _R, _R, _R),
             "chunk_row": (ps, _R, _CS, _CS, _R),
-            "paged_decode": (ps, _CS, _CS, _R, _R, _R, _R, _R, _R,
+            "paged_decode": (ps, _PS, _PS, _R, _R, _R, _R, _R, _R,
                              _R),
-            "paged_spec": (ps, _R, _CS, _CS, _R, _R, _R, _R, _R, _R,
+            "paged_spec": (ps, _R, _PS, _PS, _R, _R, _R, _R, _R, _R,
                            _R, _R, _R),
             "write_slot": (_CS, _CS, _CS, _CS, _R),
             "read_slot": (_CS, _CS, _R),
-            "pool_to_row": (_CS, _CS, _R, _R),
-            "row_to_pool": (_CS, _CS, _CS, _CS, _R),
-            "rows_to_pool": (_CS, _CS, _CS, _CS, _R, _R),
+            "pool_to_row": (_PS, _PS, _R, _R),
+            "row_to_pool": (_PS, _PS, _CS, _CS, _R),
+            "rows_to_pool": (_PS, _PS, _CS, _CS, _R, _R),
             # ring prefill: replicated weights, SEQUENCE-sharded ids
             "ring_prefill": (_R, P(None, TP_AXIS)),
         }[base]
@@ -414,13 +426,13 @@ class TPExecutor:
             "prefill_one": (_R, _R, _CS, _CS),
             "prefill_batch": (_R, _R, _CS, _CS),
             "chunk_row": (_R, _CS, _CS),
-            "paged_decode": (_R, _CS, _CS, _R),
-            "paged_spec": (_R, _R, _CS, _CS, _R, _R, _R),
+            "paged_decode": (_R, _PS, _PS, _R),
+            "paged_spec": (_R, _R, _PS, _PS, _R, _R, _R),
             "write_slot": (_CS, _CS),
             "read_slot": (_CS, _CS),
             "pool_to_row": (_CS, _CS),
-            "row_to_pool": (_CS, _CS),
-            "rows_to_pool": (_CS, _CS),
+            "row_to_pool": (_PS, _PS),
+            "rows_to_pool": (_PS, _PS),
             # (hidden, kc_row, vc_row) — everything sharded on the
             # SEQUENCE axis; ring_prefill_one re-places afterwards
             "ring_prefill": (P(None, TP_AXIS, None),
@@ -567,18 +579,34 @@ class TPExecutor:
                         lambda: _read_slot.__wrapped__)
         return self._dispatch(fn, kc, vc, slot)
 
+    # The pool<->row copies are paged.py's own bodies: a shard's part
+    # of a pool row is its heads side by side, of a cache row its head
+    # slice, so the same blocks<->row turn serves each shard (and, with
+    # the block width read off the pool, the paged arena AND the prefix
+    # cache's private pool whatever their block sizes).
+
     def pool_to_row(self, pool_k, pool_v, idx, n_used):
-        fn = self._twin("pool_to_row", (), lambda: _pool_to_row_body)
+        from functools import partial
+
+        from .paged import _pool_to_row
+
+        fn = self._twin("pool_to_row", (),
+                        lambda: partial(_pool_to_row.__wrapped__,
+                                        head_dim=self._head_dim))
         return self._dispatch(fn, pool_k, pool_v, idx, n_used)
 
     def row_to_pool(self, pool_k, pool_v, kc_row, vc_row, idx):
-        fn = self._twin("row_to_pool", (), lambda: _row_to_pool_body,
-                        donate=(0, 1))
+        from .paged import _row_to_pool
+
+        fn = self._twin("row_to_pool", (),
+                        lambda: _row_to_pool.__wrapped__, donate=(0, 1))
         return self._dispatch(fn, pool_k, pool_v, kc_row, vc_row, idx)
 
     def rows_to_pool(self, pool_k, pool_v, kc_rows, vc_rows, sel, idx):
+        from .paged import _rows_to_pool
+
         fn = self._twin("rows_to_pool", (),
-                        lambda: _rows_to_pool_body, donate=(0, 1))
+                        lambda: _rows_to_pool.__wrapped__, donate=(0, 1))
         return self._dispatch(fn, pool_k, pool_v, kc_rows, vc_rows,
                               sel, idx)
 
@@ -610,7 +638,7 @@ class TPExecutor:
             x = (jnp.take(params["wte"], ids[0], axis=0)[None]
                  + jnp.take(params["wpe"], pos, axis=0)[None])
             ks, vs = [], []
-            for p in params["blocks"]:
+            for p in G._layers(params):
                 h = G._ln(x, p["ln1_s"], p["ln1_b"], eps)
                 q = h @ p["wq"] + p["bq"]
                 k = h @ p["wk"] + p["bk"]
@@ -697,45 +725,3 @@ class TPExecutor:
             "kv_bytes_per_shard": self._kv_bytes,
             "sharded_dispatches": self._c_dispatch.value,
         }
-
-
-# -- copy-twin bodies --------------------------------------------------------
-# The pool<->row copies take the per-leaf block width off the leaf's
-# own shape (paged._leaf_to_row/_leaf_to_pool), so ONE body serves the
-# paged arena AND the prefix cache's private pool whatever their block
-# sizes — exactly prefix._blocks_to_row/_row_to_blocks' math, restated
-# here positionally for the shard_map wrapper.
-
-def _pool_to_row_body(pool_k, pool_v, idx, n_used):
-    from .paged import _leaf_to_row
-
-    def gather(pool):
-        return _leaf_to_row(pool, idx, n_used, pool.shape[3])
-
-    return jax.tree.map(gather, pool_k), jax.tree.map(gather, pool_v)
-
-
-def _row_to_pool_body(pool_k, pool_v, kc_row, vc_row, idx):
-    from .paged import _leaf_to_pool
-
-    def scatter(pool, row):
-        return _leaf_to_pool(pool, row, idx, pool.shape[3])
-
-    return (jax.tree.map(scatter, pool_k, kc_row),
-            jax.tree.map(scatter, pool_v, vc_row))
-
-
-def _rows_to_pool_body(pool_k, pool_v, kc_rows, vc_rows, sel, idx):
-    import jax.numpy as jnp
-
-    from .paged import _leaf_to_pool
-
-    def scatter(pool, rows):
-        r = jnp.take(rows, sel, axis=1)
-        r = jnp.moveaxis(r, 1, 2)
-        s = r.shape
-        r = r.reshape(s[0], 1, s[1], s[2] * s[3], *s[4:])
-        return _leaf_to_pool(pool, r, idx, pool.shape[3])
-
-    return (jax.tree.map(scatter, pool_k, kc_rows),
-            jax.tree.map(scatter, pool_v, vc_rows))

@@ -456,13 +456,14 @@ def test_kernel_vs_gather_token_identity(model, draft):
 def test_kernel_logits_allclose_gather_oracle(model):
     """Unit-level oracle for the online-softmax accumulator: one
     decode step through ``decode_step_paged`` against a random pool
-    vs the row-math ``decode_step`` on the SAME KV materialized into
-    a row — logits allclose (reduction order is the only difference),
-    the written block's untouched lanes BYTE-equal to the pool (the
-    read-modify-write round-trips bytes), and layer 0's written K row
-    bitwise equal to the row path's (identical input, identical
-    projection)."""
+    (a live lane and a dead one) vs the row-math ``decode_step`` on the
+    SAME KV materialized into a row — logits allclose (reduction order
+    is the only difference), every pool byte but the written row and
+    the trash block BYTE-equal to the pool before (the whole-block
+    read-modify-write round-trips bytes; the dead lane writes only the
+    trash block), and the written K rows equal to the row path's."""
     from singa_tpu.models import gpt2_decode as gd
+    from singa_tpu.ops.paged_attention import row_to_blocks
     import jax.numpy as jnp
 
     params = gd.extract_params(model)
@@ -471,43 +472,211 @@ def test_kernel_logits_allclose_gather_oracle(model):
     D = cfg.n_embd // cfg.n_head
     B, N = 8, 6
     rng = np.random.RandomState(0)
-    pool_k = rng.randn(L, N + 1, H, B, D).astype(np.float32)
-    pool_v = rng.randn(L, N + 1, H, B, D).astype(np.float32)
+    pool_k = rng.randn(L, N + 1, B, H * D).astype(np.float32)
+    pool_v = rng.randn(L, N + 1, B, H * D).astype(np.float32)
     pos, tok = 13, 7              # mid-block: block 1, offset 5
-    tbl = np.full(4, N, np.int32)
-    tbl[:2] = [3, 1]              # non-contiguous blocks, trash-padded
-    x = (params["wte"][tok] + params["wpe"][pos])[None, None, :]
+    tbl = np.full((2, 4), N, np.int32)
+    tbl[:, :2] = [3, 1]           # non-contiguous blocks, trash-padded
+    x = params["wte"][tok] + params["wpe"][pos]
     n_blk = (pos + B - 1) // B
     eps = float(cfg.layer_norm_eps)
-    logits_k, kb, vb = gd.decode_step_paged(
-        params, x, jnp.asarray(pool_k), jnp.asarray(pool_v),
-        jnp.asarray(tbl), jnp.int32(pos), jnp.int32(n_blk),
-        cfg.n_head, eps, block=B, trash=N)
+    logits_k, pk2, pv2 = gd.decode_step_paged(
+        params, jnp.stack([x, x]), jnp.asarray(pool_k),
+        jnp.asarray(pool_v), jnp.asarray(tbl),
+        jnp.asarray([pos, 0], jnp.int32), jnp.asarray([True, False]),
+        jnp.int32(n_blk), cfg.n_head, eps, block=B, trash=N)
     # oracle: the same KV materialized into a (max_len) row
-    W = len(tbl) * B
+    W = tbl.shape[1] * B
     row_k = np.zeros((L, 1, H, W, D), np.float32)
     row_v = np.zeros((L, 1, H, W, D), np.float32)
-    for j, b in enumerate(tbl[:2]):
-        row_k[:, 0, :, j * B:(j + 1) * B] = pool_k[:, b]
-        row_v[:, 0, :, j * B:(j + 1) * B] = pool_v[:, b]
+    for j, b in enumerate(tbl[0, :2]):
+        blk = lambda p: p[:, b].reshape(L, B, H, D).transpose(0, 2, 1, 3)
+        row_k[:, 0, :, j * B:(j + 1) * B] = blk(pool_k)
+        row_v[:, 0, :, j * B:(j + 1) * B] = blk(pool_v)
+    # the layout helper agrees with the hand-built row
+    np.testing.assert_array_equal(
+        np.asarray(row_to_blocks(jnp.asarray(row_k), B))[:, :2],
+        pool_k[:, tbl[0, :2]])
     logits_r, kc2, vc2 = gd.decode_step(
-        params, x, jnp.asarray(row_k), jnp.asarray(row_v),
-        jnp.int32(pos), cfg.n_head, eps)
+        params, x[None, None, :], jnp.asarray(row_k),
+        jnp.asarray(row_v), jnp.int32(pos), cfg.n_head, eps)
     np.testing.assert_allclose(np.asarray(logits_k)[0],
                                np.asarray(logits_r)[0],
                                rtol=2e-5, atol=2e-5)
-    # written block = pool block tbl[1], lane pos % B replaced
-    kb = np.asarray(kb)           # (L, H, B, D)
+    # only row pos % B of block tbl[1] (and the trash block) changed
+    pk2 = np.asarray(pk2)
     off = pos % B
-    untouched = [i for i in range(B) if i != off]
-    np.testing.assert_array_equal(kb[:, :, untouched],
-                                  pool_k[:, 1][:, :, untouched])
-    # layer 0's K row: same x, same projection — bitwise
-    np.testing.assert_array_equal(
-        kb[0][:, off], np.asarray(kc2)[0, 0][:, pos])
+    same = np.ones(pool_k.shape[1:3], bool)
+    same[1, off] = same[N] = False
+    np.testing.assert_array_equal(pk2[:, same], pool_k[:, same])
+    assert not np.array_equal(pk2[:, 1, off], pool_k[:, 1, off])
+    # the written K rows: layer 0's from the same x through the same
+    # projection (each path compiles its own layer body, so to rounding)
+    kb = pk2[:, 1, off].reshape(L, H, D)
     np.testing.assert_allclose(
-        kb[:, :, off], np.asarray(kc2)[:, 0][:, :, pos],
+        kb[0], np.asarray(kc2)[0, 0][:, pos], rtol=2e-6, atol=2e-6)
+    np.testing.assert_allclose(
+        kb, np.asarray(kc2)[:, 0][:, :, pos],
         rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("n_kv,d,quant", [(20, 64, False), (4, 128, False),
+                                          (1, 64, False), (4, 128, True)])
+def test_pool_layout_helpers(n_kv, d, quant):
+    """ops/paged_attention.py alone: a cache row scattered into a pool
+    through a block table with trash entries and gathered back is the
+    row again (``row_to_blocks`` / ``blocks_to_row``, values and scales
+    leaves), ``write_rows`` changes one row of one block and nothing
+    else but the trash block, and ``paged_attn`` over the token-a-row
+    pool equals plain softmax attention over the gathered row."""
+    import jax
+    import jax.numpy as jnp
+
+    from singa_tpu.models.gpt2_decode import _quantize_kv
+    from singa_tpu.ops import paged_attention as pa
+
+    L, B, N, nb = 2, 8, 9, 4
+    rng = np.random.RandomState(n_kv + d)
+
+    def cache_row():
+        r = jnp.asarray(rng.randn(L, 1, n_kv, nb * B, d), jnp.float32)
+        return _quantize_kv(r) if quant else r
+
+    row_k, row_v = cache_row(), cache_row()
+    pools = [jax.tree.map(lambda z: z + 7, pa.pool_zeros(
+        L, N + 1, B, n_kv, d, jnp.float32, quant)) for _ in "kv"]
+    assert jax.tree.leaves(pools[0])[0].shape == (L, N + 1, B, n_kv * d)
+    # blocks 5, 2, 7 hold the row's first three; the fourth lands in
+    # the trash block (N)
+    idx = jnp.asarray([5, 2, 7, N], jnp.int32)
+    pool_k, pool_v = (jax.tree.map(
+        lambda p, r: p.at[:, idx].set(pa.row_to_blocks(r, B)), pool, row)
+        for pool, row in zip(pools, (row_k, row_v)))
+    back = jax.tree.map(
+        lambda p, hd: pa.blocks_to_row(jnp.take(p, idx[:3], axis=1), hd),
+        pool_k, pa.leaf_dims(pool_k, d))
+    jax.tree.map(lambda b, r: np.testing.assert_array_equal(
+        np.asarray(b), np.asarray(r)[:, 0, :, :3 * B]), back, row_k)
+    untouched = [b for b in range(N) if b not in (5, 2, 7)]
+    for leaf in jax.tree.leaves(pool_k):
+        assert (np.asarray(leaf)[:, untouched] == 7).all()
+
+    # one new row a lane: lane 0 live at position 13 (block 2, row 5),
+    # lane 1 dead
+    tables = jnp.asarray([[5, 2, 7, N]] * 2, jnp.int32)
+    pos, live = jnp.asarray([13, 0]), jnp.asarray([True, False])
+    new = jax.tree.map(
+        lambda p: jnp.asarray(rng.randn(2, 1, p.shape[-1]), p.dtype),
+        pool_k)
+    wrote = jax.tree.map(
+        lambda p, r: pa.write_rows(p, 1, r, tables, pos, live, B, N),
+        pool_k, new)
+    for w, p, r in zip(*map(jax.tree.leaves, (wrote, pool_k, new))):
+        w, p = np.asarray(w), np.asarray(p)
+        np.testing.assert_array_equal(w[1, 2, 5], np.asarray(r)[0, 0])
+        same = np.ones(p.shape[:3], bool)
+        same[1, 2, 5] = same[:, N] = False
+        np.testing.assert_array_equal(w[same], p[same])
+
+    # attention of 2 queries a K/V head at position 21 (3 keys of the
+    # third block live) plus one current key
+    g, p_limit = 2, 21
+    q = jnp.asarray(rng.randn(n_kv, g, 1, d), jnp.float32)
+    cur = jax.tree.map(lambda p: p[0, 0, :1], pool_k)     # any one row
+    out = pa.paged_attn(q, pool_k, pool_v, 1, tables[0], p_limit, 3, B,
+                        N, cur, cur, jnp.ones((1, 1), bool), d ** -0.5)
+
+    def dense(row):                     # (H, W, D) float32 of layer 1
+        if quant:
+            return np.asarray(row[0])[1, 0] * np.asarray(row[1])[1, 0][
+                ..., None]
+        return np.asarray(row)[1, 0]
+
+    def cur_dense():
+        if quant:
+            return (np.asarray(cur[0], np.float32).reshape(1, n_kv, d)
+                    * np.asarray(cur[1])[..., None]).transpose(1, 0, 2)
+        return np.asarray(cur).reshape(1, n_kv, d).transpose(1, 0, 2)
+
+    k = np.concatenate([dense(row_k)[:, :p_limit], cur_dense()], 1)
+    v = np.concatenate([dense(row_v)[:, :p_limit], cur_dense()], 1)
+    sc = np.einsum("kgqd,ktd->kgqt", np.asarray(q), k) * d ** -0.5
+    pr = np.exp(sc - sc.max(-1, keepdims=True))
+    pr /= pr.sum(-1, keepdims=True)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.einsum("kgqt,ktd->kgqd", pr, v),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["dense", "int8"])
+def test_arena_copies_are_byte_exact(quant):
+    """The arena's device copies over the token-a-row pool, with no
+    engine around them: swap-out -> swap-in into other blocks, image
+    export -> import into a second arena, the batched admission scatter
+    and the copy-on-write block copy all move exactly the rows' bytes
+    (values and scales leaves), leave every other block alone, and an
+    image of another head geometry with the same row width is refused
+    typed."""
+    import jax
+    import jax.numpy as jnp
+
+    from singa_tpu.serve.kvimage import KVImageError
+    from singa_tpu.serve.paged import PagedKVArena
+
+    L, H, D, B, N, W = 2, 4, 16, 8, 12, 32
+    rng = np.random.RandomState(int(quant))
+
+    def arena(label, n_kv=H, d=D):
+        a = PagedKVArena(PagedConfig(block_size=B, num_blocks=N), L, n_kv,
+                         d, jnp.float32, W, quant=quant,
+                         engine_label=label)
+        fill = lambda z: jnp.asarray(
+            rng.randint(-100, 100, z.shape).astype(z.dtype))
+        a.pool_k = jax.tree.map(fill, a.pool_k)
+        a.pool_v = jax.tree.map(fill, a.pool_v)
+        return a
+
+    def blocks(a, ids):
+        return [np.asarray(leaf)[:, ids] for leaf in
+                jax.tree.leaves((a.pool_k, a.pool_v))]
+
+    def same(xs, ys):
+        for x, y in zip(xs, ys):
+            np.testing.assert_array_equal(x, y)
+
+    a, b = arena("copies-a"), arena("copies-b")
+    try:
+        src, dst = [7, 2, 9], [4, 11, 0]
+        others = [i for i in range(N) if i not in dst]
+        want, before = blocks(a, src), blocks(a, others)
+        a.swap_in(a.swap_out(src, 3), dst)
+        same(blocks(a, dst), want)
+        same(blocks(a, others), before)
+        # ship: a narrow image of two blocks into another arena
+        img = a.export_image(src[:2], 2)
+        assert img.width == 2 * B
+        b.import_image(img, {0: 5, 1: 3})
+        same(blocks(b, [5, 3]), blocks(a, src[:2]))
+        # the admission scatter: rows 2 and 0 of a batch of three
+        rows = [jax.tree.map(lambda *r: jnp.concatenate(r, axis=1),
+                             *(a.gather_row([i, i + 1])[kv]
+                               for i in (1, 4, 7))) for kv in (0, 1)]
+        b.scatter_rows(rows[0], rows[1], [2, 0],
+                       [{0: 10, 1: 6}, {0: 8}])
+        same(blocks(b, [10, 6, 8]), blocks(a, [7, 8, 1]))
+        # copy-on-write
+        b.copy_block(10, 1)
+        same(blocks(b, [1]), blocks(b, [10]))
+        # half the heads of twice the size: the same row width
+        c = arena("copies-c", n_kv=H // 2, d=2 * D)
+        try:
+            with pytest.raises(KVImageError, match="incompatible"):
+                c.import_image(img, {0: 1})
+        finally:
+            c.unregister()
+    finally:
+        a.unregister()
+        b.unregister()
 
 
 def test_prefill_width_invariance(model):
@@ -683,3 +852,45 @@ def test_metrics_and_health_surface(model):
     snap2 = registry().snapshot()
     assert f"serve.paged.blocks_free{{engine={lbl}}}" \
         not in snap2["gauges"]
+
+
+def test_compiled_programs_report_their_temporaries(model):
+    """``_aot_call`` puts what a paged program keeps beside its
+    arguments (``temp_bytes``) and how much of them it updates where
+    they lie (``alias_bytes``: at least the two pools) on its
+    ``serve/compile`` span, and the temporaries into the gauge
+    ``serve.paged.program_temp_bytes{program=}`` -- a step that stopped
+    being in place says so at set-up, on any backend."""
+    import jax
+
+    from singa_tpu.observe import trace
+
+    trace.enable()
+    trace.clear()
+    try:
+        # a pool geometry no other test uses: the program compiles here
+        eng = model.serve(max_slots=2,
+                          paged=PagedConfig(block_size=8, num_blocks=11))
+        try:
+            pools = sum(a.nbytes for a in jax.tree.leaves(
+                (eng.paged_arena.pool_k, eng.paged_arena.pool_v)))
+            h = eng.submit(GenerationRequest(
+                np.arange(1, 9, dtype=np.int32), max_new_tokens=4,
+                temperature=0.0))
+            eng.run_until_complete(max_steps=200)
+            assert h.done()
+        finally:
+            eng.close(force=True)
+        spans = [e for e in trace.events()
+                 if e.get("name") == "serve/compile"
+                 and e.get("args", {}).get("fn") == "paged_decode_kernel"]
+    finally:
+        trace.disable()
+        trace.clear()
+    assert spans, "the decode kernel compiled under no serve/compile span"
+    args = spans[-1]["args"]
+    assert args["alias_bytes"] >= pools
+    assert 0 <= args["temp_bytes"] < pools + 64 * 2 ** 20
+    gauges = registry().snapshot()["gauges"]
+    assert gauges["serve.paged.program_temp_bytes"
+                  "{program=paged_decode_kernel}"] == args["temp_bytes"]

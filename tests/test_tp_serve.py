@@ -166,6 +166,61 @@ def test_paged_preempt_resume_parity_tp2(model):
     assert pg["blocks_used"] == 0, "leaked blocks after drain"
 
 
+@pytest.mark.parametrize("cache_dtype", [None, "int8"],
+                         ids=["dense", "int8"])
+def test_paged_pool_shards_its_rows(model, cache_dtype):
+    """The token-a-row pool under TP: every leaf is sharded on its LAST
+    axis (a row is the K/V heads side by side: contiguous heads a
+    shard; the int8 scales leaf the same way), and a host image of a
+    request's blocks is byte-compatible with the unsharded engine's --
+    rows laid in by the sharded engine gather back equal through the
+    single-device pool's copies."""
+    import jax
+
+    kw = dict(max_slots=2, paged=PagedConfig(block_size=8, num_blocks=6))
+    if cache_dtype:
+        kw["cache_dtype"] = cache_dtype
+    cfg = model.cfg
+    H, D = cfg.n_kv_head, cfg.n_embd // cfg.n_head
+    eng, ref = model.serve(tp=2, **kw), model.serve(**kw)
+    try:
+        leaves = jax.tree.leaves(eng.paged_arena.pool_k)
+        widths = [H * D, H] if cache_dtype else [H * D]
+        for leaf, w in zip(leaves, widths):
+            assert leaf.shape == (cfg.n_layer, 7, 8, w)
+            assert leaf.addressable_shards[0].data.shape[-1] == w // 2
+        hs = [e.submit(GenerationRequest(
+            list(range(1, 12)), max_new_tokens=6, temperature=0.0))
+            for e in (eng, ref)]
+        def slot(e):
+            return next((s for s in e._slots if s is not None), None)
+
+        while not (slot(eng) and slot(ref)):
+            eng.step()
+            ref.step()
+        # the prompt's first block, from each engine's own pool
+        imgs = [e.paged_arena.swap_out(slot(e).blocks[:1], 1)
+                for e in (eng, ref)]
+        for a, b in zip(jax.tree.leaves((imgs[0].kc, imgs[0].vc)),
+                        jax.tree.leaves((imgs[1].kc, imgs[1].vc))):
+            # the psum reorders a float sum: an int8 value may land one
+            # step of its scale away
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32), np.asarray(b, np.float32),
+                rtol=1e-4, atol=1.0 if a.dtype == np.int8 else 2e-5)
+        # and either image lands in the other engine's pool
+        imgs[0].validate(8, bool(cache_dtype),
+                         pool_k=ref.paged_arena.pool_k, head_dim=D)
+        imgs[1].validate(8, bool(cache_dtype),
+                         pool_k=eng.paged_arena.pool_k, head_dim=D)
+        for e in (eng, ref):
+            e.run_until_complete(max_steps=400)
+        assert _parity([hs[0].result().tokens], [hs[1].result().tokens])
+    finally:
+        eng.close(force=True)
+        ref.close(force=True)
+
+
 def test_warm_prefix_parity_tp2(model):
     """Prefix-cache rows as sharded pytrees: a shared system prompt
     makes later admissions warm (sharded gather + sharded chunk
