@@ -442,14 +442,12 @@ def chaos_paged(report):
         "resilience.engine_restarts", 0)
     completed = wedged = typed_failed = 0
     preempted_total = 0
-    # default PagedConfig kernel: the BLOCK-NATIVE decode path (the
-    # gather-tax round) — the recovery invariants below therefore
-    # cover the kernel, and the serve.paged_copy fault site still
-    # fires on the admission scatter and the swap gather/scatter
-    # (those copies kept their fixed-shape form; swap is off the hot
-    # path — docs/SERVING.md)
+    # the recovery invariants below cover the block-native decode
+    # path, and the serve.paged_copy fault site still fires on the
+    # admission scatter and the swap gather/scatter (those copies kept
+    # their fixed-shape form; swap is off the hot path —
+    # docs/SERVING.md)
     pcfg = PagedConfig(block_size=8, num_blocks=6)
-    assert pcfg.kernel == "block"
     for fail_after in (2, 7):
         sup = EngineSupervisor(
             m, max_slots=2, restart_budget=2, paged=pcfg)
@@ -493,7 +491,7 @@ def chaos_paged(report):
         "engine_restarts": restarts,
         "preemptions": preempted_total,
         "blocks_leaked": 0,
-        "kernel": pcfg.kernel,
+        "kernel": "block",
     }
     assert wedged == 0, f"{wedged} paged requests wedged/lost"
     assert completed + typed_failed == 2 * len(workload)
@@ -529,7 +527,6 @@ def chaos_fork(report):
     rng = np.random.RandomState(15)
     prompt = rng.randint(0, 256, 12).astype(np.int32)
     pcfg = PagedConfig(block_size=8, num_blocks=32)
-    assert pcfg.kernel == "block"
     n_branches = 3
 
     def run(inject):
@@ -583,7 +580,7 @@ def chaos_fork(report):
         "engine_restarts": restarts,
         "blocks_leaked": leak0 + leak1 + leak2,
         "fresh_pool_parity": bool(fresh_parity),
-        "kernel": pcfg.kernel,
+        "kernel": "block",
     }
     sf = report["serve_fork"]
     assert sf["wedged_or_lost"] == 0, "fork branches wedged/lost"

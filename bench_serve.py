@@ -395,11 +395,10 @@ def run_paged(m, workload, engine_outs):
         },
         "concurrency_gain": peak_p / peak_s,
         "speedup_tokens_per_s": wall_s / wall_p,
-        # the block-native decode kernel (PagedConfig default since
-        # the gather-tax round) — CI gates that the hot path is the
-        # kernel and that its decode TPOT stays within 2x of the slot
-        # arena's (the gather path priced this at ~6x)
-        "kernel": pcfg.kernel,
+        # the block-native decode kernel, the one paged decode path —
+        # CI gates that its decode TPOT stays within 2x of the slot
+        # arena's
+        "kernel": "block",
         "tpot_p50_ratio": (snap_p["latency"]["tpot"]["p50"]
                            / snap_s["latency"]["tpot"]["p50"]),
         "preemptions": pg["preemptions"],

@@ -912,13 +912,10 @@ def _advance_chunk(params, x, kc, vc, pos, n_head, eps, moe_top_k=2,
     return _logits(x, params), kc, vc
 
 
-# -- block-native paged decode attention (the gather-tax round) --------------
-# The serve engine's paged pool steps (serve/paged.py) used to gather
-# every live slot's blocks into a fixed (max_len)-wide row inside the
-# executable before attention ran — a transient O(max_len) workspace a
-# real PagedAttention kernel (vLLM) never allocates, and O(max_len)
-# attention work whatever the slot's actual length.  The kernel below
-# computes flash-style attention DIRECTLY over the block pool with the
+# -- block-native paged decode attention -------------------------------------
+# The serve engine's paged pool steps (serve/paged.py) never lay a
+# slot's blocks out as a (max_len)-wide row: the kernel below computes
+# flash-style attention DIRECTLY over the block pool with the
 # block table as the index structure: a ``lax.fori_loop`` over the
 # slot's live blocks with online-softmax accumulation (running max,
 # rescaled partial sums — the FlashAttention recurrence), trash-block
@@ -938,11 +935,11 @@ def _advance_chunk(params, x, kc, vc, pos, n_head, eps, moe_top_k=2,
 #
 # Parity pins (docs/SERVING.md "Paged KV and preemption"): online
 # softmax REORDERS the float reduction, so bitwise equality to the
-# row-softmax gather path is impossible by construction — the contract
-# is (a) token streams identical to the gather path (and therefore to
-# the slot engine / offline oracles) away from exact argmax/CDF ties,
-# the same caveat TP serving documents for its psum, and (b) per-step
-# logits allclose to the gather oracle (tests/test_paged.py pins both,
+# row-softmax math (``decode_step`` on a materialized row) is impossible
+# by construction — the contract is (a) token streams identical to the
+# slot engine / offline oracles away from exact argmax/CDF ties, the
+# same caveat TP serving documents for its psum, and (b) per-step
+# logits allclose to the row math (tests/test_paged.py pins both,
 # plus byte equality of the untouched lanes of every written block —
 # the read-modify-write keeps pool bytes round-tripping).  int8
 # pools dequantize PER BLOCK inside the accumulator (the same folded
@@ -1148,6 +1145,50 @@ def spec_verify(t_logits, d_probs, props, key, temp, top_p, top_k,
     out = jnp.where(greedy, cands, out_s)
     a_draft = jnp.where(greedy, a_greedy, a_sampled)
     return out, a_draft.astype(jnp.int32)
+
+
+def _batch1(c):
+    """Insert the width-1 batch axis on a cache pytree (dense arrays
+    or (values, scales) tuples)."""
+    return jax.tree.map(lambda a: a[:, None], c)
+
+
+def _unbatch1(c):
+    return jax.tree.map(lambda a: a[:, 0], c)
+
+
+def _draft_propose(d_params, dkc_r, dvc_r, t_c, p_c, k_draft, temp,
+                   top_p, spec_k, dn, de, dm, top_k, use_top_p):
+    """The DRAFT half of one slot's speculative chunk: ``spec_k``
+    sequential draft decode steps propose ``spec_k - 1`` tokens (the
+    extra step processes the last proposal as an input so a
+    full-accept chunk leaves the draft cache a valid row ahead — the
+    same trick as the offline ``_spec_row``).  Shared by the
+    slot-arena spec row and the paged-kernel spec row, so the
+    proposal chain (and therefore the verify outcome) cannot drift
+    between memory models.  Returns (props (spec_k-1,), d_probs
+    (spec_k-1, V), dkc_b, dvc_b) with the draft rows batched."""
+    ts = jnp.maximum(temp, 1e-6)
+
+    def dstep(c, k):
+        dkc_b, dvc_b, tok_, dpos = c
+        x = (d_params["wte"][tok_] + d_params["wpe"][dpos])[None, None]
+        lg, dkc_b, dvc_b = _advance_one(d_params, x, dkc_b, dvc_b,
+                                        dpos, dn, de, moe_top_k=dm)
+        # post-filter draft distribution (the q of the accept
+        # ratio) AND the proposal drawn from it — the identical
+        # filter chain _sample uses, via the shared helper
+        fl = _filter_logits(lg[0], ts, top_p, top_k, use_top_p)
+        nxt_s = jax.random.categorical(k, fl).astype(jnp.int32)
+        nxt_g = jnp.argmax(lg[0]).astype(jnp.int32)
+        nxt = jnp.where(temp <= 0.0, nxt_g, nxt_s)
+        return ((dkc_b, dvc_b, nxt, dpos + 1),
+                (nxt, jax.nn.softmax(fl)))
+
+    dkeys = jax.random.split(k_draft, spec_k)
+    (dkc_b, dvc_b, _, _), (props_all, q_all) = jax.lax.scan(
+        dstep, (_batch1(dkc_r), _batch1(dvc_r), t_c, p_c), dkeys)
+    return props_all[:-1], q_all[:-1], dkc_b, dvc_b
 
 
 def _rep_mask_init(ids, live, vocab):

@@ -38,7 +38,6 @@ FEATURES = frozenset({
     "prefix_cache=", "fork",
     "the slot arena (serving without paged=)",
     "whole-prompt admission (paged= without prefill_token_budget)",
-    "the gather kernel (PagedConfig(kernel='gather'))",
     "KV image ship"})
 
 

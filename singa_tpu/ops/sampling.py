@@ -71,3 +71,19 @@ def sample(logit, key, temperature, top_p, greedy, top_k, use_top_p,
     return jax.random.categorical(key, logit).astype(jnp.int32)
 
 
+def select_sample(logit, key, temp, top_k, top_p, use_top_p, mask=None):
+    """Per-row sampling with a TRACED greedy flag.  The offline paths
+    bake ``greedy`` in as a static (one compile per mode); a slot pool
+    mixes greedy and sampled requests in one executable, so compute
+    both branches of the SAME ``sample`` the offline path uses and
+    select — the greedy branch is argmax over the identical f32 logit,
+    the sampled branch divides by max(temp, 1e-6) exactly as
+    ``generate`` does, so either way the chosen token matches the
+    offline token bit for bit.  ``mask`` (V,) bool or None is the
+    constrained-decoding vocab mask, forwarded to the shared
+    ``sample`` (None / all-True are bitwise no-ops)."""
+    g = sample(logit, key, temp, top_p, True, top_k, use_top_p,
+               mask=mask)
+    s = sample(logit, key, jnp.maximum(temp, 1e-6), top_p, False,
+               top_k, use_top_p, mask=mask)
+    return jnp.where(temp <= 0.0, g, s).astype(jnp.int32)

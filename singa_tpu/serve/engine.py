@@ -59,8 +59,8 @@ shared with the prefix cache — a request's KV is a block list grown
 as decode advances, admission is bounded by blocks free rather than
 slots free, and pool pressure PREEMPTS (swap a request's blocks to
 host byte-exactly, resume later) instead of stalling.  The paged pool
-steps vmap the same ``_decode_row``/``_spec_row`` math the slot-arena
-steps do, so the two memory models produce bit-identical streams.
+steps attend block-natively over the pool (online softmax), so the two
+memory models produce token-identical streams.
 
 Long-context serving (the long-context round; docs/SERVING.md
 "Long-context serving"):
@@ -85,8 +85,8 @@ Long-context serving (the long-context round; docs/SERVING.md
 
 Scope: dense/GQA/MoE models (everything _advance_one supports with a
 position-indexed dense cache).  Sliding-window models serve in paged
-mode only (windowed without ``paged=``, windowed + prefix cache, and
-windowed + ``kernel="gather"`` stay rejected typed);
+mode only (windowed without ``paged=`` and windowed + prefix cache
+stay rejected typed);
 repetition_penalty/min_p are offline-only knobs.  int8 arenas compose
 with the prefix cache since the paged round (pytree-generic block
 pools; cache-enabled int8 engines route every admission through the
@@ -112,8 +112,7 @@ import numpy as np
 # speculation, and the sharded executors' per-row twins
 from ..models import gpt2_decode as _gpt2
 from ..models.served import FEATURES
-from ..ops.sampling import filter_logits as _filter_logits
-from ..ops.sampling import sample as _sample
+from ..ops.sampling import select_sample as _select_sample
 from ..observe import monitor as _monitor
 from ..observe import requests as _reqs
 from ..observe import stepprof as _stepprof
@@ -122,8 +121,7 @@ from ..resilience import faults as _faults
 from ..utils.logging import get_channel
 from .fork import BranchHandle, ForkHandle
 from .paged import (PagedConfig, PagedKVArena, _aot_call,
-                    _paged_decode_kernel, _paged_decode_step,
-                    _paged_spec_kernel, _paged_spec_step)
+                    _paged_decode_kernel, _paged_spec_kernel)
 from .prefix import (PrefixCache, PrefixCacheConfig, SessionHandle,
                      _kv_zeros, _read_slot)
 from .request import (DeadlineExceededError, EngineFailedError,
@@ -133,43 +131,15 @@ from .scheduler import FIFOScheduler, PriorityScheduler
 from .stats import EngineStats
 
 
-def _default_family():
-    """The family of a paged program called with none: the sharded
-    executors' twins (tp/ep/pp), which serve GPT-2 alone."""
-    return _gpt2.FAMILY
-
-
-def _select_sample(logit, key, temp, top_k, top_p, use_top_p,
-                   mask=None):
-    """Per-row sampling with a TRACED greedy flag.  The offline paths
-    bake ``greedy`` in as a static (one compile per mode); a slot pool
-    mixes greedy and sampled requests in one executable, so compute
-    both branches of the SAME ``_sample`` the offline path uses and
-    select — the greedy branch is argmax over the identical f32 logit,
-    the sampled branch divides by max(temp, 1e-6) exactly as
-    ``generate`` does, so either way the chosen token matches the
-    offline token bit for bit.  ``mask`` (V,) bool or None is the
-    constrained-decoding vocab mask, forwarded to the shared
-    ``_sample`` (None / all-True are bitwise no-ops)."""
-    g = _sample(logit, key, temp, top_p, True, top_k, use_top_p,
-                mask=mask)
-    s = _sample(logit, key, jnp.maximum(temp, 1e-6), top_p, False,
-                top_k, use_top_p, mask=mask)
-    return jnp.where(temp <= 0.0, g, s).astype(jnp.int32)
-
-
 def _decode_row(params, kc_r, vc_r, tok, pos_r, live_r, key, temp,
                 top_p, n_head, eps, moe_top_k, top_k, use_top_p,
-                tp_axis=None, tp_world=1, ep=None, mask=None,
-                with_lp=False):
+                tp_axis=None, tp_world=1, ep=None):
     """ONE slot's decode-step math — kc_r/vc_r: (L, H_kv, max_len, D)
     cache rows (int8 arenas are (values, scales) pytrees, so the
     batch-axis insert/strip is tree-mapped rather than indexed).
-    Shared by the slot-arena pool step below AND the paged pool step
-    (serve/paged.py), so the two memory models run literally the same
-    per-row ops and cannot drift.  ``tp_axis``/``tp_world`` thread the
-    tensor-parallel mesh axis through (serve/tp.py's sharded twins;
-    defaults leave the serial math bit-identical)."""
+    ``tp_axis``/``tp_world`` thread the tensor-parallel mesh axis
+    through (serve/tp.py's sharded twins; defaults leave the serial
+    math bit-identical)."""
     p_c = jnp.where(live_r, pos_r, 0)
     t_c = jnp.where(live_r, tok, 0)
     x = (params["wte"][t_c] + params["wpe"][p_c])[None, None, :]
@@ -180,18 +150,9 @@ def _decode_row(params, kc_r, vc_r, tok, pos_r, live_r, key, temp,
         ep=ep)
     ks = jax.random.split(key)
     nxt = _select_sample(logits[0], ks[0], temp, top_k, top_p,
-                         use_top_p, mask=mask)
-    out = (nxt, jax.tree.map(lambda a: a[:, 0], kc2),
-           jax.tree.map(lambda a: a[:, 0], vc2), ks[1])
-    if with_lp:
-        # chosen-token logprob under the RAW model distribution (not
-        # the filtered one) — the fork round's best-of-n ranking
-        # signal; an extra output, never an input, so the sampled
-        # token chain is untouched
-        lp = jax.nn.log_softmax(
-            logits[0].astype(jnp.float32))[nxt]
-        out = out + (lp,)
-    return out
+                         use_top_p)
+    return (nxt, jax.tree.map(lambda a: a[:, 0], kc2),
+            jax.tree.map(lambda a: a[:, 0], vc2), ks[1])
 
 
 @partial(jax.jit,
@@ -305,7 +266,7 @@ def _prefill_rows(params, ids, n_head, eps, moe_top_k, quant=False):
          donate_argnums=(2, 3))
 def _chunk_row(params, ids, kc_row, vc_row, off, state=None,
                n_valid=None, *, n_head, eps, moe_top_k, chunk,
-               window=None, tp_axis=None, tp_world=1, ep=None, fam=None):
+               window=None, tp_axis=None, tp_world=1, ep=None, fam):
     """Offset prefill of ONE block-width window through the family's
     ``chunk_row`` (models/served.py): tokens at positions
     [off, off+chunk) of the padded ``ids`` row, advanced against a
@@ -315,10 +276,7 @@ def _chunk_row(params, ids, kc_row, vc_row, off, state=None,
     are the prompt's and not padding).  ``off`` is traced, so every
     admission's every window rides one executable.  Returns ((1, chunk,
     E) final-norm hidden, kc_row, vc_row[, state]) — rows donated, the
-    admission loop rebinds.  ``fam=None`` is the GPT-2 family: the
-    sharded executors (tp/ep/pp) wrap this function and predate the
-    contract."""
-    fam = fam or _default_family()
+    admission loop rebinds."""
     hidden, kc_row, vc_row, state = fam.chunk_row(
         params, ids, kc_row, vc_row, state, off, n_valid, chunk=chunk,
         n_head=n_head, eps=eps, moe_top_k=moe_top_k, window=window,
@@ -330,14 +288,13 @@ def _chunk_row(params, ids, kc_row, vc_row, off, state=None,
 
 @partial(jax.jit, static_argnames=("top_k", "use_top_p", "fam"))
 def _first_from_hidden(params, hidden, row, key, temp, top_p, top_k,
-                       use_top_p, mask=None, fam=None):
+                       use_top_p, mask=None, *, fam):
     """Sample the admission token from a chunk's hidden block: row
     ``row`` of ``hidden`` (1, chunk, E) is position prompt_len-1.
     Mirrors the tail of ``_prefill_one`` exactly — same (1, 1, E)
     logits projection, same key split, same ``_select_sample`` — so a
     warm admission's first token matches the cold path's bit for bit
     given a bitwise-equal hidden row."""
-    fam = fam or _default_family()
     last_h = jax.lax.dynamic_index_in_dim(hidden, row, axis=1,
                                           keepdims=False)     # (1, E)
     logit0 = fam.logits(params, last_h[:, None, :])[0, 0]     # (V,)
@@ -347,68 +304,23 @@ def _first_from_hidden(params, hidden, row, key, temp, top_p, top_k,
     return tok0, ks[1]
 
 
-def _batch1(c):
-    """Insert the width-1 batch axis on a cache pytree (dense arrays
-    or (values, scales) tuples)."""
-    return jax.tree.map(lambda a: a[:, None], c)
-
-
-def _unbatch1(c):
-    return jax.tree.map(lambda a: a[:, 0], c)
-
-
-def _draft_propose(d_params, dkc_r, dvc_r, t_c, p_c, k_draft, temp,
-                   top_p, spec_k, dn, de, dm, top_k, use_top_p):
-    """The DRAFT half of one slot's speculative chunk: ``spec_k``
-    sequential draft decode steps propose ``spec_k - 1`` tokens (the
-    extra step processes the last proposal as an input so a
-    full-accept chunk leaves the draft cache a valid row ahead — the
-    same trick as the offline ``_spec_row``).  Shared by the
-    slot-arena spec row and the paged-kernel spec row, so the
-    proposal chain (and therefore the verify outcome) cannot drift
-    between memory models.  Returns (props (spec_k-1,), d_probs
-    (spec_k-1, V), dkc_b, dvc_b) with the draft rows batched."""
-    ts = jnp.maximum(temp, 1e-6)
-
-    def dstep(c, k):
-        dkc_b, dvc_b, tok_, dpos = c
-        x = (d_params["wte"][tok_] + d_params["wpe"][dpos])[None, None]
-        lg, dkc_b, dvc_b = _gpt2._advance_one(d_params, x, dkc_b, dvc_b,
-                                        dpos, dn, de, moe_top_k=dm)
-        # post-filter draft distribution (the q of the accept
-        # ratio) AND the proposal drawn from it — the identical
-        # filter chain _sample uses, via the shared helper
-        fl = _filter_logits(lg[0], ts, top_p, top_k, use_top_p)
-        nxt_s = jax.random.categorical(k, fl).astype(jnp.int32)
-        nxt_g = jnp.argmax(lg[0]).astype(jnp.int32)
-        nxt = jnp.where(temp <= 0.0, nxt_g, nxt_s)
-        return ((dkc_b, dvc_b, nxt, dpos + 1),
-                (nxt, jax.nn.softmax(fl)))
-
-    dkeys = jax.random.split(k_draft, spec_k)
-    (dkc_b, dvc_b, _, _), (props_all, q_all) = jax.lax.scan(
-        dstep, (_batch1(dkc_r), _batch1(dvc_r), t_c, p_c), dkeys)
-    return props_all[:-1], q_all[:-1], dkc_b, dvc_b
-
-
 def _spec_row(t_params, d_params, kc_r, vc_r, dkc_r, dvc_r, tok, pos_r,
               live_r, key, temp, top_p, spec_k, tn, te, tm, dn, de, dm,
               top_k, use_top_p, tp_axis=None, tp_world=1, ep=None):
     """ONE slot's speculative-chunk math: the shared draft proposal
-    scan (:func:`_draft_propose`), then ONE target chunk advance
+    scan (``gpt2_decode._draft_propose``), then ONE target chunk advance
     (``_advance_chunk`` — a single cache read serves all ``spec_k``
     positions), then :func:`~singa_tpu.models.gpt2_decode.spec_verify`
     decides the accept count: greedy match for ``temp <= 0`` rows,
     rejection sampling with residual resample for sampled rows — both
     in the SAME executable (temp is traced, like ``_select_sample``).
-    Shared by the slot-arena spec step and the paged GATHER spec step
-    (serve/paged.py) — one definition, no drift; the paged BLOCK
-    kernel (``paged._paged_spec_kernel``) runs the same draft scan and
-    verify per lane around a lane-batched block-native target chunk."""
+    The paged spec program (``paged._paged_spec_kernel``) runs the same
+    draft scan and verify per lane around a lane-batched block-native
+    target chunk."""
     p_c = jnp.where(live_r, pos_r, 0)
     t_c = jnp.where(live_r, tok, 0)
     k_draft, k_verify, k_next = jax.random.split(key, 3)
-    props, d_probs, dkc_b, dvc_b = _draft_propose(
+    props, d_probs, dkc_b, dvc_b = _gpt2._draft_propose(
         d_params, dkc_r, dvc_r, t_c, p_c, k_draft, temp, top_p,
         spec_k, dn, de, dm, top_k, use_top_p)
 
@@ -420,14 +332,13 @@ def _spec_row(t_params, d_params, kc_r, vc_r, dkc_r, dvc_r, tok, pos_r,
     # scan above runs replicated on every shard (same inputs → same
     # proposals bitwise), which is what keeps any draft geometry legal
     # whatever the tp width
-    lg, kc2, vc2 = _gpt2._advance_chunk(t_params, xs, _batch1(kc_r),
-                                  _batch1(vc_r), p_c, tn, te,
-                                  moe_top_k=tm, tp_axis=tp_axis,
-                                  tp_world=tp_world, ep=ep)
+    lg, kc2, vc2 = _gpt2._advance_chunk(
+        t_params, xs, _gpt2._batch1(kc_r), _gpt2._batch1(vc_r), p_c, tn,
+        te, moe_top_k=tm, tp_axis=tp_axis, tp_world=tp_world, ep=ep)
     out, a_draft = _gpt2.spec_verify(lg[0], d_probs, props, k_verify,
                                temp, top_p, top_k, use_top_p)
-    return (out, a_draft, _unbatch1(kc2), _unbatch1(vc2),
-            _unbatch1(dkc_b), _unbatch1(dvc_b), k_next)
+    return (out, a_draft, _gpt2._unbatch1(kc2), _gpt2._unbatch1(vc2),
+            _gpt2._unbatch1(dkc_b), _gpt2._unbatch1(dvc_b), k_next)
 
 
 @partial(jax.jit,
@@ -595,42 +506,29 @@ class _LocalExec:
 
     def paged_decode_step(self, params, pool_k, pool_v, tables, toks,
                           pos, live, keys, temps, top_p, block,
-                          kernel="block", masks=None, with_lp=False,
-                          state=None, slots=None):
-        name, fn = (("paged_decode_kernel", _paged_decode_kernel)
-                    if kernel == "block"
-                    else ("paged_decode_step", _paged_decode_step))
-        # the gather path is refused for windowed models, and for a
-        # family with state of its own; it knows GPT-2's rows alone
-        args, extra = (masks,), {}
-        if kernel == "block":
-            args = (masks, state, slots)
-            extra = {"window": self._e._window, "fam": self._e._fam}
-        return _aot_call(name, fn,
+                          masks=None, with_lp=False, state=None,
+                          slots=None):
+        e = self._e
+        return _aot_call("paged_decode_kernel", _paged_decode_kernel,
                          params, pool_k, pool_v, tables, toks, pos,
-                         live, keys, temps, top_p, *args, block=block,
-                         _memo=self._aot_memo,
-                         _token=(name, toks.shape[0],
+                         live, keys, temps, top_p, masks, state, slots,
+                         block=block, _memo=self._aot_memo,
+                         _token=("paged_decode_kernel", toks.shape[0],
                                  masks is not None, with_lp),
-                         with_lp=with_lp,
-                         **self._e._statics, **extra)
+                         with_lp=with_lp, window=e._window, fam=e._fam,
+                         **e._statics)
 
     def paged_spec_step(self, t_params, d_params, pool_k, pool_v, dkc,
                         dvc, tables, toks, pos, live, keys, temps,
-                        top_p, block, kernel="block"):
+                        top_p, block):
         e = self._e
         st = e._statics
-        name, fn = (("paged_spec_kernel", _paged_spec_kernel)
-                    if kernel == "block"
-                    else ("paged_spec_step", _paged_spec_step))
-        extra = ({"window": e._window} if kernel == "block" else {})
-        return _aot_call(name, fn,
+        return _aot_call("paged_spec_kernel", _paged_spec_kernel,
                          t_params, d_params, pool_k, pool_v, dkc, dvc,
                          tables, toks, pos, live, keys, temps, top_p,
                          _memo=self._aot_memo,
-                         _token=(name, toks.shape[0]),
-                         **extra,
-                         block=block, spec_k=e.spec_k,
+                         _token=("paged_spec_kernel", toks.shape[0]),
+                         window=e._window, block=block, spec_k=e.spec_k,
                          tn=st["n_head"], te=st["eps"],
                          tm=st["moe_top_k"], dn=e._d_statics[0],
                          de=e._d_statics[1], dm=e._d_statics[2],
@@ -856,13 +754,12 @@ class InferenceEngine:
     host, resume byte-identically later) instead of stalling.  Pair
     with ``scheduler="priority"`` so urgent arrivals overtake and
     preempt background work.  Decode runs the BLOCK-NATIVE
-    online-softmax kernel by default (``PagedConfig.kernel``),
-    admissions prefill at narrow widths and batch per scheduling
-    pass, and the pool step dispatches at a compacted width covering
-    only the live slots — token streams stay identical to the slot
-    engine's (bitwise under ``kernel="gather"``; token-identical
-    with an allclose logits pin under the kernel — docs/SERVING.md
-    "Paged KV and preemption" has the full pin taxonomy)."""
+    online-softmax kernel, admissions prefill at narrow widths and
+    batch per scheduling pass, and the pool step dispatches at a
+    compacted width covering only the live slots — token streams stay
+    identical to the slot engine's, with an allclose logits pin
+    against the row math (docs/SERVING.md "Paged KV and preemption"
+    has the pin taxonomy)."""
 
     def __init__(self, model, max_slots=8, max_len=None, dtype=None,
                  scheduler=None, top_k=0, top_p=None,
@@ -882,18 +779,14 @@ class InferenceEngine:
             "the slot arena (serving without paged=)": not _on(paged),
             "whole-prompt admission (paged= without "
             "prefill_token_budget)": _on(paged) and _paged_knob(
-                paged, "prefill_token_budget", None) is None,
-            "the gather kernel (PagedConfig(kernel='gather'))":
-                _paged_knob(paged, "kernel", "block") == "gather"})
+                paged, "prefill_token_budget", None) is None})
         # sliding-window models serve in PAGED mode only (the
         # long-context round): block tables are position-indexed, so
         # a windowed slot drops fully-out-of-window blocks back to
         # the free list as ``pos`` advances — long chats hold
         # O(window) blocks instead of O(length).  The slot arena's
         # worst-case rows still cannot roll, so windowed WITHOUT
-        # paged= stays refused, as does the "gather" parity kernel
-        # (it materializes the whole row and would attend freed
-        # blocks) — both checked below once the paged config parses.
+        # paged= stays refused.
         self._window = fam.window(cfg)
         if self._window is not None and (paged is None
                                          or paged is False):
@@ -906,29 +799,18 @@ class InferenceEngine:
                 "position-indexed rows cannot roll — offline "
                 "windowed GPT2LMHead.generate covers the no-engine "
                 "case")
-        if self._window is not None:
-            # the remaining windowed composition limits, checked
-            # BEFORE any registry/arena state exists so a refused
-            # construction leaks nothing
-            _pk = (paged.kernel if isinstance(paged, PagedConfig)
-                   else paged.get("kernel", "block")
-                   if isinstance(paged, dict) else "block")
-            if _pk != "block":
-                raise ValueError(
-                    f"sliding-window serving requires "
-                    f"PagedConfig(kernel='block'), got {_pk!r}: the "
-                    f"gather oracle materializes the full row and "
-                    f"would attend blocks the windowed slot already "
-                    f"dropped")
-            if prefix_cache is not None and prefix_cache is not False:
-                raise NotImplementedError(
-                    "prefix_cache on a sliding-window model: windowed "
-                    "slots drop out-of-window blocks, so a retiring "
-                    "request's prompt chain is no longer a contiguous "
-                    "block prefix the radix tree could adopt; serve "
-                    "windowed models without a prefix cache "
-                    "(docs/SERVING.md 'Long-context serving' "
-                    "composition matrix)")
+        if self._window is not None and _on(prefix_cache):
+            # the remaining windowed composition limit, checked BEFORE
+            # any registry/arena state exists so a refused construction
+            # leaks nothing
+            raise NotImplementedError(
+                "prefix_cache on a sliding-window model: windowed "
+                "slots drop out-of-window blocks, so a retiring "
+                "request's prompt chain is no longer a contiguous "
+                "block prefix the radix tree could adopt; serve "
+                "windowed models without a prefix cache "
+                "(docs/SERVING.md 'Long-context serving' "
+                "composition matrix)")
         if max_slots < 1:
             raise ValueError(f"max_slots must be >= 1, got {max_slots}")
         self.model = model
@@ -1560,7 +1442,7 @@ class InferenceEngine:
             # (the windowed GPT2LMHead.generate fallback); see
             # docs/SERVING.md "Long-context serving" for what still
             # refuses (windowed without paged=, windowed + prefix
-            # cache, windowed + kernel='gather').
+            # cache).
             raise ValueError(
                 f"prompt ({len(request.prompt_ids)}) + max_new_tokens "
                 f"({request.max_new_tokens})"
@@ -2095,8 +1977,7 @@ class InferenceEngine:
                 self._block_tables(), jnp.asarray(self._toks),
                 jnp.asarray(self._pos), jnp.asarray(live),
                 self._keys, jnp.asarray(self._temps),
-                self._top_p, arena.block_size,
-                kernel=arena.config.kernel)
+                self._top_p, arena.block_size)
         else:
             (out, a_draft, self._kc, self._vc, self._dkc,
              self._dvc, self._keys) = self._x.pool_spec_step(
@@ -2215,8 +2096,7 @@ class InferenceEngine:
                     jnp.asarray(self._pos[sel_in]),
                     jnp.asarray(live_w), keys_w,
                     jnp.asarray(self._temps[sel_in]),
-                    self._top_p, arena.block_size,
-                    kernel=arena.config.kernel, **fkw)
+                    self._top_p, arena.block_size, **fkw)
                 next_toks, arena.pool_k, arena.pool_v, keys2 = \
                     res[:4]
                 self._keys = _set_rows(
@@ -2236,8 +2116,7 @@ class InferenceEngine:
                     jnp.asarray(self._toks),
                     jnp.asarray(self._pos), jnp.asarray(live),
                     self._keys, jnp.asarray(self._temps),
-                    self._top_p, arena.block_size,
-                    kernel=arena.config.kernel, **fkw)
+                    self._top_p, arena.block_size, **fkw)
                 (next_toks, arena.pool_k, arena.pool_v,
                  self._keys) = res[:4]
             if need_lp:
@@ -3309,14 +3188,9 @@ class InferenceEngine:
 
     def _sched_admissions(self, navail, now):
         """One scheduler consultation, shared by the whole-prompt and
-        budgeted passes so the two cannot drift: cap by the
-        admission-interleave knob, pass the warm-prefix cost pricer
-        when the scheduler takes one, and reject deadline-expired
-        requests.  Returns the admit list."""
-        if self.paged_arena is not None \
-                and self.paged_arena.config.admit_per_step is not None:
-            navail = min(navail,
-                         self.paged_arena.config.admit_per_step)
+        budgeted passes so the two cannot drift: pass the warm-prefix
+        cost pricer when the scheduler takes one, and reject
+        deadline-expired requests.  Returns the admit list."""
         if self._sched_cost is not None:
             admit, expired = self.scheduler.schedule(
                 navail, now, cost=self._sched_cost)

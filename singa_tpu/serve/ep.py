@@ -571,10 +571,10 @@ class EPExecutor:
         return body
 
     def _mk_paged(self, base, **statics):
-        """A paged pool step of serve/paged.py (``base``, block kernel
-        or gather oracle) with the ep triple threaded: the lanes fold
-        their own routing stats (``gpt2_decode._ep_lane_stats``), the
-        collector around the program hands the sums out."""
+        """A paged pool step of serve/paged.py (``base``) with the ep
+        triple threaded: the lanes fold their own routing stats
+        (``gpt2_decode._ep_lane_stats``), the collector around the
+        program hands the sums out."""
         from ..models import gpt2_decode as G
 
         ne = self.n_expert
@@ -587,27 +587,24 @@ class EPExecutor:
 
         return body
 
-    def _mk_paged_decode(self, block, kernel):
-        from .paged import _paged_decode_kernel, _paged_decode_step
+    def _mk_paged_decode(self, block):
+        from ..models import gpt2_decode as G
+        from .paged import _paged_decode_kernel
 
-        if kernel == "block":
-            return self._mk_paged(_paged_decode_kernel, block=block,
-                                  window=self._window, **self._statics)
-        return self._mk_paged(_paged_decode_step, block=block,
+        return self._mk_paged(_paged_decode_kernel, block=block,
+                              window=self._window, fam=G.FAMILY,
                               **self._statics)
 
-    def _mk_paged_spec(self, block, kernel):
-        from .paged import _paged_spec_kernel, _paged_spec_step
+    def _mk_paged_spec(self, block):
+        from .paged import _paged_spec_kernel
 
         st = self._statics
         spec_k, (dn, de, dm) = self._spec
-        kw = dict(block=block, spec_k=spec_k, tn=st["n_head"],
-                  te=st["eps"], tm=st["moe_top_k"], dn=dn, de=de, dm=dm,
-                  top_k=st["top_k"], use_top_p=st["use_top_p"])
-        if kernel == "block":
-            return self._mk_paged(_paged_spec_kernel,
-                                  window=self._window, **kw)
-        return self._mk_paged(_paged_spec_step, **kw)
+        return self._mk_paged(
+            _paged_spec_kernel, block=block, window=self._window,
+            spec_k=spec_k, tn=st["n_head"], te=st["eps"],
+            tm=st["moe_top_k"], dn=dn, de=de, dm=dm, top_k=st["top_k"],
+            use_top_p=st["use_top_p"])
 
     def _mk_prefill_one(self):
         from .engine import _prefill_one
@@ -671,7 +668,8 @@ class EPExecutor:
             with G._ep_collecting() as rec:
                 out = _chunk_row.__wrapped__(
                     params, ids, kc_row, vc_row, off, **ck,
-                    tp_axis=TP_AXIS, tp_world=tpw, ep=ep3)
+                    fam=G.FAMILY, tp_axis=TP_AXIS, tp_world=tpw,
+                    ep=ep3)
             cnt, drp = _fold_ep_stats(rec, ne)
             return (*out, cnt, drp)
 
@@ -695,10 +693,9 @@ class EPExecutor:
                                     temps, top_p)
 
     def paged_decode_step(self, params, pool_k, pool_v, tables, toks,
-                          pos, live, keys, temps, top_p, block,
-                          kernel="block"):
-        fn = self._twin("paged_decode", (block, kernel, self._window),
-                        lambda: self._mk_paged_decode(block, kernel),
+                          pos, live, keys, temps, top_p, block):
+        fn = self._twin("paged_decode", (block, self._window),
+                        lambda: self._mk_paged_decode(block),
                         donate=(1, 2))
         return self._dispatch_stats(fn, params, pool_k, pool_v,
                                     tables, toks, pos, live, keys,
@@ -706,11 +703,11 @@ class EPExecutor:
 
     def paged_spec_step(self, t_params, d_params, pool_k, pool_v, dkc,
                         dvc, tables, toks, pos, live, keys, temps,
-                        top_p, block, kernel="block"):
+                        top_p, block):
         spec_k, d_st = self._spec
         fn = self._twin(
-            "paged_spec", (block, kernel, spec_k, d_st, self._window),
-            lambda: self._mk_paged_spec(block, kernel),
+            "paged_spec", (block, spec_k, d_st, self._window),
+            lambda: self._mk_paged_spec(block),
             donate=(2, 3, 4, 5))
         return self._dispatch_stats(fn, t_params, d_params, pool_k,
                                     pool_v, dkc, dvc, tables, toks,

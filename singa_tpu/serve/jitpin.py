@@ -28,10 +28,9 @@ def jit_cache_size():
     for f in (E._pool_decode_step, E._pool_spec_step, E._prefill_one,
               E._prefill_batch, E._prefill_rows, E._write_slot,
               E._chunk_row,
-              E._first_from_hidden, P._read_slot, G._paged_decode_step,
-              G._paged_spec_step, G._paged_decode_kernel,
-              G._paged_spec_kernel, G._pool_to_row, G._row_to_pool,
-              G._rows_to_pool):
+              E._first_from_hidden, P._read_slot,
+              G._paged_decode_kernel, G._paged_spec_kernel,
+              G._pool_to_row, G._row_to_pool, G._rows_to_pool):
         try:
             total += f._cache_size()
         except Exception:

@@ -32,27 +32,24 @@ carry.  This module collapses both into one pool:
   grown block-by-block as decode advances.  Capacity is "blocks free",
   not "slots free": a 20-token request holds one block, not a
   ``max_len`` row, so far more requests fit the same bytes;
-* **paged pool step** — TWO implementations behind
-  ``PagedConfig.kernel``.  The default (``"block"``) is a
-  BLOCK-NATIVE online-softmax decode kernel
-  (``gpt2_decode.decode_step_paged`` / ``chunk_step_paged``,
-  dispatched by ``_paged_decode_kernel`` / ``_paged_spec_kernel``
-  below): flash-style attention directly over the pool with the
-  block table as the index structure — a ``fori_loop`` over each
-  slot's live blocks (bound = the longest LIVE slot's block count,
-  one traced scalar), running-max + rescaled-partial-sum
-  accumulation, trash and beyond-``pos`` lanes masked, int8
-  dequantized per block inside the accumulator; the workspace is
-  O(block_size) and the write-back is a read-modify-write, layer by
-  layer, of the one or two blocks the step touched, so pool bytes
-  still round-trip exactly.  ``"gather"`` keeps the original materialize-a-row path
-  (``engine._decode_row`` / ``_spec_row`` on a transient
-  ``(L, S, H, W, D)`` workspace — bitwise the slot engine's math) as
-  the parity oracle: kernel streams are pinned TOKEN-identical to it
-  with an allclose logits oracle (online softmax reorders the float
-  reduction; tests/test_paged.py).  Either way the PERSISTENT KV
-  allocation (what the capacity model and ``bench_serve.py --paged``
-  count) is the pool alone;
+* **paged pool step** — ONE implementation, block-native: the
+  decode program (``_paged_decode_kernel``, through the family's
+  ``decode_step``; GPT-2's is ``gpt2_decode.decode_step_paged``) and
+  the speculative program (``_paged_spec_kernel``, through
+  ``gpt2_decode.chunk_step_paged``) run flash-style online-softmax
+  attention directly over the pool with the block table as the index
+  structure — a ``fori_loop`` over each slot's live blocks (bound =
+  the longest LIVE slot's block count, one traced scalar),
+  running-max + rescaled-partial-sum accumulation, trash and
+  beyond-``pos`` lanes masked, int8 dequantized per block inside the
+  accumulator; the workspace is O(block_size) and the write-back is a
+  read-modify-write, layer by layer, of the one or two blocks the
+  step touched, so pool bytes round-trip exactly.  Token streams are
+  pinned identical to the slot engine's, and logits allclose to the
+  materialized-row math (``gpt2_decode.decode_step``): online softmax
+  reorders the float reduction, so bitwise logit equality is
+  impossible by construction (tests/test_paged.py).  The PERSISTENT
+  KV allocation (what the capacity model counts) is the pool alone;
 * **preemption / swap** — a request's blocks can be evicted to HOST
   memory mid-decode (``swap_out``: one fixed-shape gather + device
   sync) and restored later (``swap_in``: one scatter).  The copy is
@@ -103,14 +100,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..model import _cost_args
+from ..models import gpt2_decode as _gpt2
 from ..observe import monitor as _monitor
 from ..observe import trace as _trace
 from ..observe.registry import registry as _default_registry
 from ..resilience import faults as _faults
 from ..utils.logging import get_channel
 from ..ops.paged_attention import (blocks_to_row, leaf_dims, pool_zeros,
-                                   row_to_blocks, take_blocks,
-                                   write_rows)
+                                   row_to_blocks, take_blocks)
+from ..ops.sampling import select_sample as _select_sample
 
 __all__ = ["PagedConfig", "PagedKVArena"]
 
@@ -130,46 +128,20 @@ class PagedConfig:
     — compare against the slot arena's ``2 * L * max_slots * max_len *
     H_kv * D`` to hold the byte budget fixed (docs/SERVING.md "Paged
     KV").
-    ``kernel``: how the pool steps read KV — ``"block"`` (default)
-    runs the block-native online-softmax decode kernel
-    (``gpt2_decode.decode_step_paged``: O(block_size) workspace,
-    attention work proportional to each step's LIVE blocks, trash and
-    beyond-``pos`` lanes masked); ``"gather"`` keeps the original
-    materialize-a-row path (O(max_len) workspace and attention work —
-    bitwise the slot engine's math) as the parity oracle and an
-    escape hatch.  Streams are token-identical between the two
-    (tests/test_paged.py pins kernel-vs-gather token identity plus an
-    allclose logits oracle; online softmax reorders the float
-    reduction, so bitwise logit equality is impossible by
-    construction).
-    ``admit_per_step``: optional ADMISSION INTERLEAVE BUDGET — at
-    most this many prefills per scheduling pass (None = unlimited,
-    the historical behavior).  A paged engine admits by blocks free,
-    so a burst of arrivals otherwise prefills en masse inside one
-    step and every live slot's decode TPOT absorbs the stall; a
-    small budget (2–3) spreads the same prefill work across steps,
-    trading a little TTFT headroom (paged TTFT is ~10-20x below the
-    slot arena's to begin with) for flat decode cadence — the
-    Sarathi-style chunked-prefill budget in miniature (ROADMAP item
-    2a; the request ledger's stall phase is the proof metric).
-    ``prefill_token_budget``: the REAL Sarathi-style chunked-prefill
-    budget (the long-context round): at most this many prefill
-    TOKENS per engine step, and — unlike ``admit_per_step``, which
-    only caps how many whole prefills a pass runs — a single
-    admission whose prompt exceeds the budget is SPLIT across
+    ``prefill_token_budget``: the Sarathi-style chunked-prefill
+    budget: at most this many prefill TOKENS per engine step, and a
+    single admission whose prompt exceeds the budget is SPLIT across
     consecutive steps in block-multiple chunks (the engine's
     ``_chunk_row`` / ``gpt2_decode.prefill_chunk`` executables,
     chunk rows pinned bitwise against full prefill), so one 32k
     document admission can never stall the live decode lanes for
     more than one chunk's latency per step.  Must be a multiple of
-    ``block_size``; None = off (whole-prompt admissions, the
-    historical behavior).  docs/SERVING.md "Long-context serving"
-    has the budget-vs-admit_per_step semantics table."""
+    ``block_size``; None = off (whole-prompt admissions).
+    docs/SERVING.md "Long-context serving" has the budget's
+    semantics table."""
 
     block_size: int = 32
     num_blocks: int = 128
-    kernel: str = "block"
-    admit_per_step: int | None = None
     prefill_token_budget: int | None = None
 
     def __post_init__(self):
@@ -179,15 +151,6 @@ class PagedConfig:
         if self.num_blocks < 1:
             raise ValueError(
                 f"num_blocks must be >= 1, got {self.num_blocks}")
-        if self.kernel not in ("block", "gather"):
-            raise ValueError(
-                f"kernel must be 'block' (block-native online-softmax "
-                f"decode) or 'gather' (materialized-row oracle), got "
-                f"{self.kernel!r}")
-        if self.admit_per_step is not None and self.admit_per_step < 1:
-            raise ValueError(
-                f"admit_per_step must be >= 1 (or None for "
-                f"unlimited), got {self.admit_per_step}")
         if self.prefill_token_budget is not None:
             if self.prefill_token_budget < self.block_size \
                     or self.prefill_token_budget % self.block_size:
@@ -279,168 +242,13 @@ def _rows_to_pool(pool_k, pool_v, kc_rows, vc_rows, sel, idx):
             jax.tree.map(scatter, pool_v, vc_rows))
 
 
-# -- the "gather" oracle's pool access --------------------------------------
-
-def _gather_rows(pool, tbl, head_dim):
-    """In-step row gather of one lane, (L, H, W, ...) per leaf (no batch
-    axis, no zero mask — the decode position mask covers everything past
-    ``pos``, and every position <= pos lives in an allocated block by
-    the engine's growth invariant)."""
-    return jax.tree.map(
-        lambda p, d: blocks_to_row(take_blocks(p, tbl), d), pool,
-        leaf_dims(pool, head_dim))
-
-
-def _new_rows(rows, pos, n):
-    """The ``n`` rows a step wrote at ``pos`` (traced) into one lane's
-    (L, H, W, ...) cache leaves, as the pool stores rows:
-    (L, n, H[·D]) per leaf."""
-    def cut(leaf):
-        new = jax.lax.dynamic_slice_in_dim(leaf, pos, n, axis=2)
-        new = jnp.moveaxis(new, 1, 2)            # (L, n, H, ...)
-        return new.reshape(new.shape[:2] + (-1,))
-
-    return jax.tree.map(cut, rows)
-
-
-def _lay_rows(pool, new, tables, pos, live, block, trash):
-    """Every lane's new rows ``new`` (L, S, n, X per leaf, from
-    :func:`_new_rows`) into the pool, a layer at a time (whole-block
-    read-modify-write; dead lanes write the trash block)."""
-    def leaf(p, rows):
-        for li in range(p.shape[0]):
-            p = write_rows(p, li, rows[li], tables, pos, live, block,
-                           trash)
-        return p
-
-    return jax.tree.map(leaf, pool, new)
-
-
 # -- paged pool steps --------------------------------------------------------
-# The per-row math is engine._decode_row/_spec_row — the SAME functions
-# the slot-arena steps vmap — so the paged engine's logits are bitwise
-# the slot engine's (the gathered row equals the slot row at every
-# position <= pos: blocks round-trip as byte copies, and positions
-# beyond pos are masked before they can contribute).  Imported lazily
-# at call time to avoid a module cycle (engine imports this module for
-# the arena class).
-
-@partial(jax.jit,
-         static_argnames=("block", "n_head", "eps", "moe_top_k",
-                          "top_k", "use_top_p", "tp_axis", "tp_world",
-                          "ep", "with_lp"),
-         donate_argnums=(1, 2))
-def _paged_decode_step(params, pool_k, pool_v, tables, toks, pos, live,
-                       keys, temps, top_p, masks=None, block=None,
-                       n_head=None, eps=None, moe_top_k=None,
-                       top_k=None, use_top_p=None, tp_axis=None,
-                       tp_world=1, ep=None, with_lp=False):
-    """Advance EVERY slot one token against the block pool: tables
-    (S, W//B) int32 block ids (trash-padded), pools donated.  Per slot:
-    gather its blocks into a row, run the shared decode-row math, then
-    write ONLY the row at ``pos`` back (one read-modified block per
-    slot per layer; dead slots write the trash block).  Returns
-    (next_toks, pool_k, pool_v, new_keys) — plus a (S,) chosen-token
-    logprob vector when ``with_lp`` (static; the fork round's
-    best-of-n ranking signal).  ``masks`` is None (legacy math,
-    bitwise unchanged) or a (S, V) bool vocab-mask batch (constrained
-    decoding — False lanes are NEG_INF'd before the shared sample
-    chain; an all-True row is a bitwise no-op)."""
-    from ..models import gpt2_decode as _gpt2
-    from .engine import _decode_row
-
-    trash = jax.tree.leaves(pool_k)[0].shape[1] - 1
-    head_dim = params["wte"].shape[1] // n_head
-    p_c = jnp.where(live, pos, 0)
-
-    def row(tbl, tok, pos_r, live_r, key, temp, mask_r):
-        with _gpt2._ep_collecting() as rec:
-            res = _decode_row(
-                params, _gather_rows(pool_k, tbl, head_dim),
-                _gather_rows(pool_v, tbl, head_dim), tok, pos_r,
-                live_r, key, temp, top_p, n_head, eps, moe_top_k,
-                top_k, use_top_p, tp_axis=tp_axis, tp_world=tp_world,
-                ep=ep, mask=mask_r, with_lp=with_lp)
-        nxt, kc2, vc2, k2 = res[:4]
-        lp = res[4] if with_lp else jnp.float32(0.0)
-        at = jnp.where(live_r, pos_r, 0)
-        return (nxt, _new_rows(kc2, at, 1), _new_rows(vc2, at, 1), k2,
-                lp, _gpt2._ep_lane_stats(rec, live_r))
-
-    m_ax = None if masks is None else 0
-    nxt, kn, vn, keys2, lps, stats = jax.vmap(
-        row, in_axes=(0, 0, 0, 0, 0, 0, m_ax),
-        out_axes=(0, 1, 1, 0, 0, 0))(tables, toks, pos, live, keys,
-                                     temps, masks)
-    _gpt2._ep_record_lanes(stats)
-    pool_k = _lay_rows(pool_k, kn, tables, p_c, live, block, trash)
-    pool_v = _lay_rows(pool_v, vn, tables, p_c, live, block, trash)
-    if with_lp:
-        return nxt, pool_k, pool_v, keys2, lps
-    return nxt, pool_k, pool_v, keys2
-
-
-@partial(jax.jit,
-         static_argnames=("block", "spec_k", "tn", "te", "tm", "dn",
-                          "de", "dm", "top_k", "use_top_p", "tp_axis",
-                          "tp_world", "ep"),
-         donate_argnums=(2, 3, 4, 5))
-def _paged_spec_step(t_params, d_params, pool_k, pool_v, dkc, dvc,
-                     tables, toks, pos, live, keys, temps, top_p,
-                     block, spec_k, tn, te, tm, dn, de, dm, top_k,
-                     use_top_p, tp_axis=None, tp_world=1, ep=None):
-    """Speculative chunk against the block pool: the TARGET cache is
-    paged (gather row -> shared spec-row math -> write back the chunk's
-    rows, into the one or two blocks they span — ``spec_k <=
-    block_size`` is validated at engine construction so a chunk never
-    spans more than two); the DRAFT arena stays slot-shaped (donated,
-    advanced in lockstep — it is small by construction and carries no
-    prefix cache).  Returns (out, a_draft, pool_k, pool_v, dkc, dvc,
-    new_keys)."""
-    from ..models import gpt2_decode as _gpt2
-    from .engine import _spec_row
-
-    trash = jax.tree.leaves(pool_k)[0].shape[1] - 1
-    head_dim = t_params["wte"].shape[1] // tn
-    p_c = jnp.where(live, pos, 0)
-
-    def row(dkc_r, dvc_r, tbl, tok, pos_r, live_r, key, temp):
-        with _gpt2._ep_collecting() as rec:
-            out, a_draft, kc2, vc2, dkc2, dvc2, k2 = _spec_row(
-                t_params, d_params,
-                _gather_rows(pool_k, tbl, head_dim),
-                _gather_rows(pool_v, tbl, head_dim), dkc_r, dvc_r,
-                tok, pos_r, live_r, key, temp, top_p, spec_k, tn, te,
-                tm, dn, de, dm, top_k, use_top_p, tp_axis=tp_axis,
-                tp_world=tp_world, ep=ep)
-        at = jnp.where(live_r, pos_r, 0)
-        return (out, a_draft, _new_rows(kc2, at, spec_k),
-                _new_rows(vc2, at, spec_k), dkc2, dvc2, k2,
-                _gpt2._ep_lane_stats(rec, live_r))
-
-    out, a_draft, kn, vn, dkc, dvc, keys2, stats = jax.vmap(
-        row, in_axes=(1, 1, 0, 0, 0, 0, 0, 0),
-        out_axes=(0, 0, 1, 1, 1, 1, 0, 0))(
-        dkc, dvc, tables, toks, pos, live, keys, temps)
-    _gpt2._ep_record_lanes(stats)
-    pool_k = _lay_rows(pool_k, kn, tables, p_c, live, block, trash)
-    pool_v = _lay_rows(pool_v, vn, tables, p_c, live, block, trash)
-    return out, a_draft, pool_k, pool_v, dkc, dvc, keys2
-
-
-# -- block-native pool steps (the gather-tax round) --------------------------
-# Same signatures as the gather steps above, but no row is ever
-# materialized: flash-style online-softmax attention DIRECTLY over the
-# pool with the block table as the index structure — a fori_loop over
-# each slot's live blocks, O(block_size) workspace.  The loop bound is
-# the MAX live-block count across the pool (one traced scalar, so one
-# executable serves every step and work scales with the longest LIVE
-# slot, not with max_len).  The lanes go through each layer's matmuls
-# together; only the attention is per lane; each layer writes its new
-# rows into the pool it carries (``write_rows``), so the donated pool is
-# updated where it lies.  Host-side block accounting, growth,
-# preemption/swap, and the prefix cache are untouched — they see the
-# same (tables, pools) contract.
+# Attention runs directly over the pool (module docstring).  The block
+# loop's bound is one traced scalar, so one executable serves every
+# step; the lanes go through each layer's matmuls together and only
+# the attention is per lane; each layer writes its new rows into the
+# pool it carries (``write_rows``), so the donated pool is updated
+# where it lies.
 
 def _block_bounds(pos, live, block, window):
     """(clamped positions, the block loop's upper bound, its lower
@@ -465,20 +273,19 @@ def _paged_decode_kernel(params, pool_k, pool_v, tables, toks, pos,
                          block=None, n_head=None, eps=None,
                          moe_top_k=None, top_k=None, use_top_p=None,
                          window=None, tp_axis=None, tp_world=1,
-                         ep=None, with_lp=False, fam=None):
-    """Advance EVERY slot one token against the block pool WITHOUT
-    gathering rows, through the family's ``decode_step``
-    (models/served.py): per slot, online-softmax attention over its
-    live blocks (beyond-``pos`` and trash lanes masked) plus the step's
-    own K/V as the current lane, the new K/V written back into the
-    block containing ``pos`` (dead slots write the trash block) — and,
+                         ep=None, with_lp=False, *, fam):
+    """Advance EVERY slot one token against the block pool, through
+    the family's ``decode_step`` (models/served.py): per slot,
+    online-softmax attention over its live blocks (beyond-``pos`` and
+    trash lanes masked) plus the step's own K/V as the current lane,
+    the new K/V written back into the block containing ``pos`` (dead
+    slots write the trash block) — and,
     for a family with per-slot state, row ``slots[w]`` of each
     ``state`` arena read, advanced and written back (dead lanes: the
-    trash row).  Then each lane samples from its logits.  Returns
-    (next_toks, pool_k, pool_v, new_keys[, logprobs][, state]) — the
-    same contract as :func:`_paged_decode_step`.  ``fam=None`` is the
-    GPT-2 family (the sharded executors wrap this function and predate
-    the contract).
+    trash row).  Then each lane samples from its logits (``masks``:
+    None, or a (S, V) bool vocab-mask batch for constrained decoding —
+    an all-True row is a bitwise no-op).  Returns (next_toks, pool_k,
+    pool_v, new_keys[, logprobs (S,) when ``with_lp``][, state]).
 
     ``window`` (static): sliding-window decode (the long-context
     round) — each slot's query additionally masks pool lanes at
@@ -489,9 +296,6 @@ def _paged_decode_kernel(params, pool_k, pool_v, tables, toks, pos,
     blocks back to the free list host-side; their table entries are
     trash by then, so the bound is a work optimization, never a
     correctness input)."""
-    from .engine import _default_family, _select_sample
-
-    fam = fam or _default_family()
     trash = jax.tree.leaves(pool_k)[0].shape[1] - 1
     _, n_blk, blk_lo = _block_bounds(pos, live, block, window)
     logits, pool_k, pool_v, state = fam.decode_step(
@@ -533,17 +337,16 @@ def _paged_spec_kernel(t_params, d_params, pool_k, pool_v, dkc, dvc,
                        use_top_p, window=None, tp_axis=None,
                        tp_world=1, ep=None):
     """Speculative chunk against the block pool, block-natively: the
-    draft scan and verify are the gather step's, lane by lane (shared
-    helpers in engine.py — the accept logic cannot drift); between
-    them the TARGET advances every lane's chunk together through
-    ``gpt2_decode.chunk_step_paged``: chunk-query online-softmax
-    attention over the pool, each layer writing the chunk's rows into
-    the one or two blocks they span (``spec_k <= block_size`` is
-    validated at engine construction).  Returns (out, a_draft, pool_k,
-    pool_v, dkc, dvc, new_keys)."""
-    from ..models import gpt2_decode as _gpt2
-    from .engine import _draft_propose, _unbatch1
-
+    draft scan and verify are the slot spec step's, lane by lane
+    (``gpt2_decode._draft_propose`` / ``spec_verify`` — the accept
+    logic cannot drift); between them the TARGET advances every lane's
+    chunk together through ``gpt2_decode.chunk_step_paged``:
+    chunk-query online-softmax attention over the pool, each layer
+    writing the chunk's rows into the one or two blocks they span
+    (``spec_k <= block_size`` is validated at engine construction).
+    The DRAFT arena stays slot-shaped (donated, advanced in lockstep —
+    it is small by construction and carries no prefix cache).  Returns
+    (out, a_draft, pool_k, pool_v, dkc, dvc, new_keys)."""
     trash = jax.tree.leaves(pool_k)[0].shape[1] - 1
     # the LOWEST query of a verify chunk is position pos itself, so the
     # decode kernel's lower bound covers every query
@@ -552,11 +355,11 @@ def _paged_spec_kernel(t_params, d_params, pool_k, pool_v, dkc, dvc,
 
     def draft(dkc_r, dvc_r, tok, pos_r, key, temp):
         k_draft, k_verify, k_next = jax.random.split(key, 3)
-        props, d_probs, dkc_b, dvc_b = _draft_propose(
+        props, d_probs, dkc_b, dvc_b = _gpt2._draft_propose(
             d_params, dkc_r, dvc_r, tok, pos_r, k_draft, temp, top_p,
             spec_k, dn, de, dm, top_k, use_top_p)
-        return (props, d_probs, _unbatch1(dkc_b), _unbatch1(dvc_b),
-                k_verify, k_next)
+        return (props, d_probs, _gpt2._unbatch1(dkc_b),
+                _gpt2._unbatch1(dvc_b), k_verify, k_next)
 
     props, d_probs, dkc, dvc, k_verify, keys2 = jax.vmap(
         draft, in_axes=(1, 1, 0, 0, 0, 0),

@@ -58,9 +58,9 @@ stage-boundary hop — the engine fails TYPED and the supervisor
 rebuilds (bench_chaos.py ``chaos_pp`` gates zero wedged/lost/leaked).
 
 Scope (every refusal typed at construction, BEFORE any registry
-registration): requires ``paged=`` with the block kernel (the tentpole
-memory model — per-stage block pools); ``stages`` must divide
-``n_layer``; dense/GQA models only (MoE stacks heterogeneous block
+registration): requires ``paged=`` (the tentpole memory model —
+per-stage block pools); ``stages`` must divide ``n_layer``;
+dense/GQA models only (MoE stacks heterogeneous block
 dicts — serve MoE with ``ep=``); no speculative draft (the draft's
 sequential proposal scan would serialize the pipeline, and a draft of
 mismatched depth cannot even take the stage split); no sliding
@@ -80,6 +80,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..observe import trace as _trace
 from ..observe.registry import registry as _default_registry
+from ..ops.sampling import select_sample as _select_sample
 from ..parallel.sharding import PP as PP_AXIS
 from ..parallel.sharding import create_pp_mesh
 from ..resilience import faults as _faults
@@ -205,15 +206,6 @@ def check_pp(config, cfg, model_plan=None, paged=None,
             "per-stage slice of the paged block pool "
             "(docs/SERVING.md 'Expert-parallel and pipeline "
             "serving'); the slot arena has no stage split")
-    kern = (paged.kernel if hasattr(paged, "kernel")
-            else paged.get("kernel", "block")
-            if isinstance(paged, dict) else "block")
-    if kern != "block":
-        raise ValueError(
-            f"pp= requires PagedConfig(kernel='block'), got {kern!r}: "
-            f"the stage bodies run the per-layer block-native kernel "
-            f"directly over their pool slice — the gather oracle "
-            f"materializes full rows no stage owns")
     if draft_model is not None:
         raise ValueError(
             f"pp= with a speculative draft: the draft's spec_k "
@@ -269,8 +261,7 @@ class PPExecutor:
         if model_plan is not None or \
                 getattr(cfg, "moe_every", None) is not None or \
                 cfg.n_layer % config.stages != 0:
-            check_pp(config, cfg, model_plan=model_plan,
-                     paged=_BlockKernelSentinel())
+            check_pp(config, cfg, model_plan=model_plan, paged=True)
         self.mesh = create_pp_mesh(config.stages,
                                    devices=config.devices)
         self.config = config
@@ -479,7 +470,6 @@ class PPExecutor:
     # -- twin bodies ------------------------------------------------------
     def _mk_paged_decode(self, block):
         from ..models import gpt2_decode as G
-        from .engine import _select_sample
 
         st = self._statics
         n_head, eps = st["n_head"], st["eps"]
@@ -612,7 +602,6 @@ class PPExecutor:
 
     def _mk_prefill_one(self):
         from ..models import gpt2_decode as G
-        from .engine import _select_sample
 
         st = self._statics
         top_k, use_top_p = st["top_k"], st["use_top_p"]
@@ -635,7 +624,6 @@ class PPExecutor:
 
     def _mk_prefill_batch(self):
         from ..models import gpt2_decode as G
-        from .engine import _select_sample
 
         st = self._statics
         top_k, use_top_p = st["top_k"], st["use_top_p"]
@@ -709,8 +697,7 @@ class PPExecutor:
 
     # -- the executor surface (paged subset — check_pp guarantees it) -----
     def paged_decode_step(self, params, pool_k, pool_v, tables, toks,
-                          pos, live, keys, temps, top_p, block,
-                          kernel="block"):
+                          pos, live, keys, temps, top_p, block):
         fn = self._twin("paged_decode", (block,),
                         lambda: self._mk_paged_decode(block),
                         donate=(1, 2))
@@ -797,11 +784,3 @@ class PPExecutor:
             "sharded_dispatches": self._c_dispatch.value,
             "boundary_hops": self._c_hops.value,
         }
-
-
-class _BlockKernelSentinel:
-    """Stands in for a PagedConfig in the defensive re-validation
-    path (the engine already validated the REAL paged config before
-    construction)."""
-
-    kernel = "block"

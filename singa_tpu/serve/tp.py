@@ -74,6 +74,7 @@ from dataclasses import dataclass
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..models import gpt2_decode as G
 from ..observe import trace as _trace
 from ..observe.registry import registry as _default_registry
 from ..parallel.sharding import TP as TP_AXIS
@@ -343,7 +344,7 @@ class TPExecutor:
 
     def set_window(self, window):
         """Sliding-window width (or None) — a STATIC every prefill
-        and block-kernel twin bakes in, so it rides each twin's
+        and paged-step twin bakes in, so it rides each twin's
         ``extra`` key slot (two engines for the same weights with
         different windows must not share a twin)."""
         self._window = None if window is None else int(window)
@@ -478,21 +479,16 @@ class TPExecutor:
                               top_p)
 
     def paged_decode_step(self, params, pool_k, pool_v, tables, toks,
-                          pos, live, keys, temps, top_p, block,
-                          kernel="block"):
+                          pos, live, keys, temps, top_p, block):
         from functools import partial
 
-        from .paged import _paged_decode_kernel, _paged_decode_step
+        from .paged import _paged_decode_kernel
 
-        base = (_paged_decode_kernel if kernel == "block"
-                else _paged_decode_step)
-        # only the block kernel takes the window static (the gather
-        # oracle is refused for windowed engines at construction)
-        wkw = ({"window": self._window} if kernel == "block" else {})
         fn = self._twin(
-            "paged_decode", (block, kernel, self._window),
-            lambda: partial(base.__wrapped__,
-                            block=block, **self._statics, **wkw,
+            "paged_decode", (block, self._window),
+            lambda: partial(_paged_decode_kernel.__wrapped__,
+                            block=block, **self._statics,
+                            window=self._window, fam=G.FAMILY,
                             tp_axis=TP_AXIS, tp_world=self.tp),
             donate=(1, 2))
         return self._dispatch(fn, params, pool_k, pool_v, tables,
@@ -500,24 +496,21 @@ class TPExecutor:
 
     def paged_spec_step(self, t_params, d_params, pool_k, pool_v, dkc,
                         dvc, tables, toks, pos, live, keys, temps,
-                        top_p, block, kernel="block"):
+                        top_p, block):
         from functools import partial
 
-        from .paged import _paged_spec_kernel, _paged_spec_step
+        from .paged import _paged_spec_kernel
 
         st = self._statics
         spec_k, (dn, de, dm) = self._spec
-        base = (_paged_spec_kernel if kernel == "block"
-                else _paged_spec_step)
-        wkw = ({"window": self._window} if kernel == "block" else {})
         fn = self._twin(
-            "paged_spec", (block, kernel, spec_k, dn, de, dm,
-                           self._window),
-            lambda: partial(base.__wrapped__, block=block,
+            "paged_spec", (block, spec_k, dn, de, dm, self._window),
+            lambda: partial(_paged_spec_kernel.__wrapped__, block=block,
                             spec_k=spec_k, tn=st["n_head"],
                             te=st["eps"], tm=st["moe_top_k"], dn=dn,
                             de=de, dm=dm, top_k=st["top_k"],
-                            use_top_p=st["use_top_p"], **wkw,
+                            use_top_p=st["use_top_p"],
+                            window=self._window,
                             tp_axis=TP_AXIS, tp_world=self.tp),
             donate=(2, 3, 4, 5))
         return self._dispatch(fn, t_params, d_params, pool_k, pool_v,
@@ -559,7 +552,7 @@ class TPExecutor:
         ck = self._chunk
         fn = self._twin(
             "chunk_row", tuple(sorted(ck.items())),
-            lambda: partial(_chunk_row.__wrapped__, **ck,
+            lambda: partial(_chunk_row.__wrapped__, **ck, fam=G.FAMILY,
                             tp_axis=TP_AXIS, tp_world=self.tp),
             donate=(2, 3))
         return self._dispatch(fn, params, ids, kc_row, vc_row, off)
@@ -622,7 +615,6 @@ class TPExecutor:
         import jax.numpy as jnp
         from jax import lax
 
-        from ..models import gpt2_decode as G
         from ..parallel.communicator import _record_collective
         from ..parallel.ring_attention import ring_self_attention
 
@@ -707,7 +699,8 @@ class TPExecutor:
         self.ring_prefills += 1
         tok0, carry_key = _first_from_hidden(
             params, hidden, jnp.int32(plen - 1), key, temp, top_p,
-            top_k=st["top_k"], use_top_p=st["use_top_p"])
+            top_k=st["top_k"], use_top_p=st["use_top_p"],
+            fam=G.FAMILY)
         return tok0, carry_key, kc_row, vc_row
 
     # -- lifecycle / reporting -------------------------------------------

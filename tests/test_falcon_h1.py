@@ -275,18 +275,20 @@ def test_engine_imports_no_gpt2_name_on_the_paged_path():
     speculation and the sharded executors' per-row twins still do)."""
     import ast
 
-    src = {name: open(os.path.join(ROOT, "singa_tpu", "serve", name)).read()
-           for name in ("engine.py", "paged.py")}
-    tree = ast.parse(src["engine.py"])
+    src = {name: open(os.path.join(ROOT, "singa_tpu", *name.split("/")))
+           .read()
+           for name in ("serve/engine.py", "serve/paged.py",
+                        "ops/sampling.py")}
+    tree = ast.parse(src["serve/engine.py"])
     for node in ast.walk(tree):
         if isinstance(node, ast.ImportFrom) and node.module \
                 and node.module.endswith("gpt2_decode"):
             pytest.fail(f"engine.py imports {[a.name for a in node.names]} "
                         f"from {node.module}")
-    paged_path = {"engine.py": {"_chunk_row", "_first_from_hidden",
-                                "_select_sample", "_write_state",
-                                "_read_state"},
-                  "paged.py": {"_paged_decode_kernel", "_aot_call"}}
+    paged_path = {"serve/engine.py": {"_chunk_row", "_first_from_hidden",
+                                      "_write_state", "_read_state"},
+                  "serve/paged.py": {"_paged_decode_kernel", "_aot_call"},
+                  "ops/sampling.py": {"select_sample"}}
     for name, fns in paged_path.items():
         found = set()
         for node in ast.walk(ast.parse(src[name])):
@@ -327,8 +329,6 @@ def test_the_new_family_serves_with_gpt2s_math_out_of_reach(built,
     ("prefix_cache=", dict(prefix_cache=True)),
     ("the slot arena (serving without paged=)", dict(paged=None)),
     ("whole-prompt admission", dict(paged=PagedConfig(block_size=8))),
-    ("the gather kernel", dict(paged=PagedConfig(
-        block_size=8, kernel="gather", prefill_token_budget=8))),
 ])
 def test_what_the_new_family_lacks_is_refused_by_name(built, feature, kw):
     m, _, _ = built

@@ -68,11 +68,15 @@ def _run(m, work, max_slots=2, max_steps=4000, **kw):
     return outs, snap
 
 
-def test_cold_parity_and_clean_accounting(model):
+@pytest.mark.parametrize("seed", [0, 20])
+def test_cold_parity_and_clean_accounting(model, seed):
     """Cold paged streams (greedy AND seeded sampling mixed in one
     pool) are byte-identical to the slot engine's, and a drained
-    engine returns every block."""
-    work = _workload(0, 8, sampled=True)
+    engine returns every block.  Online softmax reorders the float
+    reduction, so this (plus the logits oracle below) is the parity
+    pin; bitwise logit equality is impossible by construction
+    (docs/SERVING.md "Paged KV and preemption")."""
+    work = _workload(seed, 8, sampled=True)
     base, _ = _run(model, work)
     outs, snap = _run(model, work,
                       paged=PagedConfig(block_size=8, num_blocks=32))
@@ -107,12 +111,17 @@ def test_gqa_paged_parity():
     assert snap["paged"]["preemptions"] > 0  # pool was over-committed
 
 
-def test_spec_paged_greedy_parity(model, draft):
+@pytest.mark.parametrize("seed,n,n_hi,oracle", [
+    (3, 5, 12, "plain"), (21, 4, 10, "speculative")])
+def test_spec_paged_greedy_parity(model, draft, seed, n, n_hi, oracle):
     """Speculative decoding over the paged target arena: greedy
-    streams equal the plain engine's (verify chunks scatter one or
-    two blocks back per slot per step)."""
-    work = _workload(3, 5, n_lo=4, n_hi=12, p_lo=4, p_hi=12)
-    base, _ = _run(model, work, max_slots=3)
+    streams equal the slot engine's, plain and speculative (the
+    chunk-query accumulator against the same draft proposal chain;
+    verify chunks scatter one or two blocks back per slot per step)."""
+    work = _workload(seed, n, n_lo=4, n_hi=n_hi, p_lo=4, p_hi=12)
+    spec = (dict(draft_model=draft, spec_k=3)
+            if oracle == "speculative" else {})
+    base, _ = _run(model, work, max_slots=3, **spec)
     outs, snap = _run(model, work, max_slots=3, draft_model=draft,
                       spec_k=3,
                       paged=PagedConfig(block_size=8, num_blocks=32))
@@ -424,36 +433,51 @@ def test_config_validation_typed_errors(model, draft):
     eng.close()
 
 
-def test_kernel_vs_gather_token_identity(model, draft):
-    """The block-native kernel (PagedConfig default) and the
-    materialized-row gather path (``kernel="gather"``) stream
-    TOKEN-IDENTICAL — greedy and seeded sampling mixed in one pool,
-    plain and speculative.  Online softmax reorders the float
-    reduction, so this (plus the logits oracle below) is the parity
-    pin; bitwise logit equality is impossible by construction
-    (docs/SERVING.md "Paged KV and preemption")."""
-    assert PagedConfig().kernel == "block"  # the kernel IS the default
-    work = _workload(20, 8, sampled=True)
-    outs_g, _ = _run(model, work,
-                     paged=PagedConfig(block_size=8, num_blocks=32,
-                                       kernel="gather"))
-    outs_k, _ = _run(model, work,
-                     paged=PagedConfig(block_size=8, num_blocks=32))
-    assert all(np.array_equal(a, b) for a, b in zip(outs_k, outs_g))
-    # speculative chunks too: the chunk-query accumulator against the
-    # same draft proposal chain
-    work2 = _workload(21, 4, n_lo=4, n_hi=10, p_lo=4, p_hi=12)
-    sg, _ = _run(model, work2, max_slots=3, draft_model=draft,
-                 spec_k=3, paged=PagedConfig(block_size=8,
-                                             num_blocks=32,
-                                             kernel="gather"))
-    sk, _ = _run(model, work2, max_slots=3, draft_model=draft,
-                 spec_k=3, paged=PagedConfig(block_size=8,
-                                             num_blocks=32))
-    assert all(np.array_equal(a, b) for a, b in zip(sk, sg))
+@pytest.mark.parametrize("gone", [dict(kernel="gather"),
+                                  dict(admit_per_step=2)])
+def test_removed_paged_options_are_unknown_fields(model, gone):
+    """``PagedConfig`` is the pool's geometry and one budget; the
+    options that went are refused as any unknown field is, in both
+    forms ``paged=`` takes."""
+    import dataclasses
+
+    assert [f.name for f in dataclasses.fields(PagedConfig)] == [
+        "block_size", "num_blocks", "prefill_token_budget"]
+    with pytest.raises(TypeError):
+        PagedConfig(block_size=8, **gone)
+    with pytest.raises(TypeError):
+        model.serve(paged=dict(block_size=8, **gone))
 
 
-def test_kernel_logits_allclose_gather_oracle(model):
+def test_kv_memory_and_below_import_nothing_from_the_engine():
+    """The arrows point one way, engine -> paged -> models/ops: no
+    module below the engine imports it, at any depth of nesting (a
+    lazy import inside a function body is still an import)."""
+    import ast
+    import glob
+    import os
+
+    pkg = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "singa_tpu")
+    files = [os.path.join(pkg, "serve", "paged.py"),
+             os.path.join(pkg, "models", "gpt2_decode.py"),
+             os.path.join(pkg, "models", "served.py"),
+             *sorted(glob.glob(os.path.join(pkg, "ops", "*.py")))]
+    assert len(files) > 5
+    for path in files:
+        for node in ast.walk(ast.parse(open(path).read())):
+            if isinstance(node, ast.ImportFrom):
+                names = [node.module or ""] + [
+                    f"{node.module or ''}.{a.name}" for a in node.names]
+            elif isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            else:
+                continue
+            hit = [n for n in names if n.split(".")[-1] == "engine"]
+            assert not hit, (os.path.relpath(path, pkg), node.lineno, hit)
+
+
+def test_kernel_logits_allclose_row_math_oracle(model):
     """Unit-level oracle for the online-softmax accumulator: one
     decode step through ``decode_step_paged`` against a random pool
     (a live lane and a dead one) vs the row-math ``decode_step`` on the

@@ -293,10 +293,6 @@ def test_config_validation(model):
     # pp without paged: the memory model IS the stage-sliced pool
     with pytest.raises(ValueError, match="requires paged="):
         model.serve(max_slots=2, pp=2)
-    # pp with the gather oracle kernel
-    with pytest.raises(ValueError, match="kernel='block'"):
-        model.serve(max_slots=2, pp=2,
-                    paged=PagedConfig(block_size=8, kernel="gather"))
     # stages not dividing n_layer
     m3 = _build(GPT2Config.tiny(dropout=0.0, n_layer=3))
     with pytest.raises(ValueError, match="does not divide n_layer"):

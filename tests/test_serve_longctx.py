@@ -513,11 +513,6 @@ def test_longctx_config_validation(model, windowed, draft):
     # the paged path instead of only the offline fallback
     with pytest.raises(NotImplementedError, match="paged"):
         windowed.serve()
-    # windowed + gather kernel: the oracle path would attend freed
-    # blocks
-    with pytest.raises(ValueError, match="kernel"):
-        windowed.serve(paged=PagedConfig(block_size=B, num_blocks=8,
-                                         kernel="gather"))
     # windowed + prefix cache: dropped blocks break the radix
     # contiguity contract
     with pytest.raises(NotImplementedError, match="prefix"):

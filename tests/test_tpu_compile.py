@@ -164,13 +164,14 @@ def _in_place(comp):
 
 @pytest.mark.parametrize("lanes", [24, 48])
 def test_gpt2_large_decode_step_is_in_place(gpt2l, lanes):
+    from singa_tpu.models import gpt2_decode
     from singa_tpu.serve import paged
 
     sds, params, pool, _ = gpt2l
     _in_place(paged._paged_decode_kernel.lower(
         params, pool, pool, *_lanes(sds, lanes), block=GB, n_head=GH,
         eps=1e-5, moe_top_k=2, top_k=0, use_top_p=False,
-        window=None).compile())
+        window=None, fam=gpt2_decode.FAMILY).compile())
 
 
 @pytest.mark.parametrize("rows,width", [(4, 128), (1, GW)])
