@@ -3,7 +3,10 @@ released when they are due.  Open loop (the mix's ``arrivals`` has a
 ``rate_per_s``): independent users, sent on the arrival schedule whether
 or not earlier requests have finished, each timed from when it was due.
 Closed loop (``arrivals`` has ``clients``) and session turns: sent after
-the request they wait for has finished.
+the request they wait for has finished.  Nothing follows a caller's last
+request, so a closed-loop window is the cell's only while every caller
+still has requests to send: ``chains_at`` counts how far they had got at
+the window's end and raises ``RanDry`` where one had ended its chain.
 
 From the program it takes ``model.serve()``, ``submit``, ``step`` and the
 ``on_token`` callback (where the benchmark stamps its own clock), the
@@ -22,6 +25,52 @@ from benchmark.harness.output import say
 
 CLOCK = time.perf_counter
 DRAIN_CAP_S = 20.0
+
+
+class RanDry(RuntimeError):
+    """A caller of a closed loop had no request left before the window
+    ended: the window measured fewer callers than the cell states."""
+
+
+def _mix_file(cell):
+    mix = next((w["traffic"] for w in loader.manifest()["workloads"]
+                if w["name"] == cell["name"]), None)
+    return (f"benchmark/traffic/{mix}.json" if mix
+            else "the cell's traffic mix")
+
+
+def chains_at(cell, reqs, logs, t1):
+    """Closed loop: how many of the mix's requests had been sent when the
+    window ended at ``t1``, and by the caller that was furthest along.  A
+    rate over fewer callers than the cell states is not that cell's rate,
+    so a caller whose last request had finished before ``t1`` is an error
+    that says which key of which file to lengthen, and the run gives no
+    result."""
+    chains = {}
+    for i, r in enumerate(reqs):
+        chains.setdefault(r.client, []).append(i)
+
+    sent = {c: sum(1 for i in ch if logs[i].submitted is not None
+                   and logs[i].submitted < t1)
+            for c, ch in chains.items()}
+    total = sum(sent.values())
+    dry = {c: t1 - logs[ch[-1]].token_times[-1]
+           for c, ch in chains.items()
+           if logs[ch[-1]].finished and logs[ch[-1]].token_times[-1] < t1}
+    if dry:
+        first = max(dry, key=dry.get)
+        raise RanDry(
+            f"closed loop ran dry: {len(dry)} of {len(chains)} callers had "
+            f"ended their chains before the window did (caller {first} "
+            f"finished the last of its {len(chains[first])} requests "
+            f"{dry[first]:.1f} s before the window's end; {total} of "
+            f"{len(reqs)} requests sent), so the window measured fewer "
+            f"callers than the cell states; lengthen `rounds` in "
+            f"{_mix_file(cell)}")
+    far = max(sent, key=sent.get)
+    return (f"closed loop, {total} of {len(reqs)} requests sent; the "
+            f"caller furthest along had sent {sent[far]} of its "
+            f"{len(chains[far])}")
 
 
 class Session:
@@ -205,6 +254,12 @@ class Session:
                         time.sleep(max(0.0, min(wait, 0.001)))
         say(f"setup: pre-roll {preroll:.1f} s; window {t1 - t0:.3f} s; "
             f"drain {CLOCK() - t1:.2f} s")
+        if closed:
+            try:
+                say(f"window: {chains_at(cell, reqs, logs, t1)}")
+            except RanDry:
+                eng.close(force=True)
+                raise
 
         # ---- the window's numbers (host clock and program counters)
         for lg in logs:

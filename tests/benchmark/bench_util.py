@@ -20,6 +20,10 @@ TINY_SIZES = dict(vocab_size=256, n_positions=128, n_ctx=128, n_embd=64,
                   n_layer=2, n_head=4, n_inner=128)
 TINY_ENGINE = dict(block_size=8, num_blocks=96, max_slots=8,
                    prefill_token_budget=16, dtype="float32")
+# closed loop: a toy engine on a fast host ends 16 short requests a caller
+# inside a 2 s run, and the driver refuses a window whose callers ran dry;
+# 4 x 64 requests a caller outlast any test-size window
+TINY_ROUNDS = 64
 TINY_LENGTHS = dict(prompt_len={"dist": "uniform", "min": 8, "max": 60},
                     reply_len={"dist": "uniform", "min": 4, "max": 20})
 
@@ -35,6 +39,7 @@ def tiny_cell(name, root=loader.ROOT):
         mix.update(TINY_LENGTHS, preroll_s=0.5)
         if "clients" in mix["arrivals"]:
             mix["arrivals"] = {"clients": 4, "stagger_s": 0.3}
+            mix["rounds"] = TINY_ROUNDS
         else:
             mix["arrivals"] = dict(mix["arrivals"], rate_per_s=20.0)
         if "sessions" in mix:

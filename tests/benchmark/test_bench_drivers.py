@@ -73,6 +73,70 @@ def test_serve_counts_processed_tokens_at_the_window_edges():
     assert s.check(11, run)["served_logit_gap_max"] <= 1e-4
 
 
+def test_a_closed_loop_whose_callers_ran_dry_gives_no_result():
+    """One request a caller: every chain has ended long before the window
+    does, and what is left of the window measures an idle engine.  The
+    run raises, and says which key of which file to lengthen."""
+    cell = tiny_cell("gpt2l-serve-longdoc")
+    cell["traffic"] = dict(cell["traffic"], requests_per_client=1, rounds=1)
+    with pytest.raises(RuntimeError, match="closed loop ran dry") as e:
+        measure(cell, SERVE_LIMITS)
+    said = str(e.value)
+    assert "lengthen `rounds` in benchmark/traffic/longdoc.json" in said
+    assert "4 of 4 callers" in said and "4 of 4 requests sent" in said
+    assert re.search(r"caller \d finished the last of its 1 requests "
+                     r"\d\.\d s before the window's end", said)
+
+
+def test_a_closed_loop_with_chains_to_spare_says_how_far_they_got(capsys):
+    line = measure(tiny_cell("gpt2l-serve-longdoc"), SERVE_LIMITS)
+    assert line["correct"] is True
+    sent, of, furthest, each = map(int, re.search(
+        r"window: closed loop, (\d+) of (\d+) requests sent; the caller "
+        r"furthest along had sent (\d+) of its (\d+)\n",
+        capsys.readouterr().out).groups())
+    assert (of, each) == (1024, 256)
+    assert line["attempted"] < sent < of and sent / 4 <= furthest < each
+
+
+class _Log:
+    def __init__(self, submitted, token_times, max_new=2):
+        self.submitted, self.token_times = submitted, token_times
+        self.finished = len(token_times) >= max_new
+
+
+@pytest.mark.parametrize("last, dry", [
+    ([7.0, 7.5], True),          # finished 2.5 s before the window's end
+    ([9.0, 10.0], False),        # its last token falls on the edge
+    ([9.9, 10.4], False),        # finished in the drain: late, not dry
+    ([9.9], False),              # still being served
+    ([], False)], ids=["before", "on-the-edge", "in-the-drain", "running",
+                       "unsent"])
+def test_a_caller_is_dry_once_its_last_request_finished_in_the_window(
+        last, dry):
+    """``chains_at`` on a fake clock: two callers of two requests, window
+    end at 10.0; caller 0 has requests to spare, caller 1's last request
+    is the case."""
+    from benchmark.drivers import serve
+    from benchmark.harness.traffic import Request
+
+    reqs = [Request(0.0, None, 2, client=i % 2, after=i - 2)
+            for i in range(4)]
+    logs = [_Log(1.0, [2.0, 3.0]), _Log(1.5, [2.5, 4.0]),
+            _Log(3.0, [9.5]), _Log(4.0 if last else None, last)]
+    cell = {"name": "gpt2l-serve-longdoc"}
+    if not dry:
+        assert serve.chains_at(cell, reqs, logs, 10.0) == (
+            f"closed loop, {4 if last else 3} of 4 requests sent; the "
+            f"caller furthest along had sent 2 of its 2")
+        return
+    with pytest.raises(serve.RanDry, match=(
+            r"1 of 2 callers .*caller 1 finished the last of its 2 requests "
+            r"2\.5 s before .*4 of 4 requests sent.*lengthen `rounds` in "
+            r"benchmark/traffic/longdoc\.json")):
+        serve.chains_at(cell, reqs, logs, 10.0)
+
+
 def test_serve_with_a_token_altered_where_it_is_produced_is_not_correct():
     def tamper(eng):
         emit = eng._emit

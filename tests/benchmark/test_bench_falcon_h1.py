@@ -12,7 +12,7 @@ import copy
 
 import numpy as np
 import pytest
-from bench_util import measure
+from bench_util import TINY_ROUNDS, measure
 
 from benchmark.harness import loader
 
@@ -33,7 +33,7 @@ def tiny_cell():
     cell = copy.deepcopy(loader.load_cell(CELL))
     cfg = dict(cell["config"], **TINY)
     cfg["engine"] = dict(cfg["engine"], **TINY_ENGINE)
-    mix = dict(cell["traffic"], preroll_s=0.5,
+    mix = dict(cell["traffic"], preroll_s=0.5, rounds=TINY_ROUNDS,
                prompt_len={"dist": "uniform", "min": 8, "max": 60},
                reply_len={"dist": "uniform", "min": 4, "max": 20},
                arrivals={"clients": 4, "stagger_s": 0.3})

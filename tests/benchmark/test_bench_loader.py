@@ -17,9 +17,11 @@ METRICS = [m["name"] for m in MAN["per_layer"]]
 
 
 @pytest.mark.parametrize("name", CELLS)
-def test_cell_loads_with_all_its_files(name):
+def test_cell_loads_with_its_files_and_its_family(name):
     cell = loader.load_cell(name)
-    assert cell["config"]["family"] == "gpt2"
+    # the family names the cell's plain reference and its adapter
+    for kind in ("references", "adapters"):
+        loader.load_module(kind, cell["config"]["family"])
     assert cell["traffic"]["kind"] in ("train_job", "serve")
     driver = loader.load_module("drivers", cell["traffic"]["kind"])
     assert hasattr(driver, "Session")

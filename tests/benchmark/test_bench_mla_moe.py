@@ -14,7 +14,7 @@ import dataclasses
 
 import numpy as np
 import pytest
-from bench_util import measure
+from bench_util import TINY_ROUNDS, measure
 
 from benchmark.harness import loader
 
@@ -41,7 +41,7 @@ def tiny_cell():
     cfg["rope_scaling"] = dict(cfg["rope_scaling"],
                                original_max_position_embeddings=64)
     cfg["engine"] = dict(cfg["engine"], **TINY_ENGINE)
-    mix = dict(cell["traffic"], preroll_s=0.5,
+    mix = dict(cell["traffic"], preroll_s=0.5, rounds=TINY_ROUNDS,
                prompt_len={"dist": "uniform", "min": 8, "max": 60},
                reply_len={"dist": "uniform", "min": 4, "max": 20},
                arrivals={"clients": 4, "stagger_s": 0.3})

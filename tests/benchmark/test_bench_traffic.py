@@ -1,9 +1,13 @@
 """The stratified generator: every seed offers the same lengths at the
 same instants, in the order the mix fixes, and the stated quantiles."""
 
+import glob
+import json
+import os
+
 import numpy as np
 import pytest
-from bench_util import ROOT  # noqa: F401
+from bench_util import ROOT
 
 from benchmark.harness import loader, traffic
 
@@ -104,6 +108,64 @@ def test_closed_loop_rounds_and_chains():
     for i, r in enumerate(a[c:], start=c):
         assert r.after == i - c and r.client == a[r.after].client
     traffic.check_fits(a, 1024)
+
+
+@pytest.mark.parametrize("seed", [1, 2147483900])
+def test_longer_chains_start_with_the_requests_four_rounds_sent(seed):
+    """``rounds`` went 4 -> 20 (PR 35) so that the chains outlast the
+    window; each round draws its order and its token ids after the rounds
+    before it, so the first 256 requests -- all that a window at the
+    parent's speed sees -- are the ones the cell sent before."""
+    assert LONGDOC["rounds"] == 20
+    old = traffic.make_requests(dict(LONGDOC, rounds=4), seed, PHASES,
+                                50257, 1024)
+    new = traffic.make_requests(LONGDOC, seed, PHASES, 50257, 1024)
+    assert len(old) == 256 and len(new) == 1280
+    for a, b in zip(old, new):
+        assert (a.due_s, a.max_new, a.client, a.after, a.think_s) == \
+            (b.due_s, b.max_new, b.client, b.after, b.think_s)
+        assert np.array_equal(a.prompt, b.prompt)
+    # ... and every caller goes on from where its chain used to end
+    assert [r.after for r in new[256:]] == list(range(240, 1264))
+
+
+def _closed_loop_mixes():
+    out = {}
+    for path in sorted(glob.glob(os.path.join(ROOT, "benchmark", "traffic",
+                                              "*.json"))):
+        with open(path) as f:
+            mix = json.load(f)
+        if "clients" in mix.get("arrivals", {}):
+            out[os.path.basename(path)[:-len(".json")]] = mix
+    return out
+
+
+CLOSED = _closed_loop_mixes()
+# tokens/s up to which a mix's chains have to outlast pre-roll + window,
+# taken over all callers together (the first caller to end its chain does so
+# at some four fifths of that: PERF.md section 4): ISSUE 35's floors, 3 to 9
+# times the rates of PR 34 (3.4 k, 6.8 k, 4.3 k)
+CHAIN_FLOOR = {"longdoc": 10_000, "docqa": 25_000, "reason": 40_000}
+
+
+@pytest.mark.parametrize("name", sorted(CLOSED))
+def test_closed_loop_chains_outlast_the_window_at_a_faster_engine(name):
+    """Nothing follows a caller's last request.  From the data files
+    alone: the tokens of all chains over the seconds they have to last,
+    traffic's start to the window's end (the stagger's ramp lies inside
+    the pre-roll).  An engine that processes more than this a second ends
+    a chain inside the window, and the driver then refuses the run; a
+    later edit that shortens a chain fails here first."""
+    mix = CLOSED[name]
+    assert set(CHAIN_FLOOR) <= set(CLOSED)
+    rng = np.random.default_rng(0)
+    per_round = mix["arrivals"]["clients"] * mix["requests_per_client"]
+    tokens = mix["rounds"] * sum(
+        int(traffic.stratified_lengths(mix[k], per_round, rng).sum())
+        for k in ("prompt_len", "reply_len"))
+    assert mix["arrivals"]["stagger_s"] <= mix["preroll_s"]
+    lasts = mix["preroll_s"] + loader.manifest()["run_seconds"]
+    assert tokens / lasts >= CHAIN_FLOOR.get(name, 0), (tokens, lasts)
 
 
 def test_an_unknown_arrival_process_is_refused():
