@@ -1232,8 +1232,8 @@ def run_longctx():
       decode TPOT reference;
     * **budgeted**: the full mix with
       ``PagedConfig(prefill_token_budget=32)`` — each 384-token
-      admission splits into 16-token ``_chunk_row`` windows, two per
-      step, so decode lanes keep their cadence;
+      admission advances one 32-token ``_chunk_row`` launch (two
+      16-token blocks) a step, so decode lanes keep their cadence;
     * **unbudgeted**: the full mix with whole-prompt admission — one
       384-token prefill lands inside a single step and every live
       chat lane's inter-token gap absorbs it (the stall spike).

@@ -234,11 +234,15 @@ def ssd_chunk(x, b, cc, dt, a, s_in):
     return y, jnp.exp(la[-1])[:, None, None] * s_in + s_new
 
 
-def _mamba_chunk(h, p, c, ssm, conv, n_valid):
+def _mamba_chunk(h, p, c, ssm, conv, n_valid, sub=None):
     """The mixer over a chunk row: h (T, E) normalised input, ``ssm``
     (h, p, n) and ``conv`` (d_conv - 1, conv_dim) the state the row
     before left, ``n_valid`` how many of the T tokens are real (the
-    prompt's last row is padded; padding leaves the state alone).
+    prompt's last row is padded; padding leaves the state alone).  The
+    projections, the conv and the norm take the T rows together; the
+    scan walks them ``sub`` at a time in order (default: all T as one
+    chunk), each sub-chunk from the state the one before left, so a row
+    of several scan chunks computes what as many rows of one did.
     Returns (m (T, E), ssm, conv)."""
     t, kk = h.shape[0], c.mamba_d_conv
     z, xbc, dt = _mixer_inputs(h, p, c)
@@ -250,7 +254,18 @@ def _mamba_chunk(h, p, c, ssm, conv, n_valid):
     dt = jax.nn.softplus(dt + p["dt_bias"])
     dt = jnp.where(jnp.arange(t)[:, None] < n_valid, dt, 0.0)
     with jax.named_scope("ssm_scan"):
-        y, ssm = ssd_chunk(x, b, cc, dt, -jnp.exp(p["a_log"]), ssm)
+        if sub is None or sub == t:
+            y, ssm = ssd_chunk(x, b, cc, dt, -jnp.exp(p["a_log"]), ssm)
+        else:
+            # unrolled (a ``lax.scan`` of two iterations cost more than
+            # it ran: PERF.md §6, PR 36)
+            ys = []
+            for j in range(0, t, sub):
+                y_j, ssm = ssd_chunk(
+                    x[j:j + sub], b[j:j + sub], cc[j:j + sub],
+                    dt[j:j + sub], -jnp.exp(p["a_log"]), ssm)
+                ys.append(y_j)
+            y = jnp.concatenate(ys)
     y = (y + p["d"][:, None] * x).reshape(t, -1)
     return _mixer_out(y, z, p, c), ssm, conv
 
@@ -393,12 +408,14 @@ class FalconH1Family(ServedFamily):
             return _logits(params, hidden, self.cfg)
 
     def chunk_row(self, params, ids, kc_row, vc_row, state, off, n_valid,
-                  *, chunk, **_):
-        """One chunk row = one scan chunk: attention of the row's
-        queries over the private cache row below ``off`` (block by
-        block, the shared loop) and their own keys; the mixer's chunked
-        scan from the state the row before left."""
+                  *, chunk, block=None, **_):
+        """One chunk row of ``chunk`` tokens, a whole number of blocks
+        (a block = one scan chunk): attention of the row's queries over
+        the private cache row below ``off`` (block by block, the shared
+        loop) and their own keys; the mixer's chunked scan, a block at
+        a time, from the state the row before left."""
         c = self.cfg
+        block = block or chunk
         toks = jax.lax.dynamic_slice(ids, (0, off), (1, chunk))[0]
         pos = off + jnp.arange(chunk)
         x = (c.embedding_multiplier * jnp.take(params["wte"], toks, axis=0)
@@ -406,8 +423,8 @@ class FalconH1Family(ServedFamily):
         n_l, _, n_kv, width, d = kc_row.shape
         g = c.n_head // n_kv
         # what lies below off, as the blocks of a pool
-        kb, vb = row_to_blocks(kc_row, chunk), row_to_blocks(vc_row, chunk)
-        tbl = jnp.arange(width // chunk)
+        kb, vb = row_to_blocks(kc_row, block), row_to_blocks(vc_row, block)
+        tbl = jnp.arange(width // block)
         cur = jnp.tril(jnp.ones((chunk, chunk), bool))
 
         def layer(carry, lp):
@@ -421,7 +438,7 @@ class FalconH1Family(ServedFamily):
                 v = v.transpose(1, 0, 2)                    # (KV, T, D)
                 a = paged_attn(
                     q.reshape(n_kv, g, chunk, d), kb, vb, li, tbl, off,
-                    off // chunk, chunk, -1, _rows(k), _rows(v), cur,
+                    off // block, block, -1, _rows(k), _rows(v), cur,
                     1.0 / math.sqrt(d))
                 a = a.transpose(2, 0, 1, 3).reshape(chunk, -1)
                 att = c.attention_out_multiplier * (
@@ -433,7 +450,8 @@ class FalconH1Family(ServedFamily):
                     vc_row, v[None, None].astype(vc_row.dtype),
                     (li, 0, 0, off, 0))
             with jax.named_scope("ssm_proj"):
-                m, ssm, conv = _mamba_chunk(h, p, c, ssm, conv, n_valid)
+                m, ssm, conv = _mamba_chunk(h, p, c, ssm, conv, n_valid,
+                                            sub=block)
             x = x + att + m.astype(x.dtype)
             with jax.named_scope("mlp"):
                 x = x + _mlp(x, p, c).astype(x.dtype)

@@ -1928,8 +1928,10 @@ class _GPT2Family(ServedFamily):
         return _quant_flag(cache_dtype)
 
     def chunk_row(self, params, ids, kc_row, vc_row, state, off, n_valid,
-                  *, chunk, n_head, eps, moe_top_k=2, window=None,
-                  tp_axis=None, tp_world=1, ep=None):
+                  *, chunk, n_head, eps, block=None, moe_top_k=2,
+                  window=None, tp_axis=None, tp_world=1, ep=None):
+        # the row is attended whole, whatever its blocks: ``block``
+        # changes nothing here
         toks = jax.lax.dynamic_slice(ids, (0, off), (1, chunk))
         pos = off + jnp.arange(chunk)
         x = jnp.take(params["wte"], toks[0], axis=0)[None] + \

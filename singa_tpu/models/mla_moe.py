@@ -380,8 +380,9 @@ class MlaMoeFamily(ServedFamily):
                     expert_tokens_mean=float(held.mean())), incs
 
     def chunk_row(self, params, ids, kc_row, vc_row, state, off, n_valid,
-                  *, chunk, **_):
-        """One chunk row: its queries, absorbed, over the private latent
+                  *, chunk, block=None, **_):
+        """One chunk row of ``chunk`` tokens, a whole number of blocks:
+        its queries, absorbed, over the private latent
         row below ``off`` (block by block, the shared loop) and over the
         chunk's own rows; the new rows written into the private row.
         ``vc_row`` is None: the row is key and value at once.  The
@@ -389,6 +390,7 @@ class MlaMoeFamily(ServedFamily):
         its rows are never read, and a run of like tokens that all
         chose one held expert cost that expert further tiles."""
         c = self.cfg
+        block = block or chunk
         valid = None if n_valid is None else jnp.arange(chunk) < n_valid
         toks = jax.lax.dynamic_slice(ids, (0, off), (1, chunk))[0]
         pos = off + jnp.arange(chunk)
@@ -396,8 +398,8 @@ class MlaMoeFamily(ServedFamily):
         n_l, _, _, width, d = kc_row.shape
         # what lies below off, as the blocks of a pool (one head: a
         # reshape)
-        kb = kc_row.reshape(n_l, width // chunk, chunk, d)
-        tbl = jnp.arange(width // chunk)
+        kb = kc_row.reshape(n_l, width // block, block, d)
+        tbl = jnp.arange(width // block)
         cur = jnp.tril(jnp.ones((chunk, chunk), bool))
 
         def layer(carry, kind, li, i, p):
@@ -408,7 +410,7 @@ class MlaMoeFamily(ServedFamily):
                 q = _absorb(q_nope, q_r, p, c)
             with jax.named_scope("mla_attn"):
                 o_lat = paged_attn(
-                    q[None], kb, None, li, tbl, off, off // chunk, chunk,
+                    q[None], kb, None, li, tbl, off, off // block, block,
                     -1, row, None, cur, c.softmax_scale,
                     v_dim=c.kv_lora_rank)[0]
                 kc_row = jax.lax.dynamic_update_slice(

@@ -91,14 +91,21 @@ class ServedFamily:
 
     # -- the math ----------------------------------------------------------
     def chunk_row(self, params, ids, kc_row, vc_row, state, off, n_valid,
-                  *, chunk, **statics):
+                  *, chunk, block, **statics):
         """Prefill ``chunk`` prompt tokens at positions ``[off, off +
         chunk)`` of the padded ``ids`` (1, W) against a private cache
         row that holds K/V below ``off`` and the ``state`` carried from
         the row before; the first ``n_valid`` of them are the prompt's,
         the rest padding that must leave the state alone (both None for
-        a family without state, unless it is ``pad_aware``).  Returns ``(final-norm hidden (1, chunk, E),
-        kc_row, vc_row, state)``."""
+        a family without state, unless it is ``pad_aware``).  ``chunk``
+        is the launch's width and ``block`` the pool's block: ``chunk``
+        is ``block`` times a power of two (whatever the step's prefill
+        budget allows) and ``off`` a multiple of ``block``, not of
+        ``chunk``; what a family lays out or walks by the block (the
+        row as pool blocks, a scan's chunks) goes by ``block``, and the
+        same rows must come of one wide launch as of its blocks one by
+        one.  Returns ``(final-norm hidden (1, chunk, E), kc_row,
+        vc_row, state)``."""
         raise NotImplementedError
 
     def decode_step(self, params, pool_k, pool_v, state, slots, tables,

@@ -68,10 +68,13 @@ Long-context serving (the long-context round; docs/SERVING.md
 * **chunked-prefill token budget**
   (``PagedConfig(prefill_token_budget=)``): a Sarathi-style per-step
   prefill TOKEN budget — an admission whose prompt exceeds it splits
-  across consecutive steps in block-width ``_chunk_row`` windows
-  (bitwise the unbudgeted prefill), so one 32k document admission
-  never stalls the live decode lanes for more than one chunk per
-  step (the request ledger's stall phase is the proof metric);
+  across consecutive steps, ONE ``_chunk_row`` launch a request a
+  step, as wide as the step's budget allows (the block times a power
+  of two: a launch reads the layers' weights once however many
+  blocks it covers; every width is compiled when the engine is
+  built), so one 32k document admission never stalls the live decode
+  lanes for more than one step's budget (the request ledger's stall
+  phase is the proof metric);
 * **windowed paged decode**: sliding-window models
   (``GPT2Config(attn_window=W)``) serve on the PAGED engine — block
   tables drop fully-out-of-window blocks back to the free list as
@@ -260,6 +263,18 @@ def _prefill_rows(params, ids, n_head, eps, moe_top_k, quant=False):
     return kc, vc
 
 
+def _launch_of(off, block):
+    """``(first position, width)`` of the prefill window ``off`` names:
+    a scalar is ONE block at that position; a vector of ``n`` block
+    offsets is the ``n`` consecutive blocks from ``off[0]`` on.  The
+    width rides in as the SHAPE of an argument, so every executor's
+    program cache (jit's, the sharded twins', the AOT cache) keys on it
+    by itself, and the one-block call is the call it always was."""
+    if off.ndim:
+        return off[0], block * off.shape[0]
+    return off, block
+
+
 @partial(jax.jit,
          static_argnames=("n_head", "eps", "moe_top_k", "chunk",
                           "window", "tp_axis", "tp_world", "fam"),
@@ -267,20 +282,29 @@ def _prefill_rows(params, ids, n_head, eps, moe_top_k, quant=False):
 def _chunk_row(params, ids, kc_row, vc_row, off, state=None,
                n_valid=None, *, n_head, eps, moe_top_k, chunk,
                window=None, tp_axis=None, tp_world=1, ep=None, fam):
-    """Offset prefill of ONE block-width window through the family's
-    ``chunk_row`` (models/served.py): tokens at positions
-    [off, off+chunk) of the padded ``ids`` row, advanced against a
-    cache row that already holds canonical K/V below ``off`` and the
-    per-slot ``state`` the row before left (None for a family that
-    keeps none; with it ``n_valid``, how many of the window's tokens
-    are the prompt's and not padding).  ``off`` is traced, so every
-    admission's every window rides one executable.  Returns ((1, chunk,
-    E) final-norm hidden, kc_row, vc_row[, state]) — rows donated, the
-    admission loop rebinds."""
+    """Offset prefill of ONE window through the family's ``chunk_row``
+    (models/served.py): tokens at positions [off, off+width) of the
+    padded ``ids`` row, advanced against a cache row that already holds
+    canonical K/V below ``off`` and the per-slot ``state`` the window
+    before left (None for a family that keeps none; with it
+    ``n_valid``, how many of the window's tokens are the prompt's and
+    not padding).  ``chunk`` (static) is the BLOCK; the window is one
+    block at a scalar ``off`` and ``n`` blocks where ``off`` holds their
+    ``n`` offsets (:func:`_launch_of`): the budgeted path launches the
+    widest the step's budget allows, every other caller one block.
+    ``off``'s value is traced, so every admission's every window of one
+    width rides one executable.  Returns ((1, chunk, E) final-norm
+    hidden of the window's LAST block, kc_row, vc_row[, state]) — rows
+    donated, the admission loop rebinds."""
+    off, width = _launch_of(off, chunk)
     hidden, kc_row, vc_row, state = fam.chunk_row(
-        params, ids, kc_row, vc_row, state, off, n_valid, chunk=chunk,
-        n_head=n_head, eps=eps, moe_top_k=moe_top_k, window=window,
-        tp_axis=tp_axis, tp_world=tp_world, ep=ep)
+        params, ids, kc_row, vc_row, state, off, n_valid, chunk=width,
+        block=chunk, n_head=n_head, eps=eps, moe_top_k=moe_top_k,
+        window=window, tp_axis=tp_axis, tp_world=tp_world, ep=ep)
+    if width > chunk:
+        # a prompt's last window ends with its last block, and only
+        # that block's rows are ever sampled from (_first_from_hidden)
+        hidden = hidden[:, width - chunk:]
     if state is None:
         return hidden, kc_row, vc_row
     return hidden, kc_row, vc_row, state
@@ -549,16 +573,17 @@ class _LocalExec:
                               window=e._window)
 
     def chunk_row(self, params, ids, kc_row, vc_row, off, state=None,
-                  n_valid=None):
+                  n_valid=None, run=True):
+        """``run=False`` compiles the program of ``off``'s width from
+        abstract arguments and launches nothing (the engine does so
+        for every width when it is built)."""
+        # through the AOT cache whatever the family: it keeps a
+        # family's scopes, and a program compiled ahead waits there
         e = self._e
-        if state is None and not e._fam.scopes:
-            return _chunk_row(params, ids, kc_row, vc_row, off,
-                              fam=e._fam, **e._chunk_statics)
-        # a family that names scopes inside its programs: through the
-        # AOT cache, which keeps them
         return _aot_call("chunk_row", _chunk_row, params, ids, kc_row,
                          vc_row, off, state, n_valid, fam=e._fam,
-                         _memo=self._aot_memo, _token="chunk_row",
+                         _memo=self._aot_memo,
+                         _token=("chunk_row", off.shape), _run=run,
                          **e._chunk_statics)
 
     def write_slot(self, kc, vc, kc_row, vc_row, slot):
@@ -664,9 +689,11 @@ class _Prefilling:
     (the ``PagedConfig(prefill_token_budget=)`` path): the request
     holds a reserved slot index and its pool blocks, but its cache
     rows live in a private device row (``kc_row``/``vc_row``) that
-    block-width ``_chunk_row`` windows advance across STEPS — only
-    when the last chunk lands does the first token sample, the row
-    scatter into the blocks, and the slot go live.  Nothing has
+    ``_chunk_row`` launches advance across STEPS, from ``off`` to
+    ``last_off`` (the prompt's last block), each as many blocks wide
+    as the step's budget allows — only when the last block lands does
+    the first token sample (from ``hidden``, the newest launch's last
+    block), the row scatter into the blocks, and the slot go live.  Nothing has
     streamed, so an engine failure mid-prefill rejects these
     requeue-safe (``started=False``) and returns their blocks to the
     free list."""
@@ -1250,7 +1277,7 @@ class InferenceEngine:
                 pass
         # -- chunked-prefill token budget (the long-context round):
         # PagedConfig(prefill_token_budget=) splits admissions across
-        # steps in block-width _chunk_row windows — host state for the
+        # steps in _chunk_row launches of whole blocks — host state for the
         # in-flight chunked prefills lives in self._prefilling (slot
         # index -> _Prefilling; the slot is RESERVED but not live, so
         # the decode dispatch never sees it until the first token
@@ -1259,7 +1286,10 @@ class InferenceEngine:
                         if self.paged_arena is not None else None)
         self._prefilling = {}
         self._prefill_seq = itertools.count()
-        self._chunks_run = 0   # chunk-row dispatches (serve.schedule's arg)
+        # prompt blocks prefilled, and the chunk-row launches that did
+        # it (serve.schedule's args)
+        self._chunks_run = 0
+        self._launches_run = 0
         self._own_metrics = []
         # what the newest decode step's program counted about itself
         # (``ServedFamily.step_counts``), for the serve.step span
@@ -1309,10 +1339,25 @@ class InferenceEngine:
                     self._shard.set_chunk(self._chunk_statics)
             self._c_budget_chunks = self.stats.registry.counter(
                 "serve.prefill.budget_chunks",
-                help="block-width chunk dispatches the chunked-"
-                     "prefill token budget split admissions into",
+                help="prompt blocks the chunked-prefill token budget "
+                     "prefilled (a launch of n blocks counts n)",
                 engine=self.stats.engine_label)
-            self._own_metrics.append(self._c_budget_chunks)
+            self._c_launches = self.stats.registry.counter(
+                "serve.prefill.launches",
+                help="chunk-row launches of the chunked-prefill token "
+                     "budget (budget_chunks / launches = blocks a "
+                     "launch)",
+                engine=self.stats.engine_label)
+            self._own_metrics.extend([self._c_budget_chunks,
+                                      self._c_launches])
+            # a launch is as wide as the step's budget allows: the
+            # block times a power of two, widest first
+            B = self.paged_arena.block_size
+            self._launch_widths = tuple(
+                B << j for j in reversed(range(
+                    (min(self._budget, W) // B).bit_length())))
+            if self._shard is None:
+                self._compile_launch_widths()
         # -- CoW KV forking (serve/fork.py): fork-family id sequence
         # and the fork-round metrics (paged engines only — forking
         # rides on the arena's block refcounts)
@@ -1722,9 +1767,11 @@ class InferenceEngine:
                     width = self._decode_once()
                 with _trace.phase("serve.schedule", cat="serve") as sp:
                     n_pf, n_ch = self.stats.prefills, self._chunks_run
+                    n_la = self._launches_run
                     self._schedule(self._clock())
                     sp.set(admitted=self.stats.prefills - n_pf,
-                           chunks=self._chunks_run - n_ch)
+                           chunks=self._chunks_run - n_ch,
+                           launches=self._launches_run - n_la)
             except Exception as e:
                 # (a raising step has no meaningful anatomy: the
                 # phase's exit drops stepprof's open record)
@@ -3388,10 +3435,7 @@ class InferenceEngine:
         # the slot before; each chunk row carries it on
         pf.state = None
         if self._state_spec:
-            pf.state = {
-                k: jnp.zeros((self._state[k].shape[0],) + tuple(shape),
-                             dt, device=self._state_sh)
-                for k, (shape, dt) in self._state_spec.items()}
+            pf.state = self._zero_state()
             self._c_state_resets.inc()
         pf.hidden = None
         pf.off = len(nodes) * B
@@ -3415,49 +3459,108 @@ class InferenceEngine:
                      chunks=(pf.last_off - pf.off) // B + 1)
         return idx
 
+    def _compile_launch_widths(self):
+        """Compile the chunk-row program of every launch width now,
+        from abstract arguments shaped like a prefilling request's: a
+        warm-up of short prompts reaches the one-block program only,
+        and a width first met under traffic would compile there."""
+        def placed(make):
+            # what ``make`` allocates at the engine's placement, as
+            # shapes
+            return jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=self._state_sh),
+                jax.eval_shape(make))
+
+        kc_row, vc_row = placed(
+            lambda: self.paged_arena.gather_row([], n_used=0))
+        kw = {}
+        if self._state_spec:
+            kw["state"] = placed(self._zero_state)
+        if self._state_spec or self._fam.pad_aware:
+            kw["n_valid"] = jax.ShapeDtypeStruct((), jnp.int32)
+        ids = jax.ShapeDtypeStruct((1, self.max_len), jnp.int32)
+        for w in self._launch_widths:
+            self._x.chunk_row(
+                self._params, ids, kc_row, vc_row,
+                jax.eval_shape(lambda: self._launch_off(0, w)),
+                run=False, **kw)
+
+    def _zero_state(self):
+        """A prefilling request's per-slot state before its first
+        token: {kind: zeros (L, *shape)}."""
+        return {k: jnp.zeros((self._state[k].shape[0],) + tuple(shape),
+                             dt, device=self._state_sh)
+                for k, (shape, dt) in self._state_spec.items()}
+
+    def _launch_off(self, off, w):
+        """The ``off`` argument of a launch of ``w`` tokens at ``off``
+        (:func:`_launch_of`): the position itself for one block, the
+        offsets of its blocks for more."""
+        B = self.paged_arena.block_size
+        if w == B:
+            return jnp.int32(off)
+        return jnp.asarray(np.arange(off, off + w, B, dtype=np.int32))
+
     def _advance_prefilling(self, idx, left, now):
         """Spend up to ``left`` budget tokens on slot ``idx``'s
-        chunked prefill (block-width ``_chunk_row`` windows — the
-        exact executable warm admission rides, so a budgeted stream
-        is byte-identical to an unbudgeted one).  Completes the
-        admission when the last chunk lands.  Returns the remaining
-        budget."""
+        chunked prefill, a launch at a time: each the widest of the
+        engine's widths (the block times a power of two) that the
+        budget left, the blocks the prompt still needs and the row's
+        end all allow — one launch reads the layers' weights once,
+        however many blocks it covers.  A one-block launch is the exact
+        executable warm admission rides, and a wider one computes the
+        same rows (every position attends what lies below it in the
+        row), so a budgeted stream is the unbudgeted one.  Completes
+        the admission when the prompt's last block lands.  Returns the
+        remaining budget."""
         pf = self._prefilling[idx]
         B = self.paged_arena.block_size
         rid = pf.request.request_id
         plen = len(pf.request.prompt_ids)
         while left >= B and pf.off <= pf.last_off:
             if _faults._armed:
-                # chaos hook: a fault BETWEEN chunks models a raising
+                # chaos hook: a fault BETWEEN launches models a raising
                 # mid-prefill dispatch — step() fails the engine
                 # typed, the rejection is started=False (nothing
                 # streamed), and _fail returns the partial blocks to
                 # the free list (RESILIENCE.md; chaos_longctx)
                 _faults.check("serve.prefill_chunk")
+            # never past the prompt's last block — nor, then, past the
+            # row's end (the row is whole blocks), where the program's
+            # slices would clamp silently
+            room = min(left, pf.last_off - pf.off + B)
+            w = next(w for w in self._launch_widths if w <= room)
+            off = self._launch_off(pf.off, w)
+            # the prompt positions this launch really covers: the last
+            # one is cut at the prompt's end, not padded to the block
+            n_valid = min(w, plen - pf.off)
             if pf.state is None:
                 # (a pad-aware family is told where the prompt ends)
-                kw = {"n_valid": jnp.int32(min(B, plen - pf.off))} \
+                kw = {"n_valid": jnp.int32(n_valid)} \
                     if self._fam.pad_aware else {}
                 pf.hidden, pf.kc_row, pf.vc_row = self._x.chunk_row(
                     self._params, pf.ids_j, pf.kc_row, pf.vc_row,
-                    jnp.int32(pf.off), **kw)
+                    off, **kw)
             else:
                 pf.hidden, pf.kc_row, pf.vc_row, pf.state = \
                     self._x.chunk_row(
                         self._params, pf.ids_j, pf.kc_row, pf.vc_row,
-                        jnp.int32(pf.off), state=pf.state,
-                        n_valid=jnp.int32(min(B, plen - pf.off)))
-            self._c_budget_chunks.inc()
-            self._chunks_run += 1
-            # the prompt positions this chunk really covered: the last
-            # one is cut at the prompt's end, not padded to the block
-            self.stats.on_prefill_tokens(min(B, plen - pf.off))
+                        off, state=pf.state,
+                        n_valid=jnp.int32(n_valid))
+            # blocks, not launches: what the benchmark's token count
+            # multiplies by the block
+            self._c_budget_chunks.inc(w // B)
+            self._chunks_run += w // B
+            self._c_launches.inc()
+            self._launches_run += 1
+            self.stats.on_prefill_tokens(n_valid)
             if _reqs._active:
                 _reqs._ledger.on_prefill_chunk(
                     rid, engine=self.stats.engine_label,
                     t=self._clock(), offset=pf.off)
-            pf.off += B
-            left -= B
+            pf.off += w
+            left -= w
         if pf.off > pf.last_off:
             self._finish_prefilling(idx, pf)
         return left
@@ -3876,6 +3979,7 @@ class InferenceEngine:
             hidden, kc_row, vc_row = self._x.chunk_row(
                 self._params, ids_j, kc_row, vc_row, jnp.int32(off))
             self._chunks_run += 1
+            self._launches_run += 1
             if _reqs._active and rid is not None:
                 _reqs._ledger.on_prefill_chunk(
                     rid, engine=self.stats.engine_label,

@@ -430,7 +430,8 @@ def program_scopes():
 _monitor.register_cost_source(_paged_cost_tables)
 
 
-def _aot_call(name, fn, *args, _memo=None, _token=None, **statics):
+def _aot_call(name, fn, *args, _memo=None, _token=None, _run=True,
+              **statics):
     """Dispatch ``fn(*args, **statics)`` through the AOT cache.  The
     compiled executable takes only the traced args (statics were
     consumed at lowering); the cache key mirrors jit's (placement +
@@ -442,7 +443,11 @@ def _aot_call(name, fn, *args, _memo=None, _token=None, **statics):
     engine's dispatch shapes are FIXED per (step, batch width), so
     the executor caches the expensive leaf-shape key under a cheap
     token instead of re-walking ~80 param leaves every decode step
-    (a measurable host tax on the per-step path)."""
+    (a measurable host tax on the per-step path).
+    ``_run=False`` compiles and does not dispatch: ``args`` may then be
+    ``jax.ShapeDtypeStruct`` objects (with the sharding a committed argument
+    will have), and the program waits in the cache under the key its
+    first real call computes."""
     key = _memo.get(_token) if _memo is not None else None
     if key is None:
         leaves = jax.tree.leaves(args)
@@ -482,7 +487,7 @@ def _aot_call(name, fn, *args, _memo=None, _token=None, **statics):
             if scopes:
                 _keep_scopes(name, scopes, entry.as_text())
         _aot_cache[key] = entry
-    return entry(*args)
+    return entry(*args) if _run else None
 
 
 def _compile_cache_size():

@@ -657,12 +657,15 @@ class PPExecutor:
 
         ck = dict(self._chunk)
         n_head, eps = ck["n_head"], ck["eps"]
-        moe_top_k, chunk = ck["moe_top_k"], ck["chunk"]
+        moe_top_k, block = ck["moe_top_k"], ck["chunk"]
         L_loc = self._local_layers()
         stage_wave = self._stage_wave
 
         def body(params, ids, kc_row, vc_row, off):
+            from .engine import _launch_of
+
             blocks = params["blocks"]
+            off, chunk = _launch_of(off, block)
             toks = jax.lax.dynamic_slice(ids, (0, off), (1, chunk))
             pos = off + jnp.arange(chunk)
             x = jnp.take(params["wte"], toks[0], axis=0)[None] + \
@@ -691,7 +694,8 @@ class PPExecutor:
 
             h, (kc2, vc2) = stage_wave(x, layer_fn)
             h = G._ln(h, params["lnf_s"], params["lnf_b"], eps)
-            return h, kc2, vc2
+            # (the window's last block, as engine._chunk_row returns)
+            return h[:, chunk - block:] if chunk > block else h, kc2, vc2
 
         return body
 

@@ -13,6 +13,7 @@ jax.devices().
 """
 
 import os
+import sys
 
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
@@ -46,9 +47,15 @@ def _bound_jax_executable_maps():
     ~4k/min through the ONNX-conformance module).  Clearing jax's
     caches per module returns the maps to baseline; within-module
     compilation reuse — where nearly all the cache hits are — is
-    unaffected."""
+    unaffected.  The serve stack's own cache of compiled programs
+    (``serve/paged.py`` ``_aot_cache``: decode steps and chunk rows,
+    every launch width of every budgeted engine) is emptied with
+    them."""
     yield
     jax.clear_caches()
+    paged = sys.modules.get("singa_tpu.serve.paged")
+    if paged is not None:
+        paged._aot_cache.clear()
 
 
 # tests/benchmark/test_bench_loader.py::test_cell_loads_with_all_its_files

@@ -20,6 +20,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import launch_widths as lw  # noqa: E402
 from benchmark.harness import loader  # noqa: E402
 from singa_tpu import device, tensor  # noqa: E402
 from singa_tpu.serve import GenerationRequest, PagedConfig  # noqa: E402
@@ -120,6 +121,32 @@ def test_chunk_rows_carry_the_state_and_match_the_reference(ref, built,
         got.append(np.asarray(fam.logits(params, hidden))[0])
     got = np.concatenate(got)[:plen]
     np.testing.assert_allclose(got, _ref_logits(ref, w, toks), atol=TOL)
+
+
+@pytest.fixture(scope="module")
+def launch_runs(built):
+    runs = lw.Runs(lambda budget: _engine(built[0], budget=budget), 512)
+    yield runs
+    runs.close()
+
+
+@pytest.mark.parametrize("case", list(lw.CASES))
+@pytest.mark.parametrize("ratio", lw.RATIOS)
+def test_a_launch_of_several_blocks_leaves_what_one_block_at_a_time_did(
+        launch_runs, ratio, case):
+    """One launch a request a step, ``ratio`` blocks wide at most: the
+    tokens, the private K/V rows and the carried scan and conv state of
+    every admission against the engine that launches a block at a time
+    -- the scan walks a wide row a block at a time, so its arithmetic
+    is that engine's."""
+    lw.assert_same_as_one_block(launch_runs.run(ratio, case),
+                                launch_runs.run(1, case), case, ratio,
+                                atol=TOL)
+
+
+def test_one_block_lowers_to_the_program_it_was(launch_runs):
+    assert lw.chunk_row_lowering(launch_runs.engine(1)) == \
+        lw.PARENT_LOWERING["falcon_h1"]
 
 
 def test_prefill_then_decode_through_the_engines_cache(ref, built):

@@ -25,6 +25,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import launch_widths as lw  # noqa: E402
 from benchmark.harness import loader  # noqa: E402
 from singa_tpu import device, tensor  # noqa: E402
 from singa_tpu.serve import GenerationRequest, PagedConfig  # noqa: E402
@@ -361,6 +362,31 @@ def test_no_token_is_dropped_whatever_the_routing(case):
         assert float(jnp.abs(y).max()) == 0.0
 
 
+@pytest.fixture(scope="module")
+def launch_runs(built):
+    runs = lw.Runs(lambda budget: _engine(built[0], budget=budget), 512)
+    yield runs
+    runs.close()
+
+
+@pytest.mark.parametrize("case", list(lw.CASES))
+@pytest.mark.parametrize("ratio", lw.RATIOS)
+def test_a_launch_of_several_blocks_leaves_what_one_block_at_a_time_did(
+        launch_runs, ratio, case):
+    """One launch a request a step, ``ratio`` blocks wide at most: the
+    tokens and the private latent row of every admission against the
+    engine that launches a block at a time (the padding after a
+    prompt's end chooses no expert at any width)."""
+    lw.assert_same_as_one_block(launch_runs.run(ratio, case),
+                                launch_runs.run(1, case), case, ratio,
+                                atol=TOL)
+
+
+def test_one_block_lowers_to_the_program_it_was(launch_runs):
+    assert lw.chunk_row_lowering(launch_runs.engine(1)) == \
+        lw.PARENT_LOWERING["mla_moe"]
+
+
 def test_the_engine_tells_chunk_rows_where_the_prompt_ends(built,
                                                           monkeypatch):
     """A prompt's last chunk row is padded to the block; the padding's
@@ -381,13 +407,16 @@ def test_the_engine_tells_chunk_rows_where_the_prompt_ends(built,
     monkeypatch.setattr(mla_moe, "held_terms", spy)
     eng = _engine(m)
     # another hash: no program traced with the sound function is found
+    # (nor the keys of those the engine compiled when it was built)
     eng._fam = dataclasses.replace(eng._fam, cfg=dataclasses.replace(
         m.cfg, max_position_embeddings=163841))
+    eng._x._aot_memo.clear()
     assert eng._fam.pad_aware
     _serve(eng, [_prompt(13)], 2)
     eng.close()
-    # chunk rows of 8 tokens and decode steps over the lanes' bucket
-    assert seen and None not in seen and (8,) in seen
+    # one launch of two blocks (the budget's width) for the 13 tokens,
+    # and decode steps over the lanes' bucket
+    assert seen and None not in seen and (16,) in seen
 
 
 def test_yarn_frequencies_and_the_softmax_scale(ref):

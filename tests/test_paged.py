@@ -10,6 +10,7 @@ streams, and the slot-arena engine (itself parity-pinned against
 single-prompt ``generate`` in tests/test_serve.py) is the oracle, so
 preemption/swap noise cannot hide behind tolerance."""
 
+import launch_widths as lw
 import numpy as np
 import pytest
 
@@ -918,3 +919,81 @@ def test_compiled_programs_report_their_temporaries(model):
     gauges = registry().snapshot()["gauges"]
     assert gauges["serve.paged.program_temp_bytes"
                   "{program=paged_decode_kernel}"] == args["temp_bytes"]
+
+
+# -- the budgeted prefill's launch widths (tests/launch_widths.py) ---------
+
+@pytest.fixture(scope="module")
+def launch_runs(model):
+    runs = lw.Runs(lambda budget: model.serve(
+        max_slots=4, paged=PagedConfig(block_size=8, num_blocks=64,
+                                       prefill_token_budget=budget)), 256)
+    yield runs
+    runs.close()
+
+
+@pytest.mark.parametrize("case", list(lw.CASES))
+@pytest.mark.parametrize("ratio", lw.RATIOS)
+def test_a_launch_of_several_blocks_leaves_what_one_block_at_a_time_did(
+        launch_runs, ratio, case):
+    """One launch a request a step, ``ratio`` blocks wide at most: the
+    tokens and the private K/V rows of every admission against the
+    engine that launches a block at a time; ``serve.prefill.
+    budget_chunks`` counts the same blocks whatever the ratio (the
+    benchmark multiplies it by the block for its tokens), and
+    ``serve.prefill.launches`` fewer exactly when a launch can be wider
+    than a block."""
+    lw.assert_same_as_one_block(launch_runs.run(ratio, case),
+                                launch_runs.run(1, case), case, ratio,
+                                atol=1e-5)
+
+
+def test_one_block_lowers_to_the_program_it_was(launch_runs):
+    assert lw.chunk_row_lowering(launch_runs.engine(1)) == \
+        lw.PARENT_LOWERING["gpt2"]
+
+
+@pytest.mark.parametrize("ratio", lw.RATIOS)
+def test_every_launch_width_is_compiled_when_the_engine_is_built(model,
+                                                                 ratio):
+    """A warm-up of short prompts (the benchmark's: one block less a
+    token) reaches the one-block program only; prompts of every length
+    after it -- every width, at offsets even and odd -- compile
+    nothing, because the engine compiled each width from abstract
+    shapes when it was built."""
+    from singa_tpu.serve import paged
+    from singa_tpu.serve.jitpin import jit_cache_size
+
+    # a row width no other test uses: the programs compile here
+    eng = model.serve(max_slots=4, max_len=120, paged=PagedConfig(
+        block_size=8, num_blocks=64, prefill_token_budget=8 * ratio))
+    try:
+        widths = eng._launch_widths
+        assert widths == tuple(8 << j for j in reversed(
+            range(ratio.bit_length())))
+        # before any request: a program a width, under the key its
+        # first launch will look up
+        memo = eng._x._aot_memo
+        assert {t[1] for t in memo} == {() if w == 8 else (w // 8,)
+                                        for w in widths}
+        assert all(key in paged._aot_cache for key in memo.values())
+        rng = np.random.RandomState(ratio)
+
+        def serve(lengths, n_new=3):
+            hs = [eng.submit(GenerationRequest(
+                rng.randint(0, 256, n).astype(np.int32),
+                max_new_tokens=n_new, temperature=0.0))
+                for n in lengths]
+            eng.run_until_complete(max_steps=2000)
+            assert all(h.done() for h in hs)
+
+        # every decode bucket, the row copies, the first-token sampler
+        serve([7] * 4)
+        warmed = jit_cache_size()
+        serve([7, 60, 27, 117, 12, 13, 33, 5, 90])
+        assert jit_cache_size() == warmed
+        chunks, launches = (eng._c_budget_chunks.value,
+                            eng._c_launches.value)
+        assert (launches < chunks) == (ratio > 1)
+    finally:
+        eng.close(force=True)

@@ -166,6 +166,30 @@ def test_paged_preempt_resume_parity_tp2(model):
     assert pg["blocks_used"] == 0, "leaked blocks after drain"
 
 
+@pytest.mark.parametrize("ratio", [1, 4])
+def test_budgeted_launch_widths_parity_tp2(model, ratio):
+    """The budgeted prefill's launches, one block wide and as wide as
+    a budget of four blocks allows (the sharded twin retraces a width:
+    ``off`` carries it as a shape): streams equal the single-device
+    whole-prompt run's, and the blocks counted do not depend on the
+    width."""
+    work = _workload(8, 4, p_lo=20, p_hi=60, n_lo=3, n_hi=7)
+    base, _ = _run(model, work, max_slots=4)
+    eng = model.serve(max_slots=4, tp=2, paged=PagedConfig(
+        block_size=8, num_blocks=48, prefill_token_budget=8 * ratio))
+    hs = [eng.submit(GenerationRequest(
+        w["prompt"], max_new_tokens=w["n_new"],
+        temperature=w["temperature"], seed=w["seed"])) for w in work]
+    eng.run_until_complete(max_steps=4000)
+    outs = [h.result().tokens for h in hs]
+    chunks, launches = (eng._c_budget_chunks.value,
+                        eng._c_launches.value)
+    eng.close()
+    assert _parity(outs, base)
+    assert chunks == sum((len(w["prompt"]) - 1) // 8 + 1 for w in work)
+    assert (launches < chunks) == (ratio > 1)
+
+
 @pytest.mark.parametrize("cache_dtype", [None, "int8"],
                          ids=["dense", "int8"])
 def test_paged_pool_shards_its_rows(model, cache_dtype):

@@ -121,6 +121,39 @@ def test_the_chunk_row_program_compiles(shapes):
     assert "ssm_scan" in set(paged.program_scopes()["chunk"].values())
 
 
+def _row_copies(text, row):
+    """Copies in a compiled program whose result has ``row``'s dims (a
+    prefilling request's whole private row)."""
+    dims = ",".join(str(d) for d in row.shape)
+    return len(re.findall(r"= \w+\[" + dims + r"\][^\n]* copy\(", text))
+
+
+def test_a_launch_of_two_blocks_compiles(shapes):
+    """The hybrid family's 256-wide launch (docqa's budget over its
+    block): an ``off`` of two block offsets; the scan walks the two
+    blocks in order inside the program.  Temporaries stay under the
+    one-block program's bound and no copy of the whole private row
+    comes with the width."""
+    from singa_tpu.serve import engine, paged
+
+    cfg, fam, params, sds = shapes
+    row = sds((L, 1, 4, WIDTH, 128))
+    state = {"ssm": sds((L, 32, 128, 256), jnp.float32),
+             "conv": sds((L, 3, cfg.conv_dim), jnp.float32)}
+    texts = {}
+    for n in (1, 2):
+        comp = engine._chunk_row.lower(
+            params, sds((1, WIDTH), jnp.int32), row, row,
+            sds((n,) if n > 1 else (), jnp.int32), state,
+            sds((), jnp.int32), n_head=20, eps=1e-5, moe_top_k=2,
+            chunk=BLOCK, window=None, fam=fam).compile()
+        assert comp.memory_analysis().temp_size_in_bytes < 0.5e9
+        texts[n] = comp.as_text()
+    assert _row_copies(texts[2], row) <= _row_copies(texts[1], row)
+    paged._keep_scopes("chunk2", fam.scopes, texts[2])
+    assert "ssm_scan" in set(paged.program_scopes()["chunk2"].values())
+
+
 # ---- gpt2-large: 36 layers, 1280 wide, 20 heads of 64, bf16 ------------
 
 GL, GE, GH, GV, GW, GBLOCKS, GB = 36, 1280, 20, 50257, 1024, 561, 32
@@ -176,6 +209,29 @@ def test_gpt2_large_decode_step_is_in_place(gpt2l, lanes):
         params, pool, pool, *_lanes(sds, lanes), block=GB, n_head=GH,
         eps=1e-5, moe_top_k=2, top_k=0, use_top_p=False,
         window=None, fam=gpt2_decode.FAMILY).compile())
+
+
+def test_gpt2_large_launch_of_the_whole_budget_compiles(gpt2l):
+    """longdoc's budget in one launch: 4 blocks of 32 = 128 positions
+    through all 36 layers, beside the one-block program.  The private
+    rows are donated and written where they lie, temporaries stay
+    under 0.5 GB, and the width brings no copy of a whole row."""
+    from singa_tpu.models import gpt2_decode
+    from singa_tpu.serve import engine
+
+    sds, params, _, i32 = gpt2l
+    row = sds((GL, 1, GH, GW, GE // GH))
+    texts = {}
+    for n in (1, 4):
+        comp = engine._chunk_row.lower(
+            params, i32(1, GW), row, row, i32(n) if n > 1 else i32(),
+            n_head=GH, eps=1e-5, moe_top_k=2, chunk=GB, window=None,
+            fam=gpt2_decode.FAMILY).compile()
+        ma = comp.memory_analysis()
+        assert ma.alias_size_in_bytes >= 2 * 2 * GL * GW * GE
+        assert ma.temp_size_in_bytes < 0.5e9
+        texts[n] = comp.as_text()
+    assert _row_copies(texts[4], row) <= _row_copies(texts[1], row)
 
 
 @pytest.mark.parametrize("rows,width", [(4, 128), (1, GW)])
