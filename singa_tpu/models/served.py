@@ -123,7 +123,8 @@ class ServedFamily:
         """The host's reading of one decode step's ``counts`` (numpy):
         ``{name: number}`` for the arguments of the ``singa/serve.step``
         span, and ``{(counter name, labels as a sorted tuple): n}`` to
-        add to the engine's counters."""
+        add to the engine's counters -- and, where the family has any,
+        a third ``{(gauge name, labels): value}`` to set."""
         return {}, {}
 
     def logits(self, params, hidden):

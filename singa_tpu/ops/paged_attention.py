@@ -236,3 +236,129 @@ def rotary(x, pos, theta):
     x1, x2 = xf[..., :d // 2], xf[..., d // 2:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
     return out.astype(x.dtype)
+
+
+
+# -- window layers: a ring a slot ---------------------------------------------
+# A layer that attends the last ``window`` positions only keeps them in a
+# RING of ``ring >= window`` rows a slot (position ``p`` at row ``p %
+# ring``), not in the pool: its memory and its decode work are O(window)
+# whatever the sequence's length, and whatever the other lanes' lengths
+# (models/swa_moe.py; the rings live in the engine's per-slot state
+# arenas).  Rows are stored as the pool stores them: ``(ring, H_kv·D)``.
+
+
+def ring_chunk_attn(q, ring_k, ring_v, layer, off, block, row_blocks,
+                    k_cur, v_cur, scale, window):
+    """Banded attention of a chunk's queries ``q`` (n_kv, g, C, d) at
+    positions ``off + [0, C)``: over one slot's ring of layer ``layer``
+    -- ``ring_k``/``ring_v`` (J, ring, H_kv·D), holding positions
+    ``[off - ring, off)`` -- and over the chunk's own rows
+    ``k_cur``/``v_cur`` (C, H_kv·D), which are NOT in the ring yet
+    (:func:`ring_write_chunk` lays them in afterwards).  The ring is
+    walked as blocks of ``block`` rows by the shared loop
+    (:func:`paged_attn` with its band mask): block ``j`` of the
+    sequence lies in ring block ``j % (ring / block)`` -- the table, of
+    ``row_blocks`` entries (the sequence's most) -- and the walk starts
+    at the first block that holds an in-window row: O(window / block)
+    iterations wherever ``off`` is.  ``block`` divides the ring and need
+    not divide ``off``: of the walk's last block the rows from ``off`` on
+    are masked as not yet written, and of its first those that a later
+    turn of the ring has overwritten are masked as out of the band."""
+    n_j, ring, x = ring_k.shape
+    c = q.shape[2]
+    if ring % block or ring < window or c > ring:
+        raise ValueError(
+            f"a ring of {ring} rows needs whole blocks of {block}, at "
+            f"least the window ({window}) and the launch ({c} tokens)")
+    rb = ring // block
+    i = jnp.arange(c)
+    cur = (i[None, :] <= i[:, None]) & (i[:, None] - i[None, :] < window)
+    return paged_attn(
+        q, ring_k.reshape(n_j, rb, block, x),
+        ring_v.reshape(n_j, rb, block, x), layer,
+        jnp.arange(row_blocks) % rb, off, -(-off // block), block, -1,
+        k_cur, v_cur, cur, scale, window=window,
+        blk_lo=jnp.maximum(0, (off - window + 1) // block))
+
+
+def ring_write_chunk(ring, layer, rows, off, n_valid, block):
+    """Lay a chunk's rows ``rows`` (C, X) at positions ``off + [0, C)``
+    into layer ``layer`` of one slot's ring (J, ring, X), whole blocks
+    at a time (``off`` a multiple of ``block``; a chunk that passes the
+    ring's end runs on at its start).  Rows from ``n_valid`` on are
+    padding after the prompt's end and leave the ring as it was: the
+    positions they would overwrite are still inside the window."""
+    n_j, n_ring, x = ring.shape
+    c, rb = rows.shape[0], n_ring // block
+    blocks = ring.reshape(n_j, rb, block, x)
+    idx = (off // block + jnp.arange(c // block)) % rb
+    new = jnp.where((jnp.arange(c) < n_valid).reshape(-1, block, 1),
+                    rows.reshape(-1, block, x).astype(ring.dtype),
+                    blocks[layer, idx])
+    return blocks.at[layer, idx].set(new).reshape(ring.shape)
+
+
+def ring_decode_attn(q, k_new, v_new, arena_k, arena_v, at, slots, pos,
+                     scale, window):
+    """One token a lane against its ring, and the write of its new row:
+    ``q`` (W, n_kv, g, d) at positions ``pos`` (W,); ``k_new``/``v_new``
+    (W, H_kv·D) the lanes' new rows; the arenas ``(P, S + 1, J, ring,
+    H_kv·D)`` hold slot ``slots[w]``'s ring of this layer at ``[at[0],
+    slots[w], at[1]]`` (a dead lane: the trash row, position 0).
+
+    A lane at a time, in a loop whose bound is the number of lanes: the
+    lane's ring is sliced out where it lies (a gather of the lanes' rings
+    makes the compiler slice the whole arena first), its rows inside the
+    band ``(pos - window, pos)`` and the new row are attended, and the
+    new row is written at ``pos % ring``.  The work of a lane is its
+    ring's, whatever its own position and whatever the others'.
+    Returns ``(out (W, n_kv, g, d) float32, arena_k, arena_v)``."""
+    n_w, n_kv, g, d = q.shape
+    ring, x = arena_k.shape[-2:]
+    r = jnp.arange(ring)
+    f32 = jnp.float32
+    own = jnp.eye(n_kv, dtype=q.dtype)       # a query head's K/V head
+
+    def lane(i, carry):
+        a_k, a_v, out = carry
+        p, here = pos[i], (at[0], slots[i], at[1])
+        one = lambda a: jax.lax.dynamic_slice(
+            a, here + (0, 0), (1, 1, 1, ring, x)).reshape(ring, x)
+        kb, vb = one(a_k), one(a_v)
+        # row r holds the newest position below p that is r modulo ring
+        held = p - 1 - (p - 1 - r) % ring
+        vis = (held >= 0) & (held > p - window)                 # (ring,)
+        # every query head against the rows AS STORED, all K/V heads side
+        # by side: a head's query sits in its own K/V head's columns and
+        # zeros in the others', so one matmul scores all heads and one
+        # weighs the values (viewed as (ring, n_kv, d) instead, the
+        # compiler re-laid the whole arena, transposed, every step; the
+        # zeros cost a few times the operations of an attention that is
+        # bound by reading the ring)
+        qx = jnp.einsum("kgd,kj->kgjd", q[i], own).reshape(n_kv * g, x)
+        sc = jnp.einsum("hx,rx->hr", qx, kb,
+                        preferred_element_type=f32) * scale
+        sc = jnp.where(vis, sc, NEG_INF)
+        s_cur = jnp.einsum("hx,x->h", qx, k_new[i],
+                           preferred_element_type=f32) * scale
+        m = jnp.maximum(jnp.max(sc, axis=-1), s_cur)
+        pr = jnp.where(vis, jnp.exp(sc - m[:, None]), 0.0)
+        p_cur = jnp.exp(s_cur - m)
+        o = jnp.einsum("hr,rx->hx", pr.astype(vb.dtype), vb,
+                       preferred_element_type=f32) \
+            + p_cur[:, None] * v_new[i].astype(f32)[None, :]
+        o = o / (jnp.sum(pr, axis=-1) + p_cur)[:, None]
+        # ... of which a head keeps its own K/V head's columns
+        o = jnp.einsum("kgjd,kj->kgd", o.reshape(n_kv, g, n_kv, d),
+                       own.astype(f32))
+        put = lambda a, row: jax.lax.dynamic_update_slice(
+            a, row.reshape(1, 1, 1, 1, x).astype(a.dtype),
+            here + (p % ring, 0))
+        return (put(a_k, k_new[i]), put(a_v, v_new[i]),
+                jax.lax.dynamic_update_slice(out, o[None], (i, 0, 0, 0)))
+
+    a_k, a_v, out = jax.lax.fori_loop(
+        0, n_w, lane, (arena_k, arena_v,
+                       jnp.zeros((n_w, n_kv, g, d), f32)))
+    return out, a_k, a_v

@@ -2221,19 +2221,24 @@ class InferenceEngine:
 
     def _on_step_counts(self, counts):
         """One decode step's counts, as the family reads them: span
-        arguments for this step's ``serve.step`` and increments of the
-        engine's counters (made on first use, removed at close)."""
-        self._step_counts, incs = self._fam.on_step_counts(counts,
-                                                           self.cfg)
-        for key, n in incs.items():
-            c = self._count_metrics.get(key)
-            if c is None:
-                c = self._count_metrics[key] = \
-                    self.stats.registry.counter(
+        arguments for this step's ``serve.step``, increments of the
+        engine's counters and, where the family gives a third, values
+        of its gauges (each metric made on first use, removed at
+        close)."""
+        self._step_counts, incs, *gauges = self._fam.on_step_counts(
+            counts, self.cfg)
+        reg = self.stats.registry
+        for make, update, values in (
+                (reg.counter, "inc", incs),
+                (reg.gauge, "set", gauges[0] if gauges else {})):
+            for key, n in values.items():
+                m = self._count_metrics.get(key)
+                if m is None:
+                    m = self._count_metrics[key] = make(
                         key[0], engine=self.stats.engine_label,
                         **dict(key[1]))
-                self._own_metrics.append(c)
-            c.inc(n)
+                    self._own_metrics.append(m)
+                getattr(m, update)(n)
 
     def _emit_step(self, next_toks, a_draft, lps):
         """Emit one decode step's tokens slot by slot

@@ -441,3 +441,89 @@ def test_gpt2_large_and_the_hybrid_family_compile_as_at_the_parent(
     assert ma.temp_size_in_bytes == PARENT["hybrid_decode_temp"]
     assert ma.alias_size_in_bytes == PARENT["hybrid_decode_alias"]
     assert _text_hash(comp) == PARENT["hybrid_decode_text"]
+
+
+# ---- swa_moe (Trinity-Mini): two periods for eight, real widths ----------
+
+SW_P, SW_SLOTS, SW_BLOCKS, SW_WIDTH = 2, 24, 200, 16384
+
+
+@pytest.fixture(scope="module")
+def swa(shapes):
+    """(cfg, family, abstract params, sds) of the window-and-full family
+    at two periods (8 layers: every stack the programs scan) with the
+    benchmark's share: 16 experts held, an eighth of the vocabulary."""
+    from singa_tpu.models.swa_moe import (SwaMoeConfig, SwaMoeFamily,
+                                          _tensors)
+
+    sds = shapes[3]
+    cfg = SwaMoeConfig(num_hidden_layers=4 * SW_P, vocab_size=25024,
+                       experts_held=(0, 16), max_len=SW_WIDTH,
+                       dtype="bfloat16")
+    sh = cfg.shapes("model")
+    params = dict(wte=sds(sh["wte"]), head=sds(sh["head"]),
+                  lnf=sds(sh["lnf"], jnp.float32))
+    for stack, n in cfg.stack_sizes().items():
+        vec, mat = _tensors(stack)
+        params[stack] = {
+            k: sds((n,) + cfg.shapes(stack)[k],
+                   jnp.float32 if k in vec else jnp.bfloat16)
+            for k in vec + mat}
+    return cfg, SwaMoeFamily(cfg), params, sds
+
+
+def test_the_two_kind_cache_is_updated_in_place(swa):
+    """The decode program at the benchmark's 24 lanes: the full layers'
+    pool and the window layers' ring arenas (50 MB a slot at two
+    periods) are aliased and neither is copied or re-laid -- viewed as
+    (ring, heads, head size) the compiler re-laid both arenas,
+    transposed, every step (3.1 GB of temporaries at size); nor is any
+    layer's matrix (W_q and W_k, stored (in, out), were transposed out
+    of their stacks every step)."""
+    from singa_tpu.serve import paged
+
+    cfg, fam, params, sds = swa
+    n = SW_SLOTS
+    pool = sds((SW_P, SW_BLOCKS + 1, BLOCK, 512))
+    arena = sds((SW_P, n + 1, 3, 2048, 512))
+    state = {"win_k": arena, "win_v": arena}
+    comp = paged._paged_decode_kernel.lower(
+        params, pool, pool, *_lanes(sds, n, SW_WIDTH // BLOCK), None,
+        state, sds((n,), jnp.int32), block=BLOCK, n_head=32, eps=1e-5,
+        moe_top_k=2, top_k=0, use_top_p=False, window=None,
+        fam=fam).compile()
+    ma = comp.memory_analysis()
+    cache = 2 * 2 * (SW_P * (SW_BLOCKS + 1) * BLOCK * 512
+                     + SW_P * (n + 1) * 3 * 2048 * 512)
+    assert cache <= ma.alias_size_in_bytes < 1.01 * cache
+    # the smallest of: a pool (26 M elements), an arena (157 M), a
+    # layer's W_q (8.4 M)
+    assert _big_copies(comp.as_text(), floor=8e6) == []
+    assert ma.temp_size_in_bytes < 0.1e9
+    paged._keep_scopes("swa_decode", fam.scopes, comp.as_text())
+    assert {"attn_window", "attn_full", "moe_experts", "moe_shared",
+            "dense_mlp", "head"} <= set(
+                paged.program_scopes()["swa_decode"].values())
+
+
+@pytest.mark.parametrize("blocks", [1, 4])
+def test_the_two_kind_chunk_row_programs_compile(swa, blocks):
+    """A launch of one block and of the budget's four: the request's own
+    rings (2 x 6.3 M elements at two periods) and its private row are
+    what it may copy; nothing the size of a layer's stack of matrices."""
+    from singa_tpu.serve import engine, paged
+
+    cfg, fam, params, sds = swa
+    row = sds((SW_P, 1, 4, SW_WIDTH, 128))
+    ring = sds((SW_P, 3, 2048, 512))
+    comp = engine._chunk_row.lower(
+        params, sds((1, SW_WIDTH), jnp.int32), row, row,
+        sds((blocks,) if blocks > 1 else (), jnp.int32),
+        {"win_k": ring, "win_v": ring}, sds((), jnp.int32), n_head=32,
+        eps=1e-5, moe_top_k=2, chunk=BLOCK, window=None,
+        fam=fam).compile()
+    assert comp.memory_analysis().temp_size_in_bytes < 0.3e9
+    assert _big_copies(comp.as_text(), floor=17e6) == []
+    paged._keep_scopes(f"swa_chunk{blocks}", fam.scopes, comp.as_text())
+    assert {"attn_window", "attn_full", "moe_experts"} <= set(
+        paged.program_scopes()[f"swa_chunk{blocks}"].values())
