@@ -54,7 +54,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.paged_attention import (NEG_INF, paged_attn as _paged_attn,
+from ..ops.paged_attention import (NEG_INF,
+                                   paged_decode_attn as _paged_decode_attn,
                                    write_rows as _write_rows)
 from ..ops.sampling import (filter_logits as _filter_logits,
                             sample as _sample)
@@ -1006,11 +1007,12 @@ def _block_paged(x, pool_k, pool_v, p, li, tables, pos, live, n_blk,
         # position pos+j only when (pos+i) - (pos+j) < window
         i = jnp.arange(nq)
         cur_mask = cur_mask & (i[:, None] - i[None, :] < window)
-    a = jax.vmap(
-        lambda q_r, k_r, v_r, tbl, pos_r: _paged_attn(
-            q_r, pool_k, pool_v, li, tbl, pos_r, n_blk, block, trash,
-            k_r, v_r, cur_mask, 1.0 / math.sqrt(d), window=window,
-            blk_lo=blk_lo))(q, k_cur, v_cur, tables, pos)
+    # one call for every lane: the decode kernel where the operands
+    # allow it, else the block loop a lane (ops/paged_attention.py)
+    a = _paged_decode_attn(
+        q, pool_k, pool_v, li, tables, pos, block, trash, k_cur, v_cur,
+        1.0 / math.sqrt(d), cur_mask=cur_mask, window=window,
+        blk_lo=blk_lo, n_blk=n_blk, tp_axis=tp_axis)
     # the new rows depend on nothing the block loop computes, so the
     # compiler is free to put a layer's scatter before its reads of the
     # pool, and then keeps a second pool to read from: tie the rows to

@@ -50,8 +50,8 @@ import numpy as np
 
 from .. import autograd, model
 from ..ops.expert_layer import held_terms, route, swiglu
-from ..ops.paged_attention import (paged_attn, rotary, write_rows,
-                                   yarn_frequencies)
+from ..ops.paged_attention import (paged_attn, paged_decode_attn, rotary,
+                                   write_rows, yarn_frequencies)
 from ..tensor import Tensor
 from .served import ServedFamily
 
@@ -437,7 +437,6 @@ class MlaMoeFamily(ServedFamily):
         p_c = jnp.where(live, pos, 0)
         t_c = jnp.where(live, toks, 0)
         x = params["wte"][t_c]                               # (W, E)
-        one = jnp.ones((1, 1), bool)
 
         def layer(carry, kind, li, i, p):
             x, pool_k = carry
@@ -446,14 +445,10 @@ class MlaMoeFamily(ServedFamily):
                 q_nope, q_r, row = _queries_and_row(h, p, c, p_c)
                 q = _absorb(q_nope, q_r, p, c)                  # (H, W, d)
             with jax.named_scope("mla_attn"):
-                def lane(q_r_, row_r, tbl, pos_r):
-                    return paged_attn(
-                        q_r_[None, :, None], pool_k, None, li, tbl, pos_r,
-                        n_blk, block, trash, row_r[None], None, one,
-                        c.softmax_scale, v_dim=c.kv_lora_rank)[0, :, 0]
-
-                o_lat = jax.vmap(lane, in_axes=(1, 0, 0, 0))(
-                    q, row, tables, p_c)                     # (W, H, r)
+                o_lat = paged_decode_attn(
+                    q.transpose(1, 0, 2)[:, None], pool_k, None, li,
+                    tables, p_c, block, trash, row, None, c.softmax_scale,
+                    v_dim=c.kv_lora_rank, n_blk=n_blk)[:, 0]  # (W, H, r)
                 pool_k = write_rows(pool_k, li, row[:, None], tables,
                                     p_c, live, block, trash)
             with jax.named_scope("mla_proj"):

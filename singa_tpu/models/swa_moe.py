@@ -54,9 +54,10 @@ import numpy as np
 
 from .. import autograd, model
 from ..ops.expert_layer import held_terms, route, swiglu
-from ..ops.paged_attention import (paged_attn, ring_chunk_attn,
-                                   ring_decode_attn, ring_write_chunk,
-                                   rotary, row_to_blocks, write_rows)
+from ..ops.paged_attention import (paged_attn, paged_decode_attn,
+                                   ring_chunk_attn, ring_decode_attn,
+                                   ring_write_chunk, rotary, row_to_blocks,
+                                   write_rows)
 from ..tensor import Tensor
 from .served import ServedFamily
 
@@ -542,7 +543,6 @@ class SwaMoeFamily(ServedFamily):
         n_kv, d = c.n_kv_head, c.head_dim
         g = c.n_head // n_kv
         n_w = x.shape[0]
-        one = jnp.ones((1, 1), bool)
         scale = 1.0 / math.sqrt(d)
 
         def layer(carry, stack, period, j, i, p):
@@ -560,13 +560,9 @@ class SwaMoeFamily(ServedFamily):
                         scale, c.sliding_window)
             else:
                 with jax.named_scope("attn_full"):
-                    def lane(q_r, k_r, v_r, tbl, pos_r):
-                        return paged_attn(
-                            q_r[:, :, None], pool_k, pool_v, period, tbl,
-                            pos_r, n_blk, block, trash, k_r[None],
-                            v_r[None], one, scale)[:, :, 0]
-
-                    o = jax.vmap(lane)(q, k, v, tables, p_c)
+                    o = paged_decode_attn(
+                        q, pool_k, pool_v, period, tables, p_c, block,
+                        trash, k, v, scale, n_blk=n_blk)
                     pool_k = write_rows(pool_k, period, k[:, None],
                                         tables, p_c, live, block, trash)
                     pool_v = write_rows(pool_v, period, v[:, None],

@@ -20,6 +20,23 @@ becomes blocks, and blocks a row, in :func:`row_to_blocks` /
 that access pattern the compiler updates a donated pool where it lies;
 with a head's 64 values as the row it laid the whole pool out again in
 every program that scattered into it (tests/test_tpu_compile.py).
+
+A decode step's attention -- one query token a lane -- goes through
+:func:`paged_decode_attn`, one call for all lanes (GPT-2, ``mla_moe``,
+the full layers of ``swa_moe``; ``falcon_h1`` still calls
+:func:`paged_attn` a lane itself), and
+:func:`decode_attn_impl`, a pure function of the operands' shapes, says
+what that call runs.  The Pallas kernel (ops/pallas/paged_attention.py:
+the pool read where it lies, a lane walking its own blocks, rows
+contracted as stored) takes bf16 pools of whole tiles in an unsharded
+program on a TPU.  :func:`paged_attn` under ``jax.vmap`` -- every lane to
+the longest lane's bound -- keeps everything else: Q > 1 (chunk rows, a
+speculative verify chunk, :func:`ring_chunk_attn`), int8 pools,
+``window=`` / ``blk_lo``, a sharded program (``tp_axis``), any backend
+that is not a TPU.  The parity contract is one: the kernel is held to
+``vmap(paged_attn)`` up to float reordering
+(tests/test_paged_decode_kernel.py), and ``paged_attn`` to the row math
+(token streams identical, logits allclose: tests/test_paged.py).
 """
 
 import math
@@ -27,6 +44,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .pallas import paged_attention as _pallas
 
 NEG_INF = -1e30
 
@@ -168,6 +187,86 @@ def paged_attn(q, pool_k, pool_v, layer, tbl, p_limit, n_blk, block,
     # the chunk's own keys — computed this step, not yet in the pool
     m, l, acc = update(carry, k_cur, v_cur, cur_mask[None, None])
     return acc / l[..., None]
+
+
+def _varies(a):
+    """Does ``a`` (array, tracer or ShapeDtypeStruct) differ between the
+    shards of a ``shard_map`` it is traced in?"""
+    return bool(getattr(a, "vma", None)
+                or getattr(getattr(a, "aval", None), "vma", None))
+
+
+def decode_attn_impl(q, pool_k, *, window=None, blk_lo=None, tp_axis=None,
+                     backend=None):
+    """``"kernel"`` or ``"loop"``: which of the two
+    :func:`paged_decode_attn` runs for these operands -- arrays or
+    ``jax.ShapeDtypeStruct``s, only shapes and dtypes are read.  ``q`` is
+    lane-batched: (W, n_kv, g, d) a token a lane, or (W, n_kv, g, Q, d).
+
+    The kernel (ops/pallas/paged_attention.py) takes one query token a
+    lane against a bf16 pool whose rows and blocks fill whole tiles, in
+    an unsharded program on a TPU.  The loop keeps: Q > 1 (chunk rows, a
+    speculative verify chunk), int8 pools (``pool_k`` a tuple),
+    ``window=`` / ``blk_lo`` (banded serving), ``tp_axis`` or operands
+    that vary over a mesh axis (a pool sharded by head or by layer), any
+    other backend.  ``backend`` defaults to ``jax.default_backend()``."""
+    if backend is None:
+        backend = jax.default_backend()
+    nq = 1 if len(q.shape) == 4 else q.shape[3]
+    if (backend != "tpu" or nq != 1 or isinstance(pool_k, tuple)
+            or window is not None or blk_lo is not None
+            or tp_axis is not None or _varies(q) or _varies(pool_k)):
+        return "loop"
+    block, x = pool_k.shape[2:]
+    tiles = (x == q.shape[1] * q.shape[-1] and x % 128 == 0
+             and block % 16 == 0)
+    return "kernel" if tiles and pool_k.dtype == jnp.bfloat16 else "loop"
+
+
+def paged_decode_attn(q, pool_k, pool_v, layer, tables, pos, block, trash,
+                      k_cur, v_cur, scale, *, v_dim=None, cur_mask=None,
+                      window=None, blk_lo=None, n_blk=None, tp_axis=None):
+    """Every lane's attention of a decode step in one call: ``q``
+    (W, n_kv, g, d), one token a lane at position ``pos[w]`` (W,), over
+    the lane's blocks ``tables[w]`` (W, T) of layer ``layer`` (static, or
+    traced inside a layer scan) of the whole pools, plus the lanes' new
+    rows ``k_cur``/``v_cur`` (W, H_kv·D), which are not in the pool yet.
+    ``pool_v`` and ``v_cur`` None with ``v_dim``: the one-leaf latent
+    cache.  Returns (W, n_kv, g, d | v_dim) float32; a dead lane
+    (position 0, a trash table) attends its own new row and nothing
+    else, and no lane yields a NaN.
+
+    :func:`decode_attn_impl` chooses by the operands alone, never by a
+    flag: the Pallas kernel, in which a lane walks ITS OWN blocks and K/V
+    rows are read once as stored; or ``jax.vmap`` over
+    :func:`paged_attn`, the loop every lane walks to ``n_blk`` (default:
+    the longest lane's blocks).  The two agree up to float reordering
+    (tests/test_paged_decode_kernel.py).  What only the loop takes goes
+    through as well: ``q`` (W, n_kv, g, Q, d) with rows (W, Q, H_kv·D)
+    and the chunk's ``cur_mask`` (Q, Q); int8 pools and rows as
+    ``(values, scales)``; ``window=`` / ``blk_lo``; then the result has
+    ``q``'s rank."""
+    one = q.ndim == 4                      # no Q axis: give it one
+    if one:
+        q = q[:, :, :, None]
+        k_cur, v_cur = jax.tree.map(lambda r: r[:, None], (k_cur, v_cur))
+    if decode_attn_impl(q, pool_k, window=window, blk_lo=blk_lo,
+                        tp_axis=tp_axis) == "kernel":
+        k_cur, v_cur = jax.tree.map(lambda r: r[:, 0], (k_cur, v_cur))
+        out = _pallas.paged_decode_attn(
+            q[:, :, :, 0], pool_k, pool_v, layer, tables, pos, block,
+            trash, k_cur, v_cur, scale, v_dim=v_dim)[:, :, :, None]
+    else:
+        if cur_mask is None:
+            cur_mask = jnp.tril(jnp.ones((q.shape[3],) * 2, bool))
+        if n_blk is None:
+            n_blk = jnp.max((pos + block - 1) // block)
+        out = jax.vmap(
+            lambda q_r, k_r, v_r, tbl, pos_r: paged_attn(
+                q_r, pool_k, pool_v, layer, tbl, pos_r, n_blk, block,
+                trash, k_r, v_r, cur_mask, scale, window=window,
+                blk_lo=blk_lo, v_dim=v_dim))(q, k_cur, v_cur, tables, pos)
+    return out[:, :, :, 0] if one else out
 
 
 def write_rows(pool, layer, new, tables, pos, live, block, trash):

@@ -115,6 +115,7 @@ import numpy as np
 # speculation, and the sharded executors' per-row twins
 from ..models import gpt2_decode as _gpt2
 from ..models.served import FEATURES
+from ..ops.paged_attention import decode_attn_impl as _decode_attn_impl
 from ..ops.sampling import select_sample as _select_sample
 from ..observe import monitor as _monitor
 from ..observe import requests as _reqs
@@ -1108,6 +1109,7 @@ class InferenceEngine:
         # still bounds the decode vmap width, but a slot costs only
         # the blocks its request actually holds
         self.paged_arena = None
+        self._decode_attn = None    # "kernel" | "loop" (paged engines)
         self._spec_pad = 0 if draft_model is None else self.spec_k - 1
         if paged is not None and paged is not False:
             if paged is True:
@@ -1138,6 +1140,15 @@ class InferenceEngine:
                 sharding=self._state_sh, value_leaf=fam.value_leaf)
             self.stats.paged_source = self.paged_arena.snapshot
             self._kc = self._vc = None
+            # which attention this engine's decode dispatches run: the
+            # rule the programs themselves go by, on the same operands
+            # (a token a lane, or a verify chunk of spec_k; H_kv heads
+            # of D against the pool; sharded executors keep the loop)
+            self._decode_attn = _decode_attn_impl(
+                jax.ShapeDtypeStruct(
+                    (1, H_kv, 1, 1 + self._spec_pad, D), cdt),
+                self.paged_arena.pool_k, window=self._window,
+                tp_axis=None if self._shard is None else "mesh")
         else:
             self._kc = _arena(L, H_kv, D)
             self._vc = _arena(L, H_kv, D)
@@ -1786,7 +1797,9 @@ class InferenceEngine:
                    prefill_tokens=self.stats.prefill_tokens,
                    state_slots=(self.live_slots + len(self._prefilling)
                                 if self._state_spec else 0),
-                   **(self._step_counts if width else {}))
+                   **(self._step_counts if width else {}),
+                   **({"attn": self._decode_attn}
+                      if width and self._decode_attn else {}))
         pending = self.pending
         if not pending and _monitor.active():
             # drained: refresh liveness but DISARM hang detection —
@@ -2010,6 +2023,8 @@ class InferenceEngine:
                 n_live, width, toks, a_draft, lps = \
                     self._dispatch_decode(live, n_live)
             ph.set(live=n_live, width=width)
+            if self._decode_attn is not None:
+                ph.set(attn=self._decode_attn)
         if n_live == 0:
             return 0
         if _mon:
@@ -2017,7 +2032,8 @@ class InferenceEngine:
                 self._hb_source,
                 step_time=time.perf_counter() - _hb_t0,
                 fresh_compile=self.stats.decode_steps == 0)
-        self.stats.on_decode_step(n_live)
+        self.stats.on_decode_step(
+            n_live, attn_kernel=self._decode_attn == "kernel")
         # serve.emit: the emit loop — clients' on_token callbacks,
         # retires and ledger hooks included
         with _trace.phase("serve.emit", cat="serve") as ph:

@@ -101,6 +101,11 @@ class EngineStats:
                  "padding nor positions a prefix cache supplied)", **lbl)
         self._decode_steps = reg.counter(
             "serve.decode_steps", help="pool decode steps run", **lbl)
+        self._attn_kernel_steps = reg.counter(
+            "serve.decode.attn_kernel_steps",
+            help="pool decode steps whose attention over the pool ran "
+                 "the Pallas kernel, not the block loop "
+                 "(ops/paged_attention.decode_attn_impl)", **lbl)
         self._tokens_out = reg.counter(
             "serve.tokens_out", help="tokens emitted", **lbl)
         self._h_ttft = reg.histogram(
@@ -138,8 +143,8 @@ class EngineStats:
         self._registered = [
             self._submitted, self._completed, self._rej_deadline,
             self._rej_queue, self._prefills, self._prefill_tokens,
-            self._decode_steps, self._tokens_out, self._queue_depth,
-            self._occupancy,
+            self._decode_steps, self._attn_kernel_steps,
+            self._tokens_out, self._queue_depth, self._occupancy,
             self._h_ttft, self._h_tpot, self._h_queue_wait,
             self._h_admission["cold"], self._h_admission["warm"],
         ]
@@ -238,6 +243,10 @@ class EngineStats:
         return self._decode_steps.value
 
     @property
+    def attn_kernel_steps(self):
+        return self._attn_kernel_steps.value
+
+    @property
     def tokens_out(self):
         return self._tokens_out.value
 
@@ -281,8 +290,10 @@ class EngineStats:
         self._spec_drafted.inc(int(drafted))
         self._spec_chunks.inc()
 
-    def on_decode_step(self, live_slots: int):
+    def on_decode_step(self, live_slots: int, attn_kernel=False):
         self._decode_steps.inc()
+        if attn_kernel:
+            self._attn_kernel_steps.inc()
         occ = live_slots / self.max_slots
         self._occupancy_sum += occ
         self._occupancy.set(occ)

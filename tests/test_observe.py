@@ -392,12 +392,12 @@ def test_engine_stats_unregister_releases_metrics():
     a = EngineStats(2, FakeClock(), reg=reg)
     b = EngineStats(2, FakeClock(), reg=reg)
     a.on_submit()
-    assert len(reg.metrics()) == 30  # 15 per engine (incl. the
-    #   queue-wait + cold/warm admission request-phase histograms
-    #   and the prefill-token counter)
+    assert len(reg.metrics()) == 32  # 16 per engine (incl. the
+    #   queue-wait + cold/warm admission request-phase histograms,
+    #   the prefill-token counter and the kernel-step counter)
     a.unregister()
     remaining = reg.metrics()
-    assert len(remaining) == 15
+    assert len(remaining) == 16
     assert all(("engine", b.engine_label) in m.labels
                for m in remaining)
     # a fully-removed NAME frees its kind reservation
@@ -786,11 +786,49 @@ def test_a_profiler_session_records_the_step_with_its_children(
             assert decode[1] <= k[1] and \
                 k[1] + k[2] <= decode[1] + decode[2]
         assert decode[3]["paged"] == 1 and decode[3]["live"] >= 1
+        # which attention the decode dispatch ran: the block loop
+        # anywhere but on a TPU (ops/paged_attention.decode_attn_impl)
+        assert decode[3]["attn"] == "loop" and args["attn"] == "loop"
     # the budgeted prefill of the 21-token prompt ran inside the session:
     # chunk rows under serve.schedule, counted in its args
     sched = [s for s in spans if s[0] == "singa/serve.schedule"]
     assert sum(s[3]["chunks"] for s in sched) >= 1
     assert any(s[0] == "singa/serve.dispatch.chunk_row" for s in spans)
+
+
+@pytest.mark.parametrize("engine", ["paged", "paged, a verify chunk",
+                                    "slot arena"])
+def test_decode_steps_say_which_attention_they_ran(tiny_model, engine):
+    """``serve.decode.attn_kernel_steps`` counts the decode dispatches
+    whose attention over the pool ran the Pallas kernel, beside
+    ``serve.decode_steps``: none of them here (no TPU), by the rule the
+    programs themselves dispatch on; an engine without a pool makes no
+    claim."""
+    import numpy as np
+
+    from singa_tpu.serve import GenerationRequest, PagedConfig
+
+    kw = {}
+    if engine != "slot arena":
+        kw["paged"] = PagedConfig(block_size=8, num_blocks=32)
+    if engine == "paged, a verify chunk":
+        kw.update(draft_model=tiny_model, spec_k=2)
+    eng = tiny_model.serve(max_slots=2, **kw)
+    try:
+        h = eng.submit(GenerationRequest(np.arange(9) % 256,
+                                         max_new_tokens=6,
+                                         temperature=0.0))
+        while eng.pending:
+            eng.step()
+        h.result()
+        assert eng._decode_attn == (None if engine == "slot arena"
+                                    else "loop")
+        assert eng.stats.decode_steps >= 3
+        assert eng.stats.attn_kernel_steps == 0
+        name = "serve.decode.attn_kernel_steps"
+        assert any(m.name == name for m in eng.stats._registered)
+    finally:
+        eng.close()
 
 
 @pytest.mark.parametrize("path", ["cold", "budgeted", "warm"])

@@ -10,8 +10,13 @@ bytes are the two pools' data and nothing more (no padded rows), and
 the temporaries stay small.  The latent-attention, routed-expert family
 (``mla_moe``) at a dense layer and two expert layers for one and five,
 at the benchmark's 128 lanes and its pool of 1,920 + 1 blocks of 128
-rows: ONE pool leaf, updated where it lies.  The topology is described
-inside a fixture, never at import.
+rows: ONE pool leaf, updated where it lies.  The decode programs choose
+their attention by the backend (``ops/paged_attention.decode_attn_impl``)
+and the backend here is the CPU, so they compile with the block loop --
+what int8 pools, windows, ``tp=`` and every CPU run keep -- unless a test
+says "tpu" for its duration (``on_a_tpu``): then they compile WITH the
+Pallas decode kernel, and are held to the same.  The topology is
+described inside a fixture, never at import.
 """
 
 import os
@@ -527,3 +532,87 @@ def test_the_two_kind_chunk_row_programs_compile(swa, blocks):
     paged._keep_scopes(f"swa_chunk{blocks}", fam.scopes, comp.as_text())
     assert {"attn_window", "attn_full", "moe_experts"} <= set(
         paged.program_scopes()[f"swa_chunk{blocks}"].values())
+
+
+# ---- the decode programs WITH the Pallas kernel ---------------------------
+
+
+@pytest.fixture
+def on_a_tpu(monkeypatch):
+    """Code that asks ``jax.default_backend()`` sees the CPU here and
+    takes its CPU branch (on-chip-measurement guide, section 2); the
+    decode programs choose their attention by it, so a test that
+    compiles them as the chip runs them says "tpu" itself -- and lets
+    no trace made under the other answer stand in for its own."""
+    jax.clear_caches()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    yield
+    jax.clear_caches()
+
+
+def _decode_program(name, request):
+    """(the decode program's arguments, its statics, bytes of its
+    cache's data, its scope of the attention, the most it may keep
+    beside its arguments -- what the loop's program keeps today, as
+    compiled here from PR 38's tree: 1.16, 32.9 and 43.3 MB -- and
+    the elements from which a copy counts as one of a pool or of a
+    stack of matrices) of one family at its benchmark width."""
+    if name == "gpt2-large":
+        from singa_tpu.models import gpt2_decode
+        sds, params, pool, _ = request.getfixturevalue("gpt2l")
+        args = (params, pool, pool, *_lanes(sds, 24))
+        kw = dict(block=GB, n_head=GH, fam=gpt2_decode.FAMILY)
+        return args, kw, POOLS, None, PARENT["gpt2l_decode24_temp"], 50e6
+    if name == "mla_moe":
+        cfg, fam, params, sds = request.getfixturevalue("mla")
+        pool = sds((ML, MBLOCKS + 1, MB, cfg.pool_row_width))
+        args = (params, pool, None, *_lanes(sds, MLANES, MW // MB), None,
+                None, None)
+        kw = dict(block=MB, n_head=128, fam=fam)
+        data = 2 * ML * (MBLOCKS + 1) * MB * 640
+        return args, kw, data, "mla_attn", 32_880_128, data / 20
+    cfg, fam, params, sds = request.getfixturevalue("swa")
+    n = SW_SLOTS
+    pool = sds((SW_P, SW_BLOCKS + 1, BLOCK, 512))
+    arena = sds((SW_P, n + 1, 3, 2048, 512))
+    args = (params, pool, pool, *_lanes(sds, n, SW_WIDTH // BLOCK), None,
+            {"win_k": arena, "win_v": arena}, sds((n,), jnp.int32))
+    kw = dict(block=BLOCK, n_head=32, fam=fam)
+    cache = 2 * 2 * (SW_P * (SW_BLOCKS + 1) * BLOCK * 512
+                     + SW_P * (n + 1) * 3 * 2048 * 512)
+    return args, kw, cache, "attn_full", 43_291_648, 8e6
+
+
+@pytest.mark.parametrize("family", ["gpt2-large", "mla_moe", "swa_moe"])
+def test_the_decode_program_compiles_with_the_kernel(family, request,
+                                                     on_a_tpu):
+    """As the chip runs it: the attention over the pool is the Pallas
+    kernel (one custom call a layer body, the pools its operands where
+    they lie), the program still updates the donated cache in place --
+    aliased bytes are the cache's data and nothing more, no operation
+    copies or re-lays a pool -- it keeps no more beside its arguments
+    than the loop's program does, and the kernel's instruction lies
+    under the family's scope of the attention, named as a device trace
+    names it (``benchmark/readers/scopes.py`` splits the decode
+    program's time by these)."""
+    from singa_tpu.serve import paged
+
+    args, kw, cache, scope, temp, floor = _decode_program(family, request)
+    comp = paged._paged_decode_kernel.lower(
+        *args, **kw, eps=1e-5, moe_top_k=2, top_k=0, use_top_p=False,
+        window=None).compile()
+    text = comp.as_text()
+    calls = re.findall(r"%(paged_decode_attn[\w.\-]*) = ", text)
+    assert calls, "the decode program holds no kernel"
+    ma = comp.memory_analysis()
+    print(f"{family}: {len(calls)} kernel call(s), temporaries "
+          f"{ma.temp_size_in_bytes}, aliased {ma.alias_size_in_bytes}")
+    assert cache <= ma.alias_size_in_bytes < 1.01 * cache
+    assert _big_copies(text, floor) == []
+    assert ma.temp_size_in_bytes <= temp
+    if scope is not None:
+        paged._keep_scopes(f"kernel/{family}", kw["fam"].scopes, text)
+        found = paged.program_scopes()[f"kernel/{family}"]
+        under = {v for k, v in found.items()
+                 if k.split(" ")[0] in calls}
+        assert under == {scope}, (calls, under)
