@@ -1,12 +1,13 @@
-"""Step-anatomy profiler: a host-clock decomposition of every
-``engine.step()``, and a host-fence ESTIMATE of the device's share.
+"""Step anatomy: a host-clock decomposition of every ``engine.step()``
+— always on — and, behind :func:`enable`, a host-fence ESTIMATE of the
+device's share with its histograms, ring and trace records.
 
 The RequestLedger attributes per-REQUEST phases (queue/prefill/decode/
 stall); this module says where one STEP's wall time goes on the host's
 clock.  It has no fence sites of its own: ``observe.trace.phase()`` —
 the one call at each step-level site of ``serve/engine.py`` — feeds it
-through a hook that :func:`enable` registers, so the segments below are
-the program's ``singa/serve.*`` phases under their short names:
+through a hook, so the segments below are the program's
+``singa/serve.*`` phases under their short names:
 
 * **host segments** — ``schedule`` (the scheduling pass), ``admit``
   (one admission's host work), ``prefix_lookup`` (radix-cache probes),
@@ -17,25 +18,57 @@ the program's ``singa/serve.*`` phases under their short names:
   is prefix_lookup time, never double-counted as admit), and time
   under no segment lands in ``other`` — so the segments always sum to
   the wall exactly, the RequestLedger's seal-time idiom.
+
+**Always on**, from the first ``InferenceEngine`` a process builds
+(:func:`install`; a training process never installs it): the hook and
+the segment stack above — two clock reads a segment phase and a
+dictionary update, no fence, no histogram, no trace record, nothing
+kept of a step but what follows.  It answers, in every window, traced
+or not, where a rare long iteration went:
+
+* :func:`iterations` — per step ``(t0, wall_s, gap_s, sync_s)`` in a
+  ring of 16,384 (a 60 s window of 6.6 ms steps is ~9,000).  ``gap_s``
+  is the time from the previous ``step()``'s return on the same engine
+  to this one's start, and only if that step returned ``pending``: the
+  CALLER's time while work was waiting.  After a step that left no
+  work the gap is idleness by design and reads 0.
+* :func:`long_iterations` — the full record (engine, step, ``t0``,
+  ``wall_s``, ``gap_s``, ``median_s``, the segment seconds, ``where``
+  with the seconds put down to each, and the step's own args ``live``,
+  ``width``, ``queue_depth``, ``admitted``, ``chunks``, ``launches``)
+  of an iteration whose ``gap_s + wall_s`` was over four times the
+  median of the engine's last 256 AND over it by 100 ms, once the
+  engine has 64 iterations behind it (warm-up's first steps are not
+  reported).  Newest 256 kept.
+* counters ``serve.step.long_iterations{engine=,where=}`` and
+  ``serve.step.long_seconds{engine=,where=}``: ``where`` is ``caller``
+  (the gap over its median), ``sync`` (the ``sync`` segment over its
+  median) or ``host`` (the rest of the excess: every other segment of
+  ``step()``).  Created on an engine's first long iteration and removed
+  with its other series at close (:func:`forget_engine`); the rings
+  are the module's and outlive the engine.
+* one ``WARNING`` on the engine's logger (channel ``serve``) a long
+  iteration, at most one an engine every 5 s, with the count of those
+  it swallowed — what leaves the finding in an untraced run's output.
+
+**Behind** :func:`enable`, exactly as before — sinks added to the one
+record the always-on path makes, never a second accounting:
+
 * **the ``device`` column is a host-fence estimate, not device time** —
-  while the profiler is on, :func:`fence_device` at the executor seam
-  (``engine._ProfExec``: ``_LocalExec``, ``TPExecutor`` and the ep/pp
-  executors all route through it) blocks the host on each dispatch's
-  output and books dispatch-return → ``block_until_ready`` as
-  ``device``.  That serialises the dispatches it times, runs on the
-  host's clock, and includes queueing behind earlier work; ``bubble_frac
-  = (wall - device) / wall`` inherits all three.  The device's real busy
-  and idle time comes from a ``jax.profiler`` trace, where the same
-  phases sit as ``singa/`` spans under the device's operations
-  (docs/OBSERVABILITY.md "Span API").
-* **zero cost when off** — ``phase()`` reads one hook slot; the seam's
-  fence is ONE module-flag read (``if stepprof._active:``).  No clock
-  call, nothing enters jitted code (the fence only adds a
-  ``block_until_ready`` on already-dispatched outputs, so the recompile
-  pin holds with the profiler ON).
-
-Publication surfaces:
-
+  :func:`fence_device` at the executor seam (``engine._ProfExec``:
+  ``_LocalExec``, ``TPExecutor`` and the ep/pp executors all route
+  through it) blocks the host on each dispatch's output and books
+  dispatch-return → ``block_until_ready`` as ``device``.  That
+  serialises the dispatches it times, runs on the host's clock, and
+  includes queueing behind earlier work; ``bubble_frac = (wall -
+  device) / wall`` inherits all three.  The device's real busy and idle
+  time comes from a ``jax.profiler`` trace, where the same phases sit
+  as ``singa/`` spans under the device's operations
+  (docs/OBSERVABILITY.md "Span API").  The seam's fence is ONE
+  module-flag read (``if stepprof._active:``) while off, and the
+  always-on half never blocks on an output; nothing enters jitted code
+  either way (the fence only adds a ``block_until_ready`` on
+  already-dispatched outputs, so the recompile pin holds).
 * registry: ``serve.step.{wall_s,host_s,device_s}{engine=}`` and
   ``serve.step.segment_s{engine=,segment=}`` histograms on a dedicated
   100µs–5s ladder (:data:`STEP_BUCKETS` — the default request ladder
@@ -58,25 +91,35 @@ Publication surfaces:
   section; :func:`culprit` feeds the Watchdog so a step-time anomaly
   names host-vs-device.
 
-Profiler state is MODULE-level (like trace/monitor): an
-``EngineSupervisor`` restart builds a fresh engine under the same
-profiler, whose fresh ``engine=`` label starts fresh series while the
-dead engine's are removed.
+The fence's ``device`` column, ``bubble_frac`` and the
+``serve.step.{host_s,device_s,bubble_frac}`` families are read by no
+benchmark metric and remain a ``simplicity`` issue's to remove (PERF.md
+§7; 13 tests go with them).  The always-on half neither extends them
+nor depends on them.
+
+State is MODULE-level (like trace/monitor): an ``EngineSupervisor``
+restart builds a fresh engine under the same module, whose fresh
+``engine=`` label starts fresh series while the dead engine's are
+removed.  The always-on clock is ``time.perf_counter``; while
+``enable(clock=...)`` is on, that clock stamps the one record, the
+always-on log included.
 """
 
 from __future__ import annotations
 
 import collections
+import statistics
 import threading
 import time
 
+from ..utils.logging import get_channel as _get_channel
 from .registry import registry as _registry
 from . import trace as _trace
 
-__all__ = ["StepProfiler", "enable", "disable", "active", "profiler",
-           "section", "why_slow_summary", "culprit", "records",
-           "forget_engine", "SEGMENTS", "STEP_BUCKETS",
-           "FRACTION_BUCKETS"]
+__all__ = ["StepProfiler", "install", "enable", "disable", "active",
+           "profiler", "iterations", "long_iterations", "section",
+           "why_slow_summary", "culprit", "records", "forget_engine",
+           "SEGMENTS", "WHERE", "STEP_BUCKETS", "FRACTION_BUCKETS"]
 
 #: segment taxonomy (docs/OBSERVABILITY.md "Step anatomy"): the named
 #: host segments, the host-fenced device windows, and the remainder
@@ -103,12 +146,40 @@ STEP_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
 FRACTION_BUCKETS = (0.01, 0.02, 0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6,
                     0.7, 0.8, 0.9, 0.95, 0.99, 1.0)
 
+#: where a long iteration's excess over the median is put down to
+WHERE = ("caller", "sync", "host")
+
+#: the long-iteration rule and the always-on rings' sizes
+LONG_FACTOR = 4.0          # over this many times the running median
+LONG_OVER_S = 0.1          # AND over it by this many seconds
+LONG_WARMUP = 64           # iterations an engine must have behind it
+MEDIAN_WINDOW = 256        # iterations the running median looks back
+ITERATION_RING = 16384     # a 60 s window of 6.6 ms steps is ~9,000
+LONG_RING = 256
+WARN_EVERY_S = 5.0         # at most one warning an engine in this long
+_STEP_ARGS = ("live", "width", "queue_depth", "admitted", "chunks",
+              "launches")
+_LONG_HELP = {
+    "long_iterations": "iterations (the caller's gap + step()) far over "
+                       "the engine's running median, by where most of "
+                       "the excess went",
+    "long_seconds": "seconds over the running median in those "
+                    "iterations, by where they went"}
+
 # Module-global fast path, mirroring trace._active: `if not
 # stepprof._active: <skip>` is the ENTIRE disabled cost of a fence
 # site.  _prof is non-None exactly while _active is True.
 _active = False
 _prof = None
 _tls = threading.local()
+
+# the always-on half: installed by the first engine built, never taken
+# out again by disable()
+_installed = False
+_clock = time.perf_counter
+_iterations = collections.deque(maxlen=ITERATION_RING)
+_long = collections.deque(maxlen=LONG_RING)
+_engines = {}              # engine label -> _EngineLog
 
 _block_until_ready = None  # lazy jax import (observe stays jax-free
 #                            until a profiled dispatch actually runs)
@@ -122,6 +193,16 @@ def _block(out):
     return _block_until_ready(out)
 
 
+def install():
+    """Turn the always-on half on (idempotent): every
+    ``InferenceEngine`` calls this as it is built.  Host clock stamps
+    only — :func:`active` stays False and no dispatch is fenced."""
+    global _installed
+    if not _installed:
+        _installed = True
+        _trace._set_phase_hook(_HOOK)
+
+
 def enable(clock=None, ring=512, reg=None) -> "StepProfiler":
     """Attach a fresh process-wide profiler and turn the fences on.
     ``clock``: ``() -> float`` seconds — pass the trace clock when
@@ -131,22 +212,46 @@ def enable(clock=None, ring=512, reg=None) -> "StepProfiler":
     global _active, _prof
     _prof = StepProfiler(clock=clock, ring=ring, reg=reg)
     _active = True
-    _trace._set_phase_hook((_phase_enter, _phase_exit))
+    _trace._set_phase_hook(_HOOK)
+    _forget_gaps()
     return _prof
 
 
 def disable(unregister=True):
-    """Turn the fences off and detach.  ``unregister=True`` (default)
+    """Turn the fences off and detach the profiler; the always-on half
+    stays if an engine installed it.  ``unregister=True`` (default)
     also removes every ``serve.step.*`` series the profiler created —
     the retire-unregisters contract; pass False to keep them readable
     (export after disable)."""
     global _active, _prof
     p, _prof = _prof, None
     _active = False
-    _trace._set_phase_hook(None)
+    if not _installed:
+        _trace._set_phase_hook(None)
     _tls.cur = None
+    _forget_gaps()
     if p is not None and unregister:
         p.unregister()
+
+
+def _forget_gaps():
+    # the clock may differ on the two sides of enable()/disable(): the
+    # next step of every engine measures no gap across the change
+    for e in _engines.values():
+        e.pending = False
+
+
+def _reset():
+    """The always-on half as a process that has built no engine has it
+    (the tests' way back to that state)."""
+    global _installed
+    _installed = False
+    if not _active:
+        _trace._set_phase_hook(None)
+    for label in list(_engines):
+        forget_engine(label)
+    _iterations.clear()
+    _long.clear()
 
 
 def active() -> bool:
@@ -162,12 +267,113 @@ def forget_engine(label):
     """Remove a closed engine's ``serve.step.*{engine=label}`` series
     (``engine._release_everything`` calls this): a supervisor-rebuilt
     engine's fresh label must not leave the dead one's series frozen
-    in the exposition.  Safe no-op when the profiler is off."""
+    in the exposition.  The iteration rings keep what the engine
+    logged."""
+    e = _engines.pop(label, None)
+    if e is not None and e.counters:
+        _registry().remove(*e.counters.values())
     if _prof is not None:
         _prof.forget_engine(label)
 
 
-# -- the hook ``trace.phase()`` calls while the profiler is on ---------
+# -- the always-on log --------------------------------------------------
+
+def _within(t0, since, until):
+    return ((since is None or t0 >= since)
+            and (until is None or t0 < until))
+
+
+def iterations(since=None, until=None) -> list:
+    """``(t0, wall_s, gap_s, sync_s)`` of the newest steps of every
+    engine of this process, oldest first; ``since <= t0 < until`` on
+    ``time.perf_counter``."""
+    return [r for r in tuple(_iterations) if _within(r[0], since, until)]
+
+
+def long_iterations(since=None, until=None) -> list:
+    """The full records of the newest long iterations (module
+    docstring), oldest first, filtered like :func:`iterations`."""
+    return [r for r in tuple(_long) if _within(r["t0"], since, until)]
+
+
+class _EngineLog:
+    """One engine's side of the always-on log: the running median's
+    window, when its last step returned and whether work was left, its
+    counters once it has had a long iteration, the warning's limiter."""
+
+    __slots__ = ("label", "n", "recent", "last_end", "pending",
+                 "counters", "warned_at", "swallowed")
+
+    def __init__(self, label):
+        self.label = label
+        self.n = 0
+        self.recent = collections.deque(maxlen=MEDIAN_WINDOW)
+        self.last_end = 0.0
+        self.pending = False
+        self.counters = {}
+        self.warned_at = None
+        self.swallowed = 0
+
+    def note(self, st, now, wall, args):
+        gap = max(st.t0 - self.last_end, 0.0) if self.pending else 0.0
+        self.last_end = now
+        self.pending = bool(args.get("pending"))
+        sync = st.seg.get("sync", 0.0)
+        _iterations.append((st.t0, wall, gap, sync))
+        took = gap + wall
+        if took > LONG_OVER_S and self.n >= LONG_WARMUP:
+            median = statistics.median(r[0] for r in self.recent)
+            if took > LONG_FACTOR * median and took > median + LONG_OVER_S:
+                self._long(st, now, wall, gap, sync, median, args)
+        self.recent.append((took, gap, sync))
+        self.n += 1
+
+    def _long(self, st, now, wall, gap, sync, median, args):
+        caller = max(gap - statistics.median(
+            r[1] for r in self.recent), 0.0)
+        in_sync = max(sync - statistics.median(
+            r[2] for r in self.recent), 0.0)
+        parts = {"caller": caller, "sync": in_sync,
+                 "host": max(gap + wall - median - caller - in_sync, 0.0)}
+        where = max(parts, key=parts.get)
+        seen = dict(st.args, **args)
+        rec = {"engine": self.label, "step": st.step, "t0": st.t0,
+               "wall_s": wall, "gap_s": gap, "median_s": median,
+               "segments": dict(st.seg), "where": where,
+               "excess_s": parts,
+               **{k: seen[k] for k in _STEP_ARGS if k in seen}}
+        _long.append(rec)
+        reg = _registry()
+        for name, key, n in [("long_iterations", where, 1)] + [
+                ("long_seconds", w, v) for w, v in parts.items() if v]:
+            c = self.counters.get((name, key))
+            if c is None:
+                c = self.counters[name, key] = reg.counter(
+                    "serve.step." + name, help=_LONG_HELP[name],
+                    engine=self.label, where=key)
+            c.inc(n)
+        if (self.warned_at is not None
+                and now - self.warned_at < WARN_EVERY_S):
+            self.swallowed += 1
+            return
+        more = (f"; {self.swallowed} more long iterations since the "
+                f"last such line" if self.swallowed else "")
+        self.warned_at, self.swallowed = now, 0
+        _get_channel("serve").warning("%s%s", _describe(rec), more)
+
+
+def _describe(rec) -> str:
+    """One long iteration as the line its warning carries: segments by
+    size, then the step's own args."""
+    segs = " ".join(f"{k} {v:.3f}" for k, v in sorted(
+        rec["segments"].items(), key=lambda kv: -kv[1]))
+    args = " ".join(f"{k} {rec[k]}" for k in _STEP_ARGS if k in rec)
+    return (f"serve step {rec['step']} (engine {rec['engine']}) took "
+            f"{rec['wall_s']:.3f} s after a {rec['gap_s']:.3f} s gap "
+            f"(median iteration {rec['median_s']:.3f} s): {segs}; {args}")
+
+
+# -- the hook ``trace.phase()`` calls -----------------------------------
 
 def _phase_enter(name, args):
     """``serve.step`` opens a step record, and so does
@@ -182,9 +388,9 @@ def _phase_enter(name, args):
     if name == "serve.step" or (name == "serve.prefix_build"
                                 and st is None):
         p = _prof
-        if p is None:
-            return None
-        p.step_begin(args.get("engine"), step=args.get("step"))
+        _tls.cur = _StepState(p, args.get("engine"), args.get("step"),
+                              _clock if p is None else p._clock,
+                              name == "serve.step")
         return _STEP
     if st is None:
         return None
@@ -197,16 +403,41 @@ def _phase_enter(name, args):
     return st
 
 
-def _phase_exit(token, failed):
+def _phase_exit(token, failed, args):
     if token is not _STEP:
         token.pop()
+        if args:          # serve.schedule's admitted, chunks, launches
+            token.args.update(args)
         return
     st = getattr(_tls, "cur", None)
     _tls.cur = None
     # a step that raised has no meaningful anatomy: drop its record
-    if (not failed and st is not None and st.owner is _prof
-            and _prof is not None):
-        _prof._finish(st)
+    if failed or st is None:
+        return
+    now = st.clock()
+    while st.stack:          # a dangling fence closes at step end
+        st.pop()
+    wall = max(now - st.t0, 0.0)
+    other = wall - sum(st.seg.values())
+    if other > 0.0:
+        st.seg["other"] = st.seg.get("other", 0.0) + other
+    if st.is_step:
+        e = _engines.get(st.engine)
+        if e is None:
+            e = _engines[st.engine] = _EngineLog(st.engine)
+        e.note(st, now, wall, args)
+    if st.owner is not None and st.owner is _prof:
+        st.owner._publish_step(st, wall)
+
+
+def _step_elapsed():
+    """Seconds from the open step's start to its newest stamp (the end
+    of the last segment closed): what ``Phase.step_elapsed`` reads."""
+    st = getattr(_tls, "cur", None)
+    return None if st is None else st.last - st.t0
+
+
+_HOOK = (_phase_enter, _phase_exit, _step_elapsed)
 
 
 def fence_device(out):
@@ -217,10 +448,10 @@ def fence_device(out):
     times and includes queueing behind earlier work — the device trace
     is the source for device time.  The block is the ONLY added work —
     it runs on already-dispatched outputs, so nothing new enters
-    jitted code and the recompile pin holds.  Outside an open step
-    it does nothing."""
+    jitted code and the recompile pin holds.  Outside an open step, or
+    in one that opened before :func:`enable`, it does nothing."""
     st = getattr(_tls, "cur", None)
-    if st is None:
+    if st is None or st.owner is None:
         return
     st.push("device")
     _block(out)
@@ -270,23 +501,29 @@ def records() -> list:
 # -- the profiler ------------------------------------------------------
 
 class _StepState:
-    """One step's open record: an exclusive-time segment stack plus
-    the device windows.  Allocated only while the profiler is ON."""
+    """One step's open record: an exclusive-time segment stack, and —
+    for the profiler's sinks only (``owner``) — the host pieces and the
+    device windows."""
 
     __slots__ = ("owner", "engine", "step", "t0", "last", "stack",
-                 "seg", "pieces", "dev", "dev_windows", "clock")
+                 "seg", "args", "pieces", "dev", "dev_windows", "clock",
+                 "is_step")
 
-    def __init__(self, owner, engine, step, clock):
+    def __init__(self, owner, engine, step, clock, is_step):
         self.owner = owner
         self.engine = engine
         self.step = step
         self.clock = clock
+        self.is_step = is_step     # engine.step(), not a build quantum
         self.t0 = self.last = clock()
         self.stack = []
         self.seg = {}
-        self.pieces = []       # (segment, t_start, dur) host intervals
+        self.args = {}         # what the phases inside found (set())
+        # for the profiler's ring alone: (segment, t_start, dur) host
+        # intervals and (t_start, dur) device-busy intervals
+        self.pieces = [] if owner is not None else None
+        self.dev_windows = [] if owner is not None else None
         self.dev = 0.0
-        self.dev_windows = []  # (t_start, dur) device-busy intervals
 
     def push(self, name):
         now = self.clock()
@@ -295,7 +532,8 @@ class _StepState:
             cur = self.stack[-1]
             dt = now - self.last
             self.seg[cur] = self.seg.get(cur, 0.0) + dt
-            self.pieces.append((cur, self.last, dt))
+            if self.pieces is not None:
+                self.pieces.append((cur, self.last, dt))
         self.stack.append(name)
         self.last = now
 
@@ -306,13 +544,16 @@ class _StepState:
         name = self.stack.pop()
         t0, dt = self.last, now - self.last
         self.seg[name] = self.seg.get(name, 0.0) + dt
-        self.pieces.append((name, t0, dt))
+        if self.pieces is not None:
+            self.pieces.append((name, t0, dt))
         self.last = now
         return (t0, dt)
 
 
 class StepProfiler:
-    """Per-step host/device time attribution (module docstring).
+    """The sinks :func:`enable` adds to each step's record (module
+    docstring): the fenced ``device`` column, histograms, ring, trace
+    records.
 
     Single-writer per thread (each engine's step loop is
     single-threaded; concurrent engines on different threads each
@@ -330,18 +571,9 @@ class StepProfiler:
         self.steps = 0
 
     # -- recording -------------------------------------------------------
-    def step_begin(self, engine, step=None):
-        _tls.cur = _StepState(self, engine, step, self._clock)
-
-    def _finish(self, st):
-        now = self._clock()
-        while st.stack:          # a dangling fence closes at step end
-            st.pop()
-        wall = max(now - st.t0, 0.0)
+    def _publish_step(self, st, wall):
+        """A sealed step (segments sum to ``wall``) into every sink."""
         seg = st.seg
-        other = wall - sum(seg.values())
-        if other > 0.0:
-            seg["other"] = seg.get("other", 0.0) + other
         device = st.dev
         host = max(wall - device, 0.0)
         bubble = (host / wall) if wall > 0.0 else 0.0

@@ -257,8 +257,9 @@ def span(name: str, cat: str = "app", **args):
 #
 # The profiler's annotation class is imported (and subclassed) on the
 # first phase() call, so ``observe`` stays importable without JAX.
-# ``_phase_hook`` is stepprof's ``(enter, exit)`` pair while
-# ``stepprof.enable()`` is on — registered from there, so this module
+# ``_phase_hook`` is stepprof's ``(enter, exit, step_elapsed)`` triple
+# from the first serve engine a process builds (its always-on half) or
+# from ``stepprof.enable()`` — registered from there, so this module
 # never imports stepprof.
 _phase_hook = None
 _Annotation = None
@@ -266,8 +267,10 @@ _Phase = None
 
 
 def _set_phase_hook(hook):
-    """Internal (stepprof.enable/disable): ``(enter(name, args) ->
-    token | None, exit(token, failed))`` or None."""
+    """Internal (stepprof.install/enable/disable): ``(enter(name,
+    args) -> token | None, exit(token, failed, args), step_elapsed()
+    -> seconds | None)`` or None.  ``exit`` gets the phase's args as
+    ``set()`` left them."""
     global _phase_hook
     _phase_hook = hook
 
@@ -287,6 +290,14 @@ def _build_phase_classes():
             self.set_metadata(**args)
             return self
 
+        def step_elapsed(self):
+            """Seconds from the start of the engine step this phase
+            lies in to the newest stamp of its step-anatomy record (the
+            end of the last segment closed so far), read off the
+            record and not off the clock; ``None`` where there is no
+            record."""
+            return None
+
     class Phase(Annotation):
         """The same, plus the host record (``trace._active``) and
         the step-anatomy segment (``stepprof``'s hook)."""
@@ -305,7 +316,13 @@ def _build_phase_classes():
             self.set_metadata(**args)
             if self._span is not None:
                 self._span.set(**args)
+            if self._token is not None:
+                self._args.update(args)
             return self
+
+        def step_elapsed(self):
+            return (self._hook[2]() if self._hook is not None
+                    else None)
 
         def __enter__(self):
             super().__enter__()
@@ -317,7 +334,7 @@ def _build_phase_classes():
 
         def __exit__(self, et, ev, tb):
             if self._token is not None:
-                self._hook[1](self._token, et is not None)
+                self._hook[1](self._token, et is not None, self._args)
             if self._span is not None:
                 self._span.__exit__(et, ev, tb)
             return super().__exit__(et, ev, tb)
@@ -336,13 +353,15 @@ def phase(name: str, cat: str = "app", **args):
       annotation's own activity check when none is;
     * while tracing (or the flight recorder) is on, the same host
       record ``span()`` makes;
-    * while ``stepprof.enable()`` is on, the step-anatomy segment of
-      that name.
+    * once a serve engine has been built (``stepprof``'s always-on
+      half) or ``stepprof.enable()`` is on, the step-anatomy segment
+      of that name, on the host's clock.
 
     ``.set(**args)`` attaches args found inside the phase to the
     annotation and the host record.  For the handful of per-step sites
     only — never per token or per slot: unlike ``span()`` it allocates
-    when everything is off."""
+    when everything is off, and reads the clock twice once the
+    step-anatomy hook is in."""
     if _Annotation is None:
         _build_phase_classes()
     if not _active and _phase_hook is None:

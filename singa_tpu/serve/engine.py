@@ -1002,6 +1002,9 @@ class InferenceEngine:
         # kept beating (per-tenant engines are a supported pattern)
         self._hb_source = "serve.e" + self.stats.engine_label
         self._log = get_channel("serve")
+        # the always-on step log (observe/stepprof.py): host clock
+        # stamps at the phase() sites below, from the first engine on
+        _stepprof.install()
 
         model.eval()
         self._params = fam.extract_params(model, dtype=dtype)
@@ -1791,7 +1794,11 @@ class InferenceEngine:
             self.stats.on_schedule(qd)
             self.step_count += 1
             arena = self.paged_arena
+            # (pending: the step log counts the caller's time until the
+            # next step only while work was left waiting)
+            pending = self.pending
             ph.set(live=self.live_slots, width=width, queue_depth=qd,
+                   pending=pending,
                    blocks_used=(arena.blocks_used if arena is not None
                                 else 0),
                    prefill_tokens=self.stats.prefill_tokens,
@@ -1800,7 +1807,6 @@ class InferenceEngine:
                    **(self._step_counts if width else {}),
                    **({"attn": self._decode_attn}
                       if width and self._decode_attn else {}))
-        pending = self.pending
         if not pending and _monitor.active():
             # drained: refresh liveness but DISARM hang detection —
             # an idle engine between traffic bursts is not a wedged
@@ -2006,11 +2012,6 @@ class InferenceEngine:
             _faults.check("serve.decode_step")
         live = np.asarray([s is not None for s in self._slots])
         n_live = int(live.sum())
-        # watchdog heartbeat around the pool step (two clock calls,
-        # only while monitoring is on); includes the np.asarray sync,
-        # so the fed step time is real device time
-        _mon = _monitor.active()
-        _hb_t0 = time.perf_counter() if _mon else 0.0
         # serve.decode: from building the pool step's inputs to its
         # tokens on the host — input building and launch first, then
         # serve.sync, the host blocked on the device
@@ -2027,10 +2028,13 @@ class InferenceEngine:
                 ph.set(attn=self._decode_attn)
         if n_live == 0:
             return 0
-        if _mon:
+        if _monitor.active():
+            # watchdog heartbeat after the pool step, fed from the step
+            # log's own stamps and no clock of its own: the step's start
+            # to the end of serve.sync, so the np.asarray sync is in it
+            # and the fed step time is real device time
             _monitor.heartbeat(
-                self._hb_source,
-                step_time=time.perf_counter() - _hb_t0,
+                self._hb_source, step_time=ph.step_elapsed(),
                 fresh_compile=self.stats.decode_steps == 0)
         self.stats.on_decode_step(
             n_live, attn_kernel=self._decode_attn == "kernel")

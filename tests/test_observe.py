@@ -578,19 +578,23 @@ def test_observe_imports_without_jax():
 
 
 def test_phase_off_is_the_bare_annotation():
-    """With tracing and the step profiler off a phase is the profiler's
-    own annotation and nothing else: no host record, no ``_Span``, no
+    """With tracing and the step profiler off, in a process that has
+    built no serve engine (a training process: the always-on step log
+    is an engine's to install), a phase is the profiler's own
+    annotation and nothing else: no host record, no ``_Span``, no
     clock call -- ``span()`` keeps its shared no-op."""
     from jax.profiler import TraceAnnotation
 
-    from singa_tpu.observe import trace
+    from singa_tpu.observe import stepprof, trace
 
+    stepprof._reset()          # engines of earlier tests installed it
     ph = observe.phase("serve.step", cat="serve", step=3)
     assert isinstance(ph, TraceAnnotation)
     assert type(ph) is trace._Annotation
     with ph as inside:
         assert inside is ph
         assert ph.set(live=2) is ph
+        assert ph.step_elapsed() is None
     assert observe.events() == []
     assert observe.span("x") is trace._NULL_SPAN
 
@@ -614,9 +618,11 @@ def test_phase_feeds_the_host_record_when_tracing_is_on():
 
 def test_phase_feeds_the_step_profiler_through_its_hook_alone():
     """``stepprof.enable()`` registers the hook and ``disable()`` takes
-    it away; ``trace.py`` never imports ``stepprof``.  On a fake clock
-    the segments are exact: exclusive, ``other`` for time under no
-    segment, summing to the wall."""
+    it away, in a process whose engines have not installed the always-on
+    half (then it stays: tests/test_stepprof.py); ``trace.py`` never
+    imports ``stepprof``.  On a fake clock the segments are exact:
+    exclusive, ``other`` for time under no segment, summing to the
+    wall."""
     import ast
     import inspect
 
@@ -626,6 +632,7 @@ def test_phase_feeds_the_step_profiler_through_its_hook_alone():
                 if isinstance(n, (ast.Import, ast.ImportFrom))
                 for a in n.names]
     assert "stepprof" not in " ".join(imported)
+    stepprof._reset()          # engines of earlier tests installed it
     assert trace._phase_hook is None
     clk = FakeClock()
     stepprof.enable(clock=clk, reg=MetricsRegistry())
@@ -639,8 +646,10 @@ def test_phase_feeds_the_step_profiler_through_its_hook_alone():
                 with observe.phase("serve.dispatch.paged_decode_step",
                                    cat="serve"):
                     clk.advance(0.003)              # dispatch
-                with observe.phase("serve.sync", cat="serve"):
+                with observe.phase("serve.sync", cat="serve") as sy:
                     clk.advance(0.070)              # sync
+                # the step's start to its newest stamp, off the record
+                assert sy.step_elapsed() == pytest.approx(0.076)
             with observe.phase("serve.emit", cat="serve"):
                 clk.advance(0.004)                  # emit
             with observe.phase("serve.schedule", cat="serve"):
@@ -669,16 +678,17 @@ def test_phase_feeds_the_step_profiler_through_its_hook_alone():
     assert trace._phase_hook is None
 
 
-def test_a_quiet_step_makes_no_span_and_no_clock_call(tiny_model,
-                                                      monkeypatch):
+def test_a_quiet_step_makes_no_span_and_reads_only_the_step_logs_clock(
+        tiny_model, monkeypatch):
     """No profiler session, ``observe`` off, step profiler off, monitor
     off: a whole engine step allocates no ``_Span`` and reads no clock
-    through the instrumentation."""
+    through the instrumentation but the always-on step log's own, at
+    most twice a phase (observe/stepprof.py)."""
     import time
 
     import numpy as np
 
-    from singa_tpu.observe import trace
+    from singa_tpu.observe import stepprof, trace
     from singa_tpu.serve import GenerationRequest
 
     eng = tiny_model.serve(max_slots=2)
@@ -686,24 +696,34 @@ def test_a_quiet_step_makes_no_span_and_no_clock_call(tiny_model,
                                      max_new_tokens=12, temperature=0.0))
     eng.step()
     eng.step()
-    made, calls = [], [0]
+    made, calls, stamps, phases = [], [0], [0], [0]
     real_span, real_clock = trace._Span, time.perf_counter
+    real_phase = trace.phase
 
     class Counted(real_span):
         def __init__(self, *a):
             made.append(a[0])
             super().__init__(*a)
 
-    def counting():
-        calls[0] += 1
-        return real_clock()
+    def counting(n):
+        def clock():
+            n[0] += 1
+            return real_clock()
+        return clock
+
+    def counted_phase(*a, **kw):
+        phases[0] += 1
+        return real_phase(*a, **kw)
 
     try:
         monkeypatch.setattr(trace, "_Span", Counted)
-        monkeypatch.setattr(time, "perf_counter", counting)
-        monkeypatch.setattr(trace, "_clock", counting)
+        monkeypatch.setattr(time, "perf_counter", counting(calls))
+        monkeypatch.setattr(trace, "_clock", counting(calls))
+        monkeypatch.setattr(stepprof, "_clock", counting(stamps))
+        monkeypatch.setattr(trace, "phase", counted_phase)
         eng.step()
         assert made == [] and calls[0] == 0
+        assert 0 < stamps[0] <= 2 * phases[0]
         monkeypatch.setattr(time, "perf_counter", real_clock)
     finally:
         while eng.pending:

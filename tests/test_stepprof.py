@@ -1,14 +1,24 @@
-"""observe.stepprof: step-anatomy host/device attribution.
+"""observe.stepprof: step anatomy — the always-on step log and, behind
+``enable()``, the host/device attribution.
 
-The profiler's three contracts, each tested directly:
+The always-on half's contracts, each tested directly:
+
+* **the log** — every step leaves ``(t0, wall_s, gap_s, sync_s)``; an
+  iteration far over the engine's running median leaves its full
+  record, counters by where the excess went (``caller`` / ``sync`` /
+  ``host``) and one rate-limited warning; the gap counts only while
+  work was left waiting.
+* **cost** — with everything off a step reads the clock at most twice a
+  phase, allocates no histogram, never fences a dispatch, and a quiet
+  engine leaves the registry and the profiler's ring untouched.
+
+The profiler's three contracts (``enable()``), each tested directly:
 
 * **exactness** — exclusive-time segments sum to the step wall (one
   denominator, the ledger's seal-time idiom), host_s + device_s ==
   wall_s, and device windows sit inside the step span.
-* **invisibility when off** — no registry series, no ring, and ZERO
-  extra clock calls at the engine seams (the Watchdog's two
-  ``perf_counter`` calls per step are the whole budget, counted by
-  monkeypatching the clock).
+* **one accounting** — ``enable()`` adds sinks to the record the
+  always-on path makes: the log goes on through it.
 * **invisibility when on** — byte parity with the unprofiled engine
   and zero runtime recompiles (``block_until_ready`` on materialized
   outputs never enters jitted code).
@@ -19,6 +29,7 @@ restarts included), the dual-lane Chrome trace, health/why_slow
 sections, the Watchdog culprit feed, prefix-build quanta on a
 shipless engine, and FleetTelemetry's per-host lanes."""
 
+import logging
 import time
 
 import numpy as np
@@ -33,6 +44,7 @@ from singa_tpu.observe.registry import MetricsRegistry, registry
 from singa_tpu.serve import GenerationRequest, PagedConfig, \
     PrefixCacheConfig
 from singa_tpu.serve.jitpin import jit_cache_size
+from singa_tpu.utils.logging import get_channel
 
 
 @pytest.fixture(scope="module")
@@ -46,17 +58,19 @@ def model():
 
 @pytest.fixture(autouse=True)
 def _clean():
-    """Profiler off, monitor off, tracing off around each test — all
-    three are process-global module state."""
-    stepprof.disable()
-    monitor.stop()
-    observe.disable()
-    observe.clear()
+    """Profiler off, the step log as a process that has built no engine
+    has it, monitor off, tracing off around each test — all of it is
+    process-global module state."""
+    def clean():
+        stepprof.disable()
+        stepprof._reset()
+        monitor.stop()
+        observe.disable()
+        observe.clear()
+
+    clean()
     yield
-    stepprof.disable()
-    monitor.stop()
-    observe.disable()
-    observe.clear()
+    clean()
 
 
 _PROMPTS = [np.arange(9) % 256, (np.arange(4) + 3) % 256,
@@ -95,37 +109,309 @@ def test_disabled_mode_leaves_no_trace_in_registry_or_ring(model):
     assert stepprof.culprit("serve.e0") is None
 
 
-def test_disabled_mode_adds_zero_clock_calls(model, monkeypatch):
-    """The whole per-step clock budget with the profiler OFF is the
-    Watchdog's two ``perf_counter`` calls — and zero with monitoring
-    off too.  Counted by swapping the clock itself."""
+def test_disabled_mode_reads_the_clock_twice_a_phase_at_most(
+        model, monkeypatch):
+    """The cost contract of the always-on half: with the profiler, the
+    monitor and tracing off a step reads the clock at most twice a
+    phase (the step log's stamps: none of the engine's own), allocates
+    no histogram and fences nothing.  With monitoring on the engine
+    still reads no clock of its own: the Watchdog's step time comes
+    from the step log's stamps.  Counted by swapping the clocks."""
+    from singa_tpu.observe import trace
+
     eng = model.serve(max_slots=2)
     h = eng.submit(GenerationRequest(_PROMPTS[0], max_new_tokens=20,
                                      temperature=0.0))
     eng.step()  # admission + first decode: compiles out of the way
     eng.step()
-    real = time.perf_counter
-    calls = [0]
+    real, real_phase = time.perf_counter, trace.phase
+    own, log, phases = [0], [0], [0]
 
-    def counting():
-        calls[0] += 1
-        return real()
+    def counting(calls):
+        def clock():
+            calls[0] += 1
+            return real()
+        return clock
+
+    def counted_phase(*a, **kw):
+        phases[0] += 1
+        return real_phase(*a, **kw)
+
+    def no_fence(out):
+        raise AssertionError("the always-on half fenced a dispatch")
 
     try:
-        monkeypatch.setattr(time, "perf_counter", counting)
-        calls[0] = 0
+        monkeypatch.setattr(time, "perf_counter", counting(own))
+        monkeypatch.setattr(stepprof, "_clock", counting(log))
+        monkeypatch.setattr(trace, "phase", counted_phase)
+        monkeypatch.setattr(stepprof, "fence_device", no_fence)
         eng.step()
-        assert calls[0] == 0
-        monitor.start(thread=False, dump_on_hang=False)
-        calls[0] = 0
+        assert own[0] == 0
+        assert 0 < log[0] <= 2 * phases[0]
+        wd = monitor.start(thread=False, dump_on_hang=False)
+        own[0] = log[0] = phases[0] = 0
         eng.step()
-        assert calls[0] == 2
+        assert own[0] == 0 and log[0] <= 2 * phases[0]
+        fed = wd._sources["serve.e" + eng.stats.engine_label]
+        last = stepprof.iterations()[-1]
+        assert fed.n_samples == 1
+        assert 0.0 < fed.ewma_mean <= last[1]
         monkeypatch.setattr(time, "perf_counter", real)
     finally:
         monitor.stop()
         while eng.pending:
             eng.step()
         h.result()
+        eng.close()
+    assert not [k for k in registry().snapshot()["histograms"]
+                if k.startswith("serve.step.")]
+
+
+# ---------------------------------------------------------------------------
+# the always-on step log (injected clock, no engine: the phases alone)
+# ---------------------------------------------------------------------------
+
+class _Clk:
+    def __init__(self):
+        self.t = 100.0
+
+    def __call__(self):
+        return self.t
+
+
+@pytest.fixture
+def log(monkeypatch):
+    """The always-on half on a clock the test moves, with what the
+    ``serve`` channel was told."""
+    clk = _Clk()
+    monkeypatch.setattr(stepprof, "_clock", clk)
+    stepprof.install()
+    lines = []
+
+    class Keep(logging.Handler):
+        def emit(self, record):
+            lines.append(record.getMessage())
+
+    keep = Keep(level=logging.WARNING)
+    get_channel("serve").addHandler(keep)
+    yield clk, lines
+    get_channel("serve").removeHandler(keep)
+
+
+def _step(clk, n, engine="7", gap=0.001, sync=0.005, emit=0.001,
+          pending=True):
+    """One engine iteration as ``engine.step()`` writes it: 10 ms as the
+    defaults stand (1 gap, 2 dispatch, 5 sync, 1 emit, 1 schedule - the
+    gap counts only after a step that left work)."""
+    clk.t += gap
+    with observe.phase("serve.step", cat="serve", engine=engine,
+                       step=n) as ph:
+        with observe.phase("serve.decode", cat="serve"):
+            with observe.phase("serve.dispatch.paged_decode_step",
+                               cat="serve"):
+                clk.t += 0.002
+            with observe.phase("serve.sync", cat="serve"):
+                clk.t += sync
+        with observe.phase("serve.emit", cat="serve") as em:
+            clk.t += emit
+            em.set(tokens=7)
+        with observe.phase("serve.schedule", cat="serve") as sp:
+            clk.t += 0.001
+            sp.set(admitted=0, chunks=4, launches=1)
+        ph.set(live=7, width=12, queue_depth=0, pending=pending)
+
+
+def _long_counters(engine="7"):
+    return {k: v for k, v in registry().snapshot()["counters"].items()
+            if k.startswith("serve.step.long_")
+            and f"engine={engine}" in k}
+
+
+def test_a_long_sync_among_short_steps_is_logged_as_sync(log):
+    clk, lines = log
+    for n in range(300):
+        _step(clk, n, sync=2.0 if n == 200 else 0.005)
+    its = stepprof.iterations()
+    assert len(its) == 300
+    t0, wall, gap, sync = its[200]
+    assert (wall, gap, sync) == pytest.approx((2.004, 0.001, 2.0))
+    assert its[199][0] < t0 < its[201][0]
+    rec, = stepprof.long_iterations()
+    assert (rec["engine"], rec["step"], rec["where"]) == ("7", 200, "sync")
+    assert rec["t0"] == t0 and rec["median_s"] == pytest.approx(0.010)
+    assert rec["segments"] == pytest.approx(
+        {"dispatch": 0.002, "sync": 2.0, "emit": 0.001,
+         "schedule": 0.001})
+    assert rec["excess_s"] == pytest.approx(
+        {"caller": 0.0, "sync": 1.995, "host": 0.0}, abs=1e-9)
+    assert {k: rec[k] for k in ("live", "width", "queue_depth",
+                                "admitted", "chunks", "launches")} == {
+        "live": 7, "width": 12, "queue_depth": 0, "admitted": 0,
+        "chunks": 4, "launches": 1}
+    got = _long_counters()
+    assert got["serve.step.long_iterations{engine=7,where=sync}"] == 1
+    assert got["serve.step.long_seconds{engine=7,where=sync}"] == \
+        pytest.approx(1.995)
+    assert set(got) == {
+        "serve.step.long_iterations{engine=7,where=sync}",
+        "serve.step.long_seconds{engine=7,where=sync}"}
+    assert lines == [
+        "serve step 200 (engine 7) took 2.004 s after a 0.001 s gap "
+        "(median iteration 0.010 s): sync 2.000 dispatch 0.002 emit "
+        "0.001 schedule 0.001; live 7 width 12 queue_depth 0 admitted "
+        "0 chunks 4 launches 1"]
+    # the window filters are on t0, half open
+    assert stepprof.iterations(since=t0, until=its[201][0]) == [its[200]]
+    assert stepprof.long_iterations(until=t0) == []
+    assert stepprof.long_iterations(since=t0) == [rec]
+    # the engine's close takes its counters, not what it logged
+    stepprof.forget_engine("7")
+    assert _long_counters() == {}
+    assert len(stepprof.iterations()) == 300
+    assert stepprof.long_iterations() == [rec]
+
+
+def test_a_gap_is_the_callers_only_while_work_was_waiting(log):
+    clk, lines = log
+    for n in range(100):
+        _step(clk, n)
+    _step(clk, 100, gap=1.0)                 # step 99 left work
+    _step(clk, 101, pending=False)           # drains the engine
+    _step(clk, 102, gap=1.0)                 # idle by design: no gap
+    _step(clk, 103, emit=0.5)                # slow host code in step()
+    caller, host = stepprof.long_iterations()
+    assert (caller["step"], caller["where"]) == (100, "caller")
+    assert caller["gap_s"] == pytest.approx(1.0)
+    assert caller["excess_s"] == pytest.approx(
+        {"caller": 0.999, "sync": 0.0, "host": 0.0}, abs=1e-9)
+    assert (host["step"], host["where"]) == (103, "host")
+    assert host["excess_s"] == pytest.approx(
+        {"caller": 0.0, "sync": 0.0, "host": 0.499}, abs=1e-9)
+    assert host["segments"]["emit"] == pytest.approx(0.5)
+    assert [r[2] for r in stepprof.iterations()[100:104]] == \
+        pytest.approx([1.0, 0.001, 0.0, 0.001])
+    got = _long_counters()
+    assert got["serve.step.long_iterations{engine=7,where=caller}"] == 1
+    assert got["serve.step.long_iterations{engine=7,where=host}"] == 1
+    assert "serve.step.long_iterations{engine=7,where=sync}" not in got
+
+
+def test_warmup_and_the_rule_keep_ordinary_steps_out(log):
+    clk, _ = log
+    # a compile in an engine's first steps is not reported...
+    for n in range(63):
+        _step(clk, n, sync=3.0 if n == 5 else 0.005)
+    # ...nor are 5 x the median under 100 ms over it, or 100 ms over a
+    # median of 73 ms (the rule wants four times it as well)
+    for n in range(63, 200):
+        _step(clk, n, sync=0.046 if n == 150 else 0.005)
+    for n in range(300):
+        _step(clk, n, engine="8", sync=0.199 if n == 280 else 0.068)
+    assert stepprof.long_iterations() == []
+    _step(clk, 300, engine="8", sync=0.300)
+    rec, = stepprof.long_iterations()
+    assert (rec["engine"], rec["step"]) == ("8", 300)
+    assert rec["median_s"] == pytest.approx(0.073)
+
+
+def test_the_warning_is_limited_and_says_what_it_swallowed(log):
+    clk, lines = log
+    for n in range(100):
+        _step(clk, n)
+    _step(clk, 100, sync=1.0)                # logged
+    _step(clk, 101, sync=1.0)                # 1 s later: counted
+    _step(clk, 102, sync=1.0)                # 2 s later: counted
+    for n in range(103, 400):                # ~3 s of ordinary steps
+        _step(clk, n)
+    _step(clk, 400, sync=1.0)                # 6 s after the first line
+    assert len(stepprof.long_iterations()) == 4
+    assert len(lines) == 2
+    assert lines[0].startswith("serve step 100 (engine 7) took 1.004 s")
+    assert lines[1].startswith("serve step 400 (engine 7) took 1.004 s")
+    assert lines[1].endswith(
+        "; 2 more long iterations since the last such line")
+    assert _long_counters()[
+        "serve.step.long_iterations{engine=7,where=sync}"] == 4
+    # one engine's limiter is not another's
+    for n in range(100):
+        _step(clk, n, engine="8")
+    _step(clk, 100, engine="8", sync=1.0)
+    assert len(lines) == 3 and "(engine 8)" in lines[2]
+
+
+def test_a_quiet_engine_logs_its_steps_and_nothing_else(model):
+    """With nothing enabled a process that has served leaves
+    ``iterations()`` covering its steps and no long one; the profiler
+    is still off, no dispatch was fenced, the registry and the
+    profiler's ring are as they were."""
+    t0 = time.perf_counter()
+    eng = model.serve(max_slots=2)
+    try:
+        _drain(eng)
+        steps = eng.step_count
+    finally:
+        eng.close()
+    its = stepprof.iterations(since=t0, until=time.perf_counter())
+    assert len(its) == steps > 0
+    assert all(w > 0 and g >= 0 and 0 < s < w for _, w, g, s in its[1:])
+    assert its == sorted(its)
+    assert stepprof.long_iterations() == []
+    assert stepprof.active() is False and stepprof.records() == []
+    snap = registry().snapshot()
+    assert not [k for kind in snap.values() for k in kind
+                if k.startswith("serve.step.")]
+
+
+def test_the_always_on_path_keeps_parity_and_never_fences(
+        model, monkeypatch):
+    def no_fence(out):
+        raise AssertionError("the always-on half fenced a dispatch")
+
+    stepprof.enable()
+    eng = model.serve(max_slots=2)
+    try:
+        want = _drain(eng)
+    finally:
+        eng.close()
+    stepprof.disable()
+    monkeypatch.setattr(stepprof, "fence_device", no_fence)
+    jit0 = jit_cache_size()
+    eng = model.serve(max_slots=2)
+    try:
+        got = _drain(eng)
+    finally:
+        eng.close()
+    assert got == want, "the step log changed tokens"
+    assert jit_cache_size() == jit0, "the step log entered jitted code"
+
+
+def test_enable_adds_sinks_to_the_one_record(model):
+    """``enable()`` on top of the always-on half: the same steps land
+    in the log and in the profiler's ring, with one wall, and the
+    fractions still seal to 1."""
+    eng = model.serve(max_slots=2)
+    try:
+        _drain(eng)
+        quiet = len(stepprof.iterations())
+        assert quiet > 0 and stepprof.records() == []
+        stepprof.enable()
+        _drain(eng)
+        recs, its = stepprof.records(), stepprof.iterations()[quiet:]
+        assert len(recs) == len(its) > 0
+        for r, (t0, wall, _, sync) in zip(recs, its):
+            assert (r["t0"], r["wall_s"]) == (t0, wall)
+            assert r["segments"].get("sync", 0.0) == sync
+            assert sum(r["segments"].values()) == \
+                pytest.approx(wall, abs=1e-9)
+            assert r["device_s"] > 0
+        fr, = [e["fractions"]
+               for e in stepprof.section()["engines"].values()]
+        assert abs(sum(fr.values()) - 1.0) < 1e-9
+        stepprof.disable()
+        _drain(eng)                   # the log goes on without it
+        assert len(stepprof.iterations()) > quiet + len(its)
+        assert len(stepprof.records()) == 0
+    finally:
         eng.close()
 
 
