@@ -694,7 +694,9 @@ class _Prefilling:
     ``last_off`` (the prompt's last block), each as many blocks wide
     as the step's budget allows — only when the last block lands does
     the first token sample (from ``hidden``, the newest launch's last
-    block), the row scatter into the blocks, and the slot go live.  Nothing has
+    block; it waits on the device in ``first`` until the pass that
+    sampled it promotes the slot), the row scatter into the blocks,
+    and the slot go live.  Nothing has
     streamed, so an engine failure mid-prefill rejects these
     requeue-safe (``started=False``) and returns their blocks to the
     free list."""
@@ -702,7 +704,7 @@ class _Prefilling:
     __slots__ = ("handle", "request", "ids_j", "kc_row", "vc_row",
                  "state", "hidden", "off", "last_off", "blocks",
                  "n_shared", "nodes", "key0", "temp", "t_admit",
-                 "admitted_step", "seq")
+                 "admitted_step", "seq", "first")
 
 
 class _PrefixJob:
@@ -1362,8 +1364,16 @@ class InferenceEngine:
                      "budget (budget_chunks / launches = blocks a "
                      "launch)",
                 engine=self.stats.engine_label)
+            self._c_early_launches = self.stats.registry.counter(
+                "serve.prefill.early_launches",
+                help="those of serve.prefill.launches that went out "
+                     "behind the decode program, before the host "
+                     "waited for its tokens (early / all = the share "
+                     "of launches the wait no longer delays)",
+                engine=self.stats.engine_label)
             self._own_metrics.extend([self._c_budget_chunks,
-                                      self._c_launches])
+                                      self._c_launches,
+                                      self._c_early_launches])
             # a launch is as wide as the step's budget allows: the
             # block times a power of two, widest first
             B = self.paged_arena.block_size
@@ -1745,6 +1755,16 @@ class InferenceEngine:
         (so backfill lands on the very step a row retires).  Returns
         ``pending``.
 
+        Under ``prefill_token_budget`` the step's two programs go out
+        back to back: the decode program is launched, then the launches
+        of the requests that were already prefilling when the step
+        began (they read their private rows, nothing of this step), and
+        only then does the host wait for the decode tokens, emit them
+        and run the schedule pass with the budget that is left — the
+        prefills whose last block has landed are promoted there, after
+        the emit, and new work is admitted (``_decode_once``,
+        ``_schedule_budgeted``).
+
         A raising decode/prefill does NOT wedge the engine: every
         in-flight and queued request is rejected with a typed
         :class:`EngineFailedError` (``started`` says which were
@@ -1777,12 +1797,15 @@ class InferenceEngine:
                     # victim) swaps ITSELF out
                     with _trace.phase("serve.grow", cat="serve"):
                         self._grow_live_slots()
+                # (serve.schedule's totals are the whole step's: the
+                # launches that go out ahead of the decode tokens too)
+                n_pf, n_ch = self.stats.prefills, self._chunks_run
+                n_la = self._launches_run
+                left = self._budget
                 if any(s is not None for s in self._slots):
-                    width = self._decode_once()
+                    width, left = self._decode_once()
                 with _trace.phase("serve.schedule", cat="serve") as sp:
-                    n_pf, n_ch = self.stats.prefills, self._chunks_run
-                    n_la = self._launches_run
-                    self._schedule(self._clock())
+                    self._schedule(self._clock(), left)
                     sp.set(admitted=self.stats.prefills - n_pf,
                            chunks=self._chunks_run - n_ch,
                            launches=self._launches_run - n_la)
@@ -1999,9 +2022,11 @@ class InferenceEngine:
     # -- internals -------------------------------------------------------
     def _decode_once(self):
         """Decode every live slot by one token (or one speculative
-        chunk) and emit.  Returns the width the pool step ran at (0
-        when a structured dead end emptied the pool before the
-        dispatch)."""
+        chunk) and emit: launch the pool step, launch what the budget
+        allows of the prefills already in flight behind it, and only
+        then wait for the tokens.  Returns the width the pool step ran
+        at (0 when a structured dead end emptied the pool before the
+        dispatch) and the prefill budget the step has left."""
         if _faults._armed:
             # chaos hook: a fault here is exactly a raising pool decode
             # (speculative mode included — the draft scan, the chunk
@@ -2011,23 +2036,37 @@ class InferenceEngine:
             # read per step
             _faults.check("serve.decode_step")
         live = np.asarray([s is not None for s in self._slots])
-        n_live = int(live.sum())
+        left = self._budget
         # serve.decode: from building the pool step's inputs to its
         # tokens on the host — input building and launch first, then
         # serve.sync, the host blocked on the device
         with _trace.phase("serve.decode", cat="serve",
                           paged=self.paged_arena is not None) as ph:
-            if self.draft is not None:
-                n_live, width, toks, a_draft, lps = \
-                    self._dispatch_spec(live, n_live)
-            else:
-                n_live, width, toks, a_draft, lps = \
-                    self._dispatch_decode(live, n_live)
+            launch = (self._launch_spec if self.draft is not None
+                      else self._launch_decode)
+            flight = launch(live, int(live.sum()))
+            if flight is None:
+                ph.set(live=0, width=0)
+                return 0, left
+            if self._prefilling:
+                # the early pass: a request that was prefilling when
+                # the step began needs nothing of this decode step, so
+                # its launches queue behind the decode program now and
+                # start the moment it ends — not after the host has
+                # noticed, emitted and scheduled.  Dispatch only: a
+                # prefill that lands here is promoted after the emit
+                # (_schedule_budgeted), never into this step's tokens
+                with _trace.phase("serve.launch", cat="serve") as lp:
+                    n_ch, n_la = self._chunks_run, self._launches_run
+                    left = self._launch_inflight(left)
+                    self._c_early_launches.inc(self._launches_run - n_la)
+                    lp.set(launches=self._launches_run - n_la,
+                           chunks=self._chunks_run - n_ch)
+            n_live, width, toks, a_draft, lps = \
+                self._collect_step(*flight)
             ph.set(live=n_live, width=width)
             if self._decode_attn is not None:
                 ph.set(attn=self._decode_attn)
-        if n_live == 0:
-            return 0
         if _monitor.active():
             # watchdog heartbeat after the pool step, fed from the step
             # log's own stamps and no clock of its own: the step's start
@@ -2044,12 +2083,13 @@ class InferenceEngine:
             t0 = self.stats.tokens_out
             self._emit_step(toks, a_draft, lps)
             ph.set(tokens=self.stats.tokens_out - t0)
-        return width
+        return width, left
 
-    def _dispatch_spec(self, live, n_live):
-        """The speculative pool step: ``(n_live, width, accepted
-        chunk tokens (S, spec_k), accepted draft counts (S,), None)``,
-        on the host."""
+    def _launch_spec(self, live, n_live):
+        """Launch the speculative pool step; nothing here reads a
+        device value.  Returns what :meth:`_collect_step` takes:
+        ``(n_live, width, accepted chunk tokens (S, spec_k), accepted
+        draft counts (S,), None, None, None)``, still on the device."""
         arena = self.paged_arena
         # (speculative paged steps run at full width: the DRAFT arena
         # is slot-indexed — compacting would have to gather/scatter
@@ -2074,15 +2114,15 @@ class InferenceEngine:
                 jnp.asarray(self._pos), jnp.asarray(live),
                 self._keys, jnp.asarray(self._temps),
                 self._top_p)
-        with _trace.phase("serve.sync", cat="serve"):
-            out = np.asarray(out)
-            a_draft = np.asarray(a_draft)
-        return n_live, self.max_slots, out, a_draft, None
+        return n_live, self.max_slots, out, a_draft, None, None, None
 
-    def _dispatch_decode(self, live, n_live):
-        """The plain pool step: ``(n_live, width, next tokens (S,),
-        None, chosen-token logprobs (S,) or None)``, on the host.
-        ``n_live`` is 0 when a structured dead end emptied the pool
+    def _launch_decode(self, live, n_live):
+        """Launch the plain pool step; nothing after the host-side
+        grammar pass reads a device value.  Returns what
+        :meth:`_collect_step` takes — ``(n_live, width, next tokens,
+        None, chosen-token logprobs or None, the family's step counts
+        or None, the lanes of a compacted step or None)``, still on the
+        device — or None when a structured dead end emptied the pool
         before the dispatch."""
         arena = self.paged_arena
         # fork/structured pre-dispatch pass (paged, non-spec):
@@ -2136,7 +2176,7 @@ class InferenceEngine:
                     [s is not None for s in self._slots])
                 n_live = int(live.sum())
                 if n_live == 0:
-                    return 0, 0, None, None, None
+                    return None
         lanes = lps = counts = None
         width = self.max_slots
         if arena is not None:
@@ -2220,8 +2260,21 @@ class InferenceEngine:
                     jnp.asarray(self._pos),
                     jnp.asarray(live), self._keys,
                     jnp.asarray(self._temps), self._top_p)
+        return n_live, width, next_toks, None, lps, counts, lanes
+
+    def _collect_step(self, n_live, width, toks, a_draft, lps, counts,
+                      lanes):
+        """Wait for the pool step :meth:`_launch_decode` or
+        :meth:`_launch_spec` launched (``serve.sync``: the host blocked
+        on the decode program alone — launches queued behind it run
+        on) and bring its outputs to the host, a compacted step's lanes
+        back at their slots.  Returns ``(n_live, width, tokens (S,) or
+        (S, spec_k), accepted draft counts (S,) or None, logprobs (S,)
+        or None)``."""
         with _trace.phase("serve.sync", cat="serve"):
-            next_toks = np.asarray(next_toks)
+            toks = np.asarray(toks)
+            if a_draft is not None:
+                a_draft = np.asarray(a_draft)
             if lps is not None:
                 lps = np.asarray(lps)
             if counts is not None:
@@ -2231,13 +2284,13 @@ class InferenceEngine:
         if lanes is not None:
             # a compacted step's lanes back at their slots
             wide = np.zeros(self.max_slots, np.int32)
-            wide[lanes] = next_toks[:len(lanes)]
-            next_toks = wide
+            wide[lanes] = toks[:len(lanes)]
+            toks = wide
             if lps is not None:
                 wide = np.zeros(self.max_slots)
                 wide[lanes] = lps[:len(lanes)]
                 lps = wide
-        return n_live, width, next_toks, None, lps
+        return n_live, width, toks, a_draft, lps
 
     def _on_step_counts(self, counts):
         """One decode step's counts, as the family reads them: span
@@ -2929,7 +2982,10 @@ class InferenceEngine:
         finally:
             self._release_prefix(slot)
 
-    def _schedule(self, now):
+    def _schedule(self, now, left):
+        """The step's scheduling pass; ``left`` is the prefill budget
+        the step has not spent yet (None without
+        ``prefill_token_budget``)."""
         if self.paged_arena is not None:
             # swapped requests re-enter BEFORE new admissions: they
             # already made progress (and streamed tokens), so leaving
@@ -2942,7 +2998,7 @@ class InferenceEngine:
             # prefills and then admits new work against the step's
             # remaining token budget — one admission can span many
             # steps, so the whole-prompt flow below does not apply
-            self._schedule_budgeted(now)
+            self._schedule_budgeted(now, left)
             return
         free = self._free_slots()
         if not free and self.scheduler.queue_depth == 0:
@@ -3337,24 +3393,43 @@ class InferenceEngine:
                     f"at {now} before a slot was available"))
 
     # -- chunked-prefill token budget (the long-context round) -----------
-    def _schedule_budgeted(self, now):
+    def _schedule_budgeted(self, now, left):
         """One scheduling pass under ``prefill_token_budget``: spend
-        at most that many prefill TOKENS this step — first on
-        in-flight chunked prefills (admission order: the FIFO contract
-        holds across steps, an expensive head request BLOCKS the
-        budget, it is never skipped), then on new admissions.  A new
-        admission whose prompt exceeds the remaining budget simply
-        carries over: its chunks continue next step, which is the
-        whole point — decode lanes dispatched BEFORE this pass
-        (step() order) never wait for more than one step's budget of
-        prefill work."""
-        left = self._budget
+        at most that many prefill TOKENS a step — first on in-flight
+        chunked prefills (admission order: the FIFO contract holds
+        across steps, an expensive head request BLOCKS the budget, it
+        is never skipped), then on new admissions.  A new admission
+        whose prompt exceeds the remaining budget simply carries over:
+        its chunks continue next step, which is the whole point —
+        decode lanes never wait for more than one step's budget of
+        prefill work.
+
+        ``left`` is what the step has not spent yet.  In a step with a
+        live lane the in-flight prefills' launches went out BEFORE this
+        pass, behind the decode program and ahead of the host's wait
+        for its tokens (``_decode_once``), so the first loop finds
+        nothing to launch; with no lane live it launches here.  Either
+        way a prefill whose last block has been launched is promoted
+        here, after the decode step's emit: a slot promoted earlier
+        would be handed a token of a decode step it was not in.
+
+        The pass dispatches before it waits: a landed prefill's
+        completion programs go out (``_finish_landed``), then the
+        admissions and their first launches, and only then does the
+        host fetch the first tokens (``_promote_landed``) — the chip is
+        still on the landed prompt's last launch meanwhile, so the
+        admission's host work and the leftover budget's launch cost
+        the step no idle time."""
+        left = self._launch_inflight(left)
+        self._finish_landed()
+        self._admit_budgeted(now, left)
+        self._promote_landed()
+
+    def _admit_budgeted(self, now, left):
+        """The admissions of one budgeted scheduling pass: start new
+        chunked prefills in the free slots, FIFO, while ``left`` budget
+        tokens allow a first launch."""
         B = self.paged_arena.block_size
-        for idx in sorted(self._prefilling,
-                          key=lambda i: self._prefilling[i].seq):
-            if left < B:
-                break
-            left = self._advance_prefilling(idx, left, now)
         free = self._free_slots()
         if not free and self.scheduler.queue_depth == 0:
             return
@@ -3382,7 +3457,9 @@ class InferenceEngine:
                 if idx is not None:
                     free.pop(0)
                     ok = True
-                    left = self._advance_prefilling(idx, left, now)
+                    left = self._launch_chunks(self._prefilling[idx],
+                                               left)
+                    self._finish_landed()
             if not ok:
                 # budget exhausted or capacity-blocked: everything
                 # scheduled from here returns to the queue FRONT in
@@ -3463,6 +3540,7 @@ class InferenceEngine:
             pf.state = self._zero_state()
             self._c_state_resets.inc()
         pf.hidden = None
+        pf.first = None
         pf.off = len(nodes) * B
         pf.last_off = ((plen - 1) // B) * B
         if self._window is not None:
@@ -3527,19 +3605,34 @@ class InferenceEngine:
             return jnp.int32(off)
         return jnp.asarray(np.arange(off, off + w, B, dtype=np.int32))
 
-    def _advance_prefilling(self, idx, left, now):
-        """Spend up to ``left`` budget tokens on slot ``idx``'s
-        chunked prefill, a launch at a time: each the widest of the
+    def _launch_inflight(self, left):
+        """Spend up to ``left`` budget tokens on the chunked prefills
+        in flight, oldest admission first; a head request that takes
+        the whole budget blocks the ones behind it.  Dispatch only —
+        nothing here reads a device value or promotes a request, so it
+        may run while the decode program is still in flight.  Returns
+        the remaining budget."""
+        B = self.paged_arena.block_size
+        for idx in sorted(self._prefilling,
+                          key=lambda i: self._prefilling[i].seq):
+            if left < B:
+                break
+            left = self._launch_chunks(self._prefilling[idx], left)
+        return left
+
+    def _launch_chunks(self, pf, left):
+        """Spend up to ``left`` budget tokens on one chunked prefill,
+        a launch at a time: each the widest of the
         engine's widths (the block times a power of two) that the
         budget left, the blocks the prompt still needs and the row's
         end all allow — one launch reads the layers' weights once,
         however many blocks it covers.  A one-block launch is the exact
         executable warm admission rides, and a wider one computes the
         same rows (every position attends what lies below it in the
-        row), so a budgeted stream is the unbudgeted one.  Completes
-        the admission when the prompt's last block lands.  Returns the
-        remaining budget."""
-        pf = self._prefilling[idx]
+        row), so a budgeted stream is the unbudgeted one.  The prompt's
+        last block is launched when ``pf.off > pf.last_off``;
+        :meth:`_finish_prefilling` then completes the admission.
+        Returns the remaining budget."""
         B = self.paged_arena.block_size
         rid = pf.request.request_id
         plen = len(pf.request.prompt_ids)
@@ -3586,16 +3679,35 @@ class InferenceEngine:
                     t=self._clock(), offset=pf.off)
             pf.off += w
             left -= w
-        if pf.off > pf.last_off:
-            self._finish_prefilling(idx, pf)
         return left
+
+    def _finish_landed(self):
+        """Dispatch the completion of every chunked prefill whose last
+        block has been launched, oldest first (``_promote_landed``
+        makes them live)."""
+        for idx, pf in sorted(self._prefilling.items(),
+                              key=lambda kv: kv[1].seq):
+            if pf.off > pf.last_off and pf.first is None:
+                self._finish_prefilling(idx, pf)
+
+    def _promote_landed(self):
+        """Promote every chunked prefill that ``_finish_landed``
+        completed, oldest first."""
+        for idx, pf in sorted(self._prefilling.items(),
+                              key=lambda kv: kv[1].seq):
+            if pf.first is not None:
+                self._promote(idx, pf)
 
     def _finish_prefilling(self, idx, pf):
         """The last chunk landed: sample the admission token from the
         final chunk's hidden block (mirrors ``_prefill_one``'s tail
-        via ``_first_from_hidden`` — bitwise the unbudgeted token),
-        scatter the row's lanes into the request's pool blocks, and
-        promote the reservation to a LIVE slot."""
+        via ``_first_from_hidden`` — bitwise the unbudgeted token) and
+        scatter the row's lanes into the request's pool blocks.
+        Dispatch only: the token stays on the device in ``pf.first``
+        until :meth:`_promote` fetches it, so the pass's admissions —
+        host work and a first launch — go out while the chip is still
+        on this prompt's last launch, not after the host has waited
+        for it."""
         arena = self.paged_arena
         req = pf.request
         plen = len(req.prompt_ids)
@@ -3630,6 +3742,15 @@ class InferenceEngine:
             self._dkc, self._dvc = _write_slot(
                 self._dkc, self._dvc, dkc_row, dvc_row,
                 jnp.int32(idx))
+        pf.first = (tok0, carry_key, ast0)
+
+    def _promote(self, idx, pf):
+        """Promote a completed chunked prefill's reservation to a LIVE
+        slot: fetch its first token (the device sync), stamp TTFT,
+        emit."""
+        req = pf.request
+        plen = len(req.prompt_ids)
+        tok0, carry_key, ast0 = pf.first
         self.stats.on_prefill()
         slot = _Slot(pf.handle, req.max_new_tokens, pf.t_admit,
                      pf.admitted_step)

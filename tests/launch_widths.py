@@ -1,5 +1,5 @@
 """What the three served families' tests share about the budgeted
-prefill's launch widths (engine ``_advance_prefilling``: one launch a
+prefill's launch widths (engine ``_launch_chunks``: one launch a
 request a step, as wide as the step's budget allows): the prompts of
 each case, a run that keeps what every admission left behind, and the
 comparison with the engine whose budget is one block -- which launches a

@@ -814,6 +814,31 @@ def test_a_profiler_session_records_the_step_with_its_children(
     sched = [s for s in spans if s[0] == "singa/serve.schedule"]
     assert sum(s[3]["chunks"] for s in sched) >= 1
     assert any(s[0] == "singa/serve.dispatch.chunk_row" for s in spans)
+    # its second launch found a lane decoding: it went out in the early
+    # pass (serve.launch), inside serve.decode, behind the decode
+    # dispatch and before the wait for its tokens; serve.schedule's
+    # counts stay the whole step's
+    launch, = [s for s in spans if s[0] == "singa/serve.launch"]
+    assert (launch[3]["launches"], launch[3]["chunks"]) == (1, 1)
+
+    def holding(name):
+        return [s for s in spans if s[0] == name and s[1] <= launch[1]
+                and launch[1] + launch[2] <= s[1] + s[2]]
+    step, = holding("singa/serve.step")
+    decode, = holding("singa/serve.decode")
+
+    def of_step(name):
+        return [s for s in spans if s[0] == name and step[1] <= s[1]
+                and s[1] + s[2] <= step[1] + step[2]]
+    dispatch, = of_step("singa/serve.dispatch.paged_decode_step")
+    row, = of_step("singa/serve.dispatch.chunk_row")
+    sync, = of_step("singa/serve.sync")
+    assert dispatch[1] + dispatch[2] <= launch[1] <= row[1]
+    assert row[1] + row[2] <= launch[1] + launch[2] <= sync[1]
+    assert sync[1] + sync[2] <= decode[1] + decode[2]
+    total, = of_step("singa/serve.schedule")
+    assert (total[3]["launches"], total[3]["chunks"]) == (1, 1)
+    assert sum(s[3]["launches"] for s in sched) == 2
 
 
 @pytest.mark.parametrize("engine", ["paged", "paged, a verify chunk",

@@ -271,6 +271,75 @@ def test_a_long_sync_among_short_steps_is_logged_as_sync(log):
     assert stepprof.long_iterations() == [rec]
 
 
+def test_the_early_launch_pass_opens_no_segment(log):
+    """``serve.launch`` (the launches that go out behind the decode
+    program, before ``serve.sync``) is a phase with no segment of its
+    own: the dispatches inside it are ``dispatch``, the rest of it
+    ``other``, the record still seals to the wall, and the step's
+    ``chunks`` / ``launches`` stay ``serve.schedule``'s totals."""
+    clk, lines = log
+
+    def step(n, launch_s):
+        clk.t += 0.001
+        with observe.phase("serve.step", cat="serve", engine="7",
+                           step=n) as ph:
+            with observe.phase("serve.decode", cat="serve"):
+                with observe.phase("serve.dispatch.paged_decode_step",
+                                   cat="serve"):
+                    clk.t += 0.002
+                with observe.phase("serve.launch", cat="serve") as lp:
+                    clk.t += launch_s
+                    with observe.phase("serve.dispatch.chunk_row",
+                                       cat="serve"):
+                        clk.t += 0.001
+                    lp.set(launches=1, chunks=3)
+                with observe.phase("serve.sync", cat="serve"):
+                    clk.t += 0.005
+            with observe.phase("serve.schedule", cat="serve") as sp:
+                clk.t += 0.001
+                sp.set(admitted=1, chunks=4, launches=2)
+            ph.set(live=7, width=12, queue_depth=0, pending=True)
+
+    for n in range(300):
+        step(n, 0.5 if n == 200 else 0.0)
+    rec, = stepprof.long_iterations()
+    assert (rec["step"], rec["where"]) == (200, "host")
+    assert rec["segments"] == pytest.approx(
+        {"dispatch": 0.003, "sync": 0.005, "schedule": 0.001,
+         "other": 0.5})
+    assert sum(rec["segments"].values()) == pytest.approx(rec["wall_s"])
+    assert (rec["admitted"], rec["chunks"], rec["launches"]) == (1, 4, 2)
+    assert lines[0].endswith("admitted 1 chunks 4 launches 2")
+
+
+def test_a_budgeted_engines_records_seal_with_early_launches(model):
+    """A lane decoding beside a prompt of several steps' budget, the
+    fences on: every step's segments seal to its wall, the fractions to
+    1, and some of the launches went out ahead of the sync."""
+    stepprof.enable()
+    eng = model.serve(max_slots=2, paged=PagedConfig(
+        block_size=8, num_blocks=32, prefill_token_budget=8))
+    try:
+        eng.submit(GenerationRequest(np.arange(6) % 256,
+                                     max_new_tokens=12, temperature=0.0))
+        eng.step()
+        eng.submit(GenerationRequest(np.arange(40) % 256,
+                                     max_new_tokens=2, temperature=0.0))
+        while eng.pending:
+            eng.step()
+        assert 0 < eng._c_early_launches.value < eng._c_launches.value
+        recs = stepprof.records()
+        assert len(recs) == eng.step_count
+        for r in recs:
+            assert sum(r["segments"].values()) == \
+                pytest.approx(r["wall_s"], abs=1e-9)
+        fr, = [e["fractions"]
+               for e in stepprof.section()["engines"].values()]
+        assert abs(sum(fr.values()) - 1.0) < 1e-9
+    finally:
+        eng.close()
+
+
 def test_a_gap_is_the_callers_only_while_work_was_waiting(log):
     clk, lines = log
     for n in range(100):
