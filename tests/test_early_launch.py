@@ -29,7 +29,7 @@ from singa_tpu.serve import (EngineFailedError, EngineSupervisor,
 from singa_tpu.serve.jitpin import jit_cache_size
 
 B = 8       # the pool block of every engine below
-FAMILIES = ("gpt2", "falcon_h1", "mla_moe", "swa_moe")
+FAMILIES = ("gpt2", "falcon_h1", "mla_moe", "swa_moe", "conv_moe")
 
 
 def _gpt2(n_layer=2):

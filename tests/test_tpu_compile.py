@@ -616,3 +616,97 @@ def test_the_decode_program_compiles_with_the_kernel(family, request,
         under = {v for k, v in found.items()
                  if k.split(" ")[0] in calls}
         assert under == {scope}, (calls, under)
+
+
+# ---- conv_moe (LFM2-24B-A2B): the benchmark's nine layers, real widths ----
+
+CM_SLOTS, CM_BLOCKS, CM_WIDTH = 256, 3000, 8192
+
+
+@pytest.fixture(scope="module")
+def conv(shapes):
+    """(cfg, family, abstract params, sds) of the conv-and-attention
+    family as the benchmark cuts it: a dense conv layer and two periods
+    of one attention layer and three conv layers, all 64 experts of the
+    eight expert layers (10.4 GB of arguments), the whole vocabulary."""
+    from singa_tpu.models.conv_moe import (ConvMoeConfig, ConvMoeFamily,
+                                           _tensors)
+
+    sds = shapes[3]
+    cfg = ConvMoeConfig(
+        num_hidden_layers=9, num_dense_layers=1,
+        layer_types=("conv",) + ("full_attention",) + ("conv",) * 3
+        + ("full_attention",) + ("conv",) * 3,
+        max_len=CM_WIDTH, dtype="bfloat16")
+    sh = cfg.shapes("model")
+    params = dict(wte=sds(sh["wte"]), lnf=sds(sh["lnf"], jnp.float32))
+    for stack, n in cfg.stack_sizes().items():
+        vec, mat = _tensors(stack)
+        params[stack] = {
+            k: sds((n,) + cfg.shapes(stack)[k],
+                   jnp.float32 if k in vec else jnp.bfloat16)
+            for k in vec + mat}
+    return cfg, ConvMoeFamily(cfg), params, sds
+
+
+def test_the_256_lane_decode_program_updates_pool_and_tails_in_place(
+        conv, on_a_tpu):
+    """The decode program as the chip runs it, at the benchmark's 256
+    lanes and its pool of 3,000 + 1 blocks: the attention layers' pool
+    (2 x 787 MB) and the conv layers' tail arena (34 MB) are aliased and
+    neither is copied or re-laid, no expert stack (3.2 G elements) or
+    layer's matrix is copied, the attention is the Pallas kernel under
+    the family's scope, and what the program keeps beside its 12 GB of
+    arguments stays under 0.2 GB (the chip has 16)."""
+    from singa_tpu.serve import paged
+
+    cfg, fam, params, sds = conv
+    n = CM_SLOTS
+    pool = sds((2, CM_BLOCKS + 1, BLOCK, 512))
+    state = {"conv": sds((2, n + 1, 4, 2, 2048), jnp.float32)}
+    comp = paged._paged_decode_kernel.lower(
+        params, pool, pool, *_lanes(sds, n, CM_WIDTH // BLOCK), None,
+        state, sds((n,), jnp.int32), block=BLOCK, n_head=32, eps=1e-5,
+        moe_top_k=2, top_k=0, use_top_p=False, window=None,
+        fam=fam).compile()
+    ma, text = comp.memory_analysis(), comp.as_text()
+    cache = 2 * 2 * 2 * (CM_BLOCKS + 1) * BLOCK * 512 \
+        + 4 * 2 * (n + 1) * 4 * 2 * 2048
+    assert cache <= ma.alias_size_in_bytes < 1.01 * cache
+    # the smallest of: a layer's W_out (4.2 M elements), the tail arena
+    # (8.4 M), a pool (393 M), an expert stack (3.2 G); the largest copy
+    # there is, the lanes' 32 blocks of table rows, is 4.19 M
+    assert _big_copies(text, floor=4.2e6) == []
+    assert ma.temp_size_in_bytes < 0.2e9
+    assert ma.argument_size_in_bytes > 11.9e9
+    calls = re.findall(r"%(paged_decode_attn[\w.\-]*) = ", text)
+    assert calls, "the decode program holds no kernel"
+    paged._keep_scopes("conv_decode", fam.scopes, text)
+    found = paged.program_scopes()["conv_decode"]
+    assert {v for k, v in found.items()
+            if k.split(" ")[0] in calls} == {"attn_full"}
+    assert {"short_conv", "attn_full", "moe_experts", "dense_mlp",
+            "head"} <= set(found.values())
+
+
+@pytest.mark.parametrize("blocks", [1, 4])
+def test_the_conv_family_chunk_row_programs_compile(conv, blocks):
+    """A launch of one block and of the budget's four: the request's
+    private row (2 x 8.4 M elements over the two attention layers) is
+    what it may copy; nothing the size of an expert stack or of a
+    layer's in-projection (12.6 M)."""
+    from singa_tpu.serve import engine, paged
+
+    cfg, fam, params, sds = conv
+    row = sds((2, 1, 8, CM_WIDTH, 64))
+    comp = engine._chunk_row.lower(
+        params, sds((1, CM_WIDTH), jnp.int32), row, row,
+        sds((blocks,) if blocks > 1 else (), jnp.int32),
+        {"conv": sds((2, 4, 2, 2048), jnp.float32)}, sds((), jnp.int32),
+        n_head=32, eps=1e-5, moe_top_k=2, chunk=BLOCK, window=None,
+        fam=fam).compile()
+    assert comp.memory_analysis().temp_size_in_bytes < 0.2e9
+    assert _big_copies(comp.as_text(), floor=8.5e6) == []
+    paged._keep_scopes(f"conv_chunk{blocks}", fam.scopes, comp.as_text())
+    assert {"short_conv", "attn_full", "moe_experts"} <= set(
+        paged.program_scopes()[f"conv_chunk{blocks}"].values())
