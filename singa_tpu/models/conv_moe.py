@@ -61,7 +61,7 @@ from ..ops.expert_layer import held_terms, route, swiglu
 from ..ops.paged_attention import (paged_attn, paged_decode_attn, rotary,
                                    row_to_blocks, write_rows)
 from ..tensor import Tensor
-from .served import ServedFamily
+from .served import ServedFamily, seg_cat, seg_split, seg_tokens, seg_valid
 
 #: rows of an expert's tile (ops/expert_layer.held_terms): an expert's
 #: matrices are read once a tile whatever the tile's rows, and at a
@@ -288,18 +288,25 @@ def _conv_out(conv, c_gate, p, dtype):
     return (c_gate * conv).astype(dtype) @ p["w_out"]
 
 
-def _conv_chunk(a, p, tail, n_valid):
-    """The operator over T rows that follow ``tail`` (K - 1, E), the
-    convolution's inputs at the K - 1 positions before them (zeros at a
-    sequence's start).  Returns (o (T, E), the tail after the first
+def _conv_taps(u, p, tail, n_valid):
+    """The convolution over its inputs ``u`` (T, E) that follow ``tail``
+    (K - 1, E), the inputs at the K - 1 positions before them (zeros at a
+    sequence's start).  Returns (conv (T, E), the tail after the first
     ``n_valid`` rows: what lies past them is padding and leaves it
     alone)."""
-    t = a.shape[0]
-    u, c_gate = _gates(a, p)
+    t = u.shape[0]
     ext = jnp.concatenate([tail, u], axis=0)               # (K - 1 + T, E)
     conv = sum(p["conv_w"][j] * ext[j:j + t]
                for j in range(p["conv_w"].shape[0]))
     tail = jax.lax.dynamic_slice_in_dim(ext, n_valid, tail.shape[0], axis=0)
+    return conv, tail
+
+
+def _conv_chunk(a, p, tail, n_valid):
+    """The operator over T rows ``a`` of ONE sequence that follow
+    ``tail`` (:func:`_conv_taps`).  Returns (o (T, E), the tail)."""
+    u, c_gate = _gates(a, p)
+    conv, tail = _conv_taps(u, p, tail, n_valid)
     return _conv_out(conv, c_gate, p, a.dtype), tail
 
 
@@ -518,39 +525,47 @@ class ConvMoeFamily(ServedFamily):
                     expert_tokens_max=int(held.max()),
                     expert_tokens_mean=float(held.mean())), incs, gauges
 
-    def chunk_row(self, params, ids, kc_row, vc_row, state, off, n_valid,
-                  *, chunk, block=None, **_):
-        """One chunk row of ``chunk`` tokens, a whole number of blocks.
-        An attention layer: its queries over the private row below
-        ``off`` (the shared loop) and the chunk's own keys, the new rows
-        written into the private row.  A conv layer: the convolution runs
-        on from the tail the row before left, and the tail moves on by
-        the ``n_valid`` rows that are the prompt's (the padding after
-        them leaves it alone and chooses no expert)."""
+    def chunk_rows(self, params, segs, *, block, **_):
+        """One launch: each segment a whole number of blocks of one
+        request.  An attention layer: a segment's queries over its
+        private row below its ``off`` (the shared loop) and its own
+        keys, the new rows written into that row.  A conv layer: the
+        convolution runs on from the tail the segment's row before
+        left, and the tail moves on by the ``n_valid`` rows that are the
+        prompt's (what follows them leaves it alone and chooses no
+        expert).  Projections, gates and the feed-forward take the
+        segments' tokens together."""
         c = self.cfg
-        block = block or chunk
-        valid = jnp.arange(chunk) < n_valid
-        toks = jax.lax.dynamic_slice(ids, (0, off), (1, chunk))[0]
-        pos = off + jnp.arange(chunk)
+        n_tok = sum(s.chunk for s in segs)
+        valid = seg_valid(segs)
+        toks, pos = seg_tokens(segs)
         x = jnp.take(params["wte"], toks, axis=0)
-        width, d = kc_row.shape[3:]
+        width, d = segs[0].kc_row.shape[3:]
         # what lies below ``off`` is walked in strides of eight blocks
         # (models/swa_moe.py, PR 37)
         stride = min(8 * block, width)
-        kb, vb = row_to_blocks(kc_row, stride), row_to_blocks(vc_row, stride)
+        below = [(row_to_blocks(s.kc_row, stride),
+                  row_to_blocks(s.vc_row, stride)) for s in segs]
         tbl = jnp.arange(width // stride)
-        cur = jnp.tril(jnp.ones((chunk, chunk), bool))
+        cur = [jnp.tril(jnp.ones((s.chunk, s.chunk), bool)) for s in segs]
         scale = 1.0 / math.sqrt(d)
-        rows_of = lambda t: t.transpose(1, 0, 2).reshape(chunk, -1)
+        rows_of = lambda t: t.transpose(1, 0, 2).reshape(t.shape[1], -1)
 
         def layer(carry, stack, i, mi, p):
-            x, kc_row, vc_row, tails = carry
+            x, kc_rows, vc_rows, tails = carry
             ffn, op = STACKS[stack]
             if op == "conv":
                 with jax.named_scope("short_conv"):
                     a = _rms(x, p["ln_op"], c.norm_eps)
-                    o, tail = _conv_chunk(a, p, tails[mi], n_valid)
-                    tails = tails.at[mi].set(tail)
+                    before = [tl[mi] for tl in tails]
+                    u, c_gate = _gates(a, p)
+                    conv, after = zip(*(
+                        _conv_taps(u_s, p, tl, s.n_valid)
+                        for s, u_s, tl in zip(segs, seg_split(u, segs),
+                                              before)))
+                    o = _conv_out(seg_cat(conv), c_gate, p, a.dtype)
+                    tails = tuple(tl.at[mi].set(t)
+                                  for tl, t in zip(tails, after))
             else:
                 with jax.named_scope("attn_proj"):
                     a = _rms(x, p["ln_op"], c.norm_eps)
@@ -558,28 +573,41 @@ class ConvMoeFamily(ServedFamily):
                     q = _by_group(q, c)
                     k, v = k.transpose(1, 0, 2), v.transpose(1, 0, 2)
                 with jax.named_scope("attn_full"):
-                    o = paged_attn(q, kb, vb, mi, tbl, off,
-                                   -(-off // stride), stride, -1,
-                                   rows_of(k), rows_of(v), cur, scale)
-                    kc_row = jax.lax.dynamic_update_slice(
-                        kc_row, k[None, None].astype(kc_row.dtype),
-                        (mi, 0, 0, off, 0))
-                    vc_row = jax.lax.dynamic_update_slice(
-                        vc_row, v[None, None].astype(vc_row.dtype),
-                        (mi, 0, 0, off, 0))
+                    o, kc_rows, vc_rows = [], list(kc_rows), list(vc_rows)
+                    for j, (s, q_s, k_s, v_s) in enumerate(zip(
+                            segs, seg_split(q, segs, 2),
+                            seg_split(k, segs, 1), seg_split(v, segs, 1))):
+                        o.append(paged_attn(
+                            q_s, *below[j], mi, tbl, s.off,
+                            -(-s.off // stride), stride, -1, rows_of(k_s),
+                            rows_of(v_s), cur[j], scale))
+                        kc_rows[j] = jax.lax.dynamic_update_slice(
+                            kc_rows[j],
+                            k_s[None, None].astype(kc_rows[j].dtype),
+                            (mi, 0, 0, s.off, 0))
+                        vc_rows[j] = jax.lax.dynamic_update_slice(
+                            vc_rows[j],
+                            v_s[None, None].astype(vc_rows[j].dtype),
+                            (mi, 0, 0, s.off, 0))
+                    o, kc_rows, vc_rows = (seg_cat(o, 2), tuple(kc_rows),
+                                           tuple(vc_rows))
                 with jax.named_scope("attn_proj"):
-                    o = o.transpose(2, 0, 1, 3).reshape(chunk, -1)
+                    o = o.transpose(2, 0, 1, 3).reshape(n_tok, -1)
                     o = o.astype(x.dtype) @ p["wo"]
             x = x + o
             y, counts = _ffn(x, p, c, ffn, i, valid)
-            return (x + y.astype(x.dtype), kc_row, vc_row, tails), counts
+            return (x + y.astype(x.dtype), kc_rows, vc_rows, tails), counts
 
-        tails = state["conv"]
-        (x, kc_row, vc_row, flat), _ = _scan_layers(
-            layer, (x, kc_row, vc_row,
-                    tails.reshape((-1,) + tails.shape[2:])), params, c)
-        hidden = _rms(x, params["lnf"], c.norm_eps)[None]
-        return hidden, kc_row, vc_row, {"conv": flat.reshape(tails.shape)}
+        shape = segs[0].state["conv"].shape
+        (x, kc_rows, vc_rows, tails), _ = _scan_layers(
+            layer, (x, tuple(s.kc_row for s in segs),
+                    tuple(s.vc_row for s in segs),
+                    tuple(s.state["conv"].reshape((-1,) + shape[2:])
+                          for s in segs)), params, c)
+        hidden = _rms(x, params["lnf"], c.norm_eps)
+        return [(h[None], kc, vc, {"conv": tl.reshape(shape)})
+                for h, kc, vc, tl in zip(seg_split(hidden, segs), kc_rows,
+                                         vc_rows, tails)]
 
     def decode_step(self, params, pool_k, pool_v, state, slots, tables,
                     toks, pos, live, n_blk, *, block, trash, **_):

@@ -37,7 +37,7 @@ from .. import autograd, model
 from ..ops.paged_attention import (paged_attn, rotary, row_to_blocks,
                                    write_rows)
 from ..tensor import Tensor
-from .served import ServedFamily
+from .served import ServedFamily, seg_cat, seg_split, seg_tokens
 
 HI = jax.lax.Precision.HIGHEST      # the state path: float32 throughout
 #: a layer's per-channel vectors (float32 whatever ``cfg.dtype``) and
@@ -234,18 +234,18 @@ def ssd_chunk(x, b, cc, dt, a, s_in):
     return y, jnp.exp(la[-1])[:, None, None] * s_in + s_new
 
 
-def _mamba_chunk(h, p, c, ssm, conv, n_valid, sub=None):
-    """The mixer over a chunk row: h (T, E) normalised input, ``ssm``
-    (h, p, n) and ``conv`` (d_conv - 1, conv_dim) the state the row
-    before left, ``n_valid`` how many of the T tokens are real (the
-    prompt's last row is padded; padding leaves the state alone).  The
-    projections, the conv and the norm take the T rows together; the
-    scan walks them ``sub`` at a time in order (default: all T as one
-    chunk), each sub-chunk from the state the one before left, so a row
-    of several scan chunks computes what as many rows of one did.
-    Returns (m (T, E), ssm, conv)."""
-    t, kk = h.shape[0], c.mamba_d_conv
-    z, xbc, dt = _mixer_inputs(h, p, c)
+def _mamba_mix(xbc, dt, p, c, ssm, conv, n_valid, sub=None):
+    """What of the mixer runs along ONE sequence: ``xbc`` (T, conv_dim)
+    and ``dt`` (T, heads) of :func:`_mixer_inputs`, ``ssm`` (h, p, n) and
+    ``conv`` (d_conv - 1, conv_dim) the state the row before left,
+    ``n_valid`` how many of the T tokens are real (the prompt's last row
+    is padded; padding leaves the state alone).  The conv takes the T
+    rows together; the scan walks them ``sub`` at a time in order
+    (default: all T as one chunk), each sub-chunk from the state the one
+    before left, so a row of several scan chunks computes what as many
+    rows of one did.  Returns (y (T, d_ssm) before gate and norm, ssm,
+    conv)."""
+    t, kk = xbc.shape[0], c.mamba_d_conv
     ext = jnp.concatenate([conv, xbc], axis=0)            # (T+K-1, C)
     xbc = jax.nn.silu(sum(p["conv_w"][j] * ext[j:j + t] for j in range(kk))
                       + p["conv_b"])
@@ -266,7 +266,15 @@ def _mamba_chunk(h, p, c, ssm, conv, n_valid, sub=None):
                     dt[j:j + sub], -jnp.exp(p["a_log"]), ssm)
                 ys.append(y_j)
             y = jnp.concatenate(ys)
-    y = (y + p["d"][:, None] * x).reshape(t, -1)
+    return (y + p["d"][:, None] * x).reshape(t, -1), ssm, conv
+
+
+def _mamba_chunk(h, p, c, ssm, conv, n_valid, sub=None):
+    """The mixer over a chunk row of ONE sequence: h (T, E) normalised
+    input; the projections and the norm either side of
+    :func:`_mamba_mix`.  Returns (m (T, E), ssm, conv)."""
+    z, xbc, dt = _mixer_inputs(h, p, c)
+    y, ssm, conv = _mamba_mix(xbc, dt, p, c, ssm, conv, n_valid, sub)
     return _mixer_out(y, z, p, c), ssm, conv
 
 
@@ -407,28 +415,30 @@ class FalconH1Family(ServedFamily):
         with jax.named_scope("head"):
             return _logits(params, hidden, self.cfg)
 
-    def chunk_row(self, params, ids, kc_row, vc_row, state, off, n_valid,
-                  *, chunk, block=None, **_):
-        """One chunk row of ``chunk`` tokens, a whole number of blocks
-        (a block = one scan chunk): attention of the row's queries over
-        the private cache row below ``off`` (block by block, the shared
-        loop) and their own keys; the mixer's chunked scan, a block at
-        a time, from the state the row before left."""
+    def chunk_rows(self, params, segs, *, block, **_):
+        """One launch: each segment a whole number of blocks of one
+        request (a block = one scan chunk): attention of a segment's
+        queries over its private cache row below its ``off`` (block by
+        block, the shared loop) and their own keys; the mixer's conv and
+        chunked scan, a block at a time, from the state the segment's
+        row before left.  Projections, norms and the feed-forward take
+        the segments' tokens together."""
         c = self.cfg
-        block = block or chunk
-        toks = jax.lax.dynamic_slice(ids, (0, off), (1, chunk))[0]
-        pos = off + jnp.arange(chunk)
+        n_tok = sum(s.chunk for s in segs)
+        toks, pos = seg_tokens(segs)
         x = (c.embedding_multiplier * jnp.take(params["wte"], toks, axis=0)
              ).astype(params["wte"].dtype)
-        n_l, _, n_kv, width, d = kc_row.shape
+        n_l, _, n_kv, width, d = segs[0].kc_row.shape
         g = c.n_head // n_kv
         # what lies below off, as the blocks of a pool
-        kb, vb = row_to_blocks(kc_row, block), row_to_blocks(vc_row, block)
+        below = [(row_to_blocks(s.kc_row, block),
+                  row_to_blocks(s.vc_row, block)) for s in segs]
         tbl = jnp.arange(width // block)
-        cur = jnp.tril(jnp.ones((chunk, chunk), bool))
+        cur = [jnp.tril(jnp.ones((s.chunk, s.chunk), bool)) for s in segs]
 
         def layer(carry, lp):
-            x, kc_row, vc_row = carry
+            x, kc_rows, vc_rows = carry
+            kc_rows, vc_rows = list(kc_rows), list(vc_rows)
             li, p, ssm, conv = lp
             h = _rms(x, p["ln1"], c.rms_norm_eps)
             with jax.named_scope("attn"):
@@ -436,33 +446,50 @@ class FalconH1Family(ServedFamily):
                 q = rotary(q.transpose(1, 0, 2), pos, c.rope_theta)
                 k = rotary(k.transpose(1, 0, 2), pos, c.rope_theta)
                 v = v.transpose(1, 0, 2)                    # (KV, T, D)
-                a = paged_attn(
-                    q.reshape(n_kv, g, chunk, d), kb, vb, li, tbl, off,
-                    off // block, block, -1, _rows(k), _rows(v), cur,
-                    1.0 / math.sqrt(d))
-                a = a.transpose(2, 0, 1, 3).reshape(chunk, -1)
+                a = [paged_attn(
+                    q_s.reshape(n_kv, g, s.chunk, d), *below[n], li, tbl,
+                    s.off, s.off // block, block, -1, _rows(k_s),
+                    _rows(v_s), cur[n], 1.0 / math.sqrt(d))
+                    for n, (s, q_s, k_s, v_s) in enumerate(zip(
+                        segs, seg_split(q, segs, 1), seg_split(k, segs, 1),
+                        seg_split(v, segs, 1)))]
+                a = seg_cat(a, 2).transpose(2, 0, 1, 3).reshape(n_tok, -1)
                 att = c.attention_out_multiplier * (
                     a.astype(x.dtype) @ p["wo"])
-                kc_row = jax.lax.dynamic_update_slice(
-                    kc_row, k[None, None].astype(kc_row.dtype),
-                    (li, 0, 0, off, 0))
-                vc_row = jax.lax.dynamic_update_slice(
-                    vc_row, v[None, None].astype(vc_row.dtype),
-                    (li, 0, 0, off, 0))
+                for n, (s, k_s, v_s) in enumerate(zip(
+                        segs, seg_split(k, segs, 1), seg_split(v, segs, 1))):
+                    kc_rows[n] = jax.lax.dynamic_update_slice(
+                        kc_rows[n],
+                        k_s[None, None].astype(kc_rows[n].dtype),
+                        (li, 0, 0, s.off, 0))
+                    vc_rows[n] = jax.lax.dynamic_update_slice(
+                        vc_rows[n],
+                        v_s[None, None].astype(vc_rows[n].dtype),
+                        (li, 0, 0, s.off, 0))
             with jax.named_scope("ssm_proj"):
-                m, ssm, conv = _mamba_chunk(h, p, c, ssm, conv, n_valid,
-                                            sub=block)
+                z, xbc, dt = _mixer_inputs(h, p, c)
+                y, ssm, conv = zip(*(
+                    _mamba_mix(xbc_s, dt_s, p, c, ssm_s, conv_s, s.n_valid,
+                               sub=block)
+                    for s, xbc_s, dt_s, ssm_s, conv_s in zip(
+                        segs, seg_split(xbc, segs), seg_split(dt, segs),
+                        ssm, conv)))
+                m = _mixer_out(seg_cat(y), z, p, c)
             x = x + att + m.astype(x.dtype)
             with jax.named_scope("mlp"):
                 x = x + _mlp(x, p, c).astype(x.dtype)
-            return (x, kc_row, vc_row), (ssm, conv)
+            return (x, tuple(kc_rows), tuple(vc_rows)), (ssm, conv)
 
-        (x, kc_row, vc_row), (ssm, conv) = jax.lax.scan(
-            layer, (x, kc_row, vc_row),
-            (jnp.arange(n_l), params["layers"], state["ssm"],
-             state["conv"]))
-        hidden = _rms(x, params["lnf"], c.rms_norm_eps)[None]
-        return hidden, kc_row, vc_row, {"ssm": ssm, "conv": conv}
+        (x, kc_rows, vc_rows), (ssm, conv) = jax.lax.scan(
+            layer, (x, tuple(s.kc_row for s in segs),
+                    tuple(s.vc_row for s in segs)),
+            (jnp.arange(n_l), params["layers"],
+             tuple(s.state["ssm"] for s in segs),
+             tuple(s.state["conv"] for s in segs)))
+        hidden = _rms(x, params["lnf"], c.rms_norm_eps)
+        return [(h[None], kc, vc, {"ssm": sm, "conv": cv})
+                for h, kc, vc, sm, cv in zip(
+                    seg_split(hidden, segs), kc_rows, vc_rows, ssm, conv)]
 
     def decode_step(self, params, pool_k, pool_v, state, slots, tables,
                     toks, pos, live, n_blk, *, block, trash, **_):

@@ -59,7 +59,7 @@ from ..ops.paged_attention import (NEG_INF,
                                    write_rows as _write_rows)
 from ..ops.sampling import (filter_logits as _filter_logits,
                             sample as _sample)
-from .served import FEATURES, ServedFamily
+from .served import FEATURES, ServedFamily, seg_cat, seg_split
 
 
 def extract_params(m, dtype=None):
@@ -793,9 +793,51 @@ def decode_step(params, x, kc, vc, pos, n_head, eps, *, start=None,
                         tp_axis=tp_axis, tp_world=tp_world, ep=ep)
 
 
+def _chunk_attend(q, k_new, v_new, k_cache, v_cache, pos, window, dtype):
+    """ONE sequence's part of :func:`_block_chunk`: the K new K/V rows
+    (B, kv, K, d) written into its cache at ``pos`` and its K queries
+    (B, kv, g, K, d) attended against the cache.  Returns ((B, kv, g, K,
+    d), k_cache, v_cache)."""
+    quant = isinstance(k_cache, tuple)
+    klen, d = q.shape[3:]
+    if quant:
+        (kqv, ksc), (vqv, vsc) = k_cache, v_cache
+        k8, k8s = _quantize_kv(k_new)
+        v8, v8s = _quantize_kv(v_new)
+        kqv = jax.lax.dynamic_update_slice(kqv, k8, (0, 0, pos, 0))
+        ksc = jax.lax.dynamic_update_slice(ksc, k8s, (0, 0, pos))
+        vqv = jax.lax.dynamic_update_slice(vqv, v8, (0, 0, pos, 0))
+        vsc = jax.lax.dynamic_update_slice(vsc, v8s, (0, 0, pos))
+        k_cache, v_cache = (kqv, ksc), (vqv, vsc)
+        sc = jnp.einsum("bkgqd,bktd->bkgqt", q, kqv.astype(dtype))
+        sc = sc * ksc[:, :, None, None, :].astype(sc.dtype) \
+            / math.sqrt(d)
+    else:
+        k_cache = jax.lax.dynamic_update_slice(k_cache, k_new,
+                                               (0, 0, pos, 0))
+        v_cache = jax.lax.dynamic_update_slice(v_cache, v_new,
+                                               (0, 0, pos, 0))
+        sc = jnp.einsum("bkgqd,bktd->bkgqt", q, k_cache) \
+            / math.sqrt(d)
+    ctx = sc.shape[-1]
+    live = (jnp.arange(ctx)[None, :]
+            <= (pos + jnp.arange(klen))[:, None])       # (K, ctx)
+    if window is not None:
+        live = live & (jnp.arange(ctx)[None, :]
+                       > (pos + jnp.arange(klen))[:, None] - window)
+    sc = jnp.where(live[None, None, None], sc, NEG_INF)
+    p_attn = jax.nn.softmax(sc, axis=-1)
+    if quant:
+        pv = p_attn * vsc[:, :, None, None, :].astype(p_attn.dtype)
+        a = jnp.einsum("bkgqt,bktd->bkgqd", pv, vqv.astype(dtype))
+    else:
+        a = jnp.einsum("bkgqt,bktd->bkgqd", p_attn, v_cache)
+    return a, k_cache, v_cache
+
+
 def _block_chunk(x, p, k_cache, v_cache, pos, n_head, eps,
                  moe_top_k=2, window=None, tp_axis=None, tp_world=1,
-                 ep=None):
+                 ep=None, segs=None):
     """Chunked cache advance: x (B, K, E) are K consecutive tokens at
     positions pos..pos+K-1.  Writes all K K/V rows in one contiguous
     dynamic_update_slice and attends the K queries against the cache
@@ -807,14 +849,24 @@ def _block_chunk(x, p, k_cache, v_cache, pos, n_head, eps,
     ``window``: sliding-window band — query i additionally masks
     positions <= pos + i - window (LINEAR cache, the paged serve
     engine's windowed chunk prefill; the rolling-cache decode path
-    is _block_decode's)."""
-    quant = isinstance(k_cache, tuple)
-    kq0 = k_cache[0] if quant else k_cache
+    is _block_decode's).
+
+    ``segs`` (models/served.py ``Segment``s): the K tokens are the
+    segments' laid end to end, ``k_cache`` / ``v_cache`` / ``pos`` a
+    list with an entry a segment; the projections and the feed-forward
+    take them together and each attends its own cache."""
+    if segs is None:
+        caches = [(k_cache, v_cache, pos)]
+        split = lambda t, axis: [t]
+    else:
+        caches = list(zip(k_cache, v_cache, pos))
+        split = lambda t, axis: seg_split(t, segs, axis)
+    kq0 = caches[0][0]
+    kq0 = kq0[0] if isinstance(kq0, tuple) else kq0
     b, klen, e = x.shape
     d = e // n_head
     n_kv = kq0.shape[1]         # LOCAL kv heads (H_kv / tp_world)
     g = n_head // (n_kv * tp_world)
-    ctx = kq0.shape[2]
     h = _ln(x, p["ln1_s"], p["ln1_b"], eps)
     q = (h @ p["wq"] + p["bq"]).reshape(b, klen, n_kv, g, d) \
         .transpose(0, 2, 3, 1, 4)                       # (B,kv,g,K,d)
@@ -822,47 +874,24 @@ def _block_chunk(x, p, k_cache, v_cache, pos, n_head, eps,
         .transpose(0, 2, 1, 3)                          # (B,kv,K,d)
     v_new = (h @ p["wv"] + p["bv"]).reshape(b, klen, n_kv, d) \
         .transpose(0, 2, 1, 3)
-    if quant:
-        (kqv, ksc), (vqv, vsc) = k_cache, v_cache
-        k8, k8s = _quantize_kv(k_new)
-        v8, v8s = _quantize_kv(v_new)
-        kqv = jax.lax.dynamic_update_slice(kqv, k8, (0, 0, pos, 0))
-        ksc = jax.lax.dynamic_update_slice(ksc, k8s, (0, 0, pos))
-        vqv = jax.lax.dynamic_update_slice(vqv, v8, (0, 0, pos, 0))
-        vsc = jax.lax.dynamic_update_slice(vsc, v8s, (0, 0, pos))
-        k_cache, v_cache = (kqv, ksc), (vqv, vsc)
-        sc = jnp.einsum("bkgqd,bktd->bkgqt", q, kqv.astype(x.dtype))
-        sc = sc * ksc[:, :, None, None, :].astype(sc.dtype) \
-            / math.sqrt(d)
-    else:
-        k_cache = jax.lax.dynamic_update_slice(k_cache, k_new,
-                                               (0, 0, pos, 0))
-        v_cache = jax.lax.dynamic_update_slice(v_cache, v_new,
-                                               (0, 0, pos, 0))
-        sc = jnp.einsum("bkgqd,bktd->bkgqt", q, k_cache) \
-            / math.sqrt(d)
-    live = (jnp.arange(ctx)[None, :]
-            <= (pos + jnp.arange(klen))[:, None])       # (K, ctx)
-    if window is not None:
-        live = live & (jnp.arange(ctx)[None, :]
-                       > (pos + jnp.arange(klen))[:, None] - window)
-    sc = jnp.where(live[None, None, None], sc, NEG_INF)
-    p_attn = jax.nn.softmax(sc, axis=-1)
-    if quant:
-        pv = p_attn * vsc[:, :, None, None, :].astype(p_attn.dtype)
-        a = jnp.einsum("bkgqt,bktd->bkgqd", pv, vqv.astype(x.dtype))
-    else:
-        a = jnp.einsum("bkgqt,bktd->bkgqd", p_attn, v_cache)
+    a, k_cache, v_cache = zip(*(
+        _chunk_attend(q_s, k_s, v_s, kc, vc, at, window, x.dtype)
+        for q_s, k_s, v_s, (kc, vc, at) in zip(
+            split(q, 3), split(k_new, 2), split(v_new, 2), caches)))
+    a = seg_cat(a, 3)
     a = a.transpose(0, 3, 1, 2, 4).reshape(b, klen, e // tp_world)
     x = x + (_tp_psum(a @ p["wo"], tp_axis, tp_world) + p["bo"])
     h = _ln(x, p["ln2_s"], p["ln2_b"], eps)
     x = x + _mlp(h, p, moe_top_k, tp_axis=tp_axis, tp_world=tp_world,
                  ep=ep)
-    return x, k_cache, v_cache
+    if segs is None:
+        return x, k_cache[0], v_cache[0]
+    return x, list(k_cache), list(v_cache)
 
 
 def prefill_chunk(params, x, kc, vc, pos, n_head, eps, *, moe_top_k=2,
-                  window=None, tp_axis=None, tp_world=1, ep=None):
+                  window=None, tp_axis=None, tp_world=1, ep=None,
+                  segs=None):
     """PUBLIC offset-prefill entry (the prefix cache's contract;
     serve.prefix round).  Advance every layer by a K-token chunk —
     ``x``: (B, K, E) embedded inputs at positions ``pos..pos+K-1``
@@ -893,7 +922,7 @@ def prefill_chunk(params, x, kc, vc, pos, n_head, eps, *, moe_top_k=2,
         x, kl, vl = _block_chunk(x, p, *caches, pos, n_head, eps,
                                  moe_top_k=moe_top_k, window=window,
                                  tp_axis=tp_axis, tp_world=tp_world,
-                                 ep=ep)
+                                 ep=ep, segs=segs)
         return x, (kl, vl)
 
     x, (kc, vc) = _over_layers(params, layer, x, (kc, vc))
@@ -1929,20 +1958,24 @@ class _GPT2Family(ServedFamily):
     def quant_flag(self, cache_dtype):
         return _quant_flag(cache_dtype)
 
-    def chunk_row(self, params, ids, kc_row, vc_row, state, off, n_valid,
-                  *, chunk, n_head, eps, block=None, moe_top_k=2,
-                  window=None, tp_axis=None, tp_world=1, ep=None):
-        # the row is attended whole, whatever its blocks: ``block``
-        # changes nothing here
-        toks = jax.lax.dynamic_slice(ids, (0, off), (1, chunk))
-        pos = off + jnp.arange(chunk)
+    def chunk_rows(self, params, segs, *, n_head, eps, block=None,
+                   moe_top_k=2, window=None, tp_axis=None, tp_world=1,
+                   ep=None):
+        # each row is attended whole, whatever its blocks: ``block``
+        # changes nothing here, and ``n_valid`` neither (what follows it
+        # is K/V above the positions any query of the launch reads)
+        toks = seg_cat([jax.lax.dynamic_slice(
+            s.ids, (0, s.off), (1, s.chunk)) for s in segs], 1)
+        pos = seg_cat([s.off + jnp.arange(s.chunk) for s in segs])
         x = jnp.take(params["wte"], toks[0], axis=0)[None] + \
             jnp.take(params["wpe"], pos, axis=0)[None]
-        hidden, kc_row, vc_row = prefill_chunk(
-            params, x, kc_row, vc_row, off, n_head, eps,
-            moe_top_k=moe_top_k, window=window, tp_axis=tp_axis,
-            tp_world=tp_world, ep=ep)
-        return hidden, kc_row, vc_row, None
+        hidden, kc_rows, vc_rows = prefill_chunk(
+            params, x, [s.kc_row for s in segs], [s.vc_row for s in segs],
+            [s.off for s in segs], n_head, eps, moe_top_k=moe_top_k,
+            window=window, tp_axis=tp_axis, tp_world=tp_world, ep=ep,
+            segs=segs)
+        return [(h, kc, vc, None) for h, kc, vc in zip(
+            seg_split(hidden, segs, 1), kc_rows, vc_rows)]
 
     def decode_step(self, params, pool_k, pool_v, state, slots, tables,
                     toks, pos, live, n_blk, *, block, trash, n_head,

@@ -53,7 +53,7 @@ from ..ops.expert_layer import held_terms, route, swiglu
 from ..ops.paged_attention import (paged_attn, paged_decode_attn, rotary,
                                    write_rows, yarn_frequencies)
 from ..tensor import Tensor
-from .served import ServedFamily
+from .served import ServedFamily, seg_cat, seg_split, seg_tokens, seg_valid
 
 #: a layer's float32 tensors (whatever ``cfg.dtype``), by kind of layer
 _ATTN_VECTORS = ("ln1", "q_norm", "kv_norm", "ln2")
@@ -379,51 +379,58 @@ class MlaMoeFamily(ServedFamily):
                     expert_tokens_max=int(held.max()),
                     expert_tokens_mean=float(held.mean())), incs
 
-    def chunk_row(self, params, ids, kc_row, vc_row, state, off, n_valid,
-                  *, chunk, block=None, **_):
-        """One chunk row of ``chunk`` tokens, a whole number of blocks:
-        its queries, absorbed, over the private latent
-        row below ``off`` (block by block, the shared loop) and over the
-        chunk's own rows; the new rows written into the private row.
-        ``vc_row`` is None: the row is key and value at once.  The
-        padding after a prompt's end (``n_valid``) chooses no expert:
-        its rows are never read, and a run of like tokens that all
-        chose one held expert cost that expert further tiles."""
+    def chunk_rows(self, params, segs, *, block, **_):
+        """One launch: each segment a whole number of blocks of one
+        request: its queries, absorbed, over its private latent row
+        below its ``off`` (block by block, the shared loop) and over its
+        own rows; the new rows written into that row.  ``vc_row`` is
+        None: the row is key and value at once.  What follows a
+        segment's ``n_valid`` chooses no expert: its rows are never
+        read, and a run of like tokens that all chose one held expert
+        cost that expert further tiles.  Projections and the
+        feed-forward take the segments' tokens together."""
         c = self.cfg
-        block = block or chunk
-        valid = None if n_valid is None else jnp.arange(chunk) < n_valid
-        toks = jax.lax.dynamic_slice(ids, (0, off), (1, chunk))[0]
-        pos = off + jnp.arange(chunk)
+        valid = None if segs[0].n_valid is None else seg_valid(segs)
+        toks, pos = seg_tokens(segs)
         x = jnp.take(params["wte"], toks, axis=0)
-        n_l, _, _, width, d = kc_row.shape
+        n_l, _, _, width, d = segs[0].kc_row.shape
         # what lies below off, as the blocks of a pool (one head: a
         # reshape)
-        kb = kc_row.reshape(n_l, width // block, block, d)
+        below = [s.kc_row.reshape(n_l, width // block, block, d)
+                 for s in segs]
         tbl = jnp.arange(width // block)
-        cur = jnp.tril(jnp.ones((chunk, chunk), bool))
+        cur = [jnp.tril(jnp.ones((s.chunk, s.chunk), bool)) for s in segs]
 
         def layer(carry, kind, li, i, p):
-            x, kc_row = carry
+            x, kc_rows = carry
+            kc_rows = list(kc_rows)
             h = _rms(x, p["ln1"], c.rms_norm_eps)
             with jax.named_scope("mla_proj"):
                 q_nope, q_r, row = _queries_and_row(h, p, c, pos)
                 q = _absorb(q_nope, q_r, p, c)
             with jax.named_scope("mla_attn"):
-                o_lat = paged_attn(
-                    q[None], kb, None, li, tbl, off, off // block, block,
-                    -1, row, None, cur, c.softmax_scale,
-                    v_dim=c.kv_lora_rank)[0]
-                kc_row = jax.lax.dynamic_update_slice(
-                    kc_row, row[None, None, None].astype(kc_row.dtype),
-                    (li, 0, 0, off, 0))
+                o_lat = []
+                for n, (s, q_s, row_s) in enumerate(zip(
+                        segs, seg_split(q, segs, 1), seg_split(row, segs))):
+                    o_lat.append(paged_attn(
+                        q_s[None], below[n], None, li, tbl, s.off,
+                        s.off // block, block, -1, row_s, None, cur[n],
+                        c.softmax_scale, v_dim=c.kv_lora_rank)[0])
+                    kc_rows[n] = jax.lax.dynamic_update_slice(
+                        kc_rows[n],
+                        row_s[None, None, None].astype(kc_rows[n].dtype),
+                        (li, 0, 0, s.off, 0))
+                o_lat = seg_cat(o_lat, 1)
             with jax.named_scope("mla_proj"):
                 x = x + _attn_out(o_lat.transpose(1, 0, 2), p, x.dtype)
             y, counts = _ffn(x, p, c, kind, i, valid)
-            return (x + y, kc_row), counts
+            return (x + y, tuple(kc_rows)), counts
 
-        (x, kc_row), _ = _scan_layers(layer, (x, kc_row), params, c)
-        hidden = _rms(x, params["lnf"], c.rms_norm_eps)[None]
-        return hidden, kc_row, None, None
+        (x, kc_rows), _ = _scan_layers(
+            layer, (x, tuple(s.kc_row for s in segs)), params, c)
+        hidden = _rms(x, params["lnf"], c.rms_norm_eps)
+        return [(h[None], kc, None, None)
+                for h, kc in zip(seg_split(hidden, segs), kc_rows)]
 
     def decode_step(self, params, pool_k, pool_v, state, slots, tables,
                     toks, pos, live, n_blk, *, block, trash, **_):

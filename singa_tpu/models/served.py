@@ -36,6 +36,11 @@ implements (:data:`FEATURES`); the engine refuses the others by name
 (``engine._require``).
 """
 
+from collections import namedtuple
+
+import jax
+import jax.numpy as jnp
+
 #: every optional feature a family may implement; the engine's one
 #: capability check maps what was asked for onto these names
 FEATURES = frozenset({
@@ -44,6 +49,46 @@ FEATURES = frozenset({
     "the slot arena (serving without paged=)",
     "whole-prompt admission (paged= without prefill_token_budget)",
     "KV image ship"})
+
+
+#: one piece of one prefilling request in a chunk-row launch
+#: (:meth:`ServedFamily.chunk_rows`): ``chunk`` tokens at ``[off, off +
+#: chunk)`` of ``ids``, the first ``n_valid`` of them real
+Segment = namedtuple(
+    "Segment", "ids kc_row vc_row state off chunk n_valid")
+
+
+def seg_cat(parts, axis=0):
+    """The segments' tokens laid end to end along ``axis``; one
+    segment's as they are (no operation)."""
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis)
+
+
+def seg_split(x, segs, axis=0):
+    """``x``, whose ``axis`` is the segments' tokens end to end, a
+    segment at a time; one segment's as it is."""
+    if len(segs) == 1:
+        return [x]
+    at, out = 0, []
+    for s in segs:
+        out.append(jax.lax.slice_in_dim(x, at, at + s.chunk, axis=axis))
+        at += s.chunk
+    return out
+
+
+def seg_tokens(segs):
+    """``(token ids, positions)`` of a launch's tokens, the segments'
+    end to end: each segment's ``chunk`` ids of its own padded row from
+    its own ``off`` on."""
+    toks = seg_cat([jax.lax.dynamic_slice(
+        s.ids, (0, s.off), (1, s.chunk))[0] for s in segs])
+    return toks, seg_cat([s.off + jnp.arange(s.chunk) for s in segs])
+
+
+def seg_valid(segs):
+    """Which of a launch's tokens are real: each segment's first
+    ``n_valid``."""
+    return seg_cat([jnp.arange(s.chunk) < s.n_valid for s in segs])
 
 
 class ServedFamily:
@@ -91,7 +136,7 @@ class ServedFamily:
 
     # -- the math ----------------------------------------------------------
     def chunk_row(self, params, ids, kc_row, vc_row, state, off, n_valid,
-                  *, chunk, block, **statics):
+                  *, chunk, block=None, **statics):
         """Prefill ``chunk`` prompt tokens at positions ``[off, off +
         chunk)`` of the padded ``ids`` (1, W) against a private cache
         row that holds K/V below ``off`` and the ``state`` carried from
@@ -99,13 +144,47 @@ class ServedFamily:
         the rest padding that must leave the state alone (both None for
         a family without state, unless it is ``pad_aware``).  ``chunk``
         is the launch's width and ``block`` the pool's block: ``chunk``
-        is ``block`` times a power of two (whatever the step's prefill
-        budget allows) and ``off`` a multiple of ``block``, not of
-        ``chunk``; what a family lays out or walks by the block (the
-        row as pool blocks, a scan's chunks) goes by ``block``, and the
-        same rows must come of one wide launch as of its blocks one by
-        one.  Returns ``(final-norm hidden (1, chunk, E), kc_row,
-        vc_row, state)``."""
+        is a whole number of blocks (as many as the step's prefill
+        budget allows: any number, not a power of two) and ``off`` a
+        multiple of ``block``, not of ``chunk``; what a family lays out
+        or walks by the block (the row as pool blocks, a scan's chunks)
+        goes by ``block``, and the same rows must come of one wide
+        launch as of its blocks one by one.  Returns ``(final-norm
+        hidden (1, chunk, E), kc_row, vc_row, state)``.
+
+        This is :meth:`chunk_rows` of one segment; a family overrides
+        that.  (``block`` left out: the launch is one block.)"""
+        return self.chunk_rows(
+            params, [Segment(ids, kc_row, vc_row, state, off, chunk,
+                             n_valid)], block=block or chunk, **statics)[0]
+
+    def chunk_rows(self, params, segs, *, block, **statics):
+        """One launch over a list of :class:`Segment`: each a piece of
+        ONE request as :meth:`chunk_row` describes it -- its own ``ids``,
+        private row, carried state, ``off``, width and ``n_valid`` -- and
+        no two of one request.  What treats a token alone (norms, the
+        projections either side of the mixing, dense and expert
+        feed-forward, the router) runs ONCE over the segments' tokens
+        laid end to end (:func:`seg_cat`), so a launch reads the weights
+        once however many requests it serves; what mixes along a
+        sequence (attention over the private row and the segment's own
+        causal part, a convolution over the carried tail, a scan from
+        the carried state) runs a segment at a time (:func:`seg_split`),
+        each against its own request's row and state.  No row is copied
+        or stacked: each is updated where it lies.
+
+        A segment of a launch of several is a SLOT: its width is the
+        slot's, a whole number of blocks, and ``n_valid`` (always given
+        then, whatever the family) may leave whole blocks of it unused.
+        What lies past ``n_valid`` must carry no state on and choose no
+        expert (as the padding of a prompt's last block); its K/V may
+        land in the row, above ``off + n_valid`` only, where the launch
+        that really covers those positions writes over it, and nothing
+        below ``off`` may change.  One segment must lower to the program
+        it always did: laying one piece end to end is no operation.
+
+        Returns a list, an entry a segment, of ``(final-norm hidden (1,
+        width, E), kc_row, vc_row, state)``."""
         raise NotImplementedError
 
     def decode_step(self, params, pool_k, pool_v, state, slots, tables,

@@ -59,7 +59,7 @@ from ..ops.paged_attention import (paged_attn, paged_decode_attn,
                                    ring_write_chunk, rotary, row_to_blocks,
                                    write_rows)
 from ..tensor import Tensor
-from .served import ServedFamily
+from .served import ServedFamily, seg_cat, seg_split, seg_tokens, seg_valid
 
 #: a layer's float32 tensors (whatever ``cfg.dtype``) and its matrices
 _VECTORS = ("ln_in", "ln_post_attn", "ln_pre_mlp", "ln_post_mlp",
@@ -447,82 +447,98 @@ class SwaMoeFamily(ServedFamily):
             # full layers, the positions inside the window of the others
             full_rows=full_rows, window_rows=win_rows), incs, gauges
 
-    def chunk_row(self, params, ids, kc_row, vc_row, state, off, n_valid,
-                  *, chunk, block=None, **_):
-        """One chunk row of ``chunk`` tokens, a whole number of blocks.
-        A full layer: its queries over the private row below ``off``
-        (block by block, the shared loop) and the chunk's own keys, the
-        new rows written into the private row.  A window layer: its
-        queries over the slot's ring -- the rows of the band below
-        ``off`` -- and the chunk's own keys, THEN the chunk's rows laid
-        into the ring (those of the prompt: the padding after
-        ``n_valid`` writes nothing and chooses no expert)."""
+    def chunk_rows(self, params, segs, *, block, **_):
+        """One launch: each segment a whole number of blocks of one
+        request.  A full layer: a segment's queries over its private
+        row below its ``off`` (block by block, the shared loop) and its
+        own keys, the new rows written into that row.  A window layer:
+        its queries over the request's ring -- the rows of the band
+        below ``off`` -- and its own keys, THEN its rows laid into the
+        ring (those of the prompt: what follows ``n_valid`` writes
+        nothing and chooses no expert).  Projections, norms, gates and
+        the feed-forward take the segments' tokens together."""
         c = self.cfg
-        block = block or chunk
-        valid = jnp.arange(chunk) < n_valid
-        toks = jax.lax.dynamic_slice(ids, (0, off), (1, chunk))[0]
-        pos = off + jnp.arange(chunk)
+        n_tok = sum(s.chunk for s in segs)
+        valid = seg_valid(segs)
+        toks, pos = seg_tokens(segs)
         x = (c.embedding_multiplier * jnp.take(params["wte"], toks, axis=0)
              ).astype(params["wte"].dtype)
-        n_l, _, n_kv, width, d = kc_row.shape
+        n_l, _, n_kv, width, d = segs[0].kc_row.shape
         # what lies below ``off`` is walked in STRIDES of eight blocks (a
         # stride's rows beyond ``off`` are masked): walked a block at a
         # time, a launch 12,000 positions in took twice a first one, and
         # the gap's p95 sat on that slope (PERF.md section 6, PR 37)
         stride = min(8 * block, width)
         rstride = min(8 * block, c.ring)
-        kb, vb = row_to_blocks(kc_row, stride), row_to_blocks(vc_row, stride)
+        below = [(row_to_blocks(s.kc_row, stride),
+                  row_to_blocks(s.vc_row, stride)) for s in segs]
         tbl = jnp.arange(width // stride)
-        cur = jnp.tril(jnp.ones((chunk, chunk), bool))
+        cur = [jnp.tril(jnp.ones((s.chunk, s.chunk), bool)) for s in segs]
         scale = 1.0 / math.sqrt(d)
         # the rings a window layer a row: (P · J, ring, X)
         flat = lambda r: r.reshape((-1,) + r.shape[2:])
-        rows_of = lambda t: t.transpose(1, 0, 2).reshape(chunk, -1)
+        rows_of = lambda t: t.transpose(1, 0, 2).reshape(t.shape[1], -1)
 
         def layer(carry, stack, period, j, i, p):
-            x, kc_row, vc_row, win_k, win_v = carry
+            x, *rows = carry
+            kc_rows, vc_rows, win_k, win_v = map(list, rows)
             ffn, kind = STACKS[stack]
             with jax.named_scope("attn_proj"):
                 a = _rms(x, p["ln_in"], c.rms_norm_eps)
                 q, k, v, gate = _qkvg(a, p, c, pos, kind)
                 q = _by_group(q, c)
                 k, v = k.transpose(1, 0, 2), v.transpose(1, 0, 2)
-            if kind == "window":
-                with jax.named_scope("attn_window"):
-                    li = period * c.n_win + j
-                    o = ring_chunk_attn(
-                        q, win_k, win_v, li, off, rstride,
-                        -(-width // rstride), rows_of(k), rows_of(v),
-                        scale, c.sliding_window)
-                    win_k = ring_write_chunk(win_k, li, rows_of(k), off,
-                                             n_valid, block)
-                    win_v = ring_write_chunk(win_v, li, rows_of(v), off,
-                                             n_valid, block)
-            else:
-                with jax.named_scope("attn_full"):
-                    o = paged_attn(q, kb, vb, period, tbl, off,
-                                   -(-off // stride), stride, -1,
-                                   rows_of(k), rows_of(v), cur, scale)
-                    kc_row = jax.lax.dynamic_update_slice(
-                        kc_row, k[None, None].astype(kc_row.dtype),
-                        (period, 0, 0, off, 0))
-                    vc_row = jax.lax.dynamic_update_slice(
-                        vc_row, v[None, None].astype(vc_row.dtype),
-                        (period, 0, 0, off, 0))
+            o = []
+            for n, (s, q_s, k_s, v_s) in enumerate(zip(
+                    segs, seg_split(q, segs, 2), seg_split(k, segs, 1),
+                    seg_split(v, segs, 1))):
+                if kind == "window":
+                    with jax.named_scope("attn_window"):
+                        li = period * c.n_win + j
+                        o.append(ring_chunk_attn(
+                            q_s, win_k[n], win_v[n], li, s.off, rstride,
+                            -(-width // rstride), rows_of(k_s),
+                            rows_of(v_s), scale, c.sliding_window))
+                        win_k[n] = ring_write_chunk(
+                            win_k[n], li, rows_of(k_s), s.off, s.n_valid,
+                            block)
+                        win_v[n] = ring_write_chunk(
+                            win_v[n], li, rows_of(v_s), s.off, s.n_valid,
+                            block)
+                else:
+                    with jax.named_scope("attn_full"):
+                        o.append(paged_attn(
+                            q_s, *below[n], period, tbl, s.off,
+                            -(-s.off // stride), stride, -1, rows_of(k_s),
+                            rows_of(v_s), cur[n], scale))
+                        kc_rows[n] = jax.lax.dynamic_update_slice(
+                            kc_rows[n],
+                            k_s[None, None].astype(kc_rows[n].dtype),
+                            (period, 0, 0, s.off, 0))
+                        vc_rows[n] = jax.lax.dynamic_update_slice(
+                            vc_rows[n],
+                            v_s[None, None].astype(vc_rows[n].dtype),
+                            (period, 0, 0, s.off, 0))
             with jax.named_scope("attn_proj"):
-                o = o.transpose(2, 0, 1, 3).reshape(chunk, -1)
+                o = seg_cat(o, 2).transpose(2, 0, 1, 3).reshape(n_tok, -1)
                 x = x + _rms(_attn_out(o, gate, p, x), p["ln_post_attn"],
                              c.rms_norm_eps)
             y, counts = _ffn(x, p, c, ffn, i, valid)
-            return (x + y, kc_row, vc_row, win_k, win_v), counts
+            return (x + y, tuple(kc_rows), tuple(vc_rows), tuple(win_k),
+                    tuple(win_v)), counts
 
-        (x, kc_row, vc_row, win_k, win_v), _ = _scan_layers(
-            layer, (x, kc_row, vc_row, flat(state["win_k"]),
-                    flat(state["win_v"])), params, c)
-        hidden = _rms(x, params["lnf"], c.rms_norm_eps)[None]
-        back = lambda r: r.reshape(state["win_k"].shape)
-        return hidden, kc_row, vc_row, {"win_k": back(win_k),
-                                        "win_v": back(win_v)}
+        (x, kc_rows, vc_rows, win_k, win_v), _ = _scan_layers(
+            layer, (x, tuple(s.kc_row for s in segs),
+                    tuple(s.vc_row for s in segs),
+                    tuple(flat(s.state["win_k"]) for s in segs),
+                    tuple(flat(s.state["win_v"]) for s in segs)),
+            params, c)
+        hidden = _rms(x, params["lnf"], c.rms_norm_eps)
+        back = lambda r: r.reshape(segs[0].state["win_k"].shape)
+        return [(h[None], kc, vc, {"win_k": back(wk), "win_v": back(wv)})
+                for h, kc, vc, wk, wv in zip(
+                    seg_split(hidden, segs), kc_rows, vc_rows, win_k,
+                    win_v)]
 
     def decode_step(self, params, pool_k, pool_v, state, slots, tables,
                     toks, pos, live, n_blk, *, block, trash, **_):

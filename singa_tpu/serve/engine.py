@@ -69,10 +69,11 @@ Long-context serving (the long-context round; docs/SERVING.md
   (``PagedConfig(prefill_token_budget=)``): a Sarathi-style per-step
   prefill TOKEN budget — an admission whose prompt exceeds it splits
   across consecutive steps, ONE ``_chunk_row`` launch a request a
-  step, as wide as the step's budget allows (the block times a power
-  of two: a launch reads the layers' weights once however many
-  blocks it covers; every width is compiled when the engine is
-  built), so one 32k document admission never stalls the live decode
+  step, as wide as the step's budget allows (any whole number of
+  blocks, and two requests' pieces of one pass in one launch where
+  the budget is four blocks or more: a launch reads the layers'
+  weights once whatever it covers; every shape is compiled when the
+  engine is built), so one 32k document admission never stalls the live decode
   lanes for more than one step's budget (the request ledger's stall
   phase is the proof metric);
 * **windowed paged decode**: sliding-window models
@@ -114,7 +115,7 @@ import numpy as np
 # paths no other family has yet: the slot arena, whole-prompt prefill,
 # speculation, and the sharded executors' per-row twins
 from ..models import gpt2_decode as _gpt2
-from ..models.served import FEATURES
+from ..models.served import FEATURES, Segment as _Segment
 from ..ops.paged_attention import decode_attn_impl as _decode_attn_impl
 from ..ops.sampling import select_sample as _select_sample
 from ..observe import monitor as _monitor
@@ -283,7 +284,7 @@ def _launch_of(off, block):
 def _chunk_row(params, ids, kc_row, vc_row, off, state=None,
                n_valid=None, *, n_head, eps, moe_top_k, chunk,
                window=None, tp_axis=None, tp_world=1, ep=None, fam):
-    """Offset prefill of ONE window through the family's ``chunk_row``
+    """Offset prefill of ONE launch through the family's ``chunk_row``
     (models/served.py): tokens at positions [off, off+width) of the
     padded ``ids`` row, advanced against a cache row that already holds
     canonical K/V below ``off`` and the per-slot ``state`` the window
@@ -291,17 +292,41 @@ def _chunk_row(params, ids, kc_row, vc_row, off, state=None,
     ``n_valid``, how many of the window's tokens are the prompt's and
     not padding).  ``chunk`` (static) is the BLOCK; the window is one
     block at a scalar ``off`` and ``n`` blocks where ``off`` holds their
-    ``n`` offsets (:func:`_launch_of`): the budgeted path launches the
-    widest the step's budget allows, every other caller one block.
+    ``n`` offsets (:func:`_launch_of`): the budgeted path launches as
+    many blocks as the step's budget allows, every other caller one.
     ``off``'s value is traced, so every admission's every window of one
     width rides one executable.  Returns ((1, chunk, E) final-norm
     hidden of the window's LAST block, kc_row, vc_row[, state]) — rows
-    donated, the admission loop rebinds."""
+    donated, the admission loop rebinds.
+
+    A launch of SEVERAL segments -- pieces of different requests, each
+    against its own row and state -- has a tuple, an entry a segment,
+    for every argument between ``params`` and the statics (``state``
+    may stay None) and returns a tuple for each result.  A segment's
+    ``off`` is then the offsets of its SLOT's blocks and its ``n_valid``
+    says how many of the slot's tokens are real: the hidden block
+    returned for it is the last one that holds a real token."""
+    statics = dict(block=chunk, n_head=n_head, eps=eps,
+                   moe_top_k=moe_top_k, window=window, tp_axis=tp_axis,
+                   tp_world=tp_world, ep=ep)
+    if isinstance(off, tuple):
+        segs = [_Segment(i, k, v, s, *_launch_of(o, chunk), n)
+                for i, k, v, s, o, n in zip(
+                    ids, kc_row, vc_row, state or (None,) * len(off),
+                    off, n_valid)]
+        outs = fam.chunk_rows(params, segs, **statics)
+        hidden, kc_row, vc_row, new = zip(*outs)
+        hidden = tuple(
+            jax.lax.dynamic_slice_in_dim(
+                h, (sg.n_valid - 1) // chunk * chunk, chunk, axis=1)
+            for h, sg in zip(hidden, segs))
+        if state is None:
+            return hidden, kc_row, vc_row
+        return hidden, kc_row, vc_row, new
     off, width = _launch_of(off, chunk)
     hidden, kc_row, vc_row, state = fam.chunk_row(
         params, ids, kc_row, vc_row, state, off, n_valid, chunk=width,
-        block=chunk, n_head=n_head, eps=eps, moe_top_k=moe_top_k,
-        window=window, tp_axis=tp_axis, tp_world=tp_world, ep=ep)
+        **statics)
     if width > chunk:
         # a prompt's last window ends with its last block, and only
         # that block's rows are ever sampled from (_first_from_hidden)
@@ -584,7 +609,10 @@ class _LocalExec:
         return _aot_call("chunk_row", _chunk_row, params, ids, kc_row,
                          vc_row, off, state, n_valid, fam=e._fam,
                          _memo=self._aot_memo,
-                         _token=("chunk_row", off.shape), _run=run,
+                         _token=("chunk_row",
+                                 tuple(o.shape for o in off)
+                                 if isinstance(off, tuple) else off.shape),
+                         _run=run,
                          **e._chunk_statics)
 
     def write_slot(self, kc, vc, kc_row, vc_row, slot):
@@ -1306,6 +1334,7 @@ class InferenceEngine:
         # it (serve.schedule's args)
         self._chunks_run = 0
         self._launches_run = 0
+        self._segments_run = 0
         self._own_metrics = []
         # what the newest decode step's program counted about itself
         # (``ServedFamily.step_counts``), for the serve.step span
@@ -1371,17 +1400,38 @@ class InferenceEngine:
                      "waited for its tokens (early / all = the share "
                      "of launches the wait no longer delays)",
                 engine=self.stats.engine_label)
+            self._c_merged_launches = self.stats.registry.counter(
+                "serve.prefill.merged_launches",
+                help="those of serve.prefill.launches that carried "
+                     "pieces of two requests (merged / all = the share "
+                     "of launches that served two prompts with one "
+                     "read of the weights)",
+                engine=self.stats.engine_label)
             self._own_metrics.extend([self._c_budget_chunks,
                                       self._c_launches,
-                                      self._c_early_launches])
-            # a launch is as wide as the step's budget allows: the
-            # block times a power of two, widest first
+                                      self._c_early_launches,
+                                      self._c_merged_launches])
+            # a launch is a list of segments.  One segment is any whole
+            # number of one request's blocks that the step's budget
+            # allows (widest first); the sharded executors, which
+            # compile a width when they first meet it, keep the block
+            # times a power of two
             B = self.paged_arena.block_size
-            self._launch_widths = tuple(
-                B << j for j in reversed(range(
-                    (min(self._budget, W) // B).bit_length())))
+            n_max = min(self._budget, W) // B
+            self._pair_blocks = 0
             if self._shard is None:
+                self._launch_widths = tuple(
+                    B * n for n in range(n_max, 0, -1))
+                # two segments of two requests ride ONE further program:
+                # two slots of half the budget each, where that is two
+                # blocks or more (a slot of one block serves only two
+                # whole one-block prompts met in one pass)
+                if n_max >= 4:
+                    self._pair_blocks = n_max // 2
                 self._compile_launch_widths()
+            else:
+                self._launch_widths = tuple(
+                    B << j for j in reversed(range(n_max.bit_length())))
         # -- CoW KV forking (serve/fork.py): fork-family id sequence
         # and the fork-round metrics (paged engines only — forking
         # rides on the arena's block refcounts)
@@ -1800,7 +1850,7 @@ class InferenceEngine:
                 # (serve.schedule's totals are the whole step's: the
                 # launches that go out ahead of the decode tokens too)
                 n_pf, n_ch = self.stats.prefills, self._chunks_run
-                n_la = self._launches_run
+                n_la, n_sg = self._launches_run, self._segments_run
                 left = self._budget
                 if any(s is not None for s in self._slots):
                     width, left = self._decode_once()
@@ -1808,7 +1858,8 @@ class InferenceEngine:
                     self._schedule(self._clock(), left)
                     sp.set(admitted=self.stats.prefills - n_pf,
                            chunks=self._chunks_run - n_ch,
-                           launches=self._launches_run - n_la)
+                           launches=self._launches_run - n_la,
+                           segments=self._segments_run - n_sg)
             except Exception as e:
                 # (a raising step has no meaningful anatomy: the
                 # phase's exit drops stepprof's open record)
@@ -3419,7 +3470,17 @@ class InferenceEngine:
         host fetch the first tokens (``_promote_landed``) — the chip is
         still on the landed prompt's last launch meanwhile, so the
         admission's host work and the leftover budget's launch cost
-        the step no idle time."""
+        the step no idle time.
+
+        Each of the two passes (the early one over the requests in
+        flight, this one's admissions) first COLLECTS its pieces — (a
+        request, the blocks of it the budget left allows), FIFO, the
+        budget spent once — and dispatches when it has them all
+        (``_launch_chunks``): a piece is one launch, and two pieces
+        that stand next to each other and fit the pair program's slots
+        are ONE.  A pass never waits for the other: what is in flight
+        goes out behind the decode program, whatever may be admitted
+        after it."""
         left = self._launch_inflight(left)
         self._finish_landed()
         self._admit_budgeted(now, left)
@@ -3428,13 +3489,15 @@ class InferenceEngine:
     def _admit_budgeted(self, now, left):
         """The admissions of one budgeted scheduling pass: start new
         chunked prefills in the free slots, FIFO, while ``left`` budget
-        tokens allow a first launch."""
+        tokens allow a first launch; their first pieces go out together
+        when the last of them is admitted."""
         B = self.paged_arena.block_size
         free = self._free_slots()
         if not free and self.scheduler.queue_depth == 0:
             return
         admit = self._sched_admissions(len(free), now)
         blocked_p = self._blocked_priority()
+        pieces = []
         for k, req in enumerate(admit):
             ok = False
             admissible = (left >= B
@@ -3457,9 +3520,8 @@ class InferenceEngine:
                 if idx is not None:
                     free.pop(0)
                     ok = True
-                    left = self._launch_chunks(self._prefilling[idx],
-                                               left)
-                    self._finish_landed()
+                    left = self._take_piece(self._prefilling[idx], left,
+                                            pieces)
             if not ok:
                 # budget exhausted or capacity-blocked: everything
                 # scheduled from here returns to the queue FRONT in
@@ -3468,6 +3530,9 @@ class InferenceEngine:
                 for r in reversed(admit[k:]):
                     self.scheduler.requeue_front(r)
                 break
+        if pieces:
+            self._launch_chunks(pieces)
+            self._finish_landed()
 
     def _start_prefilling(self, idx, req, now):
         """Begin a chunked-prefill admission at slot ``idx``: acquire
@@ -3563,10 +3628,12 @@ class InferenceEngine:
         return idx
 
     def _compile_launch_widths(self):
-        """Compile the chunk-row program of every launch width now,
-        from abstract arguments shaped like a prefilling request's: a
-        warm-up of short prompts reaches the one-block program only,
-        and a width first met under traffic would compile there."""
+        """Compile every chunk-row program the planner can choose now
+        -- each launch width, and the pair program where the engine has
+        one -- from abstract arguments shaped like a prefilling
+        request's: a warm-up of short prompts reaches the one-block
+        program only, and a shape first met under traffic would compile
+        there."""
         def placed(make):
             # what ``make`` allocates at the engine's placement, as
             # shapes
@@ -3577,17 +3644,24 @@ class InferenceEngine:
 
         kc_row, vc_row = placed(
             lambda: self.paged_arena.gather_row([], n_used=0))
+        n_valid = jax.ShapeDtypeStruct((), jnp.int32)
+        off_of = lambda w: jax.eval_shape(lambda: self._launch_off(0, w))
         kw = {}
         if self._state_spec:
             kw["state"] = placed(self._zero_state)
         if self._state_spec or self._fam.pad_aware:
-            kw["n_valid"] = jax.ShapeDtypeStruct((), jnp.int32)
+            kw["n_valid"] = n_valid
         ids = jax.ShapeDtypeStruct((1, self.max_len), jnp.int32)
         for w in self._launch_widths:
+            self._x.chunk_row(self._params, ids, kc_row, vc_row,
+                              off_of(w), run=False, **kw)
+        if self._pair_blocks:
+            two = lambda a: (a, a)
+            off = off_of(self._pair_blocks * self.paged_arena.block_size)
             self._x.chunk_row(
-                self._params, ids, kc_row, vc_row,
-                jax.eval_shape(lambda: self._launch_off(0, w)),
-                run=False, **kw)
+                self._params, two(ids), two(kc_row), two(vc_row),
+                two(off), run=False,
+                **{k: two(v) for k, v in dict(kw, n_valid=n_valid).items()})
 
     def _zero_state(self):
         """A prefilling request's per-slot state before its first
@@ -3612,31 +3686,49 @@ class InferenceEngine:
         nothing here reads a device value or promotes a request, so it
         may run while the decode program is still in flight.  Returns
         the remaining budget."""
-        B = self.paged_arena.block_size
+        pieces = []
         for idx in sorted(self._prefilling,
                           key=lambda i: self._prefilling[i].seq):
-            if left < B:
+            if left < self.paged_arena.block_size:
                 break
-            left = self._launch_chunks(self._prefilling[idx], left)
+            left = self._take_piece(self._prefilling[idx], left, pieces)
+        self._launch_chunks(pieces)
         return left
 
-    def _launch_chunks(self, pf, left):
-        """Spend up to ``left`` budget tokens on one chunked prefill,
-        a launch at a time: each the widest of the
-        engine's widths (the block times a power of two) that the
-        budget left, the blocks the prompt still needs and the row's
-        end all allow — one launch reads the layers' weights once,
-        however many blocks it covers.  A one-block launch is the exact
-        executable warm admission rides, and a wider one computes the
-        same rows (every position attends what lies below it in the
-        row), so a budgeted stream is the unbudgeted one.  The prompt's
-        last block is launched when ``pf.off > pf.last_off``;
-        :meth:`_finish_prefilling` then completes the admission.
+    def _take_piece(self, pf, left, pieces):
+        """Add to ``pieces`` what ``left`` budget tokens allow of one
+        chunked prefill this pass: ``(pf, blocks)`` — never past the
+        prompt's last block, nor, then, past the row's end (the row is
+        whole blocks), where the program's slices would clamp silently.
         Returns the remaining budget."""
         B = self.paged_arena.block_size
-        rid = pf.request.request_id
-        plen = len(pf.request.prompt_ids)
-        while left >= B and pf.off <= pf.last_off:
+        n = min(left, pf.last_off - pf.off + B) // B
+        if n > 0:
+            pieces.append((pf, n))
+        return left - n * B
+
+    def _launch_chunks(self, pieces):
+        """Dispatch one pass's ``pieces`` (:meth:`_take_piece`), in
+        their order, in the fewest launches the engine's programs
+        allow.  A launch reads the layers' weights once, whatever it
+        covers.  Two neighbours that each fit a slot of the pair
+        program (``_pair_blocks`` blocks, none of them past its row's
+        end) are ONE launch of two segments; every other piece is one
+        launch of its own where the engine holds a program of its
+        width, and the widest launches that add up to it otherwise (the
+        sharded executors' ladder).  A one-block launch is the exact
+        executable warm admission rides, and a wider or shared one
+        computes the same rows (every position attends what lies below
+        it in its own row), so a budgeted stream is the unbudgeted
+        one.  A prompt's last block is launched when ``pf.off >
+        pf.last_off``; :meth:`_finish_prefilling` then completes the
+        admission."""
+        B = self.paged_arena.block_size
+        slot = self._pair_blocks
+        fits = lambda pf, n: (n <= slot
+                              and pf.off + slot * B <= self.max_len)
+        i = 0
+        while i < len(pieces):
             if _faults._armed:
                 # chaos hook: a fault BETWEEN launches models a raising
                 # mid-prefill dispatch — step() fails the engine
@@ -3644,42 +3736,69 @@ class InferenceEngine:
                 # streamed), and _fail returns the partial blocks to
                 # the free list (RESILIENCE.md; chaos_longctx)
                 _faults.check("serve.prefill_chunk")
-            # never past the prompt's last block — nor, then, past the
-            # row's end (the row is whole blocks), where the program's
-            # slices would clamp silently
-            room = min(left, pf.last_off - pf.off + B)
-            w = next(w for w in self._launch_widths if w <= room)
-            off = self._launch_off(pf.off, w)
-            # the prompt positions this launch really covers: the last
-            # one is cut at the prompt's end, not padded to the block
-            n_valid = min(w, plen - pf.off)
-            if pf.state is None:
-                # (a pad-aware family is told where the prompt ends)
-                kw = {"n_valid": jnp.int32(n_valid)} \
-                    if self._fam.pad_aware else {}
-                pf.hidden, pf.kc_row, pf.vc_row = self._x.chunk_row(
-                    self._params, pf.ids_j, pf.kc_row, pf.vc_row,
-                    off, **kw)
+            if i + 1 < len(pieces) and fits(*pieces[i]) \
+                    and fits(*pieces[i + 1]):
+                self._launch(pieces[i:i + 2], slot * B)
+                i += 2
+                continue
+            pf, n = pieces[i]
+            w = next(w for w in self._launch_widths if w <= n * B)
+            self._launch([(pf, w // B)], w)
+            if w == n * B:
+                i += 1
             else:
-                pf.hidden, pf.kc_row, pf.vc_row, pf.state = \
-                    self._x.chunk_row(
-                        self._params, pf.ids_j, pf.kc_row, pf.vc_row,
-                        off, state=pf.state,
-                        n_valid=jnp.int32(n_valid))
+                pieces[i] = (pf, n - w // B)
+
+    def _launch(self, segs, w):
+        """ONE chunk-row launch of ``segs`` — ``(pf, real blocks)``, one
+        request's or two requests' — each in a window of ``w`` tokens
+        from its ``pf.off``, and the bookkeeping of what it covered."""
+        B = self.paged_arena.block_size
+        # the prompt positions each segment really covers: cut at the
+        # blocks the budget gave it and at the prompt's end, not padded
+        n_valid = [min(n * B, len(pf.request.prompt_ids) - pf.off)
+                   for pf, n in segs]
+        pfs = [pf for pf, _ in segs]
+        # one segment's arguments ride as themselves, several segments'
+        # as tuples with an entry a segment
+        pack = tuple if len(pfs) > 1 else (lambda per_seg: per_seg[0])
+        kw = {}
+        if pfs[0].state is not None:
+            kw["state"] = pack([pf.state for pf in pfs])
+        if len(pfs) > 1 or pfs[0].state is not None \
+                or self._fam.pad_aware:
+            # (a slot, a family with state and a pad-aware one are told
+            # where the real tokens end)
+            kw["n_valid"] = pack([jnp.int32(n) for n in n_valid])
+        out = self._x.chunk_row(
+            self._params, pack([pf.ids_j for pf in pfs]),
+            pack([pf.kc_row for pf in pfs]),
+            pack([pf.vc_row for pf in pfs]),
+            pack([self._launch_off(pf.off, w) for pf in pfs]), **kw)
+        out = list(zip(*out)) if len(pfs) > 1 else [out]
+        # the rows were donated: rebind every segment's before anything
+        # else can raise
+        for (pf, _), o in zip(segs, out):
+            pf.hidden, pf.kc_row, pf.vc_row = o[:3]
+            if len(o) > 3:
+                pf.state = o[3]
+        self._c_launches.inc()
+        self._launches_run += 1
+        if len(segs) > 1:
+            self._c_merged_launches.inc()
+        for (pf, n), n_tok in zip(segs, n_valid):
             # blocks, not launches: what the benchmark's token count
             # multiplies by the block
-            self._c_budget_chunks.inc(w // B)
-            self._chunks_run += w // B
-            self._c_launches.inc()
-            self._launches_run += 1
-            self.stats.on_prefill_tokens(n_valid)
+            self._c_budget_chunks.inc(n)
+            self._chunks_run += n
+            self._segments_run += 1
+            self.stats.on_prefill_tokens(n_tok)
             if _reqs._active:
                 _reqs._ledger.on_prefill_chunk(
-                    rid, engine=self.stats.engine_label,
-                    t=self._clock(), offset=pf.off)
-            pf.off += w
-            left -= w
-        return left
+                    pf.request.request_id,
+                    engine=self.stats.engine_label, t=self._clock(),
+                    offset=pf.off)
+            pf.off += n * B
 
     def _finish_landed(self):
         """Dispatch the completion of every chunked prefill whose last

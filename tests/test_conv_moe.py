@@ -479,16 +479,89 @@ def launch_runs(built):
     runs.close()
 
 
-@pytest.mark.parametrize("ratio", [2, 4])
+@pytest.mark.parametrize("ratio", [2, 3, 4])
 @pytest.mark.parametrize("case", list(lw.CASES))
 def test_a_wide_launch_leaves_what_one_block_at_a_time_did(
         launch_runs, case, ratio):
-    """One launch a request a step, two or four blocks wide: the tokens,
-    the private rows and the TAILS of every admission against the engine
-    that launches a block at a time."""
+    """A pass's pieces in the fewest launches, two to four blocks wide
+    and at four two requests in one: the tokens, the private rows and
+    the TAILS of every admission against the engine that launches a
+    block at a time."""
     lw.assert_same_as_one_block(launch_runs.run(ratio, case),
                                 launch_runs.run(1, case), case, ratio,
                                 atol=TOL)
+
+
+def test_one_block_lowers_to_the_program_it_was(launch_runs):
+    assert lw.chunk_row_lowering(launch_runs.engine(1)) == \
+        lw.PARENT_LOWERING["conv_moe"]
+
+
+def test_a_slots_unused_blocks_touch_nothing_and_choose_no_expert(
+        built, monkeypatch):
+    """The pair program at the family's seam: two requests' segments in
+    slots of two blocks, the first 5 tokens into its second block (13
+    real positions behind it, 11 of its slot unused), the second a whole
+    slot.  Each request's hidden rows, private rows and tails are those
+    of its own launch alone; the rows below ``off`` are the bytes they
+    were; and the expert layers are told which 21 of the 32 tokens are
+    real."""
+    from singa_tpu.models import conv_moe
+    from singa_tpu.models.served import Segment
+
+    m, _, _ = built
+    fam, cfg = m.served_family(), m.cfg
+    params = fam.extract_params(m, dtype=jnp.float32)
+    n_l, n_kv, d = fam.kv_geometry(cfg)
+    row = lambda: jnp.zeros((n_l, 1, n_kv, cfg.max_len, d), jnp.float32)
+
+    def ids_of(toks):
+        ids = np.zeros((1, cfg.max_len), np.int32)
+        ids[0, :len(toks)] = toks
+        return jnp.asarray(ids)
+
+    one = jax.jit(fam.chunk_row, static_argnames=("chunk", "block"))
+    a_ids, b_ids = ids_of(_prompt(13, 1)), ids_of(_prompt(16, 2))
+    # request a's first block, alone: what the pair finds below its off
+    _, a_kc, a_vc, a_st = one(params, a_ids, row(), row(),
+                              _zero_state(fam, cfg), jnp.int32(0),
+                              jnp.int32(8), chunk=8, block=BLOCK)
+    want_a = one(params, a_ids, a_kc, a_vc, a_st, jnp.int32(8),
+                 jnp.int32(5), chunk=8, block=BLOCK)
+    want_b = one(params, b_ids, row(), row(), _zero_state(fam, cfg),
+                 jnp.int32(0), jnp.int32(16), chunk=16, block=BLOCK)
+    segs = [Segment(a_ids, a_kc, a_vc, a_st, jnp.int32(8), 16,
+                    jnp.int32(5)),
+            Segment(b_ids, row(), row(), _zero_state(fam, cfg),
+                    jnp.int32(0), 16, jnp.int32(16))]
+    got_a, got_b = jax.jit(
+        lambda p: fam.chunk_rows(p, segs, block=BLOCK))(params)
+    for got, want, n in ((got_a, want_a, 5), (got_b, want_b, 16)):
+        np.testing.assert_allclose(got[0][0, :n], want[0][0, :n],
+                                   atol=TOL)
+        np.testing.assert_allclose(got[3]["conv"], want[3]["conv"],
+                                   atol=TOL)
+    for k in (1, 2):
+        # a's first block as it was, its second as its own launch wrote
+        np.testing.assert_array_equal(got_a[k][..., :8, :],
+                                      (a_kc, a_vc)[k - 1][..., :8, :])
+        np.testing.assert_allclose(got_a[k][..., :13, :],
+                                   want_a[k][..., :13, :], atol=TOL)
+        np.testing.assert_allclose(got_b[k], want_b[k], atol=TOL)
+
+    seen, sound = [], conv_moe.held_terms
+
+    def spy(x, idx, w, w_gu, w_down, first, valid=None, **kw):
+        jax.debug.callback(lambda v: seen.append(np.asarray(v)), valid)
+        return sound(x, idx, w, w_gu, w_down, first, valid, **kw)
+
+    monkeypatch.setattr(conv_moe, "held_terms", spy)
+    jax.block_until_ready(jax.jit(
+        lambda p: fam.chunk_rows(p, segs, block=BLOCK))(params))
+    jax.effects_barrier()
+    real = np.r_[np.arange(16) < 5, np.ones(16, bool)]
+    assert len(seen) == cfg.n_layer - cfg.num_dense_layers
+    assert all((v == real).all() for v in seen)
 
 
 def test_the_steps_counts_reach_the_span_and_the_counters(built):

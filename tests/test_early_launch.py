@@ -280,10 +280,10 @@ def test_a_pass_dispatches_its_admission_before_it_fetches_a_first_token(
         order, chunks, promote = [], eng._launch_chunks, eng._promote
         emit = eng._emit_step
 
-        def spy_chunks(p, left):
+        def spy_chunks(pieces):
             n = eng._launches_run
             try:
-                return chunks(p, left)
+                return chunks(pieces)
             finally:
                 order.extend(["launch"] * (eng._launches_run - n))
 
@@ -316,6 +316,122 @@ def test_a_pass_dispatches_its_admission_before_it_fetches_a_first_token(
         assert eng._c_early_launches.value == (1 if live else 0)
     finally:
         eng.close(force=True)
+
+
+# -- two requests in one launch ---------------------------------------------
+
+def _three_in_a_pass(eng):
+    """A lane decoding and, queued behind it, prompts of 2, 1 and 3
+    blocks on a budget of four: one pass admits all three -- (2, 1) fit
+    the pair program's slots and are ONE launch, the third gets the
+    block that is left."""
+    chat = eng.submit(_req(6, 60, seed=1))
+    eng.step()
+    return [chat] + [eng.submit(_req(n, 2, seed=s))
+                     for n, s in ((2 * B - 3, 2), (B - 1, 3), (3 * B - 2, 4))]
+
+
+def test_a_pair_in_the_pass_spends_the_budget_once_and_the_head_blocks(
+        model):
+    eng = _engine(model, budget=4 * B)
+    try:
+        assert eng._pair_blocks == 2
+        _, a, b, c = _three_in_a_pass(eng)
+        spans = _step(eng)
+        total = _one(spans, "serve.schedule")[3]
+        # four blocks, the budget: 2 + 1 in one launch, then 1 of the
+        # third's three -- which blocks whatever is queued behind it
+        # (a and b landed whole and went live in the same pass)
+        assert (total["admitted"], total["chunks"], total["launches"],
+                total["segments"]) == (2, 4, 2, 3)
+        assert eng._c_merged_launches.value == 1
+        assert not [s for s in spans if s[0] == "serve.launch"]
+        rows = [s for s in spans if s[0] == "serve.dispatch.chunk_row"]
+        assert len(rows) == 2
+        (pf,) = eng._prefilling.values()
+        assert pf.request is c.request and pf.off == B
+        d = eng.submit(_req(B - 2, 2, seed=5))
+        spans = _step(eng)
+        # c's remainder goes out early, alone (the early pass waits for
+        # no admission); d takes what is left
+        early = _one(spans, "serve.launch")[3]
+        total = _one(spans, "serve.schedule")[3]
+        assert (early["launches"], early["chunks"]) == (1, 2)
+        assert (total["admitted"], total["chunks"], total["launches"],
+                total["segments"]) == (2, 3, 2, 2)
+        assert eng._c_merged_launches.value == 1
+        eng.run_until_complete(max_steps=300)
+        assert all(h.done() for h in (a, b, c, d))
+    finally:
+        eng.close(force=True)
+
+
+def test_a_pass_that_shares_a_launch_compiles_no_program(model):
+    """Construction compiled every shape the planner can choose: after a
+    warm-up that reaches the one-block program alone, a mix that takes
+    every width and the pair program compiles nothing."""
+    eng = _engine(model, budget=4 * B, max_len=112)
+    try:
+        # one admission a pass: every decode bucket, no shared launch
+        for k in range(4):
+            eng.submit(_req(B - 1, 8, seed=k))
+            eng.step()
+        eng.run_until_complete(max_steps=50)
+        assert eng._c_merged_launches.value == 0
+        assert eng._c_launches.value == eng._c_budget_chunks.value == 4
+        warmed = jit_cache_size()
+        hs = _three_in_a_pass(eng)
+        hs += [eng.submit(_req(n, 3, seed=10 + n))
+               for n in (4 * B - 1, 5, 6, 3 * B, 2 * B + 1, 7)]
+        eng.run_until_complete(max_steps=400)
+        assert all(h.done() for h in hs)
+        assert eng._c_merged_launches.value >= 2
+        assert warmed is not None and jit_cache_size() == warmed
+    finally:
+        eng.close()
+
+
+def test_a_slot_that_would_pass_the_rows_end_is_launched_alone(model):
+    """A warm admission starts at its cached prefix: 15 of a row's 16
+    blocks in, a slot of two blocks would reach past the row's end,
+    where the program's slices clamp -- that piece goes out alone, and
+    the stream is the engine's whose budget is one block."""
+    from singa_tpu.serve import PrefixCacheConfig
+
+    base = np.random.default_rng(5).integers(0, 256, 15 * B) \
+        .astype(np.int32)
+    tail = np.random.default_rng(6).integers(0, 256, 5).astype(np.int32)
+
+    def serve(budget):
+        eng = _engine(model, budget=budget,
+                      prefix_cache=PrefixCacheConfig(block_size=B))
+        try:
+            assert eng.max_len == 16 * B
+            first = eng.submit(GenerationRequest(
+                base, max_new_tokens=2, temperature=0.0))
+            eng.run_until_complete(max_steps=200)
+            merged = eng._c_merged_launches.value
+            # admitted in one pass: a warm one (off = 15 blocks, one
+            # block to go) and a one-block prompt -- two that a pair's
+            # slots would hold, were the row longer
+            hs = [eng.submit(GenerationRequest(
+                np.concatenate([base, tail]), max_new_tokens=2,
+                temperature=0.0)), eng.submit(_req(B - 1, 2, seed=7))]
+            spans = _step(eng)
+            total = _one(spans, "serve.schedule")[3]
+            eng.run_until_complete(max_steps=200)
+            return ([list(map(int, h.result().tokens))
+                     for h in [first] + hs], total,
+                    eng._c_merged_launches.value - merged)
+        finally:
+            eng.close()
+
+    want = serve(B)
+    got = serve(4 * B)
+    assert got[0] == want[0]
+    assert (got[1]["chunks"], got[1]["launches"],
+            got[1]["segments"]) == (2, 2, 2)
+    assert got[2] == 0
 
 
 # -- the same streams as the parent's order ---------------------------------
@@ -463,6 +579,37 @@ def test_a_fault_in_the_admission_behind_a_landed_prefill_fails_typed(
     eng.close(force=True)
 
 
+def test_a_fault_behind_a_pairs_dispatch_frees_both_requests_blocks(model):
+    """The pair has been dispatched -- both rows donated and rebound --
+    when its bookkeeping raises: the engine fails typed, both requests
+    have streamed nothing and are rejected ``started=False``, and both
+    prompts' blocks come back."""
+    eng = _engine(model, budget=4 * B)
+    chat, a, b, c = _three_in_a_pass(eng)
+    counter = eng._c_merged_launches
+
+    class Boom:
+        value = property(lambda self: counter.value)
+
+        def inc(self):
+            counter.inc()
+            raise RuntimeError("behind the dispatch")
+    eng._c_merged_launches = Boom()
+    try:
+        with pytest.raises(EngineFailedError):
+            eng.step()
+    finally:
+        eng._c_merged_launches = counter
+    assert eng._c_merged_launches.value == 1 and eng._c_launches.value == 2
+    for h, started in ((chat, True), (a, False), (b, False), (c, False)):
+        with pytest.raises(EngineFailedError) as ei:
+            h.result()
+        assert ei.value.started is started
+    assert eng.paged_arena.blocks_used == 0, "mid-prefill leak"
+    assert not eng._prefilling and eng.live_slots == 0
+    eng.close(force=True)
+
+
 def test_a_supervisor_rebuilds_behind_a_fault_in_the_early_pass(model):
     want = np.asarray(model.generate(
         np.arange(64, dtype=np.int32) % 256, max_new_tokens=3,
@@ -513,5 +660,6 @@ def test_close_mid_prefill_leaves_no_series_of_the_budgets_counters(
             and dict(m.labels).get("engine") == label)
     assert "serve.prefill.early_launches" in series()
     assert "serve.prefill.launches" in series()
+    assert "serve.prefill.merged_launches" in series()
     eng.close(force=True)
     assert series() == []

@@ -958,9 +958,10 @@ def test_every_launch_width_is_compiled_when_the_engine_is_built(model,
                                                                  ratio):
     """A warm-up of short prompts (the benchmark's: one block less a
     token) reaches the one-block program only; prompts of every length
-    after it -- every width, at offsets even and odd -- compile
-    nothing, because the engine compiled each width from abstract
-    shapes when it was built."""
+    after it -- every width, at offsets even and odd, and at four blocks
+    two requests in a launch -- compile nothing, because the engine
+    compiled every shape its planner can choose from abstract shapes
+    when it was built."""
     from singa_tpu.serve import paged
     from singa_tpu.serve.jitpin import jit_cache_size
 
@@ -969,13 +970,17 @@ def test_every_launch_width_is_compiled_when_the_engine_is_built(model,
         block_size=8, num_blocks=64, prefill_token_budget=8 * ratio))
     try:
         widths = eng._launch_widths
-        assert widths == tuple(8 << j for j in reversed(
-            range(ratio.bit_length())))
-        # before any request: a program a width, under the key its
+        assert widths == tuple(8 * n for n in range(ratio, 0, -1))
+        # the pair program: two slots of half the budget, from four
+        # blocks on
+        assert eng._pair_blocks == (2 if ratio == 4 else 0)
+        shapes = {() if w == 8 else (w // 8,) for w in widths}
+        if eng._pair_blocks:
+            shapes.add(((2,), (2,)))
+        # before any request: a program a shape, under the key its
         # first launch will look up
         memo = eng._x._aot_memo
-        assert {t[1] for t in memo} == {() if w == 8 else (w // 8,)
-                                        for w in widths}
+        assert {t[1] for t in memo} == shapes
         assert all(key in paged._aot_cache for key in memo.values())
         rng = np.random.RandomState(ratio)
 
@@ -995,5 +1000,7 @@ def test_every_launch_width_is_compiled_when_the_engine_is_built(model,
         chunks, launches = (eng._c_budget_chunks.value,
                             eng._c_launches.value)
         assert (launches < chunks) == (ratio > 1)
+        # (the four short warm-up prompts were two pairs already)
+        assert (eng._c_merged_launches.value > 2) == (ratio == 4)
     finally:
         eng.close(force=True)

@@ -491,6 +491,39 @@ def test_a_launch_of_two_blocks_leaves_what_one_block_at_a_time_did(
                                 atol=TOL)
 
 
+def test_one_block_lowers_to_the_program_it_was(launch_runs):
+    assert lw.chunk_row_lowering(launch_runs.engine(1)) == \
+        lw.PARENT_LOWERING["swa_moe"]
+
+
+@pytest.fixture(scope="module")
+def wide_runs(ref):
+    """The preset with a window of four blocks, so that a budget of
+    three and of four blocks is allowed (and with four, the pair
+    program)."""
+    ad = loader.load_module("adapters", "swa_moe")
+    tiny = dict(TINY, sliding_window=4 * BLOCK)
+    m = ad.build_model(tiny, device.get_default_device(), train=False,
+                       batch_shape=(1, 16))
+    ad.put_weights(m, ref.init_weights(ref.sizes_of(tiny), 7))
+    runs = lw.Runs(lambda budget: _engine(m, budget=budget), 512)
+    yield runs
+    runs.close()
+
+
+@pytest.mark.parametrize("ratio", [3, 4])
+@pytest.mark.parametrize("case", list(lw.CASES))
+def test_three_blocks_and_two_requests_a_launch_leave_the_same(
+        wide_runs, case, ratio):
+    """Three blocks in one launch, and at a budget of four blocks two
+    requests' pieces in one: tokens, private rows and RINGS (an unused
+    block of a slot lays nothing into its ring) against a block at a
+    time."""
+    lw.assert_same_as_one_block(wide_runs.run(ratio, case),
+                                wide_runs.run(1, case), case, ratio,
+                                atol=TOL)
+
+
 def test_a_budget_wider_than_the_window_is_refused(built):
     with pytest.raises(ValueError, match="the launch"):
         _engine(built[0], budget=32)

@@ -239,6 +239,39 @@ def test_gpt2_large_launch_of_the_whole_budget_compiles(gpt2l):
     assert _row_copies(texts[4], row) <= _row_copies(texts[1], row)
 
 
+def test_gpt2_large_launch_of_three_blocks_and_of_two_requests_compile(
+        gpt2l):
+    """What a launch became when it turned into a list of segments: a
+    remainder of three blocks in ONE launch (96 positions), and two
+    requests' pieces in one -- two slots of half longdoc's budget, each
+    against its own private rows.  Every row is donated and written
+    where it lies (the pair aliases BOTH requests' rows), and neither
+    program copies a request's whole row more often than the one-block
+    program does."""
+    from singa_tpu.models import gpt2_decode
+    from singa_tpu.serve import engine
+
+    sds, params, _, i32 = gpt2l
+    row = sds((GL, 1, GH, GW, GE // GH))
+    statics = dict(n_head=GH, eps=1e-5, moe_top_k=2, chunk=GB,
+                   window=None, fam=gpt2_decode.FAMILY)
+    rows = 2 * 2 * GL * GW * GE          # K and V of one request, bf16
+    one = engine._chunk_row.lower(params, i32(1, GW), row, row, i32(),
+                                  **statics).compile().as_text()
+    three = engine._chunk_row.lower(params, i32(1, GW), row, row, i32(3),
+                                    **statics).compile()
+    two = lambda a: (a, a)
+    pair = engine._chunk_row.lower(
+        params, two(i32(1, GW)), two(row), two(row), two(i32(2)),
+        n_valid=two(i32()), **statics).compile()
+    for comp, n_req in ((three, 1), (pair, 2)):
+        ma = comp.memory_analysis()
+        assert ma.alias_size_in_bytes >= n_req * rows
+        assert ma.temp_size_in_bytes < 0.5e9
+        assert _row_copies(comp.as_text(), row) <= \
+            n_req * _row_copies(one, row)
+
+
 @pytest.mark.parametrize("rows,width", [(4, 128), (1, GW)])
 def test_gpt2_large_admission_scatter_is_in_place(gpt2l, rows, width):
     """``_rows_to_pool`` (every admission of a pass in one scatter) at a
@@ -689,22 +722,27 @@ def test_the_256_lane_decode_program_updates_pool_and_tails_in_place(
             "head"} <= set(found.values())
 
 
-@pytest.mark.parametrize("blocks", [1, 4])
+@pytest.mark.parametrize("blocks", [1, 3, 4, "pair"])
 def test_the_conv_family_chunk_row_programs_compile(conv, blocks):
-    """A launch of one block and of the budget's four: the request's
-    private row (2 x 8.4 M elements over the two attention layers) is
-    what it may copy; nothing the size of an expert stack or of a
-    layer's in-projection (12.6 M)."""
+    """A launch of one block, of three, of the budget's four, and of two
+    requests in slots of two blocks each: a request's private row (2 x
+    8.4 M elements over the two attention layers) is what it may copy;
+    nothing the size of an expert stack or of a layer's in-projection
+    (12.6 M)."""
     from singa_tpu.serve import engine, paged
 
     cfg, fam, params, sds = conv
     row = sds((2, 1, 8, CM_WIDTH, 64))
+    args = (sds((1, CM_WIDTH), jnp.int32), row, row,
+            sds((2 if blocks == "pair" else blocks,)
+                if blocks != 1 else (), jnp.int32),
+            {"conv": sds((2, 4, 2, 2048), jnp.float32)},
+            sds((), jnp.int32))
+    if blocks == "pair":
+        args = tuple((a, a) for a in args)
     comp = engine._chunk_row.lower(
-        params, sds((1, CM_WIDTH), jnp.int32), row, row,
-        sds((blocks,) if blocks > 1 else (), jnp.int32),
-        {"conv": sds((2, 4, 2, 2048), jnp.float32)}, sds((), jnp.int32),
-        n_head=32, eps=1e-5, moe_top_k=2, chunk=BLOCK, window=None,
-        fam=fam).compile()
+        params, *args, n_head=32, eps=1e-5, moe_top_k=2, chunk=BLOCK,
+        window=None, fam=fam).compile()
     assert comp.memory_analysis().temp_size_in_bytes < 0.2e9
     assert _big_copies(comp.as_text(), floor=8.5e6) == []
     paged._keep_scopes(f"conv_chunk{blocks}", fam.scopes, comp.as_text())
