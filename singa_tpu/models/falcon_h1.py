@@ -34,12 +34,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import autograd, model
+from ..ops import mamba2
+from ..ops.mamba2 import ssd_chunk  # noqa: F401  (the tests import it here)
 from ..ops.paged_attention import (paged_attn, rotary, row_to_blocks,
                                    write_rows)
 from ..tensor import Tensor
 from .served import ServedFamily, seg_cat, seg_split, seg_tokens
 
-HI = jax.lax.Precision.HIGHEST      # the state path: float32 throughout
 #: a layer's per-channel vectors (float32 whatever ``cfg.dtype``) and
 #: its matrices (``cfg.dtype``)
 _VECTORS = ("ln1", "ln2", "conv_w", "conv_b", "dt_bias", "a_log", "d",
@@ -184,16 +185,6 @@ def _mixer_inputs(h, p, c):
     return zxbcdt[:, :ds], zxbcdt[:, ds:ds + cd], zxbcdt[:, ds + cd:]
 
 
-def _split_xbc(xbc, c):
-    """Conv output (T, conv_dim) -> x (T, h, p), B and C (T, g, n)."""
-    t = xbc.shape[0]
-    ds, gn = c.mamba_d_ssm, c.mamba_n_groups * c.mamba_d_state
-    x = xbc[:, :ds].reshape(t, c.mamba_n_heads, c.mamba_d_head)
-    b = xbc[:, ds:ds + gn].reshape(t, c.mamba_n_groups, c.mamba_d_state)
-    cc = xbc[:, ds + gn:].reshape(t, c.mamba_n_groups, c.mamba_d_state)
-    return x, b, cc
-
-
 def _mixer_out(y, z, p, c):
     """Gate, grouped RMSNorm and the out-projection: y, z (T, d_ssm)."""
     t, g = y.shape[0], c.mamba_n_groups
@@ -205,76 +196,12 @@ def _mixer_out(y, z, p, c):
                                    @ p["w_out"])
 
 
-def ssd_chunk(x, b, cc, dt, a, s_in):
-    """One chunk of the Mamba-2 scan in its matrix ("state-space dual")
-    form: x (T, h, p), b and cc (T, g, n), dt (T, h) after softplus (0
-    where a token must leave the state as it is), a (h,) negative, s_in
-    (h, p, n) the state before the chunk.  Returns (y (T, h, p) without
-    the D term, the state after the chunk).  Equal to T steps of
-    ``S <- exp(dt a) S + dt x (x) B;  y = S C``."""
-    t, h, p = x.shape
-    g = b.shape[1]
-    k = h // g                                   # heads a group
-    la = jnp.cumsum(dt * a, axis=0)              # (T, h), decreasing
-    # within the chunk: y_t += sum_{s<=t} (C_t.B_s) e^{la_t-la_s} dt_s x_s
-    cb = jnp.einsum("tgn,sgn->gts", cc, b, precision=HI)
-    dec = jnp.exp(jnp.where(jnp.tril(jnp.ones((t, t), bool))[None],
-                            la.T[:, :, None] - la.T[:, None, :],
-                            -jnp.inf))           # (h, T, T)
-    m = cb[:, None].repeat(k, 1).reshape(h, t, t) * dec * dt.T[:, None, :]
-    y = jnp.einsum("hts,shp->thp", m, x, precision=HI)
-    # from the state the chunk started with
-    sg = s_in.reshape(g, k, p, -1)
-    y_in = jnp.einsum("tgn,gkpn->tgkp", cc, sg, precision=HI)
-    y = y + y_in.reshape(t, h, p) * jnp.exp(la)[:, :, None]
-    # the state after the chunk
-    w = (dt * jnp.exp(la[-1][None] - la))[:, :, None] * x     # (T, h, p)
-    s_new = jnp.einsum("sgkp,sgn->gkpn", w.reshape(t, g, k, p), b,
-                       precision=HI).reshape(h, p, -1)
-    return y, jnp.exp(la[-1])[:, None, None] * s_in + s_new
-
-
-def _mamba_mix(xbc, dt, p, c, ssm, conv, n_valid, sub=None):
-    """What of the mixer runs along ONE sequence: ``xbc`` (T, conv_dim)
-    and ``dt`` (T, heads) of :func:`_mixer_inputs`, ``ssm`` (h, p, n) and
-    ``conv`` (d_conv - 1, conv_dim) the state the row before left,
-    ``n_valid`` how many of the T tokens are real (the prompt's last row
-    is padded; padding leaves the state alone).  The conv takes the T
-    rows together; the scan walks them ``sub`` at a time in order
-    (default: all T as one chunk), each sub-chunk from the state the one
-    before left, so a row of several scan chunks computes what as many
-    rows of one did.  Returns (y (T, d_ssm) before gate and norm, ssm,
-    conv)."""
-    t, kk = xbc.shape[0], c.mamba_d_conv
-    ext = jnp.concatenate([conv, xbc], axis=0)            # (T+K-1, C)
-    xbc = jax.nn.silu(sum(p["conv_w"][j] * ext[j:j + t] for j in range(kk))
-                      + p["conv_b"])
-    conv = jax.lax.dynamic_slice_in_dim(ext, n_valid, kk - 1, axis=0)
-    x, b, cc = _split_xbc(xbc, c)
-    dt = jax.nn.softplus(dt + p["dt_bias"])
-    dt = jnp.where(jnp.arange(t)[:, None] < n_valid, dt, 0.0)
-    with jax.named_scope("ssm_scan"):
-        if sub is None or sub == t:
-            y, ssm = ssd_chunk(x, b, cc, dt, -jnp.exp(p["a_log"]), ssm)
-        else:
-            # unrolled (a ``lax.scan`` of two iterations cost more than
-            # it ran: PERF.md §6, PR 36)
-            ys = []
-            for j in range(0, t, sub):
-                y_j, ssm = ssd_chunk(
-                    x[j:j + sub], b[j:j + sub], cc[j:j + sub],
-                    dt[j:j + sub], -jnp.exp(p["a_log"]), ssm)
-                ys.append(y_j)
-            y = jnp.concatenate(ys)
-    return (y + p["d"][:, None] * x).reshape(t, -1), ssm, conv
-
-
 def _mamba_chunk(h, p, c, ssm, conv, n_valid, sub=None):
     """The mixer over a chunk row of ONE sequence: h (T, E) normalised
     input; the projections and the norm either side of
     :func:`_mamba_mix`.  Returns (m (T, E), ssm, conv)."""
     z, xbc, dt = _mixer_inputs(h, p, c)
-    y, ssm, conv = _mamba_mix(xbc, dt, p, c, ssm, conv, n_valid, sub)
+    y, ssm, conv = mamba2.mix(xbc, dt, p, ssm, conv, n_valid, sub)
     return _mixer_out(y, z, p, c), ssm, conv
 
 
@@ -284,38 +211,9 @@ def _mamba_step(h, p, c, ssm_all, conv_all, li, slots):
     p, n) and ``conv_all`` (L, S+1, d_conv - 1, conv_dim), read,
     advanced one step and written back.  Returns (m (W, E), ssm_all,
     conv_all)."""
-    w, g = h.shape[0], c.mamba_n_groups
-    conv = conv_all[li, slots]
     z, xbc, dt = _mixer_inputs(h, p, c)
-    ext = jnp.concatenate([conv, xbc[:, None]], axis=1)   # (W, K, C)
-    xbc = jax.nn.silu(jnp.einsum("kc,wkc->wc", p["conv_w"], ext,
-                                 precision=HI) + p["conv_b"])
-    x, b, cc = _split_xbc(xbc, c)           # (W, h, p), (W, g, n) x 2
-    k = c.mamba_n_heads // g
-    dt = jax.nn.softplus(dt + p["dt_bias"])               # (W, h)
-    da = jnp.exp(dt * -jnp.exp(p["a_log"]))
-    bh = jnp.repeat(b, k, axis=1)                         # (W, h, n)
-    ch = jnp.repeat(cc, k, axis=1)
-    dx = dt[:, :, None] * x                               # (W, h, p)
-    row = (1, 1) + ssm_all.shape[2:]
-
-    def lane(i, carry):
-        # one lane's state read, advanced and written back where it
-        # lies: 4 MB in, 4 MB out.  (A gather of the lanes' rows makes
-        # the compiler slice the WHOLE arena first, every layer.)
-        arena, y = carry
-        at = (li, slots[i], 0, 0, 0)
-        s = jax.lax.dynamic_slice(arena, at, row)[0, 0]
-        s = da[i][:, None, None] * s + dx[i][..., None] * bh[i][:, None, :]
-        y_i = jnp.einsum("hpn,hn->hp", s, ch[i], precision=HI)
-        return (jax.lax.dynamic_update_slice(arena, s[None, None], at),
-                jax.lax.dynamic_update_slice(y, y_i[None], (i, 0, 0)))
-
-    with jax.named_scope("ssm_step"):
-        ssm_all, y = jax.lax.fori_loop(0, w, lane,
-                                       (ssm_all, jnp.zeros_like(x)))
-    conv_all = conv_all.at[li, slots].set(ext[:, 1:])
-    y = (y + p["d"][:, None] * x).reshape(w, -1)
+    y, ssm_all, conv_all = mamba2.step(xbc, dt, p, ssm_all, conv_all,
+                                       lambda slot: (li, slot), slots)
     return _mixer_out(y, z, p, c), ssm_all, conv_all
 
 
@@ -469,7 +367,7 @@ class FalconH1Family(ServedFamily):
             with jax.named_scope("ssm_proj"):
                 z, xbc, dt = _mixer_inputs(h, p, c)
                 y, ssm, conv = zip(*(
-                    _mamba_mix(xbc_s, dt_s, p, c, ssm_s, conv_s, s.n_valid,
+                    mamba2.mix(xbc_s, dt_s, p, ssm_s, conv_s, s.n_valid,
                                sub=block)
                     for s, xbc_s, dt_s, ssm_s, conv_s in zip(
                         segs, seg_split(xbc, segs), seg_split(dt, segs),

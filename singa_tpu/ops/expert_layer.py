@@ -24,6 +24,13 @@ in a deployment, where every expert is chosen by some chip's tokens
 moved a serve cell's rate by 3% between seeds; PERF.md, PR 34).  The
 loop's trip count is traced, so one compiled program serves any
 routing.
+
+What ONE expert computes on a tile of rows is the caller's to hand in
+(``held_terms(body=)``): :func:`swiglu` over a gate-and-up stack and a
+down stack unless told (three families), :func:`relu2` over an up stack
+and a down stack for experts of two matrices (``models/ssm_moe.py``,
+whose experts work in a latent: the projections into and out of it, and
+its shared expert, sit either side of the call).
 """
 
 import jax
@@ -69,8 +76,18 @@ def swiglu(x, w_gu, w_down):
                    preferred_element_type=jnp.float32)
 
 
+def relu2(x, w_up, w_down):
+    """``W_down relu(x W_up)^2``: an expert of two matrices, ``w_up`` (E,
+    I) and ``w_down`` (I, E); accumulated in float32, returned
+    float32."""
+    h = jnp.square(jax.nn.relu(
+        jnp.dot(x, w_up, preferred_element_type=jnp.float32)))
+    return jnp.dot(h.astype(x.dtype), w_down,
+                   preferred_element_type=jnp.float32)
+
+
 def held_terms(x, idx, weights, w_gu, w_down, first, valid=None,
-               layer=None, tile=16):
+               layer=None, tile=16, body=swiglu):
     """The held experts' part of the layer: ``sum_k weights[t, k] *
     E_idx[t,k](x[t])`` over the choices that fall in ``[first, first +
     n_held)``.
@@ -80,10 +97,13 @@ def held_terms(x, idx, weights, w_gu, w_down, first, valid=None,
     experts' matrices -- or stacks of them ``(L, n_held, ...)`` with
     ``layer`` the (traced) layer to use, so that a scan over layers
     slices one expert's matrices straight out of the stack; ``valid``
-    (T,) bool: tokens that are padding choose nothing.  Returns ``(y (T,
-    E) float32, counts (n_held + 1,) int32)``: the assignments each held
-    expert received, and last those that went to experts held
-    elsewhere."""
+    (T,) bool: tokens that are padding choose nothing.  ``body(rows, w_a,
+    w_b)`` is ONE expert over a tile of rows, (tile, E) -> (tile, E)
+    float32, on that expert's slice of the two stacks: :func:`swiglu`
+    unless the caller hands in another (:func:`relu2`, over ``(n_held, E,
+    I)`` and ``(n_held, I, E)``).  Returns ``(y (T, E) float32, counts
+    (n_held + 1,) int32)``: the assignments each held expert received,
+    and last those that went to experts held elsewhere."""
     t, k = idx.shape
     e_dim = x.shape[-1]
     n_held = w_gu.shape[-3]
@@ -117,7 +137,7 @@ def held_terms(x, idx, weights, w_gu, w_down, first, valid=None,
     def one_tile(i, ys):
         e, r0 = t_exp[i], t_row[i]
         xt = jax.lax.dynamic_slice(rows, (r0, 0), (tile, e_dim))
-        y = swiglu(xt, at(w_gu, e), at(w_down, e))
+        y = body(xt, at(w_gu, e), at(w_down, e))
         mine = (r0 + jnp.arange(tile) < t_end[i])[:, None]
         old = jax.lax.dynamic_slice(ys, (r0, 0), (tile, e_dim))
         return jax.lax.dynamic_update_slice(

@@ -69,13 +69,17 @@ def shapes(one_chip):
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _big_copies(text, floor=50e6):
+def _big_copies(text, floor=50e6, hbm_only=False):
+    """Dims of the copies of more than ``floor`` elements in a compiled
+    program's text; ``hbm_only``: without the compiler's own moves of an
+    operand into its fast memory (the result's layout says ``S(1)``)."""
     out = []
-    for dims in re.findall(r"= \w+\[([\d,]+)\][^\n]* copy\(", text):
+    for dims, layout in re.findall(
+            r"= \w+\[([\d,]+)\](\{[^}]*\})?[^\n]* copy\(", text):
         n = 1
         for d in dims.split(","):
             n *= int(d)
-        if n > floor:
+        if n > floor and not (hbm_only and "S(" in layout):
             out.append(dims)
     return out
 
@@ -748,3 +752,121 @@ def test_the_conv_family_chunk_row_programs_compile(conv, blocks):
     paged._keep_scopes(f"conv_chunk{blocks}", fam.scopes, comp.as_text())
     assert {"short_conv", "attn_full", "moe_experts"} <= set(
         paged.program_scopes()[f"conv_chunk{blocks}"].values())
+
+
+# ---- ssm_moe (Nemotron-3-Super-120B-A12B): the benchmark's eleven layers,
+# ---- real widths
+
+SM_SLOTS, SM_BLOCKS, SM_WIDTH = 128, 2048, 9216
+
+
+@pytest.fixture(scope="module")
+def ssm(shapes):
+    """(cfg, family, abstract params, sds) of the one-mixer-a-layer
+    family as the benchmark cuts it: one period ``MEMEMEMEM*E``, experts
+    [0, 128) of the router's 512 in each of the five expert layers, a
+    quarter of the vocabulary (9.3 GB of arguments)."""
+    from singa_tpu.models.ssm_moe import (MATRICES, VECTORS, SsmMoeConfig,
+                                          SsmMoeFamily)
+
+    sds = shapes[3]
+    cfg = SsmMoeConfig(
+        num_hidden_layers=11, hybrid_override_pattern="MEMEMEMEM*E",
+        vocab_size=32768, experts_held=(0, 128), max_len=SM_WIDTH,
+        dtype="bfloat16")
+    sh = cfg.shapes("model")
+    params = dict(wte=sds(sh["wte"]), head=sds(sh["head"]),
+                  lnf=sds(sh["lnf"], jnp.float32))
+    for stack, n in cfg.stack_sizes().items():
+        params[stack] = {
+            k: sds((n,) + cfg.shapes(stack)[k],
+                   jnp.float32 if k in VECTORS[stack] else jnp.bfloat16)
+            for k in VECTORS[stack] + MATRICES[stack]}
+    return cfg, SsmMoeFamily(cfg), params, sds
+
+
+def _ssm_state(sds, lead):
+    """The two state arenas (or a request's carried state) under the
+    leading axes ``lead``: five Mamba layers' state (128 x 64 x 128) and
+    conv tails (4 x 10,240: a row more than the conv reads, see below),
+    float32."""
+    return {"ssm": sds(lead + (5, 128, 64, 128), jnp.float32),
+            "conv": sds(lead + (5, 4, 10240), jnp.float32)}
+
+
+def test_the_128_lane_decode_program_updates_pool_and_state_in_place(
+        ssm, on_a_tpu):
+    """The decode program as the chip runs it, at the benchmark's 128
+    lanes: the ONE attention layer's pool (2 x 134 MB) and the five Mamba
+    layers' state arenas (2.7 GB + 106 MB) are aliased and neither is
+    copied or re-laid -- two arenas of 2.8 GB copied once would not fit
+    beside 9.3 GB of weights -- no expert stack (1.8 G elements) or
+    mixer's matrix is copied, the attention is the Pallas kernel under
+    the family's scope, and what the program keeps beside its 12.4 GB of
+    arguments stays under 0.5 GB (the chip has 16).  (With the conv's
+    tail kept as the 3 rows the conv reads, the arena's second-minor axis
+    was tiled by ones at the program's boundary and by fours inside it,
+    and the compiler re-laid all of it on the way in and on the way out
+    of every step: ops/mamba2.step.)"""
+    from singa_tpu.serve import paged
+
+    cfg, fam, params, sds = ssm
+    n = SM_SLOTS
+    pool = sds((1, SM_BLOCKS + 1, BLOCK, 256))
+    comp = paged._paged_decode_kernel.lower(
+        params, pool, pool, *_lanes(sds, n, SM_WIDTH // BLOCK), None,
+        _ssm_state(sds, (1, n + 1)), sds((n,), jnp.int32), block=BLOCK,
+        n_head=32, eps=1e-5, moe_top_k=2, top_k=0, use_top_p=False,
+        window=None, fam=fam).compile()
+    ma, text = comp.memory_analysis(), comp.as_text()
+    cache = 2 * 2 * (SM_BLOCKS + 1) * BLOCK * 256 \
+        + 4 * (n + 1) * 5 * (128 * 64 * 128 + 4 * 10240)
+    print(f"ssm_moe decode: temporaries {ma.temp_size_in_bytes}, aliased "
+          f"{ma.alias_size_in_bytes}, arguments "
+          f"{ma.argument_size_in_bytes}")
+    assert cache <= ma.alias_size_in_bytes < 1.01 * cache
+    # the smallest of: a latent projection (4.2 M elements), the conv
+    # arena (26.4 M), a pool (67 M), a mixer's in-projection (76 M), the
+    # state arena (676 M), an expert stack (1.8 G).  (The one attention
+    # layer's W_o, 16.8 M, goes to the compiler's fast memory in every
+    # program of this family: not a copy in the chip's memory.)
+    assert _big_copies(text, floor=4.2e6, hbm_only=True) == []
+    assert ma.temp_size_in_bytes < 0.5e9
+    assert ma.argument_size_in_bytes > 12.3e9
+    calls = re.findall(r"%(paged_decode_attn[\w.\-]*) = ", text)
+    assert calls, "the decode program holds no kernel"
+    paged._keep_scopes("ssm_decode", fam.scopes, text)
+    found = paged.program_scopes()["ssm_decode"]
+    assert {v for k, v in found.items()
+            if k.split(" ")[0] in calls} == {"attn_full"}
+    assert {"ssm_step", "ssm_proj", "attn_full", "moe_route", "moe_latent",
+            "moe_experts", "head"} <= set(found.values())
+
+
+@pytest.mark.parametrize("blocks", [1, 2, 3, 4, "pair"])
+def test_the_ssm_family_chunk_row_programs_compile(ssm, blocks):
+    """A launch of one block, of two, three and the budget's four, and of
+    two requests in slots of two blocks each: nothing the size of an
+    expert stack, of a mixer's in-projection (76 M elements) or of a
+    request's carried state (5.2 M) is copied, and the temporaries stay
+    under 0.5 GB."""
+    from singa_tpu.serve import engine, paged
+
+    cfg, fam, params, sds = ssm
+    row = sds((1, 1, 2, SM_WIDTH, 128))
+    args = (sds((1, SM_WIDTH), jnp.int32), row, row,
+            sds((2 if blocks == "pair" else blocks,)
+                if blocks != 1 else (), jnp.int32),
+            _ssm_state(sds, (1,)), sds((), jnp.int32))
+    if blocks == "pair":
+        args = tuple((a, a) for a in args)
+    comp = engine._chunk_row.lower(
+        params, *args, n_head=32, eps=1e-5, moe_top_k=2, chunk=BLOCK,
+        window=None, fam=fam).compile()
+    ma = comp.memory_analysis()
+    print(f"ssm_moe chunk {blocks}: temporaries {ma.temp_size_in_bytes}")
+    assert ma.temp_size_in_bytes < 0.5e9
+    assert _big_copies(comp.as_text(), floor=5.3e6, hbm_only=True) == []
+    paged._keep_scopes(f"ssm_chunk{blocks}", fam.scopes, comp.as_text())
+    assert {"ssm_scan", "attn_full", "moe_experts", "moe_latent"} <= set(
+        paged.program_scopes()[f"ssm_chunk{blocks}"].values())
