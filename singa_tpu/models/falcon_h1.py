@@ -209,8 +209,10 @@ def _mamba_step(h, p, c, ssm_all, conv_all, li, slots):
     """The mixer one token a lane: h (W, E); lane w's state is row
     ``slots[w]`` of layer ``li`` of the arenas ``ssm_all`` (L, S+1, h,
     p, n) and ``conv_all`` (L, S+1, d_conv - 1, conv_dim), read,
-    advanced one step and written back.  Returns (m (W, E), ssm_all,
-    conv_all)."""
+    advanced one step and written back (``ops/mamba2.step``: the SSM
+    state of all lanes in one Pallas call on a TPU, ``li`` -- the layer
+    scan's counter -- among its prefetched indices; a lane at a time
+    anywhere else).  Returns (m (W, E), ssm_all, conv_all)."""
     z, xbc, dt = _mixer_inputs(h, p, c)
     y, ssm_all, conv_all = mamba2.step(xbc, dt, p, ssm_all, conv_all,
                                        lambda slot: (li, slot), slots)
@@ -308,6 +310,9 @@ class FalconH1Family(ServedFamily):
                          cfg.mamba_d_state), jnp.float32),
                 "conv": ((cfg.mamba_d_conv - 1, cfg.conv_dim),
                          jnp.float32)}
+
+    def state_step_impl(self, state):
+        return mamba2.step_impl(state["ssm"])
 
     def logits(self, params, hidden):
         with jax.named_scope("head"):

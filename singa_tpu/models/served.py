@@ -126,6 +126,14 @@ class ServedFamily:
         """{kind: (shape per layer per slot, dtype)}; {} = K/V only."""
         return {}
 
+    def state_step_impl(self, state):
+        """``"kernel"`` or ``"loop"``: what :meth:`decode_step` advances
+        the arenas ``state`` with, where it has two ways and a rule that
+        chooses by the operands (``ops/mamba2.step_impl``); None where
+        it has not.  The engine asks once, of its own arenas, and says
+        the answer on its decode spans."""
+        return None
+
     def window(self, cfg):
         """Sliding-window width of the attention, or None."""
         return None

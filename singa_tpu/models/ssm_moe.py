@@ -479,6 +479,9 @@ class SsmMoeFamily(ServedFamily):
                 "conv": ((cfg.state_rows, cfg.conv_kernel, cfg.conv_dim),
                          f32)}
 
+    def state_step_impl(self, state):
+        return mamba2.step_impl(state["ssm"])
+
     def logits(self, params, hidden):
         with jax.named_scope("head"):
             return _logits(params, hidden)
@@ -604,7 +607,9 @@ class SsmMoeFamily(ServedFamily):
         over its live blocks of the pool plus its own new key, the new
         K/V row written straight into the pool.  A Mamba layer: each
         lane's state read from the arenas at its slot, advanced one step
-        and written back (dead lanes: the trash row).  Pool and arenas
+        and written back (dead lanes: the trash row; ``ops/mamba2.step``:
+        all lanes in one Pallas call on a TPU, a lane at a time anywhere
+        else).  Pool and arenas
         are carried through the walk and updated in place.  Returns the
         expert layers' counts of the step after the contract's four."""
         c = self.cfg

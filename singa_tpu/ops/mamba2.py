@@ -6,7 +6,13 @@ a sequence -- the depthwise causal convolution over ``x | B | C`` and
 -- in its two forms: a chunked scan in the matrix ("state-space dual")
 form for a prefill launch (:func:`mix`, over :func:`ssd_chunk`), and one
 step a lane for a decode step (:func:`step`), each lane's state read,
-advanced and written back where it lies in the engine's state arena.
+advanced and written back where it lies in the engine's state arena:
+by ONE Pallas call over all the lanes' rows (ops/pallas/mamba2_step.py)
+where :func:`step_impl` -- a pure function of the arena -- says so, a
+float32 arena of whole tiles in an unsharded program on a TPU; by a loop
+a lane at a time (:func:`_step_loop`) everywhere else, every CPU run
+among them, which is also what the kernel is held to
+(tests/test_mamba2_step_kernel.py).
 Every served family with such a mixer calls these (``models/falcon_h1.py``:
 a mixer beside attention in every layer; ``models/ssm_moe.py``: layers
 that are a mixer and nothing else); the projections, multipliers, gate
@@ -23,6 +29,9 @@ throughout.
 
 import jax
 import jax.numpy as jnp
+
+from .paged_attention import _varies
+from .pallas import mamba2_step as _pallas
 
 HI = jax.lax.Precision.HIGHEST      # the state path: float32 throughout
 
@@ -106,6 +115,54 @@ def mix(xbc, dt, p, ssm, conv, n_valid, sub=None):
     return (y + p["d"][:, None] * x).reshape(t, -1), ssm, conv
 
 
+def step_impl(ssm_all, backend=None):
+    """``"kernel"`` or ``"loop"``: which of the two :func:`step` runs for
+    this state arena -- an array, a tracer or a ``jax.ShapeDtypeStruct``,
+    only its shape, dtype and placement are read.
+
+    The kernel (ops/pallas/mamba2_step.py: every lane's state advanced
+    in one call, the arena aliased) takes an unsharded float32 arena
+    whose rows fill whole tiles -- ``n`` a multiple of 128, ``p`` of 8 --
+    on a TPU.  The loop keeps any other backend (every CPU run), an
+    arena that varies over a mesh axis, any other shape or dtype.
+    ``backend`` defaults to ``jax.default_backend()``."""
+    if backend is None:
+        backend = jax.default_backend()
+    p, n = ssm_all.shape[-2:]
+    tiles = n % 128 == 0 and p % 8 == 0
+    return ("kernel" if backend == "tpu" and tiles
+            and ssm_all.dtype == jnp.float32 and not _varies(ssm_all)
+            else "loop")
+
+
+def _step_loop(ssm_all, at_lane, da, dx, b, cc):
+    """:func:`step`'s recurrence a lane at a time: lane i's state sliced
+    out of the arena at ``at_lane(i)``, advanced and written back --
+    serial, each iteration paying its own read and its own write.  What
+    every backend but a TPU runs, and what the kernel is held to."""
+    w, shape = da.shape[0], ssm_all.shape[-3:]
+    k = shape[0] // b.shape[1]
+    bh = jnp.repeat(b, k, axis=1)                         # (W, h, n)
+    ch = jnp.repeat(cc, k, axis=1)
+    n_lead = ssm_all.ndim - 3
+    row = (1,) * n_lead + shape
+
+    def lane(i, carry):
+        # one lane's state read, advanced and written back where it
+        # lies: 4 MB in, 4 MB out.  (A gather of the lanes' rows makes
+        # the compiler slice the WHOLE arena first, every layer.)
+        arena, y = carry
+        at = tuple(at_lane(i)) + (0, 0, 0)
+        s = jax.lax.dynamic_slice(arena, at, row)[(0,) * n_lead]
+        s = da[i][:, None, None] * s + dx[i][..., None] * bh[i][:, None, :]
+        y_i = jnp.einsum("hpn,hn->hp", s, ch[i], precision=HI)
+        return (jax.lax.dynamic_update_slice(arena, s[(None,) * n_lead],
+                                             at),
+                jax.lax.dynamic_update_slice(y, y_i[None], (i, 0, 0)))
+
+    return jax.lax.fori_loop(0, w, lane, (ssm_all, jnp.zeros_like(dx)))
+
+
 def step(xbc, dt, p, ssm_all, conv_all, lead, slots):
     """The recurrence one token a lane: ``xbc`` (W, width) and ``dt`` (W,
     h) as in :func:`mix`; lane w's state is the ``(h, p, n)`` and ``(R,
@@ -118,6 +175,14 @@ def step(xbc, dt, p, ssm_all, conv_all, lead, slots):
     and by fours inside it, and the compiler then re-lays the WHOLE
     arena on the way in and on the way out of every step:
     tests/test_tpu_compile.py).
+
+    The SSM state's step runs as :func:`step_impl` says, by the arena
+    alone and never by a flag: ONE Pallas call over all W lanes
+    (ops/pallas/mamba2_step.py: the lanes' rows of the arena streamed
+    through the chip's fast memory, a lane's fetched while the one
+    before is advanced and the one before that written back), or the
+    loop a lane at a time (:func:`_step_loop`).  The two agree up to
+    float32 reordering (tests/test_mamba2_step_kernel.py).
     Returns (y (W, h p) before gate and norm, ssm_all, conv_all)."""
     w = xbc.shape[0]
     shape = ssm_all.shape[-3:]
@@ -128,30 +193,16 @@ def step(xbc, dt, p, ssm_all, conv_all, lead, slots):
                                  ext[:, lo:] if lo else ext,
                                  precision=HI) + p["conv_b"])
     x, b, cc = split_xbc(xbc, shape)        # (W, h, p), (W, g, n) x 2
-    k = shape[0] // b.shape[1]
     dt = jax.nn.softplus(dt + p["dt_bias"])               # (W, h)
     da = jnp.exp(dt * -jnp.exp(p["a_log"]))
-    bh = jnp.repeat(b, k, axis=1)                         # (W, h, n)
-    ch = jnp.repeat(cc, k, axis=1)
     dx = dt[:, :, None] * x                               # (W, h, p)
-    n_lead = ssm_all.ndim - 3
-    row = (1,) * n_lead + shape
-
-    def lane(i, carry):
-        # one lane's state read, advanced and written back where it
-        # lies: 4 MB in, 4 MB out.  (A gather of the lanes' rows makes
-        # the compiler slice the WHOLE arena first, every layer.)
-        arena, y = carry
-        at = tuple(lead(slots[i])) + (0, 0, 0)
-        s = jax.lax.dynamic_slice(arena, at, row)[(0,) * n_lead]
-        s = da[i][:, None, None] * s + dx[i][..., None] * bh[i][:, None, :]
-        y_i = jnp.einsum("hpn,hn->hp", s, ch[i], precision=HI)
-        return (jax.lax.dynamic_update_slice(arena, s[(None,) * n_lead],
-                                             at),
-                jax.lax.dynamic_update_slice(y, y_i[None], (i, 0, 0)))
-
     with jax.named_scope("ssm_step"):
-        ssm_all, y = jax.lax.fori_loop(0, w, lane,
-                                       (ssm_all, jnp.zeros_like(x)))
+        if step_impl(ssm_all) == "kernel":
+            idx = jnp.stack([jnp.broadcast_to(a, slots.shape)
+                             for a in lead(slots)])
+            ssm_all, y = _pallas.mamba2_step(ssm_all, idx, da, dx, b, cc)
+        else:
+            ssm_all, y = _step_loop(ssm_all, lambda i: lead(slots[i]), da,
+                                    dx, b, cc)
     conv_all = conv_all.at[lead(slots)].set(ext[:, 1:])
     return (y + p["d"][:, None] * x).reshape(w, -1), ssm_all, conv_all

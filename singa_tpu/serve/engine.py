@@ -1194,11 +1194,19 @@ class InferenceEngine:
         # pool), and saved and restored with the slot's blocks
         self._state_spec = fam.state_spec(cfg)
         self._state = None
+        self._decode_state = None   # "kernel" | "loop" (a stepped state)
         if self._state_spec:
             self._state = {
                 k: jnp.zeros((L, S + 1) + tuple(shape), dt,
                              device=self._state_sh)
                 for k, (shape, dt) in self._state_spec.items()}
+            # what this engine's decode dispatches advance it with: the
+            # family's rule, on the arenas themselves
+            self._decode_state = fam.state_step_impl(self._state)
+        # ... as the serve.decode / serve.step spans say them
+        self._decode_impls = {
+            k: v for k, v in (("attn", self._decode_attn),
+                              ("state", self._decode_state)) if v}
         # draft-side state (speculative decoding): its own params and
         # its own (cheap) KV arena, advanced in lockstep by the spec
         # pool step
@@ -1879,8 +1887,7 @@ class InferenceEngine:
                    state_slots=(self.live_slots + len(self._prefilling)
                                 if self._state_spec else 0),
                    **(self._step_counts if width else {}),
-                   **({"attn": self._decode_attn}
-                      if width and self._decode_attn else {}))
+                   **(self._decode_impls if width else {}))
         if not pending and _monitor.active():
             # drained: refresh liveness but DISARM hang detection —
             # an idle engine between traffic bursts is not a wedged
@@ -2116,8 +2123,7 @@ class InferenceEngine:
             n_live, width, toks, a_draft, lps = \
                 self._collect_step(*flight)
             ph.set(live=n_live, width=width)
-            if self._decode_attn is not None:
-                ph.set(attn=self._decode_attn)
+            ph.set(**self._decode_impls)
         if _monitor.active():
             # watchdog heartbeat after the pool step, fed from the step
             # log's own stamps and no clock of its own: the step's start
@@ -2127,7 +2133,8 @@ class InferenceEngine:
                 self._hb_source, step_time=ph.step_elapsed(),
                 fresh_compile=self.stats.decode_steps == 0)
         self.stats.on_decode_step(
-            n_live, attn_kernel=self._decode_attn == "kernel")
+            n_live, attn_kernel=self._decode_attn == "kernel",
+            state_kernel=self._decode_state == "kernel")
         # serve.emit: the emit loop — clients' on_token callbacks,
         # retires and ledger hooks included
         with _trace.phase("serve.emit", cat="serve") as ph:

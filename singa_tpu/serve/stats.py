@@ -106,6 +106,12 @@ class EngineStats:
             help="pool decode steps whose attention over the pool ran "
                  "the Pallas kernel, not the block loop "
                  "(ops/paged_attention.decode_attn_impl)", **lbl)
+        self._state_kernel_steps = reg.counter(
+            "serve.decode.state_kernel_steps",
+            help="pool decode steps whose recurrent state was advanced "
+                 "by the Pallas kernel, every lane in one call a layer, "
+                 "not the loop a lane at a time "
+                 "(ops/mamba2.step_impl)", **lbl)
         self._tokens_out = reg.counter(
             "serve.tokens_out", help="tokens emitted", **lbl)
         self._h_ttft = reg.histogram(
@@ -144,6 +150,7 @@ class EngineStats:
             self._submitted, self._completed, self._rej_deadline,
             self._rej_queue, self._prefills, self._prefill_tokens,
             self._decode_steps, self._attn_kernel_steps,
+            self._state_kernel_steps,
             self._tokens_out, self._queue_depth, self._occupancy,
             self._h_ttft, self._h_tpot, self._h_queue_wait,
             self._h_admission["cold"], self._h_admission["warm"],
@@ -247,6 +254,10 @@ class EngineStats:
         return self._attn_kernel_steps.value
 
     @property
+    def state_kernel_steps(self):
+        return self._state_kernel_steps.value
+
+    @property
     def tokens_out(self):
         return self._tokens_out.value
 
@@ -290,10 +301,13 @@ class EngineStats:
         self._spec_drafted.inc(int(drafted))
         self._spec_chunks.inc()
 
-    def on_decode_step(self, live_slots: int, attn_kernel=False):
+    def on_decode_step(self, live_slots: int, attn_kernel=False,
+                       state_kernel=False):
         self._decode_steps.inc()
         if attn_kernel:
             self._attn_kernel_steps.inc()
+        if state_kernel:
+            self._state_kernel_steps.inc()
         occ = live_slots / self.max_slots
         self._occupancy_sum += occ
         self._occupancy.set(occ)

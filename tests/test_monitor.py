@@ -552,7 +552,7 @@ def test_slo_counters_unregister_with_the_engine():
     reg = MetricsRegistry()
     st = EngineStats(2, FakeClock(), reg=reg,
                      slo=SLO(ttft_p99_s=1.0))
-    assert len(reg.metrics()) == 19  # 16 base + 3 slo kinds
+    assert len(reg.metrics()) == 20  # 17 base + 3 slo kinds
     st.unregister()
     assert len(reg.metrics()) == 0
 

@@ -392,12 +392,12 @@ def test_engine_stats_unregister_releases_metrics():
     a = EngineStats(2, FakeClock(), reg=reg)
     b = EngineStats(2, FakeClock(), reg=reg)
     a.on_submit()
-    assert len(reg.metrics()) == 32  # 16 per engine (incl. the
+    assert len(reg.metrics()) == 34  # 17 per engine (incl. the
     #   queue-wait + cold/warm admission request-phase histograms,
-    #   the prefill-token counter and the kernel-step counter)
+    #   the prefill-token counter and the two kernel-step counters)
     a.unregister()
     remaining = reg.metrics()
-    assert len(remaining) == 16
+    assert len(remaining) == 17
     assert all(("engine", b.engine_label) in m.labels
                for m in remaining)
     # a fully-removed NAME frees its kind reservation
@@ -872,6 +872,76 @@ def test_decode_steps_say_which_attention_they_ran(tiny_model, engine):
         assert eng.stats.attn_kernel_steps == 0
         name = "serve.decode.attn_kernel_steps"
         assert any(m.name == name for m in eng.stats._registered)
+    finally:
+        eng.close()
+
+
+def _tiny_hybrid():
+    """A Mamba-2 mixer beside attention in each of two layers
+    (models/falcon_h1.py), widths of a few tens."""
+    import numpy as np
+
+    from singa_tpu import tensor
+    from singa_tpu.models.falcon_h1 import FalconH1Config, FalconH1LMHead
+
+    m = FalconH1LMHead(FalconH1Config(
+        vocab_size=256, hidden_size=32, num_hidden_layers=2,
+        num_attention_heads=2, num_key_value_heads=1, head_dim=16,
+        intermediate_size=64, mamba_d_ssm=32, mamba_d_state=16,
+        mamba_n_groups=1, mamba_n_heads=2, mamba_d_head=16,
+        mamba_chunk_size=8, max_len=64))
+    m.compile([tensor.from_numpy(np.zeros((1, 16), np.int32))],
+              is_train=False, use_graph=False)
+    return m
+
+
+@pytest.mark.parametrize("model", ["a recurrent state", "K/V only",
+                                   "the counter's hook"])
+def test_decode_steps_say_what_advanced_the_state(tiny_model, model):
+    """``serve.decode.state_kernel_steps`` counts the decode dispatches
+    whose recurrent state the Pallas kernel advanced (every lane in one
+    call a layer), beside ``serve.decode_steps``, and the ``serve.decode``
+    and ``serve.step`` spans say ``state="kernel"`` or ``"loop"``: the
+    loop here (no TPU), by the rule the program itself dispatches on
+    (``ops/mamba2.step_impl`` on the engine's own arena); an engine
+    whose family steps no such state makes no claim."""
+    import numpy as np
+
+    from singa_tpu.serve import GenerationRequest, PagedConfig
+
+    if model == "the counter's hook":
+        from singa_tpu.serve.stats import EngineStats
+
+        stats = EngineStats(2, FakeClock(), reg=MetricsRegistry())
+        stats.on_decode_step(1)
+        stats.on_decode_step(2, state_kernel=True)
+        assert (stats.decode_steps, stats.state_kernel_steps,
+                stats.attn_kernel_steps) == (2, 1, 0)
+        return
+    says = "loop" if model == "a recurrent state" else None
+    m = _tiny_hybrid() if says else tiny_model
+    eng = m.serve(max_slots=2, paged=PagedConfig(
+        block_size=8, num_blocks=32, prefill_token_budget=16))
+    observe.enable()
+    try:
+        h = eng.submit(GenerationRequest(np.arange(9) % 256,
+                                         max_new_tokens=6,
+                                         temperature=0.0))
+        while eng.pending:
+            eng.step()
+        h.result()
+        assert eng._decode_state == says
+        assert eng.stats.decode_steps >= 3
+        assert eng.stats.state_kernel_steps == 0
+        name = "serve.decode.state_kernel_steps"
+        assert any(m.name == name for m in eng.stats._registered)
+        decodes = [e["args"] for e in observe.events()
+                   if e["name"] == "serve.decode"]
+        steps = [e["args"] for e in observe.events()
+                 if e["name"] == "serve.step" and e["args"].get("width")]
+        assert len(decodes) == eng.stats.decode_steps == len(steps)
+        assert {a.get("state") for a in decodes + steps} == {says}
+        assert {a["attn"] for a in decodes + steps} == {"loop"}
     finally:
         eng.close()
 

@@ -84,7 +84,24 @@ def _big_copies(text, floor=50e6, hbm_only=False):
     return out
 
 
-def test_the_decode_program_compiles_in_place(shapes):
+def _step_kernel_calls(text, found):
+    """The state-step kernel's custom calls in a compiled program's
+    text, each of which ``found`` (``program_scopes()`` of that text)
+    has to put under ``ssm_step`` -- the scope whose device time
+    ``ssm_step_share`` and ``ssm_step_roofline`` read: a roofline that
+    lost its kernel to another scope would read over 100%."""
+    calls = re.findall(r"%(mamba2_step[\w.\-]*) = ", text)
+    assert calls, "the decode program holds no state-step kernel"
+    assert {v for k, v in found.items()
+            if k.split(" ")[0] in calls} == {"ssm_step"}
+    return calls
+
+
+def test_the_decode_program_compiles_in_place(shapes, on_a_tpu):
+    """As the chip runs it: the Mamba step is the Pallas kernel
+    (ops/pallas/mamba2_step.py), ONE custom call in the scanned layer
+    body, the state arena -- a carry of the layer scan, the layer index
+    traced -- its aliased operand."""
     from singa_tpu.serve import paged
 
     cfg, fam, params, sds = shapes
@@ -100,6 +117,8 @@ def test_the_decode_program_compiles_in_place(shapes):
         block=BLOCK, n_head=20, eps=1e-5, moe_top_k=2, top_k=0,
         use_top_p=False, window=None, fam=fam).compile()
     ma = comp.memory_analysis()
+    print(f"hybrid decode: temporaries {ma.temp_size_in_bytes}, aliased "
+          f"{ma.alias_size_in_bytes}")
     # the pool and the state arenas are updated where they lie
     assert ma.alias_size_in_bytes >= 2 * 2 * L * (BLOCKS + 1) * 4 * BLOCK \
         * 128 + 4 * L * (n + 1) * 32 * 128 * 256
@@ -107,11 +126,11 @@ def test_the_decode_program_compiles_in_place(shapes):
     # pool once made the compiler re-lay all of it twice a step)
     assert _big_copies(comp.as_text()) == []
     assert ma.temp_size_in_bytes < 1.0e9
-    scopes = {}
     paged._keep_scopes("decode", fam.scopes, comp.as_text())
-    for s in paged.program_scopes()["decode"].values():
-        scopes[s] = scopes.get(s, 0) + 1
-    assert {"attn", "ssm_step", "ssm_proj", "mlp", "head"} <= set(scopes)
+    found = paged.program_scopes()["decode"]
+    assert {"attn", "ssm_step", "ssm_proj", "mlp", "head"} <= set(
+        found.values())
+    assert len(_step_kernel_calls(comp.as_text(), found)) == 1
 
 
 def test_the_chunk_row_program_compiles(shapes):
@@ -841,6 +860,10 @@ def test_the_128_lane_decode_program_updates_pool_and_state_in_place(
             if k.split(" ")[0] in calls} == {"attn_full"}
     assert {"ssm_step", "ssm_proj", "attn_full", "moe_route", "moe_latent",
             "moe_experts", "head"} <= set(found.values())
+    # the five Mamba layers' steps: a kernel call in the scanned period
+    # (ME x 4) and one in the tail (M*E), the 2.7 GB arena its aliased
+    # operand
+    assert len(_step_kernel_calls(text, found)) == 2
 
 
 @pytest.mark.parametrize("blocks", [1, 2, 3, 4, "pair"])
